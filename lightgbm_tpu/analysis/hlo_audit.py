@@ -95,8 +95,8 @@ def audit_donation(fn: Any, args: Sequence[Any],
     """
     import jax
     with warnings.catch_warnings():
-        # CPU backends warn that donation is unimplemented; the alias
-        # TABLE is still recorded, which is all the audit reads
+        # a donation XLA cannot use warns at compile time; the alias
+        # TABLE below is what reports it
         warnings.simplefilter("ignore")
         jitted = jax.jit(fn, donate_argnums=tuple(donate_argnums),
                          keep_unused=True)

@@ -180,7 +180,7 @@ def sharded_frontier_fn(num_devices: int = 8,
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ..compat import shard_map
+    from jax import shard_map
     from ..core.grow import GrowParams
     from ..core.grow_frontier import grow_tree_frontier
     from ..core.split import FeatureMeta, SplitParams
@@ -220,7 +220,7 @@ def sharded_frontier_fn(num_devices: int = 8,
     # only the per-row leaf ids stay sharded
     out_specs = (out_specs[0], P("data"), out_specs[2])
     fn = shard_map(inner, mesh=mesh, in_specs=(P("data"),) * 4,
-                   out_specs=out_specs)
+                   out_specs=out_specs, check_vma=False)
     return fn, (xb, g, ones, ones), params
 
 

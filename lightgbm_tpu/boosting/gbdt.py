@@ -29,10 +29,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 import numpy as np
 
-from ..compat import shard_map
 from ..config import Config
 from ..log import Log, LightGBMError, check
 from ..io.dataset import BinnedDataset
@@ -183,7 +182,9 @@ def _hist_dtype(cfg: Config) -> str:
 def _resolve_hist_impl(cfg: Config) -> str:
     """Histogram-kernel dispatch (the GPUTreeLearner device-path analog,
     tree_learner.cpp:9-31): CPU -> XLA scatter-add; device -> the Pallas
-    VMEM-accumulator kernel, with one-hot matmul as the explicit fallback.
+    VMEM-accumulator kernel. ``auto`` resolves from the backend alone and
+    says so in the log — never by catching a failed compile: a kernel
+    that does not compile raises, whether it was named or resolved.
     gpu_use_dp (config.h:784) means what it means in the reference:
     DOUBLE-precision histogram accumulation. The Pallas kernels are
     f32-only, so dp routes to the XLA paths (scatter / one-hot matmul),
@@ -202,6 +203,8 @@ def _resolve_hist_impl(cfg: Config) -> str:
         return impl
     if impl == "auto":
         impl = ("scatter" if jax.default_backend() == "cpu" else "pallas")
+        Log.info("tpu_hist_impl=auto resolved to %s (backend %s)",
+                 impl, jax.default_backend())
     return impl
 
 
@@ -221,12 +224,11 @@ class GBDT:
             # fire counts across in-process supervised restarts
             from ..resilience import faults
             faults.install_plan(config.fault_inject, config.fault_seed)
-        if getattr(config, "compile_cache_dir", ""):
-            # persistent XLA compile cache: wired before the first jit so
-            # every executable this booster builds is cacheable — warm
-            # starts (same shapes, same jax) then compile nothing
-            from ..profiling import enable_compile_cache
-            enable_compile_cache(config.compile_cache_dir)
+        # persistent XLA compile cache: placed before the first jit so
+        # every executable this booster builds is cacheable — warm starts
+        # (same shapes, same jax) then compile nothing
+        from ..profiling import enable_compile_cache
+        enable_compile_cache(config.compile_cache_dir)
         if _hist_dtype(config) == "f64" and not jax.config.jax_enable_x64:
             # reference gpu_use_dp = double-precision histograms
             # (config.h:784); jax needs x64 enabled for f64 to exist at
@@ -575,12 +577,12 @@ class GBDT:
                                    cfg.num_leaves - 1)
         # multiclass class batching: vmapped growth measured 1.9x SLOWER
         # than sequential per-class growth on a v5e chip (1.65 vs 0.88
-        # s/iter at 500k x 28 x 5 classes, tools/onchip_r4_results.json
-        # "multiclass") — vmap serializes the growth while_loop in
-        # lockstep AND forces the sort-placement fast path off. TPU-shaped
-        # backends (the same allow-list predicate the sort-placement
-        # policy uses — NOT a hist-impl proxy, so f64/matmul TPU runs are
-        # covered too) therefore grow classes sequentially even with an
+        # s/iter at 500k x 28 x 5 classes, docs/Performance.md "Round 4",
+        # one pre-PR-1 datapoint) — vmap serializes the growth while_loop
+        # in lockstep AND forces the sort-placement fast path off.
+        # TPU-shaped backends (partition.tpu_shaped_backend — NOT a
+        # hist-impl proxy, so f64/matmul TPU runs are covered too)
+        # therefore grow classes sequentially even with an
         # uncapped pool; vmap remains the CPU default, where it wins.
         vmapped = (self.num_tree_per_iteration > 1 and pool_slots == 0
                    and not partition_mod.tpu_shaped_backend())
@@ -675,7 +677,7 @@ class GBDT:
                         else 16384)),
             # CPU: XLA scatter-add wins; TPU: the Pallas VMEM-accumulator
             # kernel is the default device path (the GPUTreeLearner analog,
-            # gpu_tree_learner.cpp:951-1045) — one-hot matmul is the fallback
+            # gpu_tree_learner.cpp:951-1045); one-hot matmul only when named
             hist_impl=hist_impl,
             hist_dtype=_hist_dtype(cfg),
             voting_top_k=(cfg.top_k if cfg.tree_learner == "voting"
@@ -1273,7 +1275,7 @@ class GBDT:
             # select, and sequential keeps one pool's worth of live
             # memory, the point of the cap — or (b) the backend is
             # TPU-shaped, where sequential measured 1.9x faster than vmap
-            # even uncapped (round-4, tools/onchip_r4_results.json).
+            # even uncapped (docs/Performance.md "Round 4").
             # params.vmapped_classes is the ONE predicate: grow_tree keys
             # its sort-placement/pool decisions off the same flag this
             # dispatch uses, so the two can never disagree.
@@ -1666,11 +1668,11 @@ class GBDT:
         # donate the threaded train-state buffers (TRAIN_BLOCK_DONATE) —
         # both are rebound to the block's outputs by the caller, so XLA
         # may alias the output into the input allocation instead of
-        # holding both live. CPU has no donation support and would warn
-        # per compile, so gate on backend.
+        # holding both live. Every backend donates (the CPU included), so
+        # the CPU tests run the path the chip takes: a reference kept to
+        # the old arrays across a block fails there too.
         donate = (self.TRAIN_BLOCK_DONATE
-                  if self.config.tpu_donate_buffers
-                  and jax.default_backend() != "cpu" else ())
+                  if self.config.tpu_donate_buffers else ())
         return jax.jit(run_block, donate_argnums=donate)
 
     def train_block_sds(self, block: int) -> Tuple[Any, ...]:
@@ -1715,7 +1717,7 @@ class GBDT:
         calls after this never compile — and with ``compile_cache_dir``
         set, later PROCESSES reload every specialization from disk.
         Returns per-bucket compile counts + seconds (reported by
-        profiling/bench). No-op unless the booster grows frontier-mode.
+        profiling). No-op unless the booster grows frontier-mode.
         """
         from .. import bucketing
         from ..profiling import backend_compile_count, compile_cache_stats
@@ -1768,7 +1770,7 @@ class GBDT:
         branch compiles INSIDE the first training block's program anyway;
         the eager ladder exists to populate the cross-process cache and to
         produce the per-bucket compile/hit/miss accounting, both of which
-        only matter in compile_cache_dir runs (bench, the CI smoke)."""
+        only matter in compile_cache_dir runs (the CI smoke)."""
         if self._ladder_warmup is None and \
                 getattr(self.config, "compile_cache_dir", ""):
             self._ladder_warmup = self.warmup_wave_ladder()
@@ -1780,11 +1782,11 @@ class GBDT:
         dispatched length, every frontier wave-width bucket's histogram
         sweep, and the materialize flush at its last shape.  Per-entry
         FLOPs / bytes / memory land as ``lgbm_costmodel_*`` gauges and
-        feed ``GET /roofline``, bench and the perf gate.
+        feed ``GET /roofline`` and the perf gate.
 
         PULL-based by design: nothing in the training loop calls this,
         so ``observability=none`` runs do zero costmodel work — and with
-        obs off it returns ``{}`` unless ``force=True`` (bench, probes
+        obs off it returns ``{}`` unless ``force=True`` (probes
         and the perf tools force it).  Arguments are mirrored as
         ``jax.ShapeDtypeStruct`` (sharding preserved), never sampled:
         extraction must not advance ``self._rng`` / ``self._bag_key`` or

@@ -64,6 +64,6 @@ def wave_width_bucket(live: int, num_leaves: int,
                       max_depth: int = -1) -> int:
     """Bucketed width a wave with ``live`` positive-gain leaves runs at —
     the host-side mirror of the grower's ``lax.switch`` branch selection,
-    used by profiling/bench occupancy accounting."""
+    used by profiling occupancy accounting."""
     return pow2_bucket(max(int(live), 1), 1,
                        frontier_max_width(num_leaves, max_depth))

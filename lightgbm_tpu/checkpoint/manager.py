@@ -37,7 +37,8 @@ class SnapshotHandle:
 
 
 def _impl_of(target):
-    """Accept a basic.Booster or a bare boosting driver (bench.py style)."""
+    """Accept a basic.Booster or a bare boosting driver (tools and tests
+    build one directly)."""
     return target._impl if hasattr(target, "_impl") else target
 
 

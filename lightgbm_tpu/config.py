@@ -219,8 +219,9 @@ _PARAMS: List[Tuple[str, type, Any, List[str]]] = [
     # persistent XLA compilation cache (jax_compilation_cache_dir):
     # compiled executables are written here and reloaded by later
     # processes, so warm starts skip backend compilation entirely —
-    # profiling.enable_compile_cache wires it before the first compile
-    # and counts hits/misses. Empty = off (jax default).
+    # profiling.enable_compile_cache places it before the first compile
+    # and counts hits/misses. JAX_COMPILATION_CACHE_DIR in the
+    # environment overrides this; empty = <checkout>/.jax_cache.
     ("compile_cache_dir", str, "", ["compilation_cache_dir",
                                     "jax_compilation_cache_dir"]),
     # batched growth: pack active rows so dead row tiles skip the slot
@@ -272,7 +273,8 @@ _PARAMS: List[Tuple[str, type, Any, List[str]]] = [
     ("serving_quantize_leaves", bool, False, ["serve_quantize_leaves"]),
     # ---- observability (lightgbm_tpu.obs; docs/Observability.md) ----
     # none: zero instrumentation (default). basic: fused blocks kept,
-    # per-block spans/events/health (<3% overhead, bench-verified).
+    # per-block spans/events/health (<3% overhead on a CPU host; not
+    # measured on the chip).
     # full: per-iteration dispatch with true spans, health within one
     # iteration, Perfetto window capture, per-iteration HBM accounting.
     ("observability", str, "none", ["obs", "observability_level"]),

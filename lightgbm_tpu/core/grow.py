@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import pcast
 from .histogram import build_histogram
 from .partition import (RowPartition, hist_for_leaf, init_partition,
                         leaf_id_from_partition, make_row_gather,
@@ -655,7 +654,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     hist_pool = jnp.zeros((num_slots, ncols_h, b, 3), hdt)
     if voting:
         # the pool holds LOCAL histograms in voting mode -> device-varying
-        hist_pool = pcast(hist_pool, (axis_name,), to="varying")
+        hist_pool = lax.pcast(hist_pool, (axis_name,), to="varying")
     if not use_partition:
         hist_pool = hist_pool.at[0].set(hist_root)
     pool_map0 = None
@@ -711,13 +710,13 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     if axis_name is not None:
         # under shard_map the carry must be marked device-varying up front:
         # it starts as a constant but becomes a function of the sharded rows
-        leaf_id0 = pcast(leaf_id0, (axis_name,), to="varying")
+        leaf_id0 = lax.pcast(leaf_id0, (axis_name,), to="varying")
     part0 = init_partition(n, l, params.row_chunk) if use_partition else None
     if part0 is not None and axis_name is not None:
         # same pcast story as leaf_id0: starts constant, becomes a function
         # of the device-local rows
         part0 = jax.tree.map(
-            lambda a: pcast(a, (axis_name,), to="varying"), part0)
+            lambda a: lax.pcast(a, (axis_name,), to="varying"), part0)
     state = _GrowState(leaf_id=leaf_id0, hist_pool=hist_pool,
                        best=best, tree=tree,
                        leaf_min=jnp.full((l,), -jnp.inf, hdt),

@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import pcast
 from .histogram import build_histogram, build_histogram_frontier
 from .grow import (GrowParams, TreeArrays, _bin_go_left, _empty_best,
                    decode_bundle_value, empty_tree, expand_hist,
@@ -315,7 +314,7 @@ def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
     leaf_id0 = jnp.zeros((n,), jnp.int32)
     if axis_name is not None:
-        leaf_id0 = pcast(leaf_id0, (axis_name,), to="varying")
+        leaf_id0 = lax.pcast(leaf_id0, (axis_name,), to="varying")
     state = _BatchState(
         leaf_id=leaf_id0, best=best, tree=tree,
         leaf_min=jnp.full((l,), -jnp.inf, jnp.float32),

@@ -54,7 +54,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import pcast
 from .histogram import build_histogram
 from .grow import (GrowParams, TreeArrays, _empty_best, empty_tree,
                    expand_hist)
@@ -160,8 +159,8 @@ def grow_tree_batched_part(xb: jnp.ndarray, grad: jnp.ndarray,
     row_leaf = jnp.where(ar < n, 0, -1).astype(jnp.int32)
     orig = jnp.where(ar < n, ar, -1)
     if axis_name is not None:
-        row_leaf = pcast(row_leaf, (axis_name,), to="varying")
-        orig = pcast(orig, (axis_name,), to="varying")
+        row_leaf = lax.pcast(row_leaf, (axis_name,), to="varying")
+        orig = lax.pcast(orig, (axis_name,), to="varying")
     leaf_begin = jnp.zeros((l,), jnp.int32)
     leaf_count = jnp.zeros((l,), jnp.int32).at[0].set(jnp.int32(n))
 

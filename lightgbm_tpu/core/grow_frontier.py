@@ -68,7 +68,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..bucketing import frontier_max_width, wave_width_ladder
-from ..compat import pcast
 from ..obs.modelstats import init_mstats, update_mstats
 from ..parallel.learners import make_frontier_learner
 from .binpack import CODES_PER_WORD, words_per_row
@@ -370,12 +369,12 @@ def root_state(hist_root, root_g, root_h, root_c, n: int, l: int, sp,
     if lrn.varying_pool:
         # the pool holds device-varying content (local histograms under
         # voting, per-device feature shards under data_rs)
-        hist_pool = pcast(hist_pool, (axis_name,), to="varying")
+        hist_pool = lax.pcast(hist_pool, (axis_name,), to="varying")
     hist_pool = hist_pool.at[0].set(hist_root)
 
     leaf_id0 = jnp.zeros((n,), jnp.int32)
     if axis_name is not None:
-        leaf_id0 = pcast(leaf_id0, (axis_name,), to="varying")
+        leaf_id0 = lax.pcast(leaf_id0, (axis_name,), to="varying")
     # health accumulator (obs): waves executed + anomalous gain, seeded
     # with the root search's gain — everything below reads values the
     # wave already computed, so no new sweeps or collectives. Anomalous

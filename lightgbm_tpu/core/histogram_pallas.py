@@ -33,12 +33,41 @@ cell owns its bin slice).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .binpack import unpack_words
+
+# The joint (slot, lo) kernels materialize a [n_slots*16, row_tile] f32
+# one-hot per feature. At the 2048-row tile that is 16 MiB at 128 slots
+# and 32 MiB at 254 — past Mosaic's default scoped-VMEM limit (16 MiB on
+# a v5e) before the bf16 copy and the double-buffered output block are
+# counted. So the row tile shrinks with the slot count to hold the
+# one-hot at this budget, and the call states the VMEM it needs.
+_ONE_HOT_BUDGET = 4 << 20
+
+
+def _slot_row_tile(row_tile: int, n_slots: int) -> int:
+    """Largest pow-2 row tile <= ``row_tile`` (>= 128 lanes) whose
+    [n_slots*16, tile] f32 one-hot fits ``_ONE_HOT_BUDGET``."""
+    cap = max(128, _ONE_HOT_BUDGET // (4 * 16 * n_slots))
+    return min(row_tile, 1 << (cap.bit_length() - 1))
+
+
+def _slot_compiler_params(out_block: tuple, row_tile: int,
+                          n_slots: int) -> pltpu.CompilerParams:
+    """Scoped-VMEM request of a joint slot kernel: the double-buffered
+    output block, the one-hot in f32 + bf16 + its select mask (4x the f32
+    one-hot covers them), and headroom for the input blocks and the
+    [K*Hi, tile] value operands — 36 MiB at 254 slots x 3 channels, never
+    below Mosaic's 16 MiB default."""
+    one_hot = 4 * 16 * n_slots * row_tile
+    need = 2 * 4 * math.prod(out_block) + 4 * one_hot + (8 << 20)
+    return pltpu.CompilerParams(vmem_limit_bytes=max(need, 16 << 20))
 
 
 def _digit_contract(a, eq, highest: bool):
@@ -95,8 +124,9 @@ def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int, highest: bool):
         a = jnp.where(hi_eq[None, :, :], vals[:, None, :],
                       0.0).reshape(k * hi_n, c)              # [K*Hi, C]
         # NB: build the one-hot in f32 and let _digit_contract downcast —
-        # a direct bf16 select on the i1 mask trips a Mosaic relayout bug
-        # on this toolchain
+        # a direct bf16 select on the i1 mask fails in Mosaic ("Invalid
+        # relayout ... vector<16x2048xi1>"; re-tested on a v5e with
+        # jax 0.9.0 / libtpu 0.0.34)
         eqlo = jnp.where(lo_eq, 1.0, 0.0)
         part = _digit_contract(a, eqlo, highest)             # [K*Hi, 16]
         out_ref[:, j, :, :] += part.reshape(k, hi_n, 16)
@@ -228,6 +258,7 @@ def build_histogram_slots6(xb: jnp.ndarray, slot: jnp.ndarray,
     one-hot width of build_histogram_slots."""
     n, f = xb.shape
     hi_n = max(1, (num_bins + 15) // 16)
+    row_tile = _slot_row_tile(row_tile, n_slots)
     f_pad = (-f) % feature_tile
     n_pad = (-n) % row_tile
     xb_t = jnp.pad(xb.T, ((0, f_pad), (0, n_pad))).astype(jnp.uint8)
@@ -239,6 +270,7 @@ def build_histogram_slots6(xb: jnp.ndarray, slot: jnp.ndarray,
 
     kernel = functools.partial(_hist_slot6_kernel, hi_n=hi_n,
                                n_slots=n_slots, highest=highest)
+    out_block = (6, feature_tile, hi_n, n_slots * 16)
     out = pl.pallas_call(
         kernel,
         grid=(fp // feature_tile, (n + n_pad) // row_tile),
@@ -248,10 +280,10 @@ def build_histogram_slots6(xb: jnp.ndarray, slot: jnp.ndarray,
             pl.BlockSpec((1, row_tile), lambda i, r: (0, r)),
             pl.BlockSpec((3, row_tile), lambda i, r: (0, r)),
         ],
-        out_specs=pl.BlockSpec((6, feature_tile, hi_n, n_slots * 16),
-                               lambda i, r: (0, i, 0, 0)),
+        out_specs=pl.BlockSpec(out_block, lambda i, r: (0, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((6, fp, hi_n, n_slots * 16),
                                        jnp.float32),
+        compiler_params=_slot_compiler_params(out_block, row_tile, n_slots),
         interpret=interpret,
     )(xb_t, slot2, sel2, vals)
     # [6, F, Hi, S, 16] -> [S, F, B, 6]
@@ -466,6 +498,7 @@ def build_histogram_slots(xb: jnp.ndarray, slot: jnp.ndarray,
     n, f = xb.shape
     k = vals.shape[0]
     hi_n = max(1, (num_bins + 15) // 16)
+    row_tile = _slot_row_tile(row_tile, n_slots)
 
     f_pad = (-f) % feature_tile
     n_pad = (-n) % row_tile
@@ -480,6 +513,7 @@ def build_histogram_slots(xb: jnp.ndarray, slot: jnp.ndarray,
 
     kernel = functools.partial(_hist_slot_kernel, hi_n=hi_n,
                                n_slots=n_slots, highest=highest)
+    out_block = (k, feature_tile, hi_n, n_slots * 16)
     out = pl.pallas_call(
         kernel,
         grid=(fp // feature_tile, (n + n_pad) // row_tile),
@@ -488,10 +522,10 @@ def build_histogram_slots(xb: jnp.ndarray, slot: jnp.ndarray,
             pl.BlockSpec((1, row_tile), lambda i, r: (0, r)),
             pl.BlockSpec((k, row_tile), lambda i, r: (0, r)),
         ],
-        out_specs=pl.BlockSpec((k, feature_tile, hi_n, n_slots * 16),
-                               lambda i, r: (0, i, 0, 0)),
+        out_specs=pl.BlockSpec(out_block, lambda i, r: (0, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, fp, hi_n, n_slots * 16),
                                        jnp.float32),
+        compiler_params=_slot_compiler_params(out_block, row_tile, n_slots),
         interpret=interpret,
     )(xb_t, slot2, vals)
     # [K, F, Hi, S, 16] -> [S, F, B, K]

@@ -25,6 +25,7 @@ _LIB_NAME = "liblgbm_tpu_native.so"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
+_origin = ""
 
 
 class _ParseResult(ctypes.Structure):
@@ -37,6 +38,7 @@ class _ParseResult(ctypes.Structure):
 
 
 def _build() -> Optional[str]:
+    global _origin
     so = os.path.join(_NATIVE_DIR, _LIB_NAME)
     srcs = [os.path.join(_NATIVE_DIR, "src", f)
             for f in ("text_parser.cpp", "binning.cpp")]
@@ -45,6 +47,7 @@ def _build() -> Optional[str]:
         return None
     if os.path.exists(so) and \
             os.path.getmtime(so) >= max(os.path.getmtime(f) for f in srcs):
+        _origin = "native library found on disk, newer than native/src"
         return so
     try:
         r = subprocess.run(["make", "-C", _NATIVE_DIR],
@@ -56,7 +59,15 @@ def _build() -> Optional[str]:
     except Exception as e:  # no make/g++ — pure-Python mode
         Log.warning("native build unavailable (%s); using Python fallbacks", e)
         return None
+    _origin = "native library built by make from native/src"
     return so if os.path.exists(so) else None
+
+
+def origin() -> str:
+    """Which host runtime this process uses: the native library (built
+    now, or found on disk) or the pure-Python fallbacks — so that a
+    report can say whose ingest time it shows."""
+    return _origin if get_lib() is not None else "pure-Python fallbacks"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:

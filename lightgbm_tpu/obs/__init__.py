@@ -9,8 +9,8 @@ serving:
   backed by it.
 - ``costmodel``: XLA cost-model extraction (FLOPs / bytes / memory per
   compiled entry point via AOT ``cost_analysis``) and per-phase roofline
-  attribution against the detected chip's peaks — feeds ``GET /roofline``,
-  bench's ``mfu_estimate`` and the perf gate.
+  attribution against the detected chip's peaks — feeds ``GET /roofline``
+  and the perf gate.
 - ``perfgate``: deterministic semantic perf counters + baseline comparison
   (``PERF_COUNTERS.json``, ``tools/perf_gate.py``).
 - ``trace``: host-side span timers (device sync only at span close), a

@@ -1,7 +1,7 @@
 """XLA cost-model extraction + roofline attribution (performance accounting).
 
-bench.py's old ``flops_per_visit = 3*256*2*2.0`` MFU formula was a guess.
-This module replaces it with XLA's own accounting: every compiled entry
+A hand-written flops-per-row formula is a guess.
+This module uses XLA's own accounting instead: every compiled entry
 point (the fused train block, each frontier wave-width bucket's histogram
 sweep, each serving predict bucket, the materialize flush) is AOT-lowered
 and compiled once, and its static costs — FLOPs, bytes accessed, peak /
@@ -45,13 +45,13 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from ..log import Log
+from ..log import Log, LightGBMError
 from .registry import MetricsRegistry, get_registry
 
 # ------------------------------------------------------------ chip peaks
 # Public per-chip peaks: bf16 matmul FLOP/s and HBM bandwidth (bytes/s).
-# This extends (and now owns) bench.py's old _PEAKS table; bench imports
-# it from here so the roofline denominator has one definition.
+# The one definition of the roofline denominator, keyed by what
+# normalize_device_kind makes of the PJRT device_kind.
 CHIP_PEAKS: Dict[str, Dict[str, float]] = {
     "v4": {"flops_per_s": 275e12, "hbm_bytes_per_s": 1.228e12},
     "v5e": {"flops_per_s": 197e12, "hbm_bytes_per_s": 0.819e12},
@@ -72,37 +72,32 @@ def detect_peaks(device_kind: Optional[str] = None
                  ) -> Optional[Dict[str, float]]:
     """Peak FLOP/s + HBM B/s for the chip generation running this
     process (or for an explicit ``device_kind`` string).  Returns None
-    on CPU / unknown hosts: a roofline there reports achieved rates
-    only, never a utilization ratio against somebody else's peak."""
+    on CPU and other non-TPU hosts: a roofline there reports achieved
+    rates only.  A TPU whose generation is not in ``CHIP_PEAKS`` raises
+    — a utilization ratio against another chip's peak is worse than
+    none."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = getattr(jax.devices()[0], "device_kind", "")
-        except Exception:  # noqa: BLE001 - diagnostics must not raise
-            return None
+        import jax
+        device_kind = jax.devices()[0].device_kind
     kind = normalize_device_kind(device_kind)
-    if not kind or "cpu" in kind:
-        return None
     for key, peaks in CHIP_PEAKS.items():
         if key in kind:
             return dict(peaks)
-    # a TPU whose generation we do not know: conservative v5e numbers
     if "tpu" in kind:
-        return dict(CHIP_PEAKS["v5e"])
+        raise LightGBMError(
+            "device_kind %r is a TPU generation CHIP_PEAKS does not list "
+            "(%s); add its published peaks before reporting utilization"
+            % (device_kind, ", ".join(sorted(CHIP_PEAKS))))
     return None
 
 
 # ------------------------------------------------------------ extraction
 def costs_from_compiled(compiled) -> Dict[str, float]:
     """Normalize ``Compiled.cost_analysis()`` + ``memory_analysis()``
-    into one flat dict.  cost_analysis returns a list of one dict on
-    this jax (older APIs returned the dict bare); memory_analysis has no
+    into one flat dict.  memory_analysis has no
     ``peak_memory_in_bytes`` here, so peak is derived as
     argument + output + temp - alias."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = compiled.cost_analysis() or {}
 
     def _pos(key):
         try:

@@ -9,7 +9,7 @@ the boosting loop, which drives it at three intensities:
 - ``observability=basic`` (level 1): the fused 64-iteration block path is
   kept; one sync + span per block, per-iteration events derived from the
   block, health vectors checked per block, HBM gauge per block.  Target
-  overhead < 3% (bench.py measures it).
+  overhead < 3% (not measured on the chip yet).
 - ``observability=full``  (level 2): the engine falls back to true
   per-iteration dispatch — real spans around every iteration, health
   flagged within one iteration, optional Perfetto capture window, HBM

@@ -39,12 +39,9 @@ def init(machines: str = "", local_listen_port: int = 12400,
     # process boundaries, which would break every learner schedule in
     # parallel/learners.py the moment the mesh spans hosts. Gloo rides the
     # same TCP fabric the coordinator already uses; TPU/GPU backends ignore
-    # the flag. Must be set before the first backend client is created —
-    # if the caller already touched jax.devices(), leave their choice alone.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jaxlib without gloo, or backend already up
-        pass
+    # the flag. It only takes effect before the first backend client is
+    # created — a caller who already touched jax.devices() keeps theirs.
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     hosts: List[str] = [m.strip() for m in machines.split(",") if m.strip()]
     if len(hosts) != num_machines:
         raise LightGBMError(

@@ -2,7 +2,7 @@
 
 The boosting iteration is one fused jit program, so per-phase time cannot be
 read from inside it; instead each phase's op is re-run standalone on the
-booster's real shapes and timed. The taxonomy mirrors the reference's
+booster's real shapes and timed. The phase list mirrors the reference's
 (init/hist/find-split/split) plus the TPU-specific partition/gather phase.
 ``jax.profiler`` traces can be layered on via trace_dir for a full timeline.
 """
@@ -19,15 +19,17 @@ import numpy as np
 
 # ---------------------------------------------------------------- compiles
 # Process-wide compile accounting, shared by serving.metrics and the
-# training-side zero-recompile invariant (bench.py, compile_cache_smoke):
+# training-side zero-recompile invariant (chip_smoke, compile_cache_smoke):
 #
 # - ``backend_compiles`` rides jax.monitoring's backend-compile duration
 #   event, so it counts REAL XLA compilations — including accidental
 #   retraces a cache key cannot see (shape leaks, weak-type flips);
+#   ``backend_compile_seconds`` sums the same event's durations;
 # - ``persistent_cache_hits``/``misses`` ride the compilation-cache events,
-#   so a warm ``compile_cache_dir`` shows up as hits. (The backend-compile
-#   duration event fires on cache hits too in this jax, so hits/misses —
-#   not the backend count — are what distinguish a warm start.)
+#   so a warm cache directory shows up as hits. (The backend-compile
+#   duration event fires on cache hits too in this jax — it then times
+#   the load — so hits/misses, not the backend count, are what
+#   distinguish a warm start.)
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -38,11 +40,16 @@ _hooks_installed = False
 # (lightgbm_tpu/obs/registry.py) so one Prometheus scrape sees them next
 # to serving/training series; this module keeps its historical API as a
 # thin shim over those series
+from .log import Log  # noqa: E402
 from .obs.registry import get_registry  # noqa: E402
 
 _c_backend = get_registry().counter(
     "lgbm_jax_backend_compiles_total",
     "XLA backend compilations observed via jax.monitoring.")
+_c_backend_secs = get_registry().counter(
+    "lgbm_jax_backend_compile_seconds_total",
+    "Seconds spent in XLA backend compilation (or loading a cached "
+    "executable) observed via jax.monitoring.")
 _c_cache_hit = get_registry().counter(
     "lgbm_jax_compile_cache_hits_total",
     "Persistent compilation-cache hits.")
@@ -54,6 +61,7 @@ _c_cache_miss = get_registry().counter(
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
     if event == _BACKEND_COMPILE_EVENT:
         _c_backend.inc()
+        _c_backend_secs.inc(duration)
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -85,28 +93,46 @@ def compile_cache_stats() -> Dict[str, int]:
     first caller anchors counting at zero)."""
     install_compile_hook()
     return {"backend_compiles": int(_c_backend.value),
+            "backend_compile_seconds": float(_c_backend_secs.value),
             "persistent_cache_hits": int(_c_cache_hit.value),
             "persistent_cache_misses": int(_c_cache_miss.value)}
 
 
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir`` (the
-    ``compile_cache_dir`` config param) and install the counters. Every
-    compile is made cacheable (no min-time/min-size floor) so a warm
-    directory means zero backend compiles on restart. Idempotent;
-    returns False when ``cache_dir`` is empty."""
-    if not cache_dir:
-        return False
+# <checkout>/.jax_cache, listed in .gitignore: a fixed place, so every run
+# of a checkout finds what the last one compiled (never a temp name, a pid
+# or the time)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compile_cache(requested: str = "") -> str:
+    """Place jax's persistent compilation cache and install the counters;
+    train, serve and the smokes all go through here. Returns the cache
+    directory in effect.
+
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment decides when set
+    (jax reads it itself; no code sets another). Otherwise the first
+    directory placed in this process stays — jax initialises its cache
+    once, at the first compile — and that is ``requested`` (the
+    ``compile_cache_dir`` param) or, by default, ``<checkout>/.jax_cache``.
+    A ``requested`` that disagrees with the directory in effect is ignored
+    with one log line. Every compile is cacheable (no min-time / min-size
+    floor), so a warm directory means zero backend compiles on restart."""
+    current = jax.config.jax_compilation_cache_dir or ""
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR") or current
+                 or os.fspath(requested) or DEFAULT_COMPILE_CACHE_DIR)
+    if cache_dir != current:
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", os.fspath(cache_dir))
-    for name, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(name, val)
-        except Exception:  # noqa: BLE001 - knob absent in this jax version
-            pass
+    if requested and \
+            os.path.abspath(requested) != os.path.abspath(cache_dir):
+        Log.warning("compile_cache_dir=%s ignored: the compile cache of "
+                    "this process is already placed at %s",
+                    requested, cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     install_compile_hook()
-    return True
+    return cache_dir
 
 
 def _timed(fn, *args, reps=3, **kw) -> float:

@@ -229,7 +229,14 @@ class ProcessSupervisor:
     ``resume``/``checkpoint_dir`` that makes a rerun continue); the
     supervisor's job is only death/hang detection, backoff, and the
     restart budget. Each attempt's index rides the LGBM_SUPERVISOR_ATTEMPT
-    env var so chaos workers can arm faults on attempt 0 only."""
+    env var so chaos workers can arm faults on attempt 0 only.
+
+    One process for each chip: an accelerator belongs to the process that
+    first touched jax, and a child that needs it then fails or hangs. This
+    supervisor is sound only while its own process stays off jax — no
+    ``jax.devices()``, no ``Dataset`` construction (binning initialises
+    the backend), nothing that imports a jitted module and calls it. Bin
+    and train in the child."""
 
     def __init__(self, argv: List[str], max_restarts: int = 3,
                  backoff_s: float = 0.5, backoff_max_s: float = 30.0,

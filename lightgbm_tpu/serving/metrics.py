@@ -184,8 +184,8 @@ class ServingMetrics:
     def record_bucket_latency(self, bucket: int, ms: float) -> None:
         """Device predict latency for one padded forward pass, keyed by
         its shape bucket (``lgbm_serving_predict_latency_ms`` histogram
-        with a ``bucket`` label; the per-bucket p50/p99 view bench.py
-        reports rides ``bucket_latency()``)."""
+        with a ``bucket`` label; the per-bucket p50/p99 view rides
+        ``bucket_latency()``)."""
         with self._lock:
             h = self._bucket_hist.get(bucket)
             if h is None:

@@ -411,7 +411,7 @@ class ServingEngine:
 
         ``extract_costs=True`` additionally runs the obs cost model over
         each warmed bucket (``predict_b<bucket>`` entries: XLA FLOPs /
-        bytes per forward pass, feeding ``GET /roofline`` and bench).
+        bytes per forward pass, feeding ``GET /roofline``).
         AOT extraction shares nothing with the serving executables, so it
         cannot retrace them — and it runs BEFORE the recompile floor is
         marked, so its own one-time compiles never trip the serving

@@ -90,7 +90,7 @@ class ModelBundle:
                   feature_names: Optional[List[str]] = None,
                   pandas_categorical=None) -> "ModelBundle":
         """Bundle a boosting driver (basic.Booster._impl or a GBDT built
-        directly, as bench.py does)."""
+        directly)."""
         models = impl.models
         check(len(models) > 0, "cannot serve an empty model")
         k = max(impl.num_tree_per_iteration, 1)

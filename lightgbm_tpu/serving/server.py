@@ -4,7 +4,7 @@ The wire layer is deliberately thin — parse JSON, hand rows to the
 MicroBatchQueue, serialize the Future's result — so every interesting
 property (bucketing, zero-recompile, sharding, metrics) lives in the
 engine underneath and is shared by both transports and by in-process
-callers (bench.py, tools/serve_smoke.py).
+callers (chip_smoke.py, tools/serve_smoke.py).
 
 HTTP API:
   POST /predict   {"model": "...", "data": [[...], ...],
@@ -314,6 +314,10 @@ def build_app(config: Config) -> ServingApp:
     if config.fault_inject:
         from ..resilience import faults
         faults.install_plan(config.fault_inject, config.fault_seed)
+    # same persistent compile cache as training, placed before warm-up
+    # compiles the predictor buckets
+    from ..profiling import enable_compile_cache
+    enable_compile_cache(config.compile_cache_dir)
     engine = ServingEngine(
         max_batch=config.serve_max_batch, min_bucket=config.serve_min_bucket,
         num_devices=config.serve_num_devices,

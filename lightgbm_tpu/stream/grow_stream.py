@@ -67,7 +67,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .. import compat
 from ..bucketing import frontier_max_width
 from ..core.grow import GrowParams, TreeArrays, expand_hist
 from ..core.grow_frontier import (_FrontierState, root_state, wave_commit,
@@ -289,8 +288,8 @@ class StreamFrontierGrower:
         self._audit_fns = {}
 
         def sm(name, fn, in_specs, out_specs):
-            raw = compat.shard_map(fn, mesh, in_specs, out_specs,
-                                   check_vma=False)
+            raw = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                out_specs=out_specs, check_vma=False)
             self._audit_fns[name] = raw
             return jax.jit(raw)
 

@@ -15,14 +15,14 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(os.path.dirname(__file__), ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 import numpy as np
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu import callback, engine
+from lightgbm_tpu.profiling import enable_compile_cache
+
+enable_compile_cache()
 
 NUM_ROUNDS = 8
 KILL_AT = 3

@@ -153,11 +153,7 @@ def test_collective_schedule_and_counts():
 def test_f64_equations_detected():
     import jax
     import jax.numpy as jnp
-    try:
-        from jax.experimental import enable_x64
-    except ImportError:
-        pytest.skip("no enable_x64 context in this jax")
-    with enable_x64():
+    with jax.enable_x64():
         jx = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jax.ShapeDtypeStruct((4,), jnp.float32))
     assert jaxpr_audit.count_f64_eqns(jx) > 0

@@ -1,5 +1,4 @@
-"""Capability matrix for the fast-path feature combinations (VERDICT r2
-weak #5): every combination of (learner) x (growth mode) x (forced/CEGB/
+"""Capability matrix for the fast-path feature combinations: every combination of (learner) x (growth mode) x (forced/CEGB/
 plain) x (pool cap) x (classes) must either train on its EXPECTED path —
 asserted via the engagement flags, so a refactor cannot silently land a
 config on the O(N x leaves) masked fallback — or refuse loudly with

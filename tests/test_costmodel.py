@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
+from lightgbm_tpu.log import LightGBMError
 from lightgbm_tpu.obs.costmodel import (CHIP_PEAKS, CostModel,
                                         costs_from_compiled, detect_peaks,
                                         get_cost_model,
@@ -159,8 +160,9 @@ def test_detect_peaks_table():
     # CPU / unknown hosts: achieved rates only, never a borrowed peak
     assert detect_peaks("cpu") is None
     assert detect_peaks("Some Weird Host") is None
-    # unknown TPU generation: conservative v5e numbers
-    assert detect_peaks("TPU v9") == CHIP_PEAKS["v5e"]
+    # unknown TPU generation: an error, never another chip's peak
+    with pytest.raises(LightGBMError, match="CHIP_PEAKS"):
+        detect_peaks("TPU v9")
 
 
 def test_roofline_row_math_and_bound():

@@ -171,7 +171,7 @@ def test_grow_tree_explicit_psum_path():
     from functools import partial
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from lightgbm_tpu.compat import shard_map
+    from jax import shard_map
     from lightgbm_tpu.core.grow import grow_tree, GrowParams
     from lightgbm_tpu.core.split import SplitParams, FeatureMeta
 
@@ -207,7 +207,8 @@ def test_grow_tree_explicit_psum_path():
                                           params, axis_name="data")[:2],
         mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data")),
-        out_specs=(jax.tree.map(lambda _: P(), tree_ref), P("data")))
+        out_specs=(jax.tree.map(lambda _: P(), tree_ref), P("data")),
+        check_vma=False)
     tree_dp, leaf_dp = jax.jit(fn)(xb, g, h, ones)
 
     assert int(tree_dp.num_leaves) == int(tree_ref.num_leaves)
@@ -295,8 +296,7 @@ def test_sync_best_split_broadcasts_winner():
             cat_bitset=jnp.full((8,), rank.astype(jnp.uint32) + 7,
                                 jnp.uint32))
 
-    from lightgbm_tpu.compat import shard_map
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         lambda _: jax.tree.map(
             lambda a: a[None],
             sync_best_split(make(jax.lax.axis_index("f")), "f")),

@@ -1,7 +1,7 @@
 """Cross-round bench trend: every BENCH_r*.json in one table.
 
-Each PR lands a ``BENCH_rNN.json`` (bench.py output, shape drifting as
-the harness grew: early rounds nest everything under ``parsed``, later
+Each PR up to 20 landed a ``BENCH_rNN.json`` (shape drifting as the
+harness grew: early rounds nest everything under ``parsed``, later
 rounds add subsystem blocks like ``streaming`` / ``distributed`` /
 ``packed_bins``).  This tool reads them ALL, extracts a tolerant set of
 headline metrics per round, and emits:

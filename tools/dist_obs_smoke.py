@@ -24,6 +24,12 @@ outside, in three phases:
 Exit code 0 = every assertion holds.  Summary JSON goes to ``--out`` (and
 stdout); per-rank event streams, crash dumps and the merged timeline land
 under ``--workdir`` for CI artifact upload.
+
+A CPU tool: it starts child processes and pins each to the CPU backend
+(``JAX_PLATFORMS=cpu``). A chip belongs to one process at a time, so this
+launcher does not run on the chip and nothing it times is a device
+number; the chip is reached with ``python chip_smoke.py`` through the
+chip tool.
 """
 import argparse
 import json

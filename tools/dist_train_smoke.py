@@ -23,6 +23,12 @@ Asserted end to end:
 
 Exit code 0 = every assertion holds.  Summary JSON goes to ``--out`` (and
 stdout); per-rank results land under ``--workdir`` for artifact upload.
+
+A CPU tool: it starts child processes and pins each to the CPU backend
+(``JAX_PLATFORMS=cpu``). A chip belongs to one process at a time, so this
+launcher does not run on the chip and nothing it times is a device
+number; the chip is reached with ``python chip_smoke.py`` through the
+chip tool.
 """
 import argparse
 import hashlib
@@ -607,7 +613,7 @@ def _run_stream_phase(args, check) -> dict:
                 if t_dist > 0 else None,
                 "efficiency": round(t_base / t_dist, 3)
                 if t_dist > 0 else None,
-                "cores": os.cpu_count() or 1}
+                "platform": "cpu", "cores": os.cpu_count() or 1}
         if weak["cores"] >= 4:
             check((weak["efficiency"] or 0) > 0.005,
                   "stream weak-scaling efficiency %s above pathology "

@@ -29,6 +29,12 @@ through a shared checkpoint directory and file-KV namespace:
 
 Exit code 0 = every assertion holds. The summary JSON goes to ``--out``
 (and stdout) for the CI artifact.
+
+A CPU tool: it starts child processes and pins each to the CPU backend
+(``JAX_PLATFORMS=cpu``). A chip belongs to one process at a time, so this
+launcher does not run on the chip and nothing it times is a device
+number; the chip is reached with ``python chip_smoke.py`` through the
+chip tool.
 """
 import argparse
 import json
@@ -71,10 +77,6 @@ def serve_replica(name: str, workdir: str) -> int:
     """One replica process: build_app over the shared checkpoint + KV
     dirs, roll the initial snapshot, warm up, publish the HTTP base URL
     under ``http/<name>``, then serve until SIGTERM."""
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(workdir, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.fleet import FileKvClient
     from lightgbm_tpu.serving.server import build_app, make_server

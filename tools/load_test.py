@@ -192,8 +192,13 @@ def main() -> int:
                         % (p99, args.p99_ms))
 
     snap = engine.metrics.snapshot()
+    import jax
     print(json.dumps({
         "ok": not failures,
+        # every latency below is this device's, whatever it is
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())},
         "failures": failures,
         "threads": args.threads,
         "requests": int(lat.size),

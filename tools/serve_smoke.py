@@ -20,7 +20,9 @@ pass, 1 on any violated assertion.
 Usage:
   python tools/serve_smoke.py [--requests 1000] [--max-batch 4096]
                               [--model path.txt] [--devices 1] [--no-http]
-CPU-friendly: JAX_PLATFORMS=cpu python tools/serve_smoke.py --requests 100
+A CPU tool (JAX_PLATFORMS=cpu python tools/serve_smoke.py --requests 100):
+its rows/s is a CPU-host figure, never a device number. The chip's serve
+path is proven by chip_smoke.py's predict_and_serve phase.
 """
 import argparse
 import json
@@ -141,8 +143,13 @@ def main() -> int:
         failures.append("%d XLA backend compiles after warmup" % recompiles)
 
     snap = engine.metrics.snapshot()
+    import jax
     print(json.dumps({
         "ok": not failures,
+        # every rate below is this device's, whatever it is
+        "device": {"platform": jax.devices()[0].platform,
+                   "kind": jax.devices()[0].device_kind,
+                   "count": len(jax.devices())},
         "failures": failures,
         "requests": args.requests,
         "rows": rows_total,

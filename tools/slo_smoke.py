@@ -31,6 +31,12 @@ observability story:
    warmup, zero server-side errors, zero shed.
 
 Exit 0 = every assertion holds. Summary JSON to ``--out`` + stdout.
+
+A CPU tool: it starts child processes and pins each to the CPU backend
+(``JAX_PLATFORMS=cpu``). A chip belongs to one process at a time, so this
+launcher does not run on the chip and nothing it times is a device
+number; the chip is reached with ``python chip_smoke.py`` through the
+chip tool.
 """
 import argparse
 import json
@@ -79,10 +85,6 @@ def _wait(pred, timeout_s=60.0, interval_s=0.05):
 def serve_replica(name: str, workdir: str) -> int:
     """One replica: build_app with SLOs + tracing + the delay fault,
     roll the initial snapshot, warm up, publish the base URL."""
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(workdir, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.fleet import FileKvClient
     from lightgbm_tpu.serving.server import build_app, make_server

@@ -57,6 +57,7 @@ def main() -> int:
     args = ap.parse_args()
     os.makedirs(args.workdir, exist_ok=True)
 
+    import jax
     import numpy as np
     import lightgbm_tpu as lgb
 
@@ -149,6 +150,9 @@ def main() -> int:
                "structure_identical": s_base == s_stream,
                "max_pred_delta": max_dp, "bin_packing": args.bin_packing,
                "chunks_word_packed": packed,
+               "device": {"platform": jax.devices()[0].platform,
+                          "kind": jax.devices()[0].device_kind,
+                          "count": len(jax.devices())},
                "pipeline": stats, "failures": failures}
     blob = json.dumps(summary, indent=2, sort_keys=True)
     print(blob)
