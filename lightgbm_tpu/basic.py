@@ -17,11 +17,12 @@ import numpy as np
 
 from .config import Config, param_dict_to_str
 from .log import Log, LightGBMError, check
-from .io.dataset import BinnedDataset, Metadata
+from .io.dataset import BinnedDataset, Metadata, bytes_copied
 from .io import model_text
 from .objectives import create_objective
 from .metrics import create_metric, default_metric_for_objective
 from .boosting import create_boosting
+from .obs.trace import recorder
 
 _label_from_pandas_warned = False
 
@@ -110,6 +111,14 @@ class Dataset:
         """Lazy init (basic.py _lazy_init:693-800)."""
         if self._binned is not None:
             return self
+        with recorder.span("ingest.construct") as span:
+            self._construct()
+            span.counts.update(
+                rows=self._binned.num_data,
+                columns=getattr(self._binned, "num_total_features", 0))
+        return self
+
+    def _construct(self) -> None:
         ref_binned = None
         if self.reference is not None:
             ref_binned = self.reference.construct()._binned
@@ -124,14 +133,15 @@ class Dataset:
             # consumed chunk-by-chunk and never materialized whole.
             # Validation sets (reference != None) and subsets stay on the
             # in-memory path — they are bounded by construction.
-            return self._construct_streamed(cfg)
+            self._construct_streamed(cfg)
+            return
 
         data = self.data
         if isinstance(data, str):
             # file path; supports the "bin once" .npz cache
             if data.endswith(".npz") or data.endswith(".bin"):
                 self._binned = BinnedDataset.load_binary(data)
-                return self
+                return
             from .io import parser as parser_mod
             if cfg.two_round and self.used_indices is None \
                     and not parser_mod.sniff_libsvm(data):
@@ -159,7 +169,7 @@ class Dataset:
                        else parser_mod.load_init_score_file(data))
                 if isc is not None:
                     self._binned.metadata.set_init_score(np.asarray(isc))
-                return self
+                return
             X, y, names = parser_mod.parse_file(data, has_header=cfg.header,
                                                 label_column=cfg.label_column)
             if self.label is None:
@@ -190,7 +200,9 @@ class Dataset:
             # column-wise and EFB packs exclusive features (io/bundle.py)
             X = data
         else:
-            X = _to_2d_float(data)
+            with recorder.span("ingest.to_float64") as span:
+                X = _to_2d_float(data)
+                span.counts["bytes_copied"] = bytes_copied(data, X)
         label = _to_1d(self.label)
         feature_names = None
         if isinstance(self.feature_name, (list, tuple)):
@@ -226,7 +238,6 @@ class Dataset:
             init_score=init_score, feature_names=feature_names,
             categorical_feature=cat, reference=ref_binned)
         self._raw_X = None if self.free_raw_data else X
-        return self
 
     def _construct_streamed(self, cfg: Config) -> "Dataset":
         """Out-of-core construction through ``lightgbm_tpu.stream``.

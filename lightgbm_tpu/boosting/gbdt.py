@@ -44,6 +44,7 @@ from ..core import tree as tree_mod
 from ..objectives import ObjectiveFunction
 from ..metrics import Metric
 from ..resilience import faults as _faults
+from ..obs.trace import recorder
 
 
 class HostTree:
@@ -167,6 +168,15 @@ def _feature_meta_from_dataset(ds: BinnedDataset, config: Config) -> FeatureMeta
         pack_partner=jnp.asarray(pack_partner))
 
 
+def _host_device_split(block_span, dispatch_span):
+    """(wall, host busy, device wait) seconds of one dispatched block, from
+    its ``train.block`` span and the ``train.block_dispatch`` span inside
+    it: the host is busy until the dispatch returns, the rest is the wait."""
+    return (block_span.duration_s,
+            (dispatch_span.end_ns - block_span.start_ns) / 1e9,
+            (block_span.end_ns - dispatch_span.end_ns) / 1e9)
+
+
 def _hist_dtype(cfg: Config) -> str:
     """Histogram accumulation dtype: tpu_hist_dtype is the explicit knob,
     gpu_use_dp (config.h:784) the reference-compatible alias for f64."""
@@ -283,7 +293,8 @@ class GBDT:
         self.obs = TrainingObs.disabled()
 
         if train_data is not None:
-            self._setup_train(train_data)
+            with recorder.span("train.setup", rows=train_data.num_data):
+                self._setup_train(train_data)
 
     # ------------------------------------------------------------ setup
     def _setup_stream_mesh(self, ds) -> np.ndarray:
@@ -497,29 +508,35 @@ class GBDT:
             and not cfg.cegb_penalty_feature_coupled
             and not cfg.cegb_penalty_feature_lazy
             and cfg.cegb_penalty_split <= 0)
-        self.xb = None if streamed else jnp.asarray(xb_np)
-        self._fp_capture = None
-        if self._explicit_fp:
-            # xb stays replicated (every FP worker holds the full data,
-            # like the reference's feature-parallel machines); each device
-            # additionally gets its own column slice for histogram work
-            self._fp_capture = self._setup_feature_parallel(xb_np)
-        elif self.mesh is not None and self.xb is not None:
-            self.xb = jax.device_put(
-                self.xb, mesh_mod.feature_sharding(self.mesh))
-        if self.objective is not None:
-            self.objective.init(ds.metadata, ds.num_data)
-            if self.mesh is not None:
-                # streamed mesh: per-row arrays go to the shard-major
-                # padded layout instead of trailing-padding
-                self.objective.pad_to(self.num_data, self.mesh,
-                                      layout=self._stream_layout)
-            elif streamed and self.num_data > ds.num_data:
-                # chunk-uniform padding: per-row objective arrays stretch
-                # to the padded length; padded rows are masked everywhere
-                self.objective.pad_to(self.num_data)
-        for m in self.train_metrics:
-            m.init(ds.metadata, ds.num_data)
+        # the host side of the transfer: no barrier waits for the device
+        with recorder.span("train.device_put_bins",
+                           bytes=0 if streamed else xb_np.nbytes):
+            self.xb = None if streamed else jnp.asarray(xb_np)
+            self._fp_capture = None
+            if self._explicit_fp:
+                # xb stays replicated (every FP worker holds the full data,
+                # like the reference's feature-parallel machines); each
+                # device additionally gets its own column slice for
+                # histogram work
+                self._fp_capture = self._setup_feature_parallel(xb_np)
+            elif self.mesh is not None and self.xb is not None:
+                self.xb = jax.device_put(
+                    self.xb, mesh_mod.feature_sharding(self.mesh))
+        with recorder.span("train.objective_init"):
+            if self.objective is not None:
+                self.objective.init(ds.metadata, ds.num_data)
+                if self.mesh is not None:
+                    # streamed mesh: per-row arrays go to the shard-major
+                    # padded layout instead of trailing-padding
+                    self.objective.pad_to(self.num_data, self.mesh,
+                                          layout=self._stream_layout)
+                elif streamed and self.num_data > ds.num_data:
+                    # chunk-uniform padding: per-row objective arrays
+                    # stretch to the padded length; padded rows are masked
+                    # everywhere
+                    self.objective.pad_to(self.num_data)
+            for m in self.train_metrics:
+                m.init(ds.metadata, ds.num_data)
 
         self._forced_splits, num_forced = self._setup_forced_splits()
         self._cegb_state = self._setup_cegb()
@@ -746,7 +763,10 @@ class GBDT:
             # CPU backends; repacking from the host array keeps this a
             # single transfer of the halved/word layout)
             from ..core.binpack import pack_words_np
-            self.xb = jnp.asarray(pack_words_np(xb_np))
+            with recorder.span("train.device_put_bins") as span:
+                words = pack_words_np(xb_np)
+                span.counts["bytes"] = words.nbytes
+                self.xb = jnp.asarray(words)
             Log.info("bin packing: %d uint8 columns stored as %d int32 "
                      "words/row on device (tpu_bin_packing=%s)",
                      word_packed_cols, self.xb.shape[1],
@@ -1109,43 +1129,47 @@ class GBDT:
         def run_iter(xb, obj_rows, fp_capture, scores, sample_mask,
                      feature_mask, grad_in, hess_in, lr, goss_active,
                      goss_key, cegb_state, stopped_in):
-            # gradients: objective or custom (grad_in) (gbdt.cpp:333-347)
-            if not use_input:
-                # bind the argument arrays onto a shallow copy — the traced
-                # values, not the captured originals, feed get_gradients
-                o = _copy.copy(obj)
-                for nm, v in zip(obj_row_names, obj_rows):
-                    setattr(o, nm, v)
-                if k == 1:
-                    g, h = o.get_gradients(scores[:, 0])
-                    g = g[:, None]
-                    h = h[:, None]
+            with jax.named_scope("lgbm.gradients"):
+                # gradients: objective or custom (grad_in) (gbdt.cpp:333-347)
+                if not use_input:
+                    # bind the argument arrays onto a shallow copy — the
+                    # traced values, not the captured originals, feed
+                    # get_gradients
+                    o = _copy.copy(obj)
+                    for nm, v in zip(obj_row_names, obj_rows):
+                        setattr(o, nm, v)
+                    if k == 1:
+                        g, h = o.get_gradients(scores[:, 0])
+                        g = g[:, None]
+                        h = h[:, None]
+                    else:
+                        g, h = o.get_gradients(scores)
                 else:
-                    g, h = o.get_gradients(scores)
-            else:
-                g, h = grad_in, hess_in
+                    g, h = grad_in, hess_in
 
-            if is_goss:
-                # GOSS one-side sampling on device (goss.hpp:87-135): keep all
-                # of the top |g*h| rows, sample the rest, amplify their
-                # grad/hess by (n - top)/other so expectations are unbiased.
-                # Warmup iterations (goss_active == 0) skip the sort entirely.
-                def goss_mult(_):
-                    gh = jnp.sum(jnp.abs(g * h), axis=1)
-                    thr = jax.lax.top_k(gh, top_cnt)[0][-1]
-                    is_top = gh >= thr
-                    u = jax.random.uniform(goss_key, (n,))
-                    p_rest = other_cnt / max(n_real - top_cnt, 1)
-                    keep_other = (~is_top) & (u < p_rest)
-                    return jnp.where(is_top, 1.0,
-                                     jnp.where(keep_other, goss_multiply, 0.0))
+                if is_goss:
+                    # GOSS one-side sampling on device (goss.hpp:87-135):
+                    # keep all of the top |g*h| rows, sample the rest,
+                    # amplify their grad/hess by (n - top)/other so
+                    # expectations are unbiased. Warmup iterations
+                    # (goss_active == 0) skip the sort entirely.
+                    def goss_mult(_):
+                        gh = jnp.sum(jnp.abs(g * h), axis=1)
+                        thr = jax.lax.top_k(gh, top_cnt)[0][-1]
+                        is_top = gh >= thr
+                        u = jax.random.uniform(goss_key, (n,))
+                        p_rest = other_cnt / max(n_real - top_cnt, 1)
+                        keep_other = (~is_top) & (u < p_rest)
+                        return jnp.where(
+                            is_top, 1.0,
+                            jnp.where(keep_other, goss_multiply, 0.0))
 
-                mult = jax.lax.cond(goss_active > 0, goss_mult,
-                                    lambda _: jnp.ones((n,), jnp.float32),
-                                    operand=None)
-                g = g * mult[:, None]
-                h = h * mult[:, None]
-                sample_mask = sample_mask * (mult > 0).astype(jnp.float32)
+                    mult = jax.lax.cond(goss_active > 0, goss_mult,
+                                        lambda _: jnp.ones((n,), jnp.float32),
+                                        operand=None)
+                    g = g * mult[:, None]
+                    h = h * mult[:, None]
+                    sample_mask = sample_mask * (mult > 0).astype(jnp.float32)
 
             # one place decides which wave-batched grower runs (the
             # shard_map and single-device branches below both use it)
@@ -1328,43 +1352,46 @@ class GBDT:
                     row_used=jnp.max(cegb_out.row_used, axis=0))
             else:
                 cegb_new = None
-            if renew_alpha is not None:
-                # device RenewTreeOutput (serial_tree_learner.cpp:850-928):
-                # refit leaf values to the weighted percentile of residuals
-                # against the PRE-update scores, exactly like the
-                # reference's post-growth renew
-                from ..core.renew import renew_leaf_values
-                rw = getattr(o, renew_w_attr, None)
-                if rw is None:
-                    rw = jnp.ones_like(o.label)
+            with jax.named_scope("lgbm.score_update"):
+                if renew_alpha is not None:
+                    # device RenewTreeOutput (serial_tree_learner.cpp:850-928):
+                    # refit leaf values to the weighted percentile of residuals
+                    # against the PRE-update scores, exactly like the
+                    # reference's post-growth renew
+                    from ..core.renew import renew_leaf_values
+                    rw = getattr(o, renew_w_attr, None)
+                    if rw is None:
+                        rw = jnp.ones_like(o.label)
 
-                def renew_one(t, li, sc_col):
-                    # scores live in the (possibly reg_sqrt-transformed)
-                    # label space the gradients were computed in
-                    lab = getattr(o, "trans_label", None)
-                    lab = o.label if lab is None else lab
-                    new_lv = renew_leaf_values(
-                        lab - sc_col, rw, li, sample_mask,
-                        params.num_leaves, renew_alpha, t.leaf_value)
-                    return t._replace(leaf_value=new_lv)
+                    def renew_one(t, li, sc_col):
+                        # scores live in the (possibly reg_sqrt-transformed)
+                        # label space the gradients were computed in
+                        lab = getattr(o, "trans_label", None)
+                        lab = o.label if lab is None else lab
+                        new_lv = renew_leaf_values(
+                            lab - sc_col, rw, li, sample_mask,
+                            params.num_leaves, renew_alpha, t.leaf_value)
+                        return t._replace(leaf_value=new_lv)
 
-                trees = jax.vmap(renew_one, in_axes=(0, 0, 1))(
-                    trees, leaf_ids, scores)
-            # score update fast path: leaf_id -> leaf_value (shrinkage applied)
-            deltas = jax.vmap(
-                lambda t, li: t.leaf_value[li] * lr)(trees, leaf_ids)  # [K, N]
-            # A fully-stumped iteration (no class tree split) means training
-            # has converged; the reference discards the tree and stops
-            # (gbdt.cpp:379-396). The stop flag accumulates ON DEVICE across
-            # iterations: once any iteration stumps, every later dispatched
-            # iteration freezes the scores too — so the async driver can
-            # discard the overshoot trees at the next flush without
-            # rewinding anything, even when bagging/feature sampling would
-            # have let a later iteration split again.
-            any_split = jnp.any(trees.num_leaves > 1)
-            stopped_out = stopped_in | ~any_split
-            apply = (any_split & ~stopped_in).astype(jnp.float32)
-            new_scores = scores + deltas.T * apply
+                    trees = jax.vmap(renew_one, in_axes=(0, 0, 1))(
+                        trees, leaf_ids, scores)
+                # score update fast path: leaf_id -> leaf_value (shrinkage
+                # applied)
+                deltas = jax.vmap(
+                    lambda t, li: t.leaf_value[li] * lr)(
+                        trees, leaf_ids)                            # [K, N]
+                # A fully-stumped iteration (no class tree split) means training
+                # has converged; the reference discards the tree and stops
+                # (gbdt.cpp:379-396). The stop flag accumulates ON DEVICE across
+                # iterations: once any iteration stumps, every later dispatched
+                # iteration freezes the scores too — so the async driver can
+                # discard the overshoot trees at the next flush without
+                # rewinding anything, even when bagging/feature sampling would
+                # have let a later iteration split again.
+                any_split = jnp.any(trees.num_leaves > 1)
+                stopped_out = stopped_in | ~any_split
+                apply = (any_split & ~stopped_in).astype(jnp.float32)
+                new_scores = scores + deltas.T * apply
             if health_on:
                 from ..obs.health import health_vec
                 health = health_vec(g, h, any_split, grower_health)
@@ -1512,57 +1539,59 @@ class GBDT:
 
         iter_idx = self.iter_
         obs = self.obs
-        t0 = time.perf_counter() if obs.enabled else 0.0
-        sample_mask = self._sample_bagging_mask(iter_idx)
-        feature_mask = self._sample_feature_mask()
-        self._bag_key, goss_key = jax.random.split(self._bag_key)
         obs.perfetto_step(iter_idx, iter_idx + 1)
-        t_disp = t0
         params = self.grow_params
         k = self.num_tree_per_iteration
         # request-scoped iteration trace (obs/reqtrace.py): a no-op span
         # unless obs_trace is on; mirrors the serving span tree with
         # per-wave children under a per-iteration root
         tspan = obs.trace_iter(iter_idx)
-        with obs.span("train_iter", iteration=iter_idx):
-            gspan = tspan.child("gradients")
-            g, h, sm = self._stream_pre(
-                self._stream_capture, self.scores, sample_mask,
-                jnp.float32(self._goss_active(iter_idx)), goss_key)
-            gspan.end()
-            trees_l, lids_l, aux_l = [], [], []
-            for c in range(k):
-                cspan = tspan.child("tree", cls=c)
-                t, li, aux = self._stream_grower.grow(
-                    g[:, c], h[:, c], sm, feature_mask,
-                    trace_span=cspan if cspan else None)
-                cspan.end()
-                trees_l.append(t)
-                lids_l.append(li)
-                aux_l.append(aux)
-            trees = jax.tree.map(lambda *a: jnp.stack(a), *trees_l)
-            leaf_ids = jnp.stack(lids_l)
-            grower_health = None
-            mstats = None
-            if params.obs_modelstats:
-                if aux_l[0][0] is not None:
-                    grower_health = jnp.stack([a[0] for a in aux_l])
-                mstats = jnp.stack([a[1] for a in aux_l])
-            elif params.obs_health:
-                grower_health = jnp.stack(aux_l)
-            pspan = tspan.child("score_commit")
-            packed, new_scores, self._stopped_dev, health = \
-                self._stream_post(
-                    self._stream_capture, trees, leaf_ids, self.scores,
-                    sm, g, h, grower_health,
-                    jnp.float32(self.shrinkage_rate), self._stopped_dev)
-            pspan.end()
+        with obs.span("train.block", start_iter=iter_idx,
+                      count=1) as block_span:
+            with obs.span("train.block_prepare"):
+                sample_mask = self._sample_bagging_mask(iter_idx)
+                feature_mask = self._sample_feature_mask()
+                self._bag_key, goss_key = jax.random.split(self._bag_key)
+            # the host wave loop IS the dispatch here: it returns when the
+            # last stage is enqueued
+            with obs.span("train.block_dispatch") as dispatch_span:
+                gspan = tspan.child("gradients")
+                g, h, sm = self._stream_pre(
+                    self._stream_capture, self.scores, sample_mask,
+                    jnp.float32(self._goss_active(iter_idx)), goss_key)
+                gspan.end()
+                trees_l, lids_l, aux_l = [], [], []
+                for c in range(k):
+                    cspan = tspan.child("tree", cls=c)
+                    t, li, aux = self._stream_grower.grow(
+                        g[:, c], h[:, c], sm, feature_mask,
+                        trace_span=cspan if cspan else None)
+                    cspan.end()
+                    trees_l.append(t)
+                    lids_l.append(li)
+                    aux_l.append(aux)
+                trees = jax.tree.map(lambda *a: jnp.stack(a), *trees_l)
+                leaf_ids = jnp.stack(lids_l)
+                grower_health = None
+                mstats = None
+                if params.obs_modelstats:
+                    if aux_l[0][0] is not None:
+                        grower_health = jnp.stack([a[0] for a in aux_l])
+                    mstats = jnp.stack([a[1] for a in aux_l])
+                elif params.obs_health:
+                    grower_health = jnp.stack(aux_l)
+                pspan = tspan.child("score_commit")
+                packed, new_scores, self._stopped_dev, health = \
+                    self._stream_post(
+                        self._stream_capture, trees, leaf_ids, self.scores,
+                        sm, g, h, grower_health,
+                        jnp.float32(self.shrinkage_rate), self._stopped_dev)
+                pspan.end()
             if obs.enabled:
-                t_disp = time.perf_counter()
-                wspan = tspan.child("device_wait")
-                jax.block_until_ready(new_scores)  # lgbm-lint: disable=LGL103 span close
-                wspan.end()
-        t_done = time.perf_counter() if obs.enabled else 0.0
+                with obs.span("train.block_wait"):
+                    wspan = tspan.child("device_wait")
+                    jax.block_until_ready(new_scores)  # lgbm-lint: disable=LGL103 span close
+                    wspan.end()
         self.scores = new_scores
 
         pend: Dict[str, Any] = {"packed": packed[None],
@@ -1574,9 +1603,10 @@ class GBDT:
         self.iter_ += 1
         if obs.enabled:
             hrow = np.asarray(health)[None]
-            obs.dispatch_done(iter_idx, 1, t_done - t0,
-                              health_rows=hrow,
-                              busy_s=t_disp - t0, wait_s=t_done - t_disp)
+            dur_s, busy_s, wait_s = _host_device_split(block_span,
+                                                       dispatch_span)
+            obs.dispatch_done(iter_idx, 1, dur_s, health_rows=hrow,
+                              busy_s=busy_s, wait_s=wait_s)
             obs.account_rows(self.num_data_orig)
             if obs.per_iteration:
                 obs.record_hbm()
@@ -1634,14 +1664,15 @@ class GBDT:
                 if bag_enabled:
                     # bagging refresh on schedule (gbdt.cpp:180-241);
                     # ranking: one uniform per QUERY, broadcast to rows
-                    refresh = (it % freq) == 0
-                    if row_group is not None:
-                        u = jax.random.uniform(bkey, (num_groups,))
-                        u = u[row_group]
-                    else:
-                        u = jax.random.uniform(bkey, (n,))
-                    new_mask = (u < frac).astype(jnp.float32)
-                    bag_mask = jnp.where(refresh, new_mask, bag_mask)
+                    with jax.named_scope("lgbm.gradients"):
+                        refresh = (it % freq) == 0
+                        if row_group is not None:
+                            u = jax.random.uniform(bkey, (num_groups,))
+                            u = u[row_group]
+                        else:
+                            u = jax.random.uniform(bkey, (n,))
+                        new_mask = (u < frac).astype(jnp.float32)
+                        bag_mask = jnp.where(refresh, new_mask, bag_mask)
                 sm = bag_mask if row_valid is None else bag_mask * row_valid
                 packed, _leaf_ids, sc2, cegb2, stopped2, health, ms = core(
                     xb, obj_rows, fp_capture, sc, sm, fm, g0, h0, lr, ga,
@@ -1889,11 +1920,14 @@ class GBDT:
 
         self._boost_from_average()
         self._maybe_warm_ladder()
-        if self._iter_core is None:
-            self._compiled_iter = self._make_train_iter_fn()
-        if self._compiled_block is None:
-            # one jitted scan; jax caches a compilation per block length
-            self._compiled_block = self._make_train_block_fn()
+        if self._iter_core is None or self._compiled_block is None:
+            with self.obs.span("train.make_block_fn"):
+                if self._iter_core is None:
+                    self._compiled_iter = self._make_train_iter_fn()
+                if self._compiled_block is None:
+                    # one jitted scan; jax caches a compilation per block
+                    # length
+                    self._compiled_block = self._make_train_block_fn()
 
         done = 0
         while done < num_iters and not self._stopped:
@@ -1905,42 +1939,44 @@ class GBDT:
                            block_len=block)
             self._last_block_len = block
             obs = self.obs
-            # host window opens before feature sampling: mask/bag-key prep
-            # is host-side work attributed to busy_s in the distributed
-            # per-block comm/compute split
-            t0 = time.perf_counter() if obs.enabled else 0.0
-            fn = self._compiled_block
-            fmasks = jnp.stack([self._sample_feature_mask()
-                                for _ in range(block)])
-            gactive = jnp.asarray(
-                [self._goss_active(self.iter_ + i) for i in range(block)],
-                jnp.float32)
-            # host-side arange: jnp.arange with a nonzero start compiles a
-            # tiny convert_element_type on the SECOND block (start=0 takes
-            # the iota path), breaking zero-recompiles-after-warmup
-            idxs = jnp.asarray(np.arange(self.iter_, self.iter_ + block,
-                                         dtype=np.int32))
-            all_keys = jax.random.split(self._bag_key, block + 1)
-            self._bag_key = all_keys[0]
+            # before the block's span opens: a capture that starts inside
+            # a span does not hold that span's annotation
             obs.perfetto_step(self.iter_, self.iter_ + block)
-            t_disp = t0
-            with obs.span("train_block", start_iter=self.iter_,
-                          count=block):
-                packs, healths, self.scores, self._bag_mask, \
-                    self._cegb_state, self._stopped_dev, mstats = fn(
-                        *self._iter_capture,
-                        self.scores, fmasks, gactive, idxs, all_keys[1:],
-                        self._bag_mask, self._cegb_state, self._stopped_dev,
-                        jnp.float32(self.shrinkage_rate))
+            with obs.span("train.block", start_iter=self.iter_,
+                          count=block) as block_span:
+                # feature sampling and the bag keys are host-side work: with
+                # the dispatch they are the block's busy_s in the distributed
+                # per-block comm/compute split
+                with obs.span("train.block_prepare"):
+                    fn = self._compiled_block
+                    fmasks = jnp.stack([self._sample_feature_mask()
+                                        for _ in range(block)])
+                    gactive = jnp.asarray(
+                        [self._goss_active(self.iter_ + i)
+                         for i in range(block)], jnp.float32)
+                    # host-side arange: jnp.arange with a nonzero start
+                    # compiles a tiny convert_element_type on the SECOND
+                    # block (start=0 takes the iota path), breaking
+                    # zero-recompiles-after-warmup
+                    idxs = jnp.asarray(np.arange(
+                        self.iter_, self.iter_ + block, dtype=np.int32))
+                    all_keys = jax.random.split(self._bag_key, block + 1)
+                    self._bag_key = all_keys[0]
+                # the call of the compiled block until it returns; on the
+                # first block: tracing, lowering, the compile or cache load
+                with obs.span("train.block_dispatch") as dispatch_span:
+                    packs, healths, self.scores, self._bag_mask, \
+                        self._cegb_state, self._stopped_dev, mstats = fn(
+                            *self._iter_capture,
+                            self.scores, fmasks, gactive, idxs, all_keys[1:],
+                            self._bag_mask, self._cegb_state,
+                            self._stopped_dev,
+                            jnp.float32(self.shrinkage_rate))
                 if obs.enabled:
-                    # async dispatch returned: host work ends here, the
-                    # remainder of the block wall is device wait
-                    t_disp = time.perf_counter()
-                    # one sync at span close; basic mode's only added
-                    # barrier, and the block boundary already is one for
-                    # the flush cadence
-                    jax.block_until_ready(self.scores)  # lgbm-lint: disable=LGL103 span close
-            t_done = time.perf_counter() if obs.enabled else 0.0
+                    # basic mode's only added barrier, and the block
+                    # boundary already is one for the flush cadence
+                    with obs.span("train.block_wait"):
+                        jax.block_until_ready(self.scores)  # lgbm-lint: disable=LGL103 span close
             self._pending.append({"packed": packs,
                                   "shrinkage": self.shrinkage_rate,
                                   "count": block,
@@ -1949,11 +1985,11 @@ class GBDT:
             done += block
             if obs.enabled:
                 hrows = np.asarray(healths)
-                obs.dispatch_done(self.iter_ - block, block,
-                                  t_done - t0,
-                                  health_rows=hrows,
-                                  busy_s=t_disp - t0,
-                                  wait_s=t_done - t_disp)
+                dur_s, busy_s, wait_s = _host_device_split(block_span,
+                                                           dispatch_span)
+                obs.dispatch_done(self.iter_ - block, block, dur_s,
+                                  health_rows=hrows, busy_s=busy_s,
+                                  wait_s=wait_s)
                 obs.account_rows(self.num_data_orig * block)
                 obs.record_hbm()
                 obs.check_health(hrows, self.iter_ - block, booster=self)
@@ -2187,47 +2223,51 @@ class GBDT:
         self._boost_from_average()
         self._maybe_warm_ladder()
         if self._compiled_iter is None:
-            self._compiled_iter = self._make_train_iter_fn()
+            with self.obs.span("train.make_block_fn"):
+                self._compiled_iter = self._make_train_iter_fn()
 
         iter_idx = self.iter_
         obs = self.obs
-        # host window opens before mask sampling (matches train_many)
-        t0 = time.perf_counter() if obs.enabled else 0.0
-        sample_mask = self._sample_bagging_mask(iter_idx)
-        feature_mask = self._sample_feature_mask()
-
-        n, k = self.num_data, self.num_tree_per_iteration
-        if grad is not None:
-            g_in = jnp.asarray(np.asarray(grad, np.float32).reshape(k, n).T
-                               if np.asarray(grad).ndim == 1 and k > 1
-                               else np.asarray(grad, np.float32).reshape(n, k))
-            h_in = jnp.asarray(np.asarray(hess, np.float32).reshape(k, n).T
-                               if np.asarray(hess).ndim == 1 and k > 1
-                               else np.asarray(hess, np.float32).reshape(n, k))
-        elif self._use_input_grads:
-            g_in, h_in = self._fixed_gradients()
-        else:
-            g_in = jnp.zeros((n, k), jnp.float32)
-            h_in = jnp.ones((n, k), jnp.float32)
-
-        self._bag_key, goss_key = jax.random.split(self._bag_key)
         obs.perfetto_step(iter_idx, iter_idx + 1)
-        t_disp = t0
-        with obs.span("train_iter", iteration=iter_idx):
-            packed, leaf_ids, new_scores, cegb_new, self._stopped_dev, \
-                health, mstats = self._compiled_iter(
-                    *self._iter_capture,
-                    self.scores, sample_mask, feature_mask, g_in, h_in,
-                    jnp.float32(self.shrinkage_rate),
-                    jnp.float32(self._goss_active(iter_idx)), goss_key,
-                    self._cegb_state, self._stopped_dev)
+        # one iteration dispatched alone is a block of one: the same spans
+        # as train_many's
+        with obs.span("train.block", start_iter=iter_idx,
+                      count=1) as block_span:
+            with obs.span("train.block_prepare"):
+                sample_mask = self._sample_bagging_mask(iter_idx)
+                feature_mask = self._sample_feature_mask()
+
+                n, k = self.num_data, self.num_tree_per_iteration
+                if grad is not None:
+                    g_in = jnp.asarray(
+                        np.asarray(grad, np.float32).reshape(k, n).T
+                        if np.asarray(grad).ndim == 1 and k > 1
+                        else np.asarray(grad, np.float32).reshape(n, k))
+                    h_in = jnp.asarray(
+                        np.asarray(hess, np.float32).reshape(k, n).T
+                        if np.asarray(hess).ndim == 1 and k > 1
+                        else np.asarray(hess, np.float32).reshape(n, k))
+                elif self._use_input_grads:
+                    g_in, h_in = self._fixed_gradients()
+                else:
+                    g_in = jnp.zeros((n, k), jnp.float32)
+                    h_in = jnp.ones((n, k), jnp.float32)
+
+                self._bag_key, goss_key = jax.random.split(self._bag_key)
+            with obs.span("train.block_dispatch") as dispatch_span:
+                packed, leaf_ids, new_scores, cegb_new, self._stopped_dev, \
+                    health, mstats = self._compiled_iter(
+                        *self._iter_capture,
+                        self.scores, sample_mask, feature_mask, g_in, h_in,
+                        jnp.float32(self.shrinkage_rate),
+                        jnp.float32(self._goss_active(iter_idx)), goss_key,
+                        self._cegb_state, self._stopped_dev)
             if obs.enabled:
-                t_disp = time.perf_counter()
-                # span-close sync: the per-iteration path is already the
-                # slow (full/host-logic) path, so one barrier per
-                # iteration is the accepted cost of true spans
-                jax.block_until_ready(new_scores)  # lgbm-lint: disable=LGL103 span close
-        t_done = time.perf_counter() if obs.enabled else 0.0
+                # the per-iteration path is already the slow
+                # (full/host-logic) path, so one barrier per iteration is
+                # the accepted cost of true spans
+                with obs.span("train.block_wait"):
+                    jax.block_until_ready(new_scores)  # lgbm-lint: disable=LGL103 span close
         self.scores = new_scores
         self._cegb_state = cegb_new
 
@@ -2240,9 +2280,10 @@ class GBDT:
         self.iter_ += 1
         if obs.enabled:
             hrow = np.asarray(health)[None]
-            obs.dispatch_done(iter_idx, 1, t_done - t0,
-                              health_rows=hrow,
-                              busy_s=t_disp - t0, wait_s=t_done - t_disp)
+            dur_s, busy_s, wait_s = _host_device_split(block_span,
+                                                       dispatch_span)
+            obs.dispatch_done(iter_idx, 1, dur_s, health_rows=hrow,
+                              busy_s=busy_s, wait_s=wait_s)
             obs.account_rows(self.num_data_orig)
             if obs.per_iteration:
                 obs.record_hbm()

@@ -26,11 +26,6 @@ from ..log import Log
 from .manager import CheckpointManager
 
 
-def _null_span():
-    from ..obs.trace import _NULL_SPAN
-    return _NULL_SPAN
-
-
 class _Checkpoint:
     before_iteration = False
     order = 25
@@ -113,9 +108,8 @@ class _Checkpoint:
             if es is not None:
                 train_loop["early_stopping"] = es
             obs = getattr(env.model._impl, "obs", None)
-            span = (obs.span("checkpoint_save", iteration=it)
-                    if obs is not None else _null_span())
-            with span:
+            from ..obs.trace import recorder
+            with (obs or recorder).span("checkpoint_save", iteration=it):
                 self.manager.save(env.model, train_loop=train_loop,
                                   eval_entry=eval_entry)
             from ..obs.registry import get_registry

@@ -416,26 +416,38 @@ class FeatureParallelCtx(NamedTuple):
     global_of_local: jnp.ndarray
 
 
+def _psum(x, axis_name):
+    """Every collective of the exact grower carries one scope, so a trace
+    of a sharded run finds the exchange named."""
+    with jax.named_scope("lgbm.exchange"):
+        return lax.psum(x, axis_name)
+
+
+def _all_gather(x, axis_name):
+    with jax.named_scope("lgbm.exchange"):
+        return lax.all_gather(x, axis_name)
+
+
 def sync_best_split(bs: BestSplit, axis_name: str) -> BestSplit:
     """SyncUpGlobalBestSplit (parallel_tree_learner.h:186-230) as one
     argmax-allreduce: every rank contributes its local best-split struct,
     the max-gain rank's struct is broadcast to all. Comm volume is
     O(struct fields), never O(F*B)."""
-    gains = lax.all_gather(bs.gain, axis_name)          # [D]
+    gains = _all_gather(bs.gain, axis_name)             # [D]
     winner = jnp.argmax(gains).astype(jnp.int32)
     mine = lax.axis_index(axis_name) == winner
 
     def bcast(v):
         if v.dtype == jnp.bool_:
             z = jnp.where(mine, v.astype(jnp.int32), 0)
-            return lax.psum(z, axis_name) > 0
+            return _psum(z, axis_name) > 0
         if v.dtype == jnp.uint32:
             # lossless: bitcast to i32 (sum of winner's word + zeros is
             # exact), never a value-cast that truncates the high bit
             z = jnp.where(mine, lax.bitcast_convert_type(v, jnp.int32), 0)
-            return lax.bitcast_convert_type(lax.psum(z, axis_name),
+            return lax.bitcast_convert_type(_psum(z, axis_name),
                                             jnp.uint32)
-        return lax.psum(jnp.where(mine, v, jnp.zeros_like(v)), axis_name)
+        return _psum(jnp.where(mine, v, jnp.zeros_like(v)), axis_name)
 
     return jax.tree.map(bcast, bs)
 
@@ -516,7 +528,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # sums — nothing to reduce (rows are replicated)
         if fp_mode or axis_name is None:
             return x
-        return lax.psum(x, axis_name)
+        return _psum(x, axis_name)
 
     # CEGB's lazy acquisition accounting reads leaf_id during growth; only
     # then is the per-split leaf_id scatter worth its cost — otherwise the
@@ -595,11 +607,11 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             with_categorical=params.with_categorical)
         top_gain, top_idx = lax.top_k(pf.gain, k)
         w = jnp.isfinite(top_gain).astype(jnp.int32)   # only real proposals
-        all_idx = lax.all_gather(top_idx, axis_name).reshape(-1)
-        all_w = lax.all_gather(w, axis_name).reshape(-1)
+        all_idx = _all_gather(top_idx, axis_name).reshape(-1)
+        all_w = _all_gather(w, axis_name).reshape(-1)
         votes = jnp.zeros((f,), jnp.int32).at[all_idx].add(all_w)
         elected = lax.top_k(votes, k2)[1]
-        cand = lax.psum(jnp.take(hist_local, elected, axis=0), axis_name)
+        cand = _psum(jnp.take(hist_local, elected, axis=0), axis_name)
         gh = jnp.zeros_like(hist_local).at[elected].set(cand)
         cand_mask = jnp.zeros((f,), bool).at[elected].set(True)
         bs = find_best_split(gh, meta, sp, sum_g, sum_h, cnt,
@@ -608,7 +620,9 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                              with_categorical=params.with_categorical)
         return bs._replace(gain=jnp.where(depth_ok, bs.gain, K_MIN_SCORE))
 
-    best_for = voting_best if voting else full_best
+    def best_for(*args, **kwargs):
+        with jax.named_scope("lgbm.split_search"):
+            return (voting_best if voting else full_best)(*args, **kwargs)
 
     # ---- root ------------------------------------------------------------
     sample_mask = sample_mask.astype(hdt)
@@ -618,13 +632,18 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     # rows on the normal path; two gathers under vmapped class batching,
     # where packing would copy the shared bin matrix per class
     # (make_row_gather docstring)
-    gather_rows = (make_row_gather(xb, stack_vals(grad, hess, sample_mask),
-                                   packed=not params.vmapped_classes)
-                   if use_partition else None)
-    root_g = psum(jnp.sum(grad * sample_mask))
-    root_h = psum(jnp.sum(hess * sample_mask))
-    root_c = psum(jnp.sum(sample_mask))
-    hist_root = hist_for_mask(sample_mask)
+    # XLA fuses the objective's gradient math into this pass over the rows
+    # (the fused op takes its root's name), so it carries the same scope
+    with jax.named_scope("lgbm.gradients"):
+        gather_rows = (make_row_gather(xb,
+                                       stack_vals(grad, hess, sample_mask),
+                                       packed=not params.vmapped_classes)
+                       if use_partition else None)
+    with jax.named_scope("lgbm.root_hist"):
+        root_g = psum(jnp.sum(grad * sample_mask))
+        root_h = psum(jnp.sum(hess * sample_mask))
+        root_c = psum(jnp.sum(sample_mask))
+        hist_root = hist_for_mask(sample_mask)
 
     tree = empty_tree(l, hdt)
     tree = tree._replace(
@@ -1042,13 +1061,13 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             mx2 = jnp.stack([l_max, r_max])
             if lp is None:
                 b2 = jax.vmap(
-                    lambda hh, sg, sh, cc, mn, mx: full_best(
+                    lambda hh, sg, sh, cc, mn, mx: best_for(
                         hh, sg, sh, cc, depth_ok, mn, mx))(
                     hist2, sg2, sh2, cc2, mn2, mx2)
             else:
                 pen2 = jnp.stack([lp, rp])
                 b2 = jax.vmap(
-                    lambda hh, sg, sh, cc, mn, mx, pen: full_best(
+                    lambda hh, sg, sh, cc, mn, mx, pen: best_for(
                         hh, sg, sh, cc, depth_ok, mn, mx,
                         gain_penalty=pen))(
                     hist2, sg2, sh2, cc2, mn2, mx2, pen2)

@@ -65,14 +65,16 @@ def hist_tile_vals(xb_rows: jnp.ndarray, vals: jnp.ndarray, num_bins: int,
     (grad*mask, hess*mask, mask) -> [F, B, 3]. Used by the row-partition
     path (core/partition.py), which gathers the stacked values in a single
     indexed read per tile."""
-    if impl.startswith("pallas"):
-        from .histogram_pallas import build_histogram_pallas_vals
-        return build_histogram_pallas_vals(
-            xb_rows, vals.T, num_bins, interpret=impl.endswith("interpret"),
-            highest="highest" in impl)
-    if impl == "scatter":
-        return _hist_scatter(xb_rows, vals, num_bins)
-    return _hist_chunk_matmul(xb_rows, vals, num_bins)
+    with jax.named_scope("lgbm.hist_tile"):
+        if impl.startswith("pallas"):
+            from .histogram_pallas import build_histogram_pallas_vals
+            return build_histogram_pallas_vals(
+                xb_rows, vals.T, num_bins,
+                interpret=impl.endswith("interpret"),
+                highest="highest" in impl)
+        if impl == "scatter":
+            return _hist_scatter(xb_rows, vals, num_bins)
+        return _hist_chunk_matmul(xb_rows, vals, num_bins)
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "row_chunk", "impl",

@@ -21,6 +21,7 @@ from typing import List, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -73,14 +74,15 @@ def pack_trees(trees) -> jnp.ndarray:
     """
     k = trees.leaf_value.shape[0]
     parts = []
-    for name, kind in _FIELDS:
-        a = getattr(trees, name).reshape(k, -1)
-        if kind in ("f32", "u32"):
-            a = lax.bitcast_convert_type(a, jnp.int32)
-        else:
-            a = a.astype(jnp.int32)
-        parts.append(a)
-    return jnp.concatenate(parts, axis=1)
+    with jax.named_scope("lgbm.tree_pack"):
+        for name, kind in _FIELDS:
+            a = getattr(trees, name).reshape(k, -1)
+            if kind in ("f32", "u32"):
+                a = lax.bitcast_convert_type(a, jnp.int32)
+            else:
+                a = a.astype(jnp.int32)
+            parts.append(a)
+        return jnp.concatenate(parts, axis=1)
 
 
 def unpack_tree(row: np.ndarray, l: int) -> SimpleNamespace:

@@ -183,16 +183,18 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
         """Shared tile load: gather the tile's bins+values rows, decide
         the split, weight the six child channels, add the histogram
         tile."""
-        idx = lax.dynamic_slice(part.order, (start,), (chunk,))
-        idx_safe = jnp.minimum(idx, n_rows - 1)
-        rows, v = gather_rows(idx_safe)                        # [chunk, F/3]
-        v = v * in_range[:, None].astype(v.dtype)
-        go_left = go_left_from_rows(rows)
-        is_l = go_left & in_range
-        is_r = (~go_left) & in_range
-        v6 = jnp.concatenate([v * is_l[:, None].astype(v.dtype),
-                              v * is_r[:, None].astype(v.dtype)],
-                             axis=1)                           # [chunk, 6]
+        with jax.named_scope("lgbm.row_gather"):
+            idx = lax.dynamic_slice(part.order, (start,), (chunk,))
+            idx_safe = jnp.minimum(idx, n_rows - 1)
+            rows, v = gather_rows(idx_safe)                    # [chunk, F/3]
+        with jax.named_scope("lgbm.route_rows"):
+            v = v * in_range[:, None].astype(v.dtype)
+            go_left = go_left_from_rows(rows)
+            is_l = go_left & in_range
+            is_r = (~go_left) & in_range
+            v6 = jnp.concatenate([v * is_l[:, None].astype(v.dtype),
+                                  v * is_r[:, None].astype(v.dtype)],
+                                 axis=1)                       # [chunk, 6]
         hist = hist_tile_vals(rows, v6, num_bins, impl)
         return idx, idx_safe, go_left, is_l, is_r, hist
 
@@ -201,8 +203,9 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
             return lid
         # max-scatter: right_leaf exceeds every id assigned so far; left
         # rows keep their id; padded/OOB duplicates contribute 0
-        val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
-        return lid.at[idx_safe].max(val, mode="promise_in_bounds")
+        with jax.named_scope("lgbm.leaf_ids"):
+            val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
+            return lid.at[idx_safe].max(val, mode="promise_in_bounds")
 
     def cond(c):
         i = c[0]
@@ -217,15 +220,17 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
         acc = acc + hist
         # in_range is a prefix mask, so within range the right-side running
         # count is (position + 1) - left count: one cumsum covers both
-        cl = jnp.cumsum(is_l.astype(jnp.int32), dtype=jnp.int32)
-        cr = (j + 1) - cl
-        kl = cl[-1]
-        kr = jnp.sum(in_range.astype(jnp.int32), dtype=jnp.int32) - kl
-        lpos = beg + nl + (cl - is_l)
-        rpos = beg + cnt - 1 - nr - (cr - is_r)
-        pos = jnp.where(go_left, lpos, rpos)
-        pos = jnp.where(in_range, pos, trash)
-        order_new = order_new.at[pos].set(idx, mode="promise_in_bounds")
+        with jax.named_scope("lgbm.partition_scatter"):
+            cl = jnp.cumsum(is_l.astype(jnp.int32), dtype=jnp.int32)
+            cr = (j + 1) - cl
+            kl = cl[-1]
+            kr = jnp.sum(in_range.astype(jnp.int32), dtype=jnp.int32) - kl
+            lpos = beg + nl + (cl - is_l)
+            rpos = beg + cnt - 1 - nr - (cr - is_r)
+            pos = jnp.where(go_left, lpos, rpos)
+            pos = jnp.where(in_range, pos, trash)
+            order_new = order_new.at[pos].set(idx,
+                                              mode="promise_in_bounds")
         lid = maybe_lid(lid, idx_safe, is_r)
         return (i + 1, nl + kl, nr + kr, order_new, lid, acc)
 
@@ -252,9 +257,12 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
             # back unchanged, so the rest of ``order`` is untouched.
             in_range = jnp.arange(chunk, dtype=jnp.int32) < cnt
             idx, idx_safe, _, is_l, is_r, acc = load_tile(beg, in_range)
-            key = jnp.where(is_l, 0, jnp.where(is_r, 1, 2)).astype(jnp.uint8)
-            _, sidx = lax.sort((key, idx), num_keys=1, is_stable=True)
-            order_new = lax.dynamic_update_slice(part.order, sidx, (beg,))
+            with jax.named_scope("lgbm.partition_scatter"):
+                key = jnp.where(is_l, 0,
+                                jnp.where(is_r, 1, 2)).astype(jnp.uint8)
+                _, sidx = lax.sort((key, idx), num_keys=1, is_stable=True)
+                order_new = lax.dynamic_update_slice(part.order, sidx,
+                                                     (beg,))
             lid = maybe_lid(leaf_id, idx_safe, is_r)
             return (order_new, lid,
                     jnp.sum(is_l.astype(jnp.int32), dtype=jnp.int32),
@@ -299,11 +307,13 @@ def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
     def body(c):
         i, acc = c
         start = beg + i * chunk
-        idx = lax.dynamic_slice(part.order, (start,), (chunk,))
-        j = jnp.arange(chunk, dtype=jnp.int32)
-        in_range = (i * chunk + j) < cnt
-        idx_safe = jnp.minimum(jnp.where(in_range, idx, 0), num_rows - 1)
-        rows, v = gather_rows(idx_safe)                        # [chunk, F/3]
+        with jax.named_scope("lgbm.row_gather"):
+            idx = lax.dynamic_slice(part.order, (start,), (chunk,))
+            j = jnp.arange(chunk, dtype=jnp.int32)
+            in_range = (i * chunk + j) < cnt
+            idx_safe = jnp.minimum(jnp.where(in_range, idx, 0),
+                                   num_rows - 1)
+            rows, v = gather_rows(idx_safe)                    # [chunk, F/3]
         v = v * in_range[:, None].astype(v.dtype)
         return i + 1, acc + hist_tile_vals(rows, v, num_bins, impl)
 
@@ -321,17 +331,18 @@ def leaf_id_from_partition(part: RowPartition, num_data: int,
     and row -> leaf is one scatter through ``order`` — O(N log L) dense work
     once per tree instead of O(N x depth) scattered writes during growth.
     """
-    # empty leaves sort past every real range
-    begins = jnp.where(part.leaf_count > 0, part.leaf_begin,
-                       jnp.int32(num_data + 1))
-    sort_begins, sort_leaf = lax.sort(
-        (begins, jnp.arange(num_leaves, dtype=jnp.int32)), num_keys=1)
-    pos = jnp.arange(num_data, dtype=jnp.int32)
-    block = jnp.searchsorted(sort_begins, pos, side="right") - 1
-    pos_leaf = sort_leaf[jnp.clip(block, 0, num_leaves - 1)]
-    rows = jnp.minimum(part.order[:num_data], num_data - 1)
-    return jnp.zeros((num_data,), jnp.int32).at[rows].set(
-        pos_leaf, mode="promise_in_bounds")
+    with jax.named_scope("lgbm.leaf_ids"):
+        # empty leaves sort past every real range
+        begins = jnp.where(part.leaf_count > 0, part.leaf_begin,
+                           jnp.int32(num_data + 1))
+        sort_begins, sort_leaf = lax.sort(
+            (begins, jnp.arange(num_leaves, dtype=jnp.int32)), num_keys=1)
+        pos = jnp.arange(num_data, dtype=jnp.int32)
+        block = jnp.searchsorted(sort_begins, pos, side="right") - 1
+        pos_leaf = sort_leaf[jnp.clip(block, 0, num_leaves - 1)]
+        rows = jnp.minimum(part.order[:num_data], num_data - 1)
+        return jnp.zeros((num_data,), jnp.int32).at[rows].set(
+            pos_leaf, mode="promise_in_bounds")
 
 
 def frontier_slots_from_partition(part: RowPartition, leaves: jnp.ndarray,

@@ -22,12 +22,14 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
 from ..config import Config
 from ..log import Log, LightGBMError, check
+from ..obs.trace import recorder
 from .binning import BinMapper, BinType, MissingType
 from .bundle import bundle_offsets, find_bundles
 
@@ -120,6 +122,14 @@ def _parse_categorical(categorical_feature, feature_names: List[str]) -> List[in
     return sorted(set(out))
 
 
+def bytes_copied(src, out: np.ndarray) -> int:
+    """What a conversion to ``out`` copied: 0 when ``out`` is ``src``'s own
+    memory (the input was taken as it came), else all of ``out``."""
+    if isinstance(src, np.ndarray) and np.may_share_memory(src, out):
+        return 0
+    return int(out.nbytes)
+
+
 class BinnedDataset:
     """The core training artifact: bin matrix + mappers + metadata.
 
@@ -192,7 +202,9 @@ class BinnedDataset:
                 raise LightGBMError("Data should be 2-D, got shape %s"
                                     % (data.shape,))
             n, f = data.shape
-            data64 = np.asarray(data, dtype=np.float64)
+            with recorder.span("ingest.to_float64") as span:
+                data64 = np.asarray(data, dtype=np.float64)
+                span.counts["bytes_copied"] = bytes_copied(data, data64)
         self = cls()
         self.num_data = n
         self.num_total_features = f
@@ -225,41 +237,52 @@ class BinnedDataset:
             cat_idx = set(_parse_categorical(
                 categorical_feature if categorical_feature is not None
                 else config.categorical_feature, self.feature_names))
-            sample_cnt = min(n, config.bin_construct_sample_cnt)
-            if sample_cnt < n:
-                rng = np.random.RandomState(config.data_random_seed)
-                sample_rows = np.sort(rng.choice(n, sample_cnt, replace=False))
-                # row id -> sample position (-1 = not sampled)
-                sample_pos = np.full(n, -1, np.int64)
-                sample_pos[sample_rows] = np.arange(sample_cnt)
-            else:
-                sample_rows = None
-                sample_pos = None
+            with recorder.span("ingest.sample_rows", rows=n):
+                sample_cnt = min(n, config.bin_construct_sample_cnt)
+                if sample_cnt < n:
+                    rng = np.random.RandomState(config.data_random_seed)
+                    sample_rows = np.sort(rng.choice(n, sample_cnt,
+                                                     replace=False))
+                    # row id -> sample position (-1 = not sampled)
+                    sample_pos = np.full(n, -1, np.int64)
+                    sample_pos[sample_rows] = np.arange(sample_cnt)
+                else:
+                    sample_rows = None
+                    sample_pos = None
 
             self.bin_mappers = []
             nz_sample: List[np.ndarray] = []   # per feature, sample positions
-            for j in range(f):
-                rows, vals = column_nonzeros(j)
-                if sample_pos is not None:
-                    pos = sample_pos[rows]
-                    keep = pos >= 0
-                    rows_s, vals_s = pos[keep], vals[keep]
-                else:
-                    rows_s, vals_s = rows, vals
-                nz_sample.append(rows_s.astype(np.int64))
-                mapper = BinMapper()
-                # only non-zero values feed FindBin, like the reference's
-                # sampler (NaNs fail both comparisons and are kept)
-                mapper.find_bin(
-                    vals_s, total_sample_cnt=sample_cnt,
-                    max_bin=config.max_bin,
-                    min_data_in_bin=config.min_data_in_bin,
-                    min_split_data=config.min_data_in_leaf,
-                    bin_type=(BinType.CATEGORICAL if j in cat_idx
-                              else BinType.NUMERICAL),
-                    use_missing=config.use_missing,
-                    zero_as_missing=config.zero_as_missing)
-                self.bin_mappers.append(mapper)
+            with recorder.span("ingest.find_bins", columns=f,
+                               sample_rows=sample_cnt) as span:
+                scan_s = find_s = 0.0
+                t = time.perf_counter()
+                for j in range(f):
+                    rows, vals = column_nonzeros(j)
+                    if sample_pos is not None:
+                        pos = sample_pos[rows]
+                        keep = pos >= 0
+                        rows_s, vals_s = pos[keep], vals[keep]
+                    else:
+                        rows_s, vals_s = rows, vals
+                    nz_sample.append(rows_s.astype(np.int64))
+                    mapper = BinMapper()
+                    t_scan = time.perf_counter()
+                    # only non-zero values feed FindBin, like the reference's
+                    # sampler (NaNs fail both comparisons and are kept)
+                    mapper.find_bin(
+                        vals_s, total_sample_cnt=sample_cnt,
+                        max_bin=config.max_bin,
+                        min_data_in_bin=config.min_data_in_bin,
+                        min_split_data=config.min_data_in_leaf,
+                        bin_type=(BinType.CATEGORICAL if j in cat_idx
+                                  else BinType.NUMERICAL),
+                        use_missing=config.use_missing,
+                        zero_as_missing=config.zero_as_missing)
+                    self.bin_mappers.append(mapper)
+                    scan_s += t_scan - t
+                    t = time.perf_counter()
+                    find_s += t - t_scan
+                span.counts.update(nonzero_scan_s=scan_s, find_bin_s=find_s)
             self.used_features = [j for j in range(f)
                                   if not self.bin_mappers[j].is_trivial]
             if not self.used_features:
@@ -267,50 +290,59 @@ class BinnedDataset:
                             "values are constant.")
 
             # ---- EFB grouping (dataset.cpp:67-177 analog) ----------------
-            if config.enable_bundle and len(self.used_features) > 1:
-                bundles = find_bundles(
-                    [nz_sample[j] for j in self.used_features], sample_cnt,
-                    [self.bin_mappers[j].num_bin for j in self.used_features],
-                    config.max_conflict_rate,
-                    sparse_threshold=config.sparse_threshold)
-                # bundle entries index into used_features; map back
-                bundles = [[self.used_features[i] for i in b] for b in bundles]
-            else:
-                bundles = [[j] for j in self.used_features]
-            self.col_features = bundles
-            self.col_offsets = []
-            self.col_num_bin = []
-            num_bin_of = {j: self.bin_mappers[j].num_bin
-                          for j in self.used_features}
-            for b in bundles:
-                offs, total = bundle_offsets(b, num_bin_of)
-                self.col_offsets.append(offs)
-                self.col_num_bin.append(total)
-            n_bundled = sum(1 for b in bundles if len(b) > 1)
-            if n_bundled:
-                Log.info("EFB: %d features bundled into %d columns "
-                         "(%d multi-feature bundles)",
-                         len(self.used_features), len(bundles), n_bundled)
-            self.col_packed = [False] * len(self.col_features)
-            # mesh learners shard/pad the feature axis assuming an identity
-            # feature->column layout; keep packing single-device-only (the
-            # booster raises if a packed dataset reaches a mesh anyway)
-            if config.enable_nbit_packing and \
-                    config.tree_learner == "serial" and not config.mesh_shape:
-                # tpu_bin_packing=nibble raises the joint-code cap to the
-                # full byte (256) so every <=16-bin pair shares a column
-                # regardless of the dataset's histogram width — the
-                # Dense4bitsBin "two bins per byte" applied dataset-wide
-                # (core/binpack.py). Other modes keep the conservative
-                # cap (B never grows past the widest existing column).
-                from ..core.binpack import resolve_bin_packing
-                from ..core.partition import tpu_shaped_backend
-                mode = resolve_bin_packing(
-                    getattr(config, "tpu_bin_packing", "auto"),
-                    streamed=False, tpu_shaped=tpu_shaped_backend(),
-                    col_num_bin=self.col_num_bin)
-                self._pack_small_pairs(
-                    pair_cap=256 if mode == "nibble" else 0)
+            with recorder.span("ingest.bundle") as span:
+                if config.enable_bundle and len(self.used_features) > 1:
+                    bundles = find_bundles(
+                        [nz_sample[j] for j in self.used_features],
+                        sample_cnt,
+                        [self.bin_mappers[j].num_bin
+                         for j in self.used_features],
+                        config.max_conflict_rate,
+                        sparse_threshold=config.sparse_threshold)
+                    # bundle entries index into used_features; map back
+                    bundles = [[self.used_features[i] for i in b]
+                               for b in bundles]
+                else:
+                    bundles = [[j] for j in self.used_features]
+                self.col_features = bundles
+                self.col_offsets = []
+                self.col_num_bin = []
+                num_bin_of = {j: self.bin_mappers[j].num_bin
+                              for j in self.used_features}
+                for b in bundles:
+                    offs, total = bundle_offsets(b, num_bin_of)
+                    self.col_offsets.append(offs)
+                    self.col_num_bin.append(total)
+                n_bundled = sum(1 for b in bundles if len(b) > 1)
+                if n_bundled:
+                    Log.info("EFB: %d features bundled into %d columns "
+                             "(%d multi-feature bundles)",
+                             len(self.used_features), len(bundles),
+                             n_bundled)
+                self.col_packed = [False] * len(self.col_features)
+                # mesh learners shard/pad the feature axis assuming an
+                # identity feature->column layout; keep packing
+                # single-device-only (the booster raises if a packed dataset
+                # reaches a mesh anyway)
+                if config.enable_nbit_packing and \
+                        config.tree_learner == "serial" \
+                        and not config.mesh_shape:
+                    # tpu_bin_packing=nibble raises the joint-code cap to
+                    # the full byte (256) so every <=16-bin pair shares a
+                    # column regardless of the dataset's histogram width —
+                    # the Dense4bitsBin "two bins per byte" applied
+                    # dataset-wide (core/binpack.py). Other modes keep the
+                    # conservative cap (B never grows past the widest
+                    # existing column).
+                    from ..core.binpack import resolve_bin_packing
+                    from ..core.partition import tpu_shaped_backend
+                    mode = resolve_bin_packing(
+                        getattr(config, "tpu_bin_packing", "auto"),
+                        streamed=False, tpu_shaped=tpu_shaped_backend(),
+                        col_num_bin=self.col_num_bin)
+                    self._pack_small_pairs(
+                        pair_cap=256 if mode == "nibble" else 0)
+                span.counts["bundles"] = len(self.col_features)
 
         # ---- build the stored uint8 columns ------------------------------
         def full_bin_column(j):
@@ -325,26 +357,32 @@ class BinnedDataset:
             return m.values_to_bins(data64[:, j]).astype(np.uint8)
 
         cols = []
-        for ci, (feats, offs) in enumerate(zip(self.col_features,
-                                               self.col_offsets)):
-            if self._col_is_packed(ci):
-                ja, jb = feats
-                nb_b = self.bin_mappers[jb].num_bin
-                colb = (full_bin_column(ja).astype(np.uint16) * nb_b
-                        + full_bin_column(jb)).astype(np.uint8)
-            elif len(feats) == 1 and offs[0] == 0:
-                colb = full_bin_column(feats[0])
-            else:
-                colb = np.zeros(n, np.uint8)
-                for off, j in zip(offs, feats):
-                    m = self.bin_mappers[j]
-                    rows, vals = column_nonzeros(j)
-                    bins = m.values_to_bins(vals)
-                    sel = bins != m.default_bin
-                    colb[rows[sel]] = (off + bins[sel]).astype(np.uint8)
-            cols.append(colb)
-        self.X_binned = (np.stack(cols, axis=1) if cols
-                         else np.zeros((n, 0), dtype=np.uint8))
+        with recorder.span("ingest.bin_columns",
+                           columns=len(self.col_features),
+                           values=n * len(self.used_features)):
+            for ci, (feats, offs) in enumerate(zip(self.col_features,
+                                                   self.col_offsets)):
+                if self._col_is_packed(ci):
+                    ja, jb = feats
+                    nb_b = self.bin_mappers[jb].num_bin
+                    colb = (full_bin_column(ja).astype(np.uint16) * nb_b
+                            + full_bin_column(jb)).astype(np.uint8)
+                elif len(feats) == 1 and offs[0] == 0:
+                    colb = full_bin_column(feats[0])
+                else:
+                    colb = np.zeros(n, np.uint8)
+                    for off, j in zip(offs, feats):
+                        m = self.bin_mappers[j]
+                        rows, vals = column_nonzeros(j)
+                        bins = m.values_to_bins(vals)
+                        sel = bins != m.default_bin
+                        colb[rows[sel]] = (off
+                                           + bins[sel]).astype(np.uint8)
+                cols.append(colb)
+        with recorder.span("ingest.stack") as span:
+            self.X_binned = (np.stack(cols, axis=1) if cols
+                             else np.zeros((n, 0), dtype=np.uint8))
+            span.counts["bytes"] = self.X_binned.nbytes
 
         self.metadata = Metadata(n)
         if label is not None:

@@ -13,9 +13,11 @@ serving:
   and the perf gate.
 - ``perfgate``: deterministic semantic perf counters + baseline comparison
   (``PERF_COUNTERS.json``, ``tools/perf_gate.py``).
-- ``trace``: host-side span timers (device sync only at span close), a
-  JSON-lines event stream, and an on-demand ``jax.profiler`` Perfetto
-  capture helper for a configurable iteration window.
+- ``trace``: the one span recorder (always on, a bounded in-memory ring,
+  every span also an annotation on the profiler's clock), a JSON-lines event
+  stream, an on-demand ``jax.profiler`` Perfetto capture helper for a
+  configurable iteration window, and ``capture_phases``, which reduces a
+  capture to device seconds by ``lgbm.*`` scope.
 - ``health``: host dispatch for device-side health flags (non-finite
   grad/hess, zero-positive-gain waves) that the training step piggy-backs
   on existing reductions — warn, checkpoint-and-abort, or raise.
@@ -38,9 +40,9 @@ serving:
   ``observability=none|basic|full`` config knob that the boosting loop
   drives.
 
-Everything is off by default (``observability=none``) and the instrumented
-code paths collapse to no-ops so the training loop's compiled program is
-byte-identical when telemetry is disabled.
+Nothing is exported by default (``observability=none``): spans are still
+recorded in memory, every other hook is a no-op, and the training loop's
+compiled program is byte-identical to an uninstrumented build.
 """
 from .health import (HEALTH_NONFINITE, HEALTH_NONFINITE_GAIN,  # noqa: F401
                      HEALTH_STUMP, HEALTH_VEC_LEN, HEALTH_WAVES,
@@ -56,8 +58,8 @@ from .reqtrace import (NULL_REQ_SPAN, NULL_TRACER,  # noqa: F401
 from .runtime import TrainingObs, resolve_health_action  # noqa: F401
 from .server import StatsServer  # noqa: F401
 from .slo import SloEngine, SloSpec  # noqa: F401
-from .trace import (EventStream, Tracer, perfetto_trace,  # noqa: F401
-                    span)
+from .trace import (EventStream, Tracer, capture_phases,  # noqa: F401
+                    perfetto_trace, recorded_spans)
 from .distributed import (DistributedObs, FlightRecorder,  # noqa: F401
                           merge_prometheus_texts, process_env,
                           straggler_skew)

@@ -355,7 +355,8 @@ def span_wall_times(registry: Optional[MetricsRegistry] = None,
         span = m.label_dict.get("span")
         if not span:
             continue
-        out[span] = (float(m.total), float(m.count))
+        # spans are dotted (train.block), cost-model entries are not
+        out[span.replace(".", "_")] = (float(m.total), float(m.count))
     return out
 
 

@@ -60,6 +60,7 @@ import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..log import Log
+from . import trace
 from .registry import MetricsRegistry, get_registry
 
 
@@ -209,6 +210,7 @@ class FlightRecorder:
         if self._installed:
             return
         self._installed = True
+        trace.feed_flight(self)     # closed spans, as events are fed
         self._prev_hook = sys.excepthook
         sys.excepthook = self._excepthook
         if threading.current_thread() is threading.main_thread():
@@ -222,6 +224,8 @@ class FlightRecorder:
         if not self._installed:
             return
         self._installed = False
+        if trace._flight is self:
+            trace.feed_flight(None)
         # == not `is`: attribute access mints a fresh bound method, so an
         # identity check never matches the one install() stored
         if sys.excepthook == self._excepthook:

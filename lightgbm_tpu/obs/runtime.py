@@ -3,9 +3,10 @@
 Built once in ``GBDT._setup_train`` from the config knobs and handed to
 the boosting loop, which drives it at three intensities:
 
-- ``observability=none``  (level 0): every hook is a no-op and the
-  health branch stays out of the compiled program — the training step is
-  byte-identical to an uninstrumented build.
+- ``observability=none``  (level 0): spans are recorded in memory
+  (obs/trace.py) and nothing is exported; every other hook is a no-op and
+  the health branch stays out of the compiled program — the training step
+  is byte-identical to an uninstrumented build.
 - ``observability=basic`` (level 1): the fused 64-iteration block path is
   kept; one sync + span per block, per-iteration events derived from the
   block, health vectors checked per block, HBM gauge per block.  Target
@@ -30,7 +31,7 @@ from .registry import get_registry
 from .reqtrace import NULL_REQ_SPAN, NULL_TRACER, RequestTracer
 from .server import StatsServer
 from .slo import SloEngine
-from .trace import EventStream, PerfettoWindow, Tracer, _NULL_SPAN
+from .trace import EventStream, PerfettoWindow, Tracer
 
 LEVELS = {"none": 0, "basic": 1, "full": 2}
 
@@ -221,10 +222,10 @@ class TrainingObs:
         return rebuild
 
     # ------------------------------------------------------------ hooks
-    def span(self, name: str, sync=None, **fields):
-        if self.level == 0:
-            return _NULL_SPAN
-        return self.tracer.span(name, sync=sync, **fields)
+    def span(self, name: str, **counts):
+        """Always recorded (obs/trace.py); exported only when the level is
+        above none."""
+        return self.tracer.span(name, **counts)
 
     def event(self, name: str, **fields) -> None:
         if self.events is not None:

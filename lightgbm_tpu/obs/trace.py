@@ -1,11 +1,16 @@
-"""Spans, event streams and Perfetto capture for the real training loop.
+"""Spans, event streams, Perfetto capture and its reduction by phase.
 
-The span API is host-side: a ``with tracer.span("hist_build")`` block
-times wall clock and only touches the device at span CLOSE, where it can
-``block_until_ready`` the arrays handed to it — one sync per span, never
-per op, so the async dispatch pipeline inside a span stays intact.  When
-tracing is disabled the span object is a shared no-op constant and the
-``with`` costs two trivial method calls.
+A span is host-side: ``with tracer.span("train.block")`` records name,
+start and end (``perf_counter_ns``), the span that was open on the thread
+when it opened, the thread, whether the body raised, and a small dict of
+numeric counts. Closed spans sit in ONE bounded ring for the process
+(``recorded_spans()``), and every span also enters a
+``jax.profiler.TraceAnnotation("lgbm." + name)``, so a profiler session
+started by anyone holds the program's spans on the device trace's clock.
+Recording is always on (about 2 us a span) and never touches the device;
+``observability=`` decides what a ``Tracer`` EXPORTS (registry summaries,
+the event stream); a span itself never waits for the device. Spans sit at layer boundaries only: never per row, column, tile or
+serving request (``obs/reqtrace.py`` keeps those).
 
 Events are JSON-lines (one object per line, ``ts`` + ``event`` keys
 always present), append-only and flushed per write so a preempted run
@@ -15,17 +20,30 @@ Perfetto capture rides ``jax.profiler.start_trace/stop_trace``; the
 trace lands under ``<dir>/plugins/profile/...`` and loads in
 ui.perfetto.dev or TensorBoard.  Capture is process-global in jax, so
 the helper refuses to nest instead of crashing mid-train.
+``capture_phases(dir)`` reduces such a capture to device seconds by
+``lgbm.*`` scope and idle seconds by host span (``tools/trace_phases.py``
+prints it).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import json
+import re
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, Iterable, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from ..log import Log
 from .registry import MetricsRegistry, get_registry
+
+
+# every scope the device program names and every host annotation a span
+# writes starts with this, so one prefix finds both in a capture
+SCOPE_PREFIX = "lgbm."
 
 
 class EventStream:
@@ -68,6 +86,9 @@ class EventStream:
             self._ring.append(rec)
         return rec
 
+    def mirrors_into(self, ring) -> bool:
+        return ring is not None and self._ring is ring
+
     def flush(self, fsync: bool = False) -> None:
         """Push buffered lines to the OS and, with ``fsync=True``, to
         disk — called from the crash paths (HealthMonitor abort, the
@@ -89,47 +110,131 @@ class EventStream:
                 self._fh.close()
 
 
-class _NullSpan:
-    """Disabled span: shared constant, ~free to enter/exit."""
+# ------------------------------------------------------------ spans
+# ONE bounded ring of closed spans for the process, whatever tracer closed
+# them: recording is always on and costs a couple of microseconds a span;
+# ``observability=`` only decides what a Tracer EXPORTS. ``start_ns`` and
+# ``end_ns`` are ``time.perf_counter_ns()``; ``WALL_ANCHOR`` pairs one
+# wall-clock reading with one perf-counter reading, so
+# ``wall_ns = WALL_ANCHOR[0] + (t_ns - WALL_ANCHOR[1])``.
+RING_SIZE = 4096
+WALL_ANCHOR = (time.time_ns(), time.perf_counter_ns())
+_ring = collections.deque(maxlen=RING_SIZE)
+_ring_lock = threading.Lock()
+_ids = itertools.count(1)
+_open = threading.local()      # .stack: this thread's open spans, outermost first
+_flight = None                 # the armed FlightRecorder, fed closed spans
 
-    __slots__ = ()
 
-    def __enter__(self):
-        return self
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
 
-    def __exit__(self, *exc):
-        return False
+
+def feed_flight(recorder) -> None:
+    """Arm (or, with None, disarm) the flight recorder that sees every
+    closed span no event stream already mirrored into it."""
+    global _flight
+    _flight = recorder
 
 
-_NULL_SPAN = _NullSpan()
+def recorded_spans() -> List[Dict]:
+    """The ring's closed spans, oldest first, as plain dicts: ``id``,
+    ``name``, ``start_ns``, ``end_ns``, ``parent`` (the id of the span
+    open on the same thread when this one opened, else None), ``thread``,
+    ``failed`` and ``counts``."""
+    with _ring_lock:
+        spans = list(_ring)
+    return [s.as_dict() for s in spans]
 
 
 class _Span:
-    def __init__(self, tracer: "Tracer", name: str, sync, fields: Dict):
-        self._tracer = tracer
+    """One span: a context manager while open, a record once closed."""
+
+    __slots__ = ("id", "name", "start_ns", "end_ns", "parent", "thread",
+                 "failed", "counts", "_tracer", "_annotation")
+
+    def __init__(self, tracer: "Tracer", name: str, counts: Dict):
+        self.id = next(_ids)
         self.name = name
-        self._sync = sync
-        self._fields = fields
-        self.duration_s = 0.0
+        self.counts = counts
+        self.start_ns = self.end_ns = 0
+        self.parent = None
+        self.thread = threading.get_ident()
+        self.failed = False
+        self._tracer = tracer
+        self._annotation = None
+
+    @property
+    def duration_s(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
+
+    def as_dict(self) -> Dict:
+        return {"id": self.id, "name": self.name, "start_ns": self.start_ns,
+                "end_ns": self.end_ns, "parent": self.parent,
+                "thread": self.thread, "failed": self.failed,
+                "counts": dict(self.counts)}
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        stack = _stack()
+        if stack:
+            self.parent = stack[-1].id
+        stack.append(self)
+        # the profiler's clock: whenever ANY jax.profiler session runs, the
+        # span lies in its /host:CPU plane beside the device's ops
+        self._annotation = TraceAnnotation(SCOPE_PREFIX + self.name)
+        self._annotation.__enter__()
+        self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        if self._sync is not None:
-            try:
-                import jax
-                jax.block_until_ready(self._sync)  # lgbm-lint: disable=LGL103 span close
-            except Exception:
-                pass
-        self.duration_s = time.perf_counter() - self._t0
-        self._tracer._close(self, failed=exc_type is not None)
+        self.end_ns = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
+        stack = _stack()
+        if self in stack:   # with what it still held, if closed out of order
+            del stack[stack.index(self):]
+        self.failed = exc_type is not None
+        _keep(self)
         return False
 
 
+def _keep(s: _Span) -> None:
+    with _ring_lock:
+        _ring.append(s)
+    mirrored = s._tracer._export(s)
+    if _flight is not None and not mirrored:
+        _flight.record("span", **_span_fields(s))
+
+
+def _span_fields(s: _Span) -> Dict:
+    """A closed span as the fields of a ``span`` event."""
+    wall_ns = WALL_ANCHOR[0] + (s.start_ns - WALL_ANCHOR[1])
+    return dict(s.counts, span=s.name, span_id=s.id, parent=s.parent,
+                thread=s.thread, start_ts=round(wall_ns / 1e9, 6),
+                dur_s=round(s.duration_s, 6), failed=s.failed)
+
+
+def record_span(name: str, duration_s: float, **counts) -> None:
+    """Record a span that has already happened and ends now (the compile
+    hook's: jax reports a duration after the fact). Its parent is the span
+    open on this thread."""
+    s = _Span(recorder, name, counts)
+    s.end_ns = time.perf_counter_ns()
+    s.start_ns = s.end_ns - int(duration_s * 1e9)
+    stack = _stack()
+    if stack:
+        s.parent = stack[-1].id
+    _keep(s)
+
+
 class Tracer:
-    """Span factory bound to a registry summary + optional event stream."""
+    """Span factory. Every span is recorded in the process ring; an
+    ``enabled`` tracer also EXPORTS each one it closes (a registry summary,
+    the event stream when it has one)."""
 
     def __init__(self, enabled: bool = True,
                  registry: Optional[MetricsRegistry] = None,
@@ -140,28 +245,28 @@ class Tracer:
         self.events = events
         self._metric = metric
 
-    def span(self, name: str, sync=None, **fields):
-        """Open a timed span.  ``sync``: arrays to ``block_until_ready``
-        at close (ONE sync point); extra ``fields`` land on the event."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, sync, fields)
+    def span(self, name: str, **counts) -> _Span:
+        """Open a span. ``counts`` start the span's dict of numeric counts,
+        which the body may add to through ``span.counts``."""
+        return _Span(self, name, counts)
 
-    def _close(self, s: _Span, failed: bool) -> None:
+    def _export(self, s: _Span) -> bool:
+        """True when the span went to an event stream that mirrors into
+        the flight recorder."""
+        if not self.enabled:
+            return False
         self._registry.summary(
             self._metric, "Wall-clock span durations.",
             labels={"span": s.name}).observe(s.duration_s)
-        if self.events is not None:
-            self.events.write("span", span=s.name,
-                              dur_s=round(s.duration_s, 6),
-                              failed=failed, **s._fields)
+        if self.events is None:
+            return False
+        self.events.write("span", **_span_fields(s))
+        return self.events.mirrors_into(_flight)
 
 
-def span(name: str, sync=None, **fields):
-    """Module-level convenience: an always-on span against the global
-    registry (no event stream).  Library code should prefer a
-    ``TrainingObs``-owned tracer, which respects ``observability=none``."""
-    return Tracer(enabled=True).span(name, sync=sync, **fields)
+# the process's own tracer for code that has no TrainingObs at hand (ingest,
+# the compile hook): records, exports nothing
+recorder = Tracer(enabled=False)
 
 
 # ------------------------------------------------------------ perfetto
@@ -235,3 +340,210 @@ class PerfettoWindow:
         if self._cm is not None:
             cm, self._cm = self._cm, None
             cm.__exit__(None, None, None)
+
+
+# ------------------------------------------------------------ reduction
+# What a v5e capture holds (looked at by hand, PR 26 and PR 27): plane
+# ``/device:TPU:n`` has the lines ``XLA Modules`` (one event per executable
+# run, named ``jit_run_block(<fingerprint>)``) and ``XLA Ops`` (every HLO
+# op, NESTED: a ``%while`` event spans the ops of its body), so busy time
+# is the union of the LEAF events. A device event carries NO scope: its name
+# is the HLO instruction's text without metadata (``%fusion.243 = ...``) and
+# its stats are times. The scope sits in plane ``/host:metadata``, whose
+# event metadata (one per executable, same name as the module's events)
+# holds the stat ``Hlo Proto``: the compiled module, where every
+# instruction has ``metadata.op_name``
+# (``jit(run_block)/while/body/.../lgbm.partition_scatter/scatter``). So an
+# op's scoped name is looked up by (module, instruction name).
+# ``jax.profiler.ProfileData`` does not expose event metadata, hence the few
+# lines of protobuf wire format below. Plane ``/host:CPU`` holds the
+# TraceAnnotations, the spans' among them, on the same clock.
+_DEVICE_PLANE = "/device:TPU:"
+_OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
+_HOST_PLANE = "/host:CPU"
+_HLO_PLANE = "/host:metadata"
+_SCOPE_RE = re.compile(re.escape(SCOPE_PREFIX) + r"[A-Za-z0-9_]+")
+
+Event = Tuple[str, int, int]           # (text, start_ns, duration_ns)
+
+
+def _varint(buf, i):
+    value = shift = 0
+    while True:
+        byte = buf[i]
+        i += 1
+        value |= (byte & 0x7F) << shift
+        if byte < 0x80:
+            return value, i
+        shift += 7
+
+
+def _wire_fields(buf):
+    """(field number, value) of one protobuf message: ints for varints,
+    memoryviews for length-delimited and fixed-width fields."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            value, i = _varint(buf, i)
+        else:
+            if kind == 2:
+                size, i = _varint(buf, i)
+            elif kind in (1, 5):
+                size = 8 if kind == 1 else 4
+            else:
+                raise ValueError("xplane: wire type %d" % kind)
+            value = buf[i:i + size]
+            i += size
+        yield key >> 3, value
+
+
+def _descend(bufs, *numbers):
+    """The sub-messages reached from ``bufs`` through these field numbers."""
+    for number in numbers:
+        bufs = [v for buf in bufs for k, v in _wire_fields(buf)
+                if k == number]
+    return bufs
+
+
+def _text(view) -> str:
+    return bytes(view).decode("utf-8", "replace")
+
+
+def hlo_op_names(xplane: bytes) -> Dict[str, Dict[str, str]]:
+    """{module: {instruction: op_name}} from the compiled modules a capture
+    carries. Field numbers: XSpace.planes=1; XPlane.name=2,
+    .event_metadata=4 (map: value=2); XEventMetadata.name=2, .stats=5;
+    XStat.bytes_value=6; HloProto.hlo_module=1; HloModuleProto
+    .computations=3; HloComputationProto.instructions=2;
+    HloInstructionProto.name=1, .metadata=7; OpMetadata.op_name=2."""
+    out: Dict[str, Dict[str, str]] = {}
+    for plane in _descend([memoryview(xplane)], 1):
+        if [_text(v) for v in _descend([plane], 2)] != [_HLO_PLANE]:
+            continue
+        for meta in _descend([plane], 4, 2):
+            names = out.setdefault(_text(_descend([meta], 2)[0]), {})
+            for instr in _descend([meta], 5, 6, 1, 3, 2):
+                for op_name in _descend([instr], 7, 2):
+                    names[_text(_descend([instr], 1)[0])] = _text(op_name)
+    return out
+
+
+def load_capture(trace_dir: str) -> Dict[str, list]:
+    """{"devices": [[(op_name, start_ns, dur_ns), ...] per chip],
+    "host": [(span name, start_ns, dur_ns), ...]} from the newest
+    ``.xplane.pb`` under ``trace_dir``. ``op_name`` is the scoped name the
+    compiled module gives the event's instruction, and the event's own
+    short name (``fusion.243``) where the module gives none."""
+    import bisect
+    import os
+    from jax.profiler import ProfileData
+    path = None
+    for root, _, files in os.walk(trace_dir):
+        for f in sorted(files):
+            if f.endswith(".xplane.pb"):
+                path = os.path.join(root, f)
+    devices, host = [], []
+    if path is None:
+        return {"devices": devices, "host": host}
+    with open(path, "rb") as fh:
+        raw = fh.read()
+    op_names = hlo_op_names(raw)
+    for plane in ProfileData.from_serialized_xspace(raw).planes:
+        if plane.name.startswith(_DEVICE_PLANE):
+            lines = {line.name: line for line in plane.lines}
+            if _OPS_LINE not in lines:
+                continue
+            modules = sorted(
+                (e.start_ns, e.start_ns + e.duration_ns, e.name)
+                for e in (lines[_MODULES_LINE].events
+                          if _MODULES_LINE in lines else ()))
+            starts = [m[0] for m in modules]
+            ops = []
+            for e in lines[_OPS_LINE].events:
+                short = e.name.split(" = ", 1)[0].lstrip("%")
+                at = bisect.bisect_right(starts, e.start_ns) - 1
+                names = op_names.get(modules[at][2], {}) \
+                    if at >= 0 and e.start_ns < modules[at][1] else {}
+                ops.append((names.get(short, short), e.start_ns,
+                            e.duration_ns))
+            devices.append(ops)
+        elif plane.name == _HOST_PLANE:
+            for line in plane.lines:
+                host.extend((e.name[len(SCOPE_PREFIX):], e.start_ns,
+                             e.duration_ns) for e in line.events
+                            if e.name.startswith(SCOPE_PREFIX))
+    return {"devices": devices, "host": host}
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The LAST ``lgbm.`` component of an op's name: the innermost scope."""
+    found = _SCOPE_RE.findall(op_name)
+    return found[-1] if found else None
+
+
+def _leaf_events(events: Iterable[Event]) -> List[Event]:
+    """Events that contain no later event, in start order."""
+    ev = sorted(events, key=lambda e: (e[1], -e[2]))
+    return [e for i, e in enumerate(ev)
+            if not (i + 1 < len(ev)
+                    and ev[i + 1][1] + ev[i + 1][2] <= e[1] + e[2])]
+
+
+def reduce_phases(events: Dict[str, list]) -> Optional[Dict]:
+    """Device seconds by scope and idle seconds by host span, averaged
+    over the chips in the capture. None when no operation ran on a device
+    or NO op carries an ``lgbm.`` scope at all (an executable compiled by
+    a build without scopes and loaded from the compile cache shows none):
+    nothing to read is never read as 0."""
+    chips = [_leaf_events(dev) for dev in events["devices"]]
+    chips = [leaves for leaves in chips if leaves]
+    if not chips:
+        return None
+    busy_ns, unscoped_ns = 0, 0
+    scope_ns: Dict[str, int] = {}
+    idle_ns: Dict[str, int] = {}
+    for leaves in chips:
+        end = None
+        for op_name, start, dur in leaves:
+            scope = scope_of(op_name)
+            if scope is None:
+                unscoped_ns += dur
+            else:
+                scope_ns[scope] = scope_ns.get(scope, 0) + dur
+            if end is None or start >= end:
+                if end is not None and start > end:
+                    span = _innermost_span(events["host"],
+                                           end + (start - end) // 2)
+                    idle_ns[span] = idle_ns.get(span, 0) + start - end
+                busy_ns += dur
+                end = start + dur
+            elif start + dur > end:
+                busy_ns += start + dur - end
+                end = start + dur
+    if not scope_ns:
+        return None
+    per = 1e9 * len(chips)
+    by_size = lambda d: dict(sorted(((k, v / per) for k, v in d.items()),
+                                    key=lambda kv: -kv[1]))
+    return {"busy_s": busy_ns / per, "by_scope": by_size(scope_ns),
+            "unscoped_s": unscoped_ns / per, "idle_by_span": by_size(idle_ns)}
+
+
+def _innermost_span(host: Iterable[Event], t: int) -> str:
+    """The shortest host span that holds instant ``t``."""
+    best, best_dur = "none", None
+    for name, start, dur in host:
+        if start <= t < start + dur and (best_dur is None or dur < best_dur):
+            best, best_dur = name, dur
+    return best
+
+
+def capture_phases(trace_dir: str) -> Optional[Dict]:
+    """One ``jax.profiler`` capture as a table: ``busy_s``, ``by_scope``
+    (device seconds under each ``lgbm.*`` scope), ``unscoped_s`` and
+    ``idle_by_span`` (device gaps by the innermost ``lgbm.*`` host span
+    their middle falls in)."""
+    return reduce_phases(load_capture(trace_dir))

@@ -16,6 +16,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax._src.core import trace_state_clean
 
 # ---------------------------------------------------------------- compiles
 # Process-wide compile accounting, shared by serving.metrics and the
@@ -31,6 +32,8 @@ import numpy as np
 #   the load — so hits/misses, not the backend count, are what
 #   distinguish a warm start.)
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _counts_lock = threading.Lock()
@@ -42,6 +45,7 @@ _hooks_installed = False
 # thin shim over those series
 from .log import Log  # noqa: E402
 from .obs.registry import get_registry  # noqa: E402
+from .obs.trace import record_span  # noqa: E402
 
 _c_backend = get_registry().counter(
     "lgbm_jax_backend_compiles_total",
@@ -50,6 +54,14 @@ _c_backend_secs = get_registry().counter(
     "lgbm_jax_backend_compile_seconds_total",
     "Seconds spent in XLA backend compilation (or loading a cached "
     "executable) observed via jax.monitoring.")
+_c_trace_secs = get_registry().counter(
+    "lgbm_jax_trace_seconds_total",
+    "Seconds spent tracing Python functions to jaxprs, observed via "
+    "jax.monitoring.")
+_c_lower_secs = get_registry().counter(
+    "lgbm_jax_lower_seconds_total",
+    "Seconds spent lowering jaxprs to MLIR modules, observed via "
+    "jax.monitoring.")
 _c_cache_hit = get_registry().counter(
     "lgbm_jax_compile_cache_hits_total",
     "Persistent compilation-cache hits.")
@@ -58,10 +70,27 @@ _c_cache_miss = get_registry().counter(
     "Persistent compilation-cache misses.")
 
 
+# event -> (span name, seconds counter): each becomes a recorded span under
+# the program span open on the compiling thread, with jax's ``fun_name``
+# kept, so a compile has a name and a block
+_COMPILE_PHASES = {
+    _TRACE_EVENT: ("jax.trace", _c_trace_secs),
+    _LOWER_EVENT: ("jax.lower", _c_lower_secs),
+    _BACKEND_COMPILE_EVENT: ("jax.backend_compile", _c_backend_secs),
+}
+
+
 def _on_event_duration(event: str, duration: float, **kwargs) -> None:
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    if event == _TRACE_EVENT and not trace_state_clean():
+        return   # a jit traced inside another's trace: the outer one holds it
+    name, seconds = phase
     if event == _BACKEND_COMPILE_EVENT:
         _c_backend.inc()
-        _c_backend_secs.inc(duration)
+    seconds.inc(duration)
+    record_span(name, duration, fun_name=kwargs.get("fun_name", ""))
 
 
 def _on_event(event: str, **kwargs) -> None:
@@ -94,6 +123,8 @@ def compile_cache_stats() -> Dict[str, int]:
     install_compile_hook()
     return {"backend_compiles": int(_c_backend.value),
             "backend_compile_seconds": float(_c_backend_secs.value),
+            "trace_seconds": float(_c_trace_secs.value),
+            "lower_seconds": float(_c_lower_secs.value),
             "persistent_cache_hits": int(_c_cache_hit.value),
             "persistent_cache_misses": int(_c_cache_miss.value)}
 
