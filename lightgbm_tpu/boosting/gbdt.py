@@ -293,8 +293,17 @@ class GBDT:
         self.obs = TrainingObs.disabled()
 
         if train_data is not None:
-            with recorder.span("train.setup", rows=train_data.num_data):
+            with recorder.span("train.setup",
+                               rows=train_data.num_data) as span:
                 self._setup_train(train_data)
+                # which placement the exact grower's tile loop is built
+                # with (0 too where another grower runs)
+                p = self.grow_params
+                span.counts["partition_window_placement"] = int(
+                    p.use_partition and not p.frontier_mode
+                    and p.batch_splits == 0
+                    and partition_mod.window_placement(p.hist_impl,
+                                                       p.vmapped_classes))
 
     # ------------------------------------------------------------ setup
     def _setup_stream_mesh(self, ds) -> np.ndarray:
@@ -596,7 +605,8 @@ class GBDT:
         # than sequential per-class growth on a v5e chip (1.65 vs 0.88
         # s/iter at 500k x 28 x 5 classes, docs/Performance.md "Round 4",
         # one pre-PR-1 datapoint) — vmap serializes the growth while_loop
-        # in lockstep AND forces the sort-placement fast path off.
+        # in lockstep AND forces the element scatter (a batched window
+        # start is a scatter again: partition.window_placement).
         # TPU-shaped backends (partition.tpu_shaped_backend — NOT a
         # hist-impl proxy, so f64/matmul TPU runs are covered too)
         # therefore grow classes sequentially even with an
@@ -686,11 +696,13 @@ class GBDT:
                 cat_smooth=cfg.cat_smooth, cat_l2=cfg.cat_l2,
                 max_cat_to_onehot=cfg.max_cat_to_onehot,
                 min_data_per_group=cfg.min_data_per_group),
-            # 0 = auto: 4096 on TPU (round-4 on-chip sweep: 1.97 vs 1.80
-            # iters/s at 16384; 65536+ strictly worse), 16384 on CPU
-            # (fewer while-loop trips win when indexed ops are cheap)
+            # 0 = auto: 4096 where the tile loop is TPU-shaped (round-4
+            # on-chip sweep: 1.97 vs 1.80 iters/s at 16384; 65536+
+            # strictly worse), 16384 on CPU (fewer while-loop trips win
+            # when indexed ops are cheap); the same rule picks the tile's
+            # placement (partition.window_placement)
             row_chunk=(int(cfg.tpu_row_chunk) or
-                       (4096 if hist_impl.startswith("pallas")
+                       (4096 if partition_mod.tpu_tiles(hist_impl)
                         else 16384)),
             # CPU: XLA scatter-add wins; TPU: the Pallas VMEM-accumulator
             # kernel is the default device path (the GPUTreeLearner analog,
@@ -1301,7 +1313,7 @@ class GBDT:
             # TPU-shaped, where sequential measured 1.9x faster than vmap
             # even uncapped (docs/Performance.md "Round 4").
             # params.vmapped_classes is the ONE predicate: grow_tree keys
-            # its sort-placement/pool decisions off the same flag this
+            # its placement/pool decisions off the same flag this
             # dispatch uses, so the two can never disagree.
             if k == 1:
                 t1, li1, cb1 = grow_one(g[:, 0], h[:, 0], cegb_state)

@@ -38,8 +38,7 @@ from jax import lax
 from .histogram import build_histogram
 from .partition import (RowPartition, hist_for_leaf, init_partition,
                         leaf_id_from_partition, make_row_gather,
-                        partition_and_hist, sort_placement_profitable,
-                        stack_vals)
+                        partition_and_hist, stack_vals, window_placement)
 from .split import (BestSplit, FeatureMeta, SplitParams, K_EPSILON,
                     K_MIN_SCORE, MISSING_NAN, MISSING_NONE, MISSING_ZERO,
                     calculate_leaf_output, find_best_split, leaf_split_gain,
@@ -107,8 +106,8 @@ class GrowParams(NamedTuple):
     with_cegb_coupled: bool = False
     with_cegb_lazy: bool = False
     # grow_tree is class-batched under jax.vmap (multiclass, uncapped
-    # pool): lax.switch would then run every branch per split, so the
-    # sort-placement fast path must stay off
+    # pool): a batched window start would be a scatter again, so the
+    # partition keeps the element scatter (partition.window_placement)
     vmapped_classes: bool = False
     # histogram pool cap (HistogramPool, feature_histogram.hpp:646-820):
     # 0 = one slot per leaf (unlimited); otherwise S < num_leaves slots with
@@ -850,12 +849,12 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                     meta.default_bin[cur.feature],
                     cur.is_categorical, cur.cat_bitset)
 
-            use_sort = sort_placement_profitable(params.hist_impl,
-                                                 params.vmapped_classes)
             part, leaf_id, hist_left_d, hist_right_d = partition_and_hist(
                 s.part, s.leaf_id, leaf, right_leaf, go_left_rows, valid,
                 params.row_chunk, gather_rows, ncols, b, params.hist_impl,
-                maintain_leaf_id=maintain_lid, use_sort=use_sort,
+                maintain_leaf_id=maintain_lid,
+                windows=window_placement(params.hist_impl,
+                                         params.vmapped_classes),
                 val_dtype=hdt)
             if axis_name is not None:
                 # one collective per split: psum the fused 6-channel

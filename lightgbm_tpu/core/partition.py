@@ -7,37 +7,36 @@ O(rows_in_leaf) instead of O(num_data) per split. The reference keeps
 partitions a leaf's range with per-thread counts + prefix sums; here the
 same invariant is maintained functionally:
 
-- ``order``   [N + chunk] int32 — row ids grouped by leaf (the padded tail
-  holds one trash slot that no leaf range ever covers).
+- ``order``   [N + chunk] int32 — row ids grouped by leaf, then a
+  chunk-long tail pad that no leaf range ever covers.
 - ``leaf_begin`` / ``leaf_count`` [L] int32 — each leaf's contiguous range.
 
-Design notes from profiling on a v5e chip: inside a sequential growth loop,
-dynamic-indexed ops (gather/scatter) cost ~0.4-0.8 ms *each* in latency
-regardless of size up to ~64k elements, while dense full-array ops run at
-memory bandwidth. The layout below therefore minimizes the NUMBER of
-indexed ops per split rather than the elements they touch:
-
-- per-row bins AND values ride behind one make_row_gather closure —
-  bit-packed side by side on the normal path, so a histogram trip does
-  ONE row gather total (two only under vmapped class batching, where
-  packing would copy the shared bin matrix per class);
-- every gather/scatter is annotated promise-in-bounds (indices are clamped
-  or routed to the trash slot first);
-- ``leaf_id`` is NOT maintained per split — it is reconstructed once per
-  tree from the final ranges (leaf_id_from_partition), replacing
-  O(N x depth) scattered writes with one dense searchsorted + one scatter.
-
 Both maintenance and consumption are chunked ``lax.while_loop``s whose trip
-count is data-dependent (ceil(count / chunk)); with the default chunk most
-leaves take a single trip. The partition scatter fills the left child
-forward from the range start and the right child backward from the range
-end, so a single pass suffices (within-leaf row order is irrelevant to
-histogram sums).
+count is data-dependent (ceil(count / chunk)). A split is ONE pass over the
+leaf's tiles: each tile's row ids come from a contiguous slice of ``order``,
+its rows from one gather through them (the analog of the reference's
+ordered-gradient gather, dataset.cpp ConstructHistograms), the histogram
+kernel prices both children, and the tile's ids are placed — lefts forward
+from the range start, rights backward from the range end — so one pass
+suffices. ``leaf_id`` is NOT maintained per split: it is reconstructed
+once per tree from the final ranges (leaf_id_from_partition).
 
-Histogram builds gather the leaf's rows through ``order`` (the analog of the
-reference's ordered-gradient gather, dataset.cpp ConstructHistograms) and
-feed fixed-size [chunk, F] tiles to the same one-hot-matmul / Pallas kernels
-as the full-data path.
+What a v5e charges for the tile loop's ops (PERF.md section 5; traced
+iterations at 26.6M x 67, 255 leaves, ~52,000 tiles of 4,096 rows):
+
+| op                                                | a call | an element |
+| element scatter of the tile's ids into ``order``  | 187 us | 45 ns      |
+| sort of the tile + two window writes (PR 28)      | 7.8 us | 1.9 ns     |
+| row gather, 4,096 rows x 79 B from 2.1 GB         |  37 us |  9 ns      |
+| histogram kernel on the tile, 67 cols x 6 chans   |  80 us | 20 ns      |
+| full-size scatter / gather, 26.6M elements        | 103 / 129 ms | 3.9 / 4.9 ns |
+
+An XLA scatter into HBM costs per ELEMENT, whatever the operand's size, and
+a tile's ids only ever go to two contiguous runs; so where the tile loop is
+TPU-shaped (window_placement) the ids are packed in the tile and written as
+two windows, and the element scatter stays where it is cheap (the CPU
+backends) and under vmap, where a batched window start would turn each
+window back into a scatter.
 """
 from __future__ import annotations
 
@@ -82,9 +81,8 @@ def make_row_gather(xb: jnp.ndarray, vals: jnp.ndarray,
 
     packed=True bit-packs [N, C] uint8 bins and [N, 3] float values side
     by side into one [N, C + 3*itemsize] uint8 array, so a histogram
-    trip does ONE row gather instead of two (round-4 measurement: trip
-    cost is bound by the NUMBER of indexed ops, not the bytes they
-    move); the per-tile unpack is a free bitcast. packed=False keeps
+    trip does ONE row gather instead of two; the per-tile unpack is a
+    free bitcast. packed=False keeps
     two gathers — required under vmapped class-batched growth, where
     the concat would materialize a PER-CLASS copy of the shared bin
     matrix."""
@@ -118,38 +116,37 @@ def tpu_shaped_backend() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def sort_placement_profitable(hist_impl: str, vmapped: bool) -> bool:
-    """Single policy for partition_and_hist's use_sort flag.
+def tpu_tiles(hist_impl: str) -> bool:
+    """The one rule for shaping the tile loop to the TPU: the Pallas
+    histogram spellings (the interpret ones too, so the CPU tests cover
+    what the chip runs). It sets the auto ``row_chunk`` (4096, else 16384)
+    and, through window_placement, how a tile's ids are placed."""
+    return hist_impl.startswith("pallas")
 
-    Round-4 on-chip re-measurement INVERTED the round-2 decision: at the
-    new auto row_chunk (4096; also at 8192/16384) the scatter loop beats
-    the single-trip sort placement on a v5e chip — 2.31 vs 1.97 iters/s
-    at the 1M x 28 bench shape (a 4096-key lax.sort per split costs more
-    than the scatter it replaced). Default is therefore OFF everywhere;
-    ``LIGHTGBM_TPU_SORT_PLACEMENT=1`` re-enables it for experiments, the
-    interpret spellings opt in so CPU tests keep covering the sort
-    branch, and vmapped class-batched growth can never use it
-    (lax.switch under vmap runs every branch per split)."""
-    if vmapped:
-        return False
-    import os
-    ov = os.environ.get("LIGHTGBM_TPU_SORT_PLACEMENT", "").strip().lower()
-    if ov in ("1", "true", "yes", "on"):
-        return True
-    if ov in ("0", "false", "no", "off"):
-        return False
-    if ov:
-        from ..log import Log
-        Log.warning("ignoring unrecognized LIGHTGBM_TPU_SORT_PLACEMENT=%r "
-                    "(use 0 or 1)" % ov)
-    return hist_impl.startswith("pallas") and hist_impl.endswith("interpret")
+
+def window_placement(hist_impl: str, vmapped: bool) -> bool:
+    """Which of partition_and_hist's two placements the tile loop is built
+    with: the contiguous windows where the loop is TPU-shaped, the element
+    scatter elsewhere — and always under vmapped class batching, where a
+    batched start index turns each window write back into a scatter."""
+    return tpu_tiles(hist_impl) and not vmapped
+
+
+def _write_window(order, packed, k, start):
+    """order[start : start + k] = packed[:k], as one read-modify-write of
+    a chunk-long window; every position past the first ``k`` keeps what it
+    held."""
+    chunk = packed.shape[0]
+    w = lax.dynamic_slice(order, (start,), (chunk,))
+    w = jnp.where(jnp.arange(chunk, dtype=jnp.int32) < k, packed, w)
+    return lax.dynamic_update_slice(order, w, (start,))
 
 
 def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
                        go_left_from_rows, valid, chunk: int,
                        gather_rows, num_cols: int, num_bins: int,
                        impl: str, maintain_leaf_id: bool = False,
-                       use_sort: bool = False, val_dtype=jnp.float32):
+                       windows: bool = False, val_dtype=jnp.float32):
     """One pass over ``leaf``'s rows that BOTH partitions the range and
     builds both children's [F, B, 3] histograms.
 
@@ -158,33 +155,49 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
     the parent's rows already gathered for the partition decision, weighting
     them into six value channels (3 per child) prices both children at one
     row visit — fewer total rows touched than smaller-child + subtraction
-    (P vs 1.5P per split), and two fewer indexed ops per split, which is
-    what actually dominates on TPU (see module docstring).
+    (P vs 1.5P per split).
 
     ``go_left_from_rows(rows[chunk, F]) -> bool[chunk]`` evaluates the split
     decision directly on the gathered feature bytes. ``gather_rows`` is a
     make_row_gather() closure owning the bins+values layout (packed:
     ONE row gather per tile serves both the routing bytes and the value
-    channels). ``use_sort`` selects the single-trip sort placement (keep
-    it off under vmap — the batching rule for lax.switch lowers to a
-    select that runs every branch per split, semantically fine but a
-    performance cliff).
+    channels).
+
+    A tile's lefts go forward from the left cursor in tile order, its
+    rights backward from the right cursor; ``windows`` (window_placement)
+    says how, and both ways give the same ``order`` inside [0, N):
+
+    - False: one element scatter of the tile's ids; rows past the leaf's
+      count go to the trash slot at the very end of the tail pad.
+    - True: a sort of the tile packs lefts at the front and rights,
+      reversed, at the back, and two window writes place them. Both
+      windows are front-aligned: the left one starts at ``beg + nl``, the
+      right one at ``beg + cnt - nr - kr`` with the packed tile rolled by
+      ``kr``, so every start is >= ``beg`` >= 0 and start + chunk <=
+      N + chunk, which the tail pad covers — dynamic_slice never clamps
+      and ``order`` needs no front pad. The masks leave the neighbours'
+      ranges and the tail pad as they were.
 
     Returns (new_part, new_leaf_id, hist_left, hist_right).
     """
     n_rows = leaf_id.shape[0]
     f = num_cols
-    order_len = part.order.shape[0]
-    trash = order_len - 1                  # never inside any leaf range
+    trash = part.order.shape[0] - 1        # never inside any leaf range
     beg = part.leaf_begin[leaf]
     cnt = jnp.where(valid, part.leaf_count[leaf], 0)
 
-    def load_tile(start, in_range):
-        """Shared tile load: gather the tile's bins+values rows, decide
-        the split, weight the six child channels, add the histogram
-        tile."""
+    def cond(c):
+        i = c[0]
+        return i * chunk < cnt
+
+    def body(c):
+        i, nl, nr, order_new, lid, acc = c
+        j = jnp.arange(chunk, dtype=jnp.int32)
+        # ahead of the gather, where the audited jaxpr has it
+        with jax.named_scope("lgbm.route_rows"):
+            in_range = (i * chunk + j) < cnt
         with jax.named_scope("lgbm.row_gather"):
-            idx = lax.dynamic_slice(part.order, (start,), (chunk,))
+            idx = lax.dynamic_slice(part.order, (beg + i * chunk,), (chunk,))
             idx_safe = jnp.minimum(idx, n_rows - 1)
             rows, v = gather_rows(idx_safe)                    # [chunk, F/3]
         with jax.named_scope("lgbm.route_rows"):
@@ -195,86 +208,48 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
             v6 = jnp.concatenate([v * is_l[:, None].astype(v.dtype),
                                   v * is_r[:, None].astype(v.dtype)],
                                  axis=1)                       # [chunk, 6]
-        hist = hist_tile_vals(rows, v6, num_bins, impl)
-        return idx, idx_safe, go_left, is_l, is_r, hist
-
-    def maybe_lid(lid, idx_safe, is_r):
-        if not maintain_leaf_id:
-            return lid
-        # max-scatter: right_leaf exceeds every id assigned so far; left
-        # rows keep their id; padded/OOB duplicates contribute 0
-        with jax.named_scope("lgbm.leaf_ids"):
-            val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
-            return lid.at[idx_safe].max(val, mode="promise_in_bounds")
-
-    def cond(c):
-        i = c[0]
-        return i * chunk < cnt
-
-    def body(c):
-        i, nl, nr, order_new, lid, acc = c
-        j = jnp.arange(chunk, dtype=jnp.int32)
-        in_range = (i * chunk + j) < cnt
-        idx, idx_safe, go_left, is_l, is_r, hist = load_tile(
-            beg + i * chunk, in_range)
-        acc = acc + hist
-        # in_range is a prefix mask, so within range the right-side running
-        # count is (position + 1) - left count: one cumsum covers both
+        with jax.named_scope("lgbm.hist_tile"):
+            acc = acc + hist_tile_vals(rows, v6, num_bins, impl)
         with jax.named_scope("lgbm.partition_scatter"):
-            cl = jnp.cumsum(is_l.astype(jnp.int32), dtype=jnp.int32)
-            cr = (j + 1) - cl
-            kl = cl[-1]
-            kr = jnp.sum(in_range.astype(jnp.int32), dtype=jnp.int32) - kl
-            lpos = beg + nl + (cl - is_l)
-            rpos = beg + cnt - 1 - nr - (cr - is_r)
-            pos = jnp.where(go_left, lpos, rpos)
-            pos = jnp.where(in_range, pos, trash)
-            order_new = order_new.at[pos].set(idx,
-                                              mode="promise_in_bounds")
-        lid = maybe_lid(lid, idx_safe, is_r)
+            if windows:
+                kl = jnp.sum(is_l.astype(jnp.int32), dtype=jnp.int32)
+                kr = jnp.sum(is_r.astype(jnp.int32), dtype=jnp.int32)
+                # unique keys: lefts by tile position, then the rows past
+                # the count, then rights by tile position from the back
+                key = jnp.where(is_l, j, jnp.where(is_r, 3 * chunk - j,
+                                                   chunk + j))
+                _, packed = lax.sort((key, idx), num_keys=1,
+                                     is_stable=False)
+                order_new = _write_window(order_new, packed, kl, beg + nl)
+                order_new = _write_window(order_new, jnp.roll(packed, kr),
+                                          kr, beg + cnt - nr - kr)
+            else:
+                # in_range is a prefix mask, so within range the right-side
+                # running count is (position + 1) - left count: one cumsum
+                # covers both
+                cl = jnp.cumsum(is_l.astype(jnp.int32), dtype=jnp.int32)
+                cr = (j + 1) - cl
+                kl = cl[-1]
+                kr = jnp.sum(in_range.astype(jnp.int32),
+                             dtype=jnp.int32) - kl
+                lpos = beg + nl + (cl - is_l)
+                rpos = beg + cnt - 1 - nr - (cr - is_r)
+                pos = jnp.where(go_left, lpos, rpos)
+                pos = jnp.where(in_range, pos, trash)
+                order_new = order_new.at[pos].set(idx,
+                                                  mode="promise_in_bounds")
+        if maintain_leaf_id:
+            # max-scatter: right_leaf exceeds every id assigned so far; left
+            # rows keep their id; padded/OOB duplicates contribute 0
+            with jax.named_scope("lgbm.leaf_ids"):
+                val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
+                lid = lid.at[idx_safe].max(val, mode="promise_in_bounds")
         return (i + 1, nl + kl, nr + kr, order_new, lid, acc)
 
-    def multi_trip(_):
-        init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), part.order,
-                leaf_id, jnp.zeros((f, num_bins, 6), val_dtype))
-        _, nl, nr, order_new, lid, acc = lax.while_loop(cond, body, init)
-        return order_new, lid, nl, nr, acc
-
-    if not use_sort:
-        # two reasons to stay on the bare while_loop (which already handles
-        # cnt == 0 and single trips): on CPU XLA's scatter is cheap and the
-        # sort is not, and under vmap (multiclass class-batched growth)
-        # lax.switch would execute ALL branches per split
-        order_new, leaf_id, n_left, n_right, acc6 = multi_trip(None)
-    else:
-        def single_trip(_):
-            # cnt <= chunk: the whole leaf fits in one tile, and the stable
-            # partition becomes a SORT + one contiguous
-            # dynamic-update-slice — no scatter, no cumsum (both are
-            # latency-bound on TPU). The tail of the slice reads whatever
-            # follows the leaf's range (the next leaf's rows / the
-            # padding); keyed 2 it sorts stably to the back and is written
-            # back unchanged, so the rest of ``order`` is untouched.
-            in_range = jnp.arange(chunk, dtype=jnp.int32) < cnt
-            idx, idx_safe, _, is_l, is_r, acc = load_tile(beg, in_range)
-            with jax.named_scope("lgbm.partition_scatter"):
-                key = jnp.where(is_l, 0,
-                                jnp.where(is_r, 1, 2)).astype(jnp.uint8)
-                _, sidx = lax.sort((key, idx), num_keys=1, is_stable=True)
-                order_new = lax.dynamic_update_slice(part.order, sidx,
-                                                     (beg,))
-            lid = maybe_lid(leaf_id, idx_safe, is_r)
-            return (order_new, lid,
-                    jnp.sum(is_l.astype(jnp.int32), dtype=jnp.int32),
-                    jnp.sum(is_r.astype(jnp.int32), dtype=jnp.int32), acc)
-
-        def dead(_):
-            return (part.order, leaf_id, jnp.int32(0), jnp.int32(0),
-                    jnp.zeros((f, num_bins, 6), val_dtype))
-
-        which = jnp.where(cnt == 0, 0, jnp.where(cnt <= chunk, 1, 2))
-        order_new, leaf_id, n_left, n_right, acc6 = lax.switch(
-            which, [dead, single_trip, multi_trip], None)
+    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), part.order,
+            leaf_id, jnp.zeros((f, num_bins, 6), val_dtype))
+    _, n_left, n_right, order_new, leaf_id, acc6 = lax.while_loop(
+        cond, body, init)
 
     leaf_begin = part.leaf_begin.at[right_leaf].set(
         jnp.where(valid, beg + n_left, part.leaf_begin[right_leaf]))

@@ -202,8 +202,8 @@ def phase_probe(booster, trace_dir: Optional[str] = None) -> Dict[str, float]:
     from .core.histogram import build_histogram
     from .core.partition import (frontier_slots_from_partition, hist_for_leaf,
                                  init_partition, make_row_gather,
-                                 partition_and_hist,
-                                 sort_placement_profitable, stack_vals)
+                                 partition_and_hist, stack_vals,
+                                 window_placement)
     from .core.split import find_best_split
 
     from .obs.trace import perfetto_trace
@@ -267,14 +267,12 @@ def phase_probe(booster, trace_dir: Optional[str] = None) -> Dict[str, float]:
         ncols = xb_cols.shape[1]
         # the real growth path: one fused pass that partitions the root and
         # prices both children — same placement selection as grow_tree
-        # (sort path on device / pallas_interpret, scatter loop on CPU)
-        use_sort = sort_placement_profitable(params.hist_impl,
-                                             params.vmapped_classes)
+        windows = window_placement(params.hist_impl, params.vmapped_classes)
         fused = jax.jit(lambda p: partition_and_hist(
             p, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
             lambda rows: half[:rows.shape[0]],
             jnp.asarray(True), params.row_chunk, gr, ncols,
-            params.num_bins, params.hist_impl, use_sort=use_sort))
+            params.num_bins, params.hist_impl, windows=windows))
         out["partition_hist_fused"] = _timed(lambda p: fused(p)[0], part)
         part2 = fused(part)[0]
         out["hist_leaf_half"] = _timed(

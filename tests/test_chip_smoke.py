@@ -2,7 +2,7 @@
 compile-cache placement and chip_smoke.py's refusal to run without a TPU.
 (Unknown chip peaks are pinned in test_costmodel.py::test_detect_peaks_table,
 the TPU-shaped backend allow-list in
-test_histogram.py::test_sort_placement_gate_is_allow_list.)"""
+test_histogram.py::test_tpu_shaped_gate_is_allow_list.)"""
 import json
 import os
 import shutil

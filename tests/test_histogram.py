@@ -162,42 +162,28 @@ def test_pallas_highest_precision_matches_scatter_tighter():
     np.testing.assert_allclose(hi6, ref6, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("fake_backend,plain_expected", [
-    ("cpu", False), ("gpu", False), ("METAL", False), ("neuron", False),
-    ("tpu", False), ("tpu_plugin", False)])
-def test_sort_placement_gate_is_allow_list(monkeypatch, fake_backend,
-                                           plain_expected):
-    """Round-4 on-chip re-measurement: the scatter loop beats the sort
-    placement at the auto row_chunk even on TPU (2.31 vs 1.97 iters/s),
-    so the default is off EVERYWHERE; the env var overrides both ways
-    and interpret spellings opt in for CPU test coverage."""
+@pytest.mark.parametrize("fake_backend", [
+    "cpu", "gpu", "METAL", "neuron", "tpu", "tpu_plugin"])
+def test_tpu_shaped_gate_is_allow_list(monkeypatch, fake_backend):
+    """The TPU-shaped allow-list is exactly jax's own "tpu" backend: a
+    plug-in that merely mentions it keeps the conservative paths. The tile
+    loop's shape (row_chunk, the placement of a tile's ids) does not ask
+    the backend at all: it follows the histogram impl, so the interpret
+    spellings run on the CPU what "pallas" runs on the chip."""
     import jax
     from lightgbm_tpu.core import partition
-    monkeypatch.delenv("LIGHTGBM_TPU_SORT_PLACEMENT", raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: fake_backend)
-    # the TPU-shaped allow-list is exactly jax's own "tpu" backend: a
-    # plug-in that merely mentions it keeps the conservative paths
     assert partition.tpu_shaped_backend() == (fake_backend == "tpu")
-    sort_placement_profitable = partition.sort_placement_profitable
-    assert not sort_placement_profitable("pallas", vmapped=True)
-    assert sort_placement_profitable("pallas", vmapped=False) \
-        == plain_expected
-    assert sort_placement_profitable("matmul", vmapped=False) \
-        == plain_expected
-    # interpret spellings opt in so CPU tests cover the sort branch
-    assert sort_placement_profitable("pallas_interpret", vmapped=False)
-    assert sort_placement_profitable("pallas_highest_interpret",
-                                     vmapped=False)
-    monkeypatch.setenv("LIGHTGBM_TPU_SORT_PLACEMENT", "1")
-    assert sort_placement_profitable("pallas", vmapped=False)
-    assert not sort_placement_profitable("pallas", vmapped=True)
-    monkeypatch.setenv("LIGHTGBM_TPU_SORT_PLACEMENT", "off")
-    assert not sort_placement_profitable("pallas_interpret", vmapped=False)
-    monkeypatch.setenv("LIGHTGBM_TPU_SORT_PLACEMENT", "bogus")
-    # unrecognized spelling: warn, fall back to the backend gate
-    assert sort_placement_profitable("pallas", vmapped=False) \
-        == plain_expected
-    assert sort_placement_profitable("pallas_interpret", vmapped=False)
+    for impl in ("pallas", "pallas_highest", "pallas_interpret",
+                 "pallas_highest_interpret"):
+        assert partition.tpu_tiles(impl)
+        assert partition.window_placement(impl, vmapped=False)
+        # a batched window start would be a scatter again
+        assert not partition.window_placement(impl, vmapped=True)
+    for impl in ("matmul", "scatter"):
+        assert not partition.tpu_tiles(impl)
+        assert not partition.window_placement(impl, vmapped=False)
+        assert not partition.window_placement(impl, vmapped=True)
 
 
 def test_slot_kernel_matches_per_slot_scatter():

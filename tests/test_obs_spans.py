@@ -139,6 +139,27 @@ def test_spans_record_under_observability_none_and_export_nothing(tmp_path):
             if m.name == "lgbm_train_span_seconds"} == reg_before
 
 
+@pytest.mark.parametrize("extra,want", [
+    ({}, 0),                                     # the CPU's element scatter
+    ({"tpu_hist_impl": "pallas_interpret"}, 1),  # the chip's tile loop
+    ({"tpu_hist_impl": "pallas_interpret", "objective": "multiclass",
+      "num_class": 3}, 0),                       # vmapped class batching
+    ({"tpu_hist_impl": "pallas_interpret", "tree_growth": "frontier"}, 0),
+], ids=["cpu_default", "pallas", "pallas_vmapped", "pallas_frontier"])
+def test_setup_span_says_which_placement_the_block_was_built_with(extra,
+                                                                  want):
+    mark = last_id()
+    X = np.random.RandomState(0).randn(300, 4)
+    y = (X[:, 0] > 0).astype(float) + (X[:, 1] > 0)
+    if "num_class" not in extra:
+        y = (y > 0).astype(float)
+    lgb.Booster(dict({"objective": "binary", "num_leaves": 4, "verbose": -1},
+                     **extra), lgb.Dataset(X, y))
+    (setup,) = spans_named("train.setup", since=mark)
+    assert setup["counts"]["partition_window_placement"] == want
+    assert setup["counts"]["rows"] == 300
+
+
 def test_enabled_tracer_exports(tmp_path):
     reg = MetricsRegistry()
     path = tmp_path / "ev.jsonl"

@@ -116,10 +116,94 @@ def test_partition_leaf_counts_consistent():
     np.testing.assert_array_equal(lid, lid2)
 
 
-def test_partition_sort_placement_matches_scatter_path():
-    """The pallas impl's single-trip sort+DUS placement must produce the
-    same partition and histograms as the chunked scatter path (interpret
-    mode exercises the sort branch on CPU)."""
+_PN, _PF, _PB = 2000, 3, 8         # the placement cases' rows, columns, bins
+# (begin, count) of the split leaf in units of the chunk c, valid, threshold
+# on column 0 (bins <= it go left): leaf 0 lies before it, leaf 2 after
+_PLACEMENT_CASES = {
+    "invalid": (lambda c: (100, c + 9), False, 3),
+    "empty": (lambda c: (100, 0), True, 3),
+    "under_one_tile": (lambda c: (100, c - 7), True, 3),
+    "exactly_one_tile": (lambda c: (100, c), True, 3),
+    "ragged_tiles": (lambda c: (37, 2 * c + c // 3), True, 3),
+    "all_left": (lambda c: (37, 2 * c + c // 3), True, _PB),
+    "all_right": (lambda c: (37, 2 * c + c // 3), True, -1),
+    "at_the_start": (lambda c: (0, c + 5), True, 3),
+    "at_the_end": (lambda c: (_PN - (c + 5), c + 5), True, 3),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _placement_problem(windows, chunk):
+    """One compiled split per (placement, chunk); the leaf, its range and
+    the threshold are arguments. Integer-valued gradients: every f32 sum is
+    exact, so the histograms compare with array_equal."""
+    from lightgbm_tpu.core.partition import (RowPartition, make_row_gather,
+                                             partition_and_hist, stack_vals)
+    r = np.random.RandomState(28)
+    xb = r.randint(0, _PB, (_PN, _PF)).astype(np.uint8)
+    vals = np.stack([r.randint(-4, 5, _PN), r.randint(1, 4, _PN),
+                     r.randint(0, 2, _PN)], axis=1).astype(np.float32)
+    order = np.concatenate([r.permutation(_PN),
+                            np.full(chunk, _PN)]).astype(np.int32)
+    gr = make_row_gather(jnp.asarray(xb), stack_vals(
+        jnp.asarray(vals[:, 0]), jnp.asarray(vals[:, 1]),
+        jnp.asarray(vals[:, 2])))
+
+    @jax.jit
+    def split(begin, count, valid, thr):
+        part = RowPartition(jnp.asarray(order), begin, count)
+        part, _, hl, hr = partition_and_hist(
+            part, jnp.zeros((_PN,), jnp.int32), jnp.int32(1), jnp.int32(3),
+            lambda rows: rows[:, 0].astype(jnp.int32) <= thr, valid, chunk,
+            gr, _PF, _PB, "scatter", windows=windows)
+        return part, hl, hr
+    # what stack_vals feeds the histograms: (g*m, h*m, m), m in {0, 1}
+    return xb, vals * vals[:, 2:], order, split
+
+
+@pytest.mark.parametrize("case", sorted(_PLACEMENT_CASES))
+@pytest.mark.parametrize("chunk", [64, 300, 512])
+@pytest.mark.parametrize("windows", [False, True],
+                         ids=["element_scatter", "windows"])
+def test_placement_matches_the_rule(windows, chunk, case):
+    """Either placement gives the order the rule states — the leaf's lefts
+    ascending from its begin, its rights descending from its end, in the
+    order the tiles met them — and leaves everything outside the leaf's
+    range as it was; a clamped window would show at either end of order."""
+    where, valid, thr = _PLACEMENT_CASES[case]
+    beg, cnt = where(chunk)
+    xb, vals, order, split = _placement_problem(windows, chunk)
+    begin = np.array([0, beg, beg + cnt, 0], np.int32)
+    count = np.array([beg, cnt, _PN - beg - cnt, 0], np.int32)
+    part, hl, hr = split(jnp.asarray(begin), jnp.asarray(count),
+                         jnp.asarray(valid), jnp.int32(thr))
+
+    ids = order[beg:beg + cnt] if valid else order[:0]
+    left = xb[ids, 0] <= thr
+    want = order.copy()
+    want_begin, want_count = begin.copy(), count.copy()
+    want_h = np.zeros((2, _PF, _PB, 3), np.float32)
+    if valid:
+        want[beg:beg + cnt] = np.concatenate([ids[left], ids[~left][::-1]])
+        want_begin[3] = beg + left.sum()
+        want_count[1], want_count[3] = left.sum(), (~left).sum()
+        for side, rows in enumerate((ids[left], ids[~left])):
+            for col in range(_PF):
+                np.add.at(want_h[side, col], xb[rows, col], vals[rows])
+    got = np.asarray(part.order)
+    # the element scatter sends a ragged tile's surplus to the last slot
+    keep = len(want) if windows else len(want) - 1
+    np.testing.assert_array_equal(got[:keep], want[:keep])
+    np.testing.assert_array_equal(np.asarray(part.leaf_begin), want_begin)
+    np.testing.assert_array_equal(np.asarray(part.leaf_count), want_count)
+    np.testing.assert_array_equal(np.asarray(hl), want_h[0])
+    np.testing.assert_array_equal(np.asarray(hr), want_h[1])
+
+
+def test_partition_window_placement_matches_scatter_path():
+    """A grown tree is the same under either placement: the Pallas impls
+    take the windows (interpret mode runs them on the CPU), "scatter" the
+    element scatter."""
     np.random.seed(9)
     n, f, b = 3000, 5, 64
     xb = np.random.randint(0, b, (n, f)).astype(np.uint8)
@@ -139,10 +223,54 @@ def test_partition_sort_placement_matches_scatter_path():
         out[impl] = (jax.tree.map(np.asarray, t_), np.asarray(li))
     t0, l0 = out["scatter"]
     t1, l1 = out["pallas_interpret"]
+    assert int(t0.num_leaves) == 15
     assert (l0 == l1).all()
     np.testing.assert_array_equal(t0.split_feature, t1.split_feature)
     np.testing.assert_allclose(t0.leaf_value, t1.leaf_value,
                                rtol=1e-4, atol=1e-5)
+
+
+def _eqns_under(jaxpr, scope):
+    """Every equation, sub-jaxprs included, whose name stack has ``scope``."""
+    for eqn in jaxpr.eqns:
+        if scope in str(eqn.source_info.name_stack):
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_under(sub, scope)
+
+
+def test_tpu_tile_loop_places_ids_without_a_scatter_into_order():
+    """The tile loop as the TPU rule builds it: under
+    lgbm.partition_scatter one sort and two window writes a tile, and no
+    scatter at all, so none whose operand has order's length (on a v5e such
+    a scatter cost 187 us a tile, half an iteration; PERF.md, PR 28)."""
+    from lightgbm_tpu.core.partition import (init_partition, make_row_gather,
+                                             partition_and_hist, stack_vals,
+                                             window_placement)
+    n, chunk, f, b = 1000, 128, 3, 8
+    impl = "pallas_interpret"
+    gr = make_row_gather(jnp.zeros((n, f), jnp.uint8), stack_vals(
+        jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+        jnp.ones((n,), jnp.float32)))
+    part = init_partition(n, 8, chunk)
+
+    def names(windows):
+        jaxpr = jax.make_jaxpr(lambda p: partition_and_hist(
+            p, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
+            lambda rows: rows[:, 0] == 1, jnp.asarray(True), chunk, gr, f,
+            b, impl, windows=windows))(part)
+        return [(e.primitive.name, e.invars[0].aval.shape)
+                for e in _eqns_under(jaxpr.jaxpr, "lgbm.partition_scatter")
+                if e.invars]
+
+    placed = names(window_placement(impl, vmapped=False))
+    ops = [name for name, _ in placed]
+    assert ops.count("sort") == 1
+    assert ops.count("dynamic_update_slice") == 2
+    assert not [o for o in ops if o.startswith("scatter")]
+    # the walk does see the other placement's scatter into order
+    assert ("scatter", part.order.shape) in names(
+        window_placement(impl, vmapped=True))
 
 
 def test_frontier_slots_from_partition():
