@@ -304,6 +304,15 @@ class GBDT:
                     and p.batch_splits == 0
                     and partition_mod.window_placement(p.hist_impl,
                                                        p.vmapped_classes))
+                # what the data made of its columns: those whose split
+                # search prices a missing direction, and those with fewer
+                # bins than max_bin allows
+                f = train_data.num_features     # not a mesh's padding
+                m = self.feature_meta
+                span.counts["features_with_missing"] = int(
+                    np.count_nonzero(np.asarray(m.missing_type)[:f]))
+                span.counts["features_short"] = int(np.count_nonzero(
+                    np.asarray(m.num_bin)[:f] < self.config.max_bin))
 
     # ------------------------------------------------------------ setup
     def _setup_stream_mesh(self, ds) -> np.ndarray:
@@ -713,6 +722,10 @@ class GBDT:
                           and self.mesh is not None else 0),
             with_categorical=bool(np.asarray(self.feature_meta.is_categorical)
                                   .any()),
+            all_rows_in_bag=(
+                not (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0)
+                and self.boosting_type not in ("goss", "rf")
+                and row_valid is None and not streamed),
             use_partition=(self.mesh is None or self._partition_on_mesh),
             partition_on_mesh=self._partition_on_mesh,
             vmapped_classes=vmapped,
@@ -1092,9 +1105,11 @@ class GBDT:
         arrays) are ARGUMENTS, not closure captures: a multi-controller jit
         may not close over arrays that span non-addressable devices, and
         the single-process path costs nothing by sharing the convention.
+        So is the per-feature metadata (zero bins, missing types, bin
+        counts): closed over it would be HLO constants, and the compiled
+        block's cache key would move with the data's values.
         ``self._iter_capture`` holds the tuple to pass each call.
         """
-        meta = self.feature_meta
         params = self.grow_params
         mesh = self.mesh
         obj = self.objective
@@ -1109,7 +1124,7 @@ class GBDT:
             and v.shape[0] in (n, self.num_data_orig)))
         self._iter_capture = (
             self.xb, tuple(getattr(obj, nm) for nm in obj_row_names),
-            self._fp_capture)
+            self._fp_capture, self.feature_meta)
         import copy as _copy
         # device-side health flags (lightgbm_tpu.obs): computed from
         # values the step already holds — two reductions over grad/hess
@@ -1138,7 +1153,7 @@ class GBDT:
             renew_w_attr = ("label_weight" if obj.name == "mape"
                             else "weights")
 
-        def run_iter(xb, obj_rows, fp_capture, scores, sample_mask,
+        def run_iter(xb, obj_rows, fp_capture, meta, scores, sample_mask,
                      feature_mask, grad_in, hess_in, lr, goss_active,
                      goss_key, cegb_state, stopped_in):
             with jax.named_scope("lgbm.gradients"):
@@ -1208,24 +1223,26 @@ class GBDT:
                                          empty_tree(params.num_leaves))
                 xb_cols, meta_loc, gofl = fp_capture
                 ml_specs = jax.tree.map(lambda _: P(FEATURE_AXIS), meta_loc)
+                meta_specs = jax.tree.map(lambda _: P(), meta)
 
-                def _fp_core(xbg, xbl, ml, go, gj, hj, mj, fm):
+                def _fp_core(xbg, xbl, ml, go, gj, hj, mj, mt, fm):
                     ctx = FeatureParallelCtx(
                         xb_local=xbl[0],
                         meta_local=jax.tree.map(lambda a: a[0], ml),
                         global_of_local=go[0])
-                    return grow_tree(xbg, gj, hj, mj, meta, fm, params,
+                    return grow_tree(xbg, gj, hj, mj, mt, fm, params,
                                      axis_name=FEATURE_AXIS, fp=ctx)[:2]
 
                 grow_fp = shard_map(
                     _fp_core, mesh=mesh,
                     in_specs=(P(), P(FEATURE_AXIS), ml_specs,
-                              P(FEATURE_AXIS), P(), P(), P(), P()),
+                              P(FEATURE_AXIS), P(), P(), P(), meta_specs,
+                              P()),
                     out_specs=(tree_spec, P()), check_vma=False)
 
                 def grow_one(gk, hk, cs):
                     t, li = grow_fp(xb, xb_cols, meta_loc, gofl, gk, hk,
-                                    sample_mask, feature_mask)
+                                    sample_mask, meta, feature_mask)
                     return t, li, None
             elif params.partition_on_mesh or params.voting_top_k > 0:
                 # explicit shard_map learners (mutually exclusive configs):
@@ -1241,6 +1258,7 @@ class GBDT:
                 from ..parallel.mesh import DATA_AXIS
                 tree_spec = jax.tree.map(lambda _: P(),
                                          empty_tree(params.num_leaves))
+                meta_specs = jax.tree.map(lambda _: P(), meta)
                 has_cegb = self._cegb_state is not None \
                     and params.voting_top_k == 0
                 # grow_one's definedness below depends on this invariant
@@ -1250,15 +1268,15 @@ class GBDT:
                     "wave-batched growth cannot carry CEGB state"
 
                 if grow_batched_fn is not None:
-                    def _grow_core(xbj, gj, hj, mj, fm):
+                    def _grow_core(xbj, gj, hj, mj, mt, fm):
                         return grow_batched_fn(
-                            xbj, gj, hj, mj, meta, fm, params,
+                            xbj, gj, hj, mj, mt, fm, params,
                             axis_name=DATA_AXIS)[:2]
                 elif has_cegb:
                     from ..core.grow import CegbState
 
-                    def _grow_core_cegb(xbj, gj, hj, mj, fm, cs):
-                        return grow_tree(xbj, gj, hj, mj, meta, fm, params,
+                    def _grow_core_cegb(xbj, gj, hj, mj, mt, fm, cs):
+                        return grow_tree(xbj, gj, hj, mj, mt, fm, params,
                                          axis_name=DATA_AXIS,
                                          forced=forced_splits, cegb=cs)
                     # acquisition state: per-feature fields replicated,
@@ -1270,16 +1288,16 @@ class GBDT:
                         _grow_core_cegb,
                         mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS),
                                              P(DATA_AXIS), P(DATA_AXIS),
-                                             P(), cegb_specs),
+                                             meta_specs, P(), cegb_specs),
                         out_specs=(tree_spec, P(DATA_AXIS), cegb_specs),
                         check_vma=False)
 
                     def grow_one(gk, hk, cs):
-                        return grow_cegb(xb, gk, hk, sample_mask,
+                        return grow_cegb(xb, gk, hk, sample_mask, meta,
                                          feature_mask, cs)
                 else:
-                    def _grow_core(xbj, gj, hj, mj, fm):
-                        return grow_tree(xbj, gj, hj, mj, meta, fm, params,
+                    def _grow_core(xbj, gj, hj, mj, mt, fm):
+                        return grow_tree(xbj, gj, hj, mj, mt, fm, params,
                                          axis_name=DATA_AXIS,
                                          forced=forced_splits)[:2]
                 if not has_cegb:
@@ -1287,12 +1305,12 @@ class GBDT:
                         _grow_core,
                         mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS),
                                              P(DATA_AXIS), P(DATA_AXIS),
-                                             P()),
+                                             meta_specs, P()),
                         out_specs=(tree_spec, P(DATA_AXIS)),
                         check_vma=False)
 
                     def grow_one(gk, hk, cs):
-                        t, li = grow_sharded(xb, gk, hk, sample_mask,
+                        t, li = grow_sharded(xb, gk, hk, sample_mask, meta,
                                              feature_mask)
                         return t, li, None
             elif grow_batched_fn is not None:
@@ -1635,7 +1653,7 @@ class GBDT:
     # scores [N, K] and the bagging mask [N].  One declaration, three
     # consumers: the executing jit below, the donation audit
     # (analysis/hlo_audit.py) and its regression test.
-    TRAIN_BLOCK_DONATE = (3, 8)
+    TRAIN_BLOCK_DONATE = (4, 9)
 
     def _build_run_block(self) -> Callable:
         """The unjitted fused-block callable — separated from
@@ -1663,7 +1681,7 @@ class GBDT:
         row_group = self._row_group          # group-aware bagging (ranking)
         num_groups = getattr(self, "_num_groups", 0)
 
-        def run_block(xb, obj_rows, fp_capture, scores, feature_masks,
+        def run_block(xb, obj_rows, fp_capture, meta, scores, feature_masks,
                       goss_actives, iter_idxs, keys, bag_mask0, cegb_state,
                       stopped_in, lr):
             g0 = jnp.zeros((n, k), jnp.float32)
@@ -1687,7 +1705,7 @@ class GBDT:
                         bag_mask = jnp.where(refresh, new_mask, bag_mask)
                 sm = bag_mask if row_valid is None else bag_mask * row_valid
                 packed, _leaf_ids, sc2, cegb2, stopped2, health, ms = core(
-                    xb, obj_rows, fp_capture, sc, sm, fm, g0, h0, lr, ga,
+                    xb, obj_rows, fp_capture, meta, sc, sm, fm, g0, h0, lr, ga,
                     gkey, cegb, stopped)
                 return (sc2, bag_mask, cegb2, stopped2), (packed, health, ms)
 
@@ -2436,7 +2454,12 @@ class GBDT:
                         ht.cat_bitset[i][v >> 5] |= np.uint32(1 << (v & 31))
             else:
                 tb = int(t.threshold_bin[i])
-                ht.threshold[i] = mapper.bin_to_value(tb)
+                # the last numeric bin of a column with a NaN bin is
+                # bounded by +inf; a split there (NaN against the rest) is
+                # stored as upstream's Common::AvoidInf stores it
+                # (tree.h Split), so that the model text can hold it
+                ht.threshold[i] = min(max(mapper.bin_to_value(tb), -1e300),
+                                      1e300)
         ht.default_left[:nn] = t.default_left[:nn]
         ht.missing_type[:nn] = t.missing_type[:nn]
         ht.is_categorical[:nn] = t.is_categorical[:nn]

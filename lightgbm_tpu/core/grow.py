@@ -64,6 +64,11 @@ class GrowParams(NamedTuple):
     # dataset has categorical features -> run the categorical split finder
     # alongside the numerical one (FindBestThreshold dispatch)
     with_categorical: bool = False
+    # no bagging, no GOSS, no padded rows: every row carries sample weight
+    # 1, so the row partition's integer counts ARE the leaves' counts. The
+    # exact grower then records those; a float32 histogram count cannot
+    # hold an odd number past 2**24 rows
+    all_rows_in_bag: bool = False
     # row-partition mode (DataPartition analog, core/partition.py): keep rows
     # grouped by leaf and build each histogram only over the leaf's rows —
     # O(N x depth) row visits per tree instead of O(N x num_leaves).
@@ -191,11 +196,11 @@ class TreeArrays(NamedTuple):
     split_gain: jnp.ndarray       # [L-1] f32
     internal_value: jnp.ndarray   # [L-1] f32 (node output)
     internal_weight: jnp.ndarray  # [L-1] f32 (sum_hess)
-    internal_count: jnp.ndarray   # [L-1] f32
+    internal_count: jnp.ndarray   # [L-1] int32 (rows; past 2**24 f32 drops odd counts)
     split_leaf: jnp.ndarray       # [L-1] int32
     leaf_value: jnp.ndarray       # [L] f32
     leaf_weight: jnp.ndarray      # [L] f32 (sum_hess)
-    leaf_count: jnp.ndarray       # [L] f32
+    leaf_count: jnp.ndarray       # [L] int32
     leaf_parent: jnp.ndarray      # [L] int32 (node index, -1 = root)
     leaf_depth: jnp.ndarray       # [L] int32
     num_leaves: jnp.ndarray       # scalar int32
@@ -219,11 +224,11 @@ def empty_tree(num_leaves: int, dtype=jnp.float32) -> TreeArrays:
         split_gain=jnp.zeros((l - 1,), dtype),
         internal_value=jnp.zeros((l - 1,), dtype),
         internal_weight=jnp.zeros((l - 1,), dtype),
-        internal_count=jnp.zeros((l - 1,), dtype),
+        internal_count=jnp.zeros((l - 1,), jnp.int32),
         split_leaf=jnp.full((l - 1,), -1, jnp.int32),
         leaf_value=jnp.zeros((l,), dtype),
         leaf_weight=jnp.zeros((l,), dtype),
-        leaf_count=jnp.zeros((l,), dtype),
+        leaf_count=jnp.zeros((l,), jnp.int32),
         leaf_parent=jnp.full((l,), -1, jnp.int32),
         leaf_depth=jnp.zeros((l,), jnp.int32),
         num_leaves=jnp.asarray(1, jnp.int32),
@@ -294,6 +299,12 @@ def _empty_best(num_leaves: int, dtype=jnp.float32) -> BestSplit:
 
 def _masked_set(arr: jnp.ndarray, idx: jnp.ndarray, val, valid) -> jnp.ndarray:
     return arr.at[idx].set(jnp.where(valid, val, arr[idx]))
+
+
+def count_i32(count: jnp.ndarray) -> jnp.ndarray:
+    """A histogram's count (a float sum of 0/1 sample weights) as the
+    integer a tree records."""
+    return jnp.round(count).astype(jnp.int32)
 
 
 def expand_hist(hist, sum_g, sum_h, cnt, meta: FeatureMeta,
@@ -638,6 +649,10 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                        stack_vals(grad, hess, sample_mask),
                                        packed=not params.vmapped_classes)
                        if use_partition else None)
+    # the tree's counts come from the partition's integers where those
+    # count what the histogram's count channel counts
+    exact_counts = (params.all_rows_in_bag and use_partition
+                    and axis_name is None)
     with jax.named_scope("lgbm.root_hist"):
         root_g = psum(jnp.sum(grad * sample_mask))
         root_h = psum(jnp.sum(hess * sample_mask))
@@ -650,7 +665,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             calculate_leaf_output(root_g, root_h, sp.lambda_l1, sp.lambda_l2,
                                   sp.max_delta_step)),
         leaf_weight=tree.leaf_weight.at[0].set(root_h),
-        leaf_count=tree.leaf_count.at[0].set(root_c))
+        leaf_count=tree.leaf_count.at[0].set(
+            jnp.int32(n) if exact_counts else count_i32(root_c)))
 
     root_pen = cegb_gain_penalty(cegb, root_c, sample_mask)
     best0 = best_for(hist_root, root_g, root_h, root_c, True,
@@ -815,23 +831,32 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
         # ---- partition rows of `leaf` (DataPartition::Split analog) ------
         right_leaf = t + 1
+        # the split column's metadata, looked up once a split: the tile
+        # loop below routes every tile by the same few scalars
+        split_missing = meta.missing_type[cur.feature]
+        split_num_bin = meta.num_bin[cur.feature]
+        split_default_bin = meta.default_bin[cur.feature]
         if params.with_efb:
             stored_col = meta.col[cur.feature]
+            split_offset = meta.offset[cur.feature]
+            split_div = (meta.pack_div[cur.feature]
+                         if meta.pack_div is not None else None)
+            split_mod = (meta.pack_mod[cur.feature]
+                         if meta.pack_mod is not None else None)
 
             def to_feat_bin(v):
                 return decode_bundle_value(
-                    v, meta.offset[cur.feature],
-                    meta.num_bin[cur.feature],
-                    meta.default_bin[cur.feature],
-                    pack_div=(meta.pack_div[cur.feature]
-                              if meta.pack_div is not None else None),
-                    pack_mod=(meta.pack_mod[cur.feature]
-                              if meta.pack_mod is not None else None))
+                    v, split_offset, split_num_bin, split_default_bin,
+                    pack_div=split_div, pack_mod=split_mod)
         else:
             stored_col = cur.feature
 
             def to_feat_bin(v):
                 return v
+
+        # a dataset without categorical columns routes without the
+        # category bitset (as the wave growers do)
+        split_is_cat = cur.is_categorical if params.with_categorical else None
 
         if use_partition:
             def go_left_rows(rows):
@@ -844,10 +869,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                   onehot_col).astype(jnp.int32)
                 return _bin_go_left(
                     to_feat_bin(colv), cur.threshold, cur.default_left,
-                    meta.missing_type[cur.feature],
-                    meta.num_bin[cur.feature],
-                    meta.default_bin[cur.feature],
-                    cur.is_categorical, cur.cat_bitset)
+                    split_missing, split_num_bin, split_default_bin,
+                    split_is_cat, cur.cat_bitset)
 
             part, leaf_id, hist_left_d, hist_right_d = partition_and_hist(
                 s.part, s.leaf_id, leaf, right_leaf, go_left_rows, valid,
@@ -868,9 +891,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             col = jnp.take(xb, stored_col, axis=1)
             go_left = _bin_go_left(
                 to_feat_bin(col), cur.threshold, cur.default_left,
-                meta.missing_type[cur.feature], meta.num_bin[cur.feature],
-                meta.default_bin[cur.feature], cur.is_categorical,
-                cur.cat_bitset)
+                split_missing, split_num_bin, split_default_bin,
+                split_is_cat, cur.cat_bitset)
             in_leaf = s.leaf_id == leaf
             leaf_id = jnp.where(valid & in_leaf & ~go_left, right_leaf,
                                 s.leaf_id)
@@ -888,6 +910,12 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         left_child = _masked_set(left_child, node, ~leaf, valid)
         right_child = _masked_set(right_child, node, ~right_leaf, valid)
 
+        if exact_counts:
+            left_count = part.leaf_count[leaf]
+            right_count = part.leaf_count[right_leaf]
+        else:
+            left_count = count_i32(cur.left_count)
+            right_count = count_i32(cur.right_count)
         depth = tree.leaf_depth[leaf] + 1
         parent_value = calculate_leaf_output(
             cur.left_sum_grad + cur.right_sum_grad,
@@ -899,7 +927,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             threshold_bin=_masked_set(tree.threshold_bin, node, cur.threshold, valid),
             default_left=_masked_set(tree.default_left, node, cur.default_left, valid),
             missing_type=_masked_set(tree.missing_type, node,
-                                     meta.missing_type[cur.feature], valid),
+                                     split_missing, valid),
             is_categorical=_masked_set(tree.is_categorical, node,
                                        cur.is_categorical, valid),
             cat_bitset=tree.cat_bitset.at[node].set(
@@ -910,7 +938,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             internal_weight=_masked_set(tree.internal_weight, node,
                                         cur.left_sum_hess + cur.right_sum_hess, valid),
             internal_count=_masked_set(tree.internal_count, node,
-                                       cur.left_count + cur.right_count, valid),
+                                       left_count + right_count, valid),
             split_leaf=_masked_set(tree.split_leaf, node, leaf, valid),
             leaf_value=_masked_set(
                 _masked_set(tree.leaf_value, leaf, cur.left_output, valid),
@@ -919,8 +947,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 _masked_set(tree.leaf_weight, leaf, cur.left_sum_hess, valid),
                 right_leaf, cur.right_sum_hess, valid),
             leaf_count=_masked_set(
-                _masked_set(tree.leaf_count, leaf, cur.left_count, valid),
-                right_leaf, cur.right_count, valid),
+                _masked_set(tree.leaf_count, leaf, left_count, valid),
+                right_leaf, right_count, valid),
             leaf_parent=_masked_set(
                 _masked_set(tree.leaf_parent, leaf, node, valid),
                 right_leaf, node, valid),

@@ -48,7 +48,7 @@ from jax import lax
 
 from .histogram import build_histogram, build_histogram_frontier
 from .grow import (GrowParams, TreeArrays, _bin_go_left, _empty_best,
-                   decode_bundle_value, empty_tree, expand_hist,
+                   count_i32, decode_bundle_value, empty_tree, expand_hist,
                    propagate_monotone_bounds)
 from .split import (BestSplit, FeatureMeta, K_MIN_SCORE,
                     calculate_leaf_output, find_best_split)
@@ -126,14 +126,14 @@ def apply_split_wave(tree: TreeArrays, leaf_min: jnp.ndarray,
         internal_weight=set_node(tree.internal_weight,
                                  cur.left_sum_hess + cur.right_sum_hess),
         internal_count=set_node(tree.internal_count,
-                                cur.left_count + cur.right_count),
+                                count_i32(cur.left_count + cur.right_count)),
         split_leaf=set_node(tree.split_leaf, safe_leaf),
         leaf_value=set_leaves(tree.leaf_value, cur.left_output,
                               cur.right_output),
         leaf_weight=set_leaves(tree.leaf_weight, cur.left_sum_hess,
                                cur.right_sum_hess),
-        leaf_count=set_leaves(tree.leaf_count, cur.left_count,
-                              cur.right_count),
+        leaf_count=set_leaves(tree.leaf_count, count_i32(cur.left_count),
+                              count_i32(cur.right_count)),
         leaf_parent=set_leaves(tree.leaf_parent, node, node),
         leaf_depth=set_leaves(tree.leaf_depth, depth, depth),
         num_leaves=nl + nvalid)
@@ -302,7 +302,7 @@ def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             calculate_leaf_output(root_g, root_h, sp.lambda_l1, sp.lambda_l2,
                                   sp.max_delta_step)),
         leaf_weight=tree.leaf_weight.at[0].set(root_h),
-        leaf_count=tree.leaf_count.at[0].set(root_c))
+        leaf_count=tree.leaf_count.at[0].set(count_i32(root_c)))
     best0 = child_best(hist_root, root_g, root_h, root_c, -jnp.inf, jnp.inf)
     best = jax.tree.map(lambda a, v: a.at[0].set(v), _empty_best(l), best0)
 

@@ -55,8 +55,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .histogram import build_histogram
-from .grow import (GrowParams, TreeArrays, _empty_best, empty_tree,
-                   expand_hist)
+from .grow import (GrowParams, TreeArrays, _empty_best, count_i32,
+                   empty_tree, expand_hist)
 from .grow_batched import (_combined_hist, _drop_set, apply_split_wave,
                            interleave_lr, route_split_rows,
                            scatter_child_best)
@@ -145,7 +145,7 @@ def grow_tree_batched_part(xb: jnp.ndarray, grad: jnp.ndarray,
             calculate_leaf_output(root_g, root_h, sp.lambda_l1, sp.lambda_l2,
                                   sp.max_delta_step)),
         leaf_weight=tree.leaf_weight.at[0].set(root_h),
-        leaf_count=tree.leaf_count.at[0].set(root_c))
+        leaf_count=tree.leaf_count.at[0].set(count_i32(root_c)))
     best0 = child_best(hist_root, root_g, root_h, root_c, -jnp.inf, jnp.inf)
     best = jax.tree.map(lambda a, v: a.at[0].set(v), _empty_best(l), best0)
 

@@ -73,7 +73,7 @@ from ..parallel.learners import make_frontier_learner
 from .binpack import CODES_PER_WORD, words_per_row
 from .histogram import build_histogram, build_histogram_frontier
 from .grow import (GrowParams, TreeArrays, _bin_go_left, _empty_best,
-                   decode_bundle_value, empty_tree, expand_hist)
+                   count_i32, decode_bundle_value, empty_tree, expand_hist)
 from .grow_batched import (_drop_set, apply_split_wave, interleave_lr,
                            scatter_child_best)
 from .split import (FeatureMeta, K_MIN_SCORE, calculate_leaf_output,
@@ -356,7 +356,7 @@ def root_state(hist_root, root_g, root_h, root_c, n: int, l: int, sp,
             calculate_leaf_output(root_g, root_h, sp.lambda_l1, sp.lambda_l2,
                                   sp.max_delta_step)),
         leaf_weight=tree.leaf_weight.at[0].set(root_h),
-        leaf_count=tree.leaf_count.at[0].set(root_c))
+        leaf_count=tree.leaf_count.at[0].set(count_i32(root_c)))
     best0 = lrn.best_root(hist_root, root_g, root_h, root_c)
     best = jax.tree.map(lambda a, v: a.at[0].set(v), _empty_best(l), best0)
 

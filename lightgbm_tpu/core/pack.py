@@ -40,11 +40,11 @@ _FIELDS: List[Tuple[str, str]] = [
     ("split_gain", "f32"),
     ("internal_value", "f32"),
     ("internal_weight", "f32"),
-    ("internal_count", "f32"),
+    ("internal_count", "i32"),
     ("split_leaf", "i32"),
     ("leaf_value", "f32"),
     ("leaf_weight", "f32"),
-    ("leaf_count", "f32"),
+    ("leaf_count", "i32"),
     ("leaf_parent", "i32"),
     ("leaf_depth", "i32"),
     ("num_leaves", "i32"),

@@ -141,6 +141,9 @@ class PerFeatureSplit(NamedTuple):
     left_sum_grad: jnp.ndarray
     left_sum_hess: jnp.ndarray
     left_count: jnp.ndarray
+    right_sum_grad: jnp.ndarray
+    right_sum_hess: jnp.ndarray
+    right_count: jnp.ndarray
     left_output: jnp.ndarray
     right_output: jnp.ndarray
 
@@ -182,24 +185,35 @@ def per_feature_split_numerical(
     skip_default = (meta.missing_type[:, None] == MISSING_ZERO) & \
         (bins == meta.default_bin[:, None])
 
-    g = jnp.where(in_numeric & ~skip_default, hist[..., 0], 0.0)
-    h = jnp.where(in_numeric & ~skip_default, hist[..., 1], 0.0)
-    c = jnp.where(in_numeric & ~skip_default, hist[..., 2], 0.0)
+    # Both sides of every candidate are summed from the histogram's own
+    # bins: the accumulated (numeric, non-default) bins as a prefix and a
+    # suffix, and the bins the scan leaves out (the NaN bin, a skipped
+    # default bin) added to the side the missing rows fall on. The
+    # reference takes the far side as leaf total minus scanned side, in
+    # double; in float32 the leaf's total and its bins, summed in
+    # different orders, disagree by more than a small child holds once one
+    # bin (zeros, NaN) carries most of the rows, and a 20-row child priced
+    # by that difference wins splits it should not. The leaf totals set
+    # the gain to beat and nothing else.
+    acc = in_numeric & ~skip_default
+    out = (bins < num_bin) & ~acc
+    hg, hh, hc = hist[..., 0], hist[..., 1], hist[..., 2]
+    g, h, c = (jnp.where(acc, a, 0.0) for a in (hg, hh, hc))
+    og, oh, oc = (jnp.sum(jnp.where(out, a, 0.0), axis=1, keepdims=True)
+                  for a in (hg, hh, hc))
 
-    pg = jnp.cumsum(g, axis=1)   # prefix over bins: left side of threshold t
-    ph = jnp.cumsum(h, axis=1)
-    pc = jnp.cumsum(c, axis=1)
-    # totals over accumulated (numeric, non-default) bins
-    tg, th, tc = pg[:, -1:], ph[:, -1:], pc[:, -1:]
+    def suffix(a):   # sum of the bins above t
+        above = jnp.cumsum(a[:, ::-1], axis=1)[:, ::-1]
+        return jnp.concatenate([above[:, 1:], jnp.zeros_like(a[:, :1])], 1)
+
+    pg, ph, pc = (jnp.cumsum(a, axis=1) for a in (g, h, c))   # bins <= t
+    sg, sh, sc = suffix(g), suffix(h), suffix(c)
 
     gain_shift = leaf_split_gain(sum_grad, sum_hess, params.lambda_l1,
                                  params.lambda_l2, params.max_delta_step)
     min_gain_shift = gain_shift + params.min_gain_to_split
 
-    def eval_candidates(lg, lh, lc):
-        rg_ = sum_grad - lg
-        rh_ = sum_hess - lh
-        rc_ = num_data - lc
+    def eval_candidates(lg, lh, lc, rg_, rh_, rc_):
         ok = ((lc >= params.min_data_in_leaf)
               & (rc_ >= params.min_data_in_leaf)
               & (lh >= params.min_sum_hessian_in_leaf)
@@ -211,16 +225,11 @@ def per_feature_split_numerical(
         return jnp.where(ok, gain, K_MIN_SCORE), lo, ro
 
     # ---- missing-left scan (reference dir=-1, runs first) -----------------
-    # right side accumulated from top numeric bins; threshold = t means
-    # right = accumulated bins > t; left = parent - right (keeps default/NaN).
-    # valid thresholds: 0 .. nb_numeric-2
-    rgL = tg - pg
-    rhL = (th - ph) + K_EPSILON
-    rcL = tc - pc
-    lgL = sum_grad - rgL
-    lhL = sum_hess - rhL
-    lcL = num_data - rcL
-    gainL, loL, roL = eval_candidates(lgL, lhL, lcL)
+    # threshold t: right = accumulated bins > t; left = accumulated bins
+    # <= t and the left-out bins (default/NaN). valid: 0 .. nb_numeric-2
+    lgL, lhL, lcL = pg + og, ph + oh + K_EPSILON, pc + oc
+    rgL, rhL, rcL = sg, sh + K_EPSILON, sc
+    gainL, loL, roL = eval_candidates(lgL, lhL, lcL, rgL, rhL, rcL)
     validL = (bins <= nb_numeric - 2) & (bins >= 0)
     # reference dir=-1 skips evaluating at scanned bin == default_bin,
     # i.e. threshold == default_bin - 1
@@ -232,13 +241,12 @@ def per_feature_split_numerical(
     bestL = jnp.take_along_axis(gainL, idxL[:, None], 1)[:, 0]
 
     # ---- missing-right scan (reference dir=+1) ----------------------------
-    # left side accumulated from bin 0; threshold t: left = bins <= t.
-    # valid thresholds: 0 .. nb_numeric-2, plus nb_numeric-1 when NaN bin
-    # exists (split purely on missingness).
-    lgR = pg + 0.0
-    lhR = ph + K_EPSILON
-    lcR = pc
-    gainR, loR, roR = eval_candidates(lgR, lhR, lcR)
+    # threshold t: left = accumulated bins <= t; right = the rest and the
+    # left-out bins. valid thresholds: 0 .. nb_numeric-2, plus
+    # nb_numeric-1 when NaN bin exists (split purely on missingness).
+    lgR, lhR, lcR = pg, ph + K_EPSILON, pc
+    rgR, rhR, rcR = sg + og, sh + oh + K_EPSILON, sc + oc
+    gainR, loR, roR = eval_candidates(lgR, lhR, lcR, rgR, rhR, rcR)
     validR = (bins <= nb_numeric - 2 + has_nan_bin.astype(jnp.int32))
     validR = validR & ~((meta.missing_type[:, None] == MISSING_ZERO)
                         & (bins == meta.default_bin[:, None]))
@@ -260,11 +268,7 @@ def per_feature_split_numerical(
     default_left = jnp.where(fix2bin, False, default_left)
 
     take = lambda a, i: jnp.take_along_axis(a, i[:, None], 1)[:, 0]
-    lg_best = jnp.where(use_right, take(lgR, idxR), take(lgL, idxL))
-    lh_best = jnp.where(use_right, take(lhR, idxR), take(lhL, idxL))
-    lc_best = jnp.where(use_right, take(lcR, idxR), take(lcL, idxL))
-    lo_best = jnp.where(use_right, take(loR, idxR), take(loL, idxL))
-    ro_best = jnp.where(use_right, take(roR, idxR), take(roL, idxL))
+    best = lambda aR, aL: jnp.where(use_right, take(aR, idxR), take(aL, idxL))
 
     # feature-level masks: sampled out, trivial, categorical handled elsewhere
     usable = feature_mask & ~meta.is_categorical & (meta.num_bin > 1)
@@ -276,11 +280,14 @@ def per_feature_split_numerical(
         gain=out_gain,
         threshold=per_feat_thr,
         default_left=default_left,
-        left_sum_grad=lg_best,
-        left_sum_hess=lh_best - K_EPSILON,   # strip the numeric-safety pad
-        left_count=lc_best,
-        left_output=lo_best,
-        right_output=ro_best,
+        left_sum_grad=best(lgR, lgL),
+        left_sum_hess=best(lhR, lhL) - K_EPSILON,   # strip the safety pad
+        left_count=best(lcR, lcL),
+        right_sum_grad=best(rgR, rgL),
+        right_sum_hess=best(rhR, rhL) - K_EPSILON,
+        right_count=best(rcR, rcL),
+        left_output=best(loR, loL),
+        right_output=best(roR, roL),
     )
 
 
@@ -310,9 +317,9 @@ def find_best_split_numerical(
         left_sum_grad=sel(pf.left_sum_grad),
         left_sum_hess=sel(pf.left_sum_hess),
         left_count=sel(pf.left_count),
-        right_sum_grad=sum_grad - sel(pf.left_sum_grad),
-        right_sum_hess=sum_hess - sel(pf.left_sum_hess),
-        right_count=num_data - sel(pf.left_count),
+        right_sum_grad=sel(pf.right_sum_grad),
+        right_sum_hess=sel(pf.right_sum_hess),
+        right_count=sel(pf.right_count),
         left_output=sel(pf.left_output),
         right_output=sel(pf.right_output),
         is_categorical=jnp.asarray(False),
@@ -471,6 +478,9 @@ def per_feature_split_categorical(
         left_sum_grad=res["lg"],
         left_sum_hess=res["lh"],
         left_count=res["lc"],
+        right_sum_grad=sum_grad - res["lg"],
+        right_sum_hess=sum_hess - res["lh"],
+        right_count=num_data - res["lc"],
         left_output=res["lo"],
         right_output=res["ro"],
     )
@@ -513,12 +523,13 @@ def find_best_split(
         left_sum_grad=sel(pf.left_sum_grad),
         left_sum_hess=sel(pf.left_sum_hess),
         left_count=sel(pf.left_count),
-        right_sum_grad=sum_grad - sel(pf.left_sum_grad),
-        right_sum_hess=sum_hess - sel(pf.left_sum_hess),
-        right_count=num_data - sel(pf.left_count),
+        right_sum_grad=sel(pf.right_sum_grad),
+        right_sum_hess=sel(pf.right_sum_hess),
+        right_count=sel(pf.right_count),
         left_output=sel(pf.left_output),
         right_output=sel(pf.right_output),
-        is_categorical=meta.is_categorical[best_f],
+        is_categorical=(meta.is_categorical[best_f] if with_categorical
+                        else jnp.asarray(False)),
         cat_bitset=bitsets[best_f],
     )
 

@@ -40,6 +40,16 @@ class PredictTree(NamedTuple):
     leaf_value: jnp.ndarray      # [L] f32
 
 
+def threshold_f32(threshold):
+    """Real-valued thresholds as the float32 the device compares with. A
+    split on missingness alone is stored as 1e300 (upstream's AvoidInf):
+    past float32's range, so it becomes the largest float32, which every
+    finite value still lies under."""
+    import numpy as np
+    top = float(np.finfo(np.float32).max)
+    return np.clip(threshold, -top, top).astype(np.float32)
+
+
 def pack_predict_table(ht, max_nodes: int, max_leaves: int,
                        cat_words: Optional[int] = None) -> "PredictTree":
     """Pad a host tree's SoA arrays to model-wide fixed shapes for stacked
@@ -61,7 +71,7 @@ def pack_predict_table(ht, max_nodes: int, max_leaves: int,
     return PredictTree(
         split_leaf=pad(ht.split_leaf, max_nodes, -1),
         split_feature=pad(ht.split_feature, max_nodes),
-        threshold=pad(ht.threshold.astype(np.float32), max_nodes),
+        threshold=pad(threshold_f32(ht.threshold), max_nodes),
         threshold_bin=pad(ht.threshold_bin, max_nodes),
         default_left=pad(ht.default_left, max_nodes),
         missing_type=pad(ht.missing_type, max_nodes),

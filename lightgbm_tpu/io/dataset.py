@@ -255,6 +255,7 @@ class BinnedDataset:
             with recorder.span("ingest.find_bins", columns=f,
                                sample_rows=sample_cnt) as span:
                 scan_s = find_s = 0.0
+                nan_values = zero_values = 0   # of the sampled rows
                 t = time.perf_counter()
                 for j in range(f):
                     rows, vals = column_nonzeros(j)
@@ -265,6 +266,8 @@ class BinnedDataset:
                     else:
                         rows_s, vals_s = rows, vals
                     nz_sample.append(rows_s.astype(np.int64))
+                    nan_values += int(np.isnan(vals_s).sum())
+                    zero_values += sample_cnt - len(vals_s)
                     mapper = BinMapper()
                     t_scan = time.perf_counter()
                     # only non-zero values feed FindBin, like the reference's
@@ -282,7 +285,9 @@ class BinnedDataset:
                     scan_s += t_scan - t
                     t = time.perf_counter()
                     find_s += t - t_scan
-                span.counts.update(nonzero_scan_s=scan_s, find_bin_s=find_s)
+                span.counts.update(nonzero_scan_s=scan_s, find_bin_s=find_s,
+                                   nan_values=nan_values,
+                                   zero_values=zero_values)
             self.used_features = [j for j in range(f)
                                   if not self.bin_mappers[j].is_trivial]
             if not self.used_features:

@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..core.tree import decision_go_left
+from ..core.tree import decision_go_left, threshold_f32
 from ..log import check
 
 
@@ -115,7 +115,7 @@ def pack_flat_forest(models, quantize: bool = False
     for ti, ht in enumerate(models):
         nn = len(ht.left_child)
         feature[ti, :nn] = ht.split_feature
-        threshold[ti, :nn] = ht.threshold.astype(np.float32)
+        threshold[ti, :nn] = threshold_f32(ht.threshold)
         default_left[ti, :nn] = ht.default_left
         missing_type[ti, :nn] = ht.missing_type
         is_categorical[ti, :nn] = ht.is_categorical

@@ -331,7 +331,8 @@ def test_committed_baseline_is_wellformed():
     assert len(sharded["collective_schedule"]) == sharded["collectives"]
     don = base["donation"]["train_block"]
     assert don["ok"] and don["missing"] == []
-    assert don["donate_argnums"] == [3, 8]
+    from lightgbm_tpu.boosting.gbdt import GBDT
+    assert don["donate_argnums"] == list(GBDT.TRAIN_BLOCK_DONATE)
 
 
 # ------------------------------------------------- donation regression

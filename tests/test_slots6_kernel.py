@@ -56,11 +56,22 @@ def _train(X, y, params, rounds=4):
     return bst, ds
 
 
-def test_batched_routing_on_efb_bundles():
+@pytest.mark.parametrize("seed", [4, 5, 6])
+def test_batched_routing_on_efb_bundles(seed):
     """Batched growth over an EFB-bundled dataset: K=1 must reproduce
     exact growth's split structure (the routing's decode_bundle_value
-    path through the one-hot selects), and K=4 must stay accurate."""
-    X, y = _exclusive_groups()
+    path through the one-hot selects), and K=4 must stay accurate.
+
+    The seeds are tables on which no node has an exact tie: two columns
+    whose cuts take other rows of the same gradients (the same count of
+    each label out of the same earlier leaves) have one gain in exact
+    arithmetic. The two growers sum a leaf's histogram in different row
+    orders, and since the split scan sums both children from the bins
+    the argmax of such a tie may fall either way, after which the trees
+    part (seed 3: tree 3's last split, 22 rows, columns 22 and 28, 5
+    rows to the right either way, gains 1.8355482 and 1.8355483; seed 7:
+    tree 0, node 26)."""
+    X, y = _exclusive_groups(seed=seed)
     base = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
             "min_data_in_leaf": 5, "tpu_hist_impl": "scatter"}
     be, ds_e = _train(X, y, dict(base, tree_growth="exact"))
@@ -82,6 +93,35 @@ def test_batched_routing_on_efb_bundles():
          - (y[:400] > 0).sum() * ((y[:400] > 0).sum() + 1) / 2)
         / max((y[:400] > 0).sum() * (400 - (y[:400] > 0).sum()), 1))
     assert abs(auc(p0) - auc(p4)) < 0.05
+
+
+@pytest.mark.parametrize("seed", [3, 7])
+def test_exact_and_batched_part_at_an_exact_tie_only(seed):
+    """The tables the test above leaves out: up to the first node the two
+    growers split differently the trees are the same, and at that node
+    both record the same gain to two float32 ulps: a tie, not another
+    split search."""
+    X, y = _exclusive_groups(seed=seed)
+    base = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
+            "min_data_in_leaf": 5, "tpu_hist_impl": "scatter"}
+    be, _ = _train(X, y, dict(base, tree_growth="exact"))
+    b1, _ = _train(X, y, dict(base, tree_growth="batched",
+                              tree_batch_splits=1))
+    for t0, t1 in zip(be.models, b1.models):
+        f0, f1 = np.asarray(t0.split_feature), np.asarray(t1.split_feature)
+        differ = np.flatnonzero(
+            (f0 != f1) | (np.asarray(t0.threshold_bin)
+                          != np.asarray(t1.threshold_bin)))
+        if len(differ):
+            break   # later trees are grown from other scores
+    else:
+        pytest.fail("seed %d has no tie any more: move it to the test above"
+                    % seed)
+    at = differ[0]
+    np.testing.assert_allclose(np.asarray(t0.split_gain)[at],
+                               np.asarray(t1.split_gain)[at], rtol=2.4e-7)
+    assert np.asarray(t0.internal_count)[at] == \
+        np.asarray(t1.internal_count)[at]
 
 
 def test_batched_part_routing_on_efb_bundles():

@@ -183,3 +183,37 @@ def test_feature_mask_excludes_features():
         jnp.asarray(hist), meta, p, jnp.float32(float(s[0])),
         jnp.float32(float(s[1])), jnp.float32(float(s[2])), jnp.asarray(mask))
     assert int(bs.feature) in (0, 2)
+
+
+def test_a_small_child_is_summed_from_its_bins_not_from_the_leaf_total():
+    """float32 sums of one leaf taken in two orders disagree by more than
+    a 25-row child holds, once one bin carries most of the rows: the
+    leaf's total handed down from its parent's scan against the bins of
+    its own histogram. A child priced as total minus the other side then
+    wins with a gain it does not have (seen on click-log-shaped data at
+    400k rows: a split of reference gain 0.001 recorded at 143.7)."""
+    nb = 6                               # five numeric bins and the NaN bin
+    hist = np.zeros((1, 8, 3), np.float32)
+    hist[0, 0] = (-4.0, 2000.0, 60000)   # the zeros: most of the rows
+    hist[0, 1] = (-12.0, 100.0, 3000)    # the real split is 0,1 | 2,3,4
+    hist[0, 2] = (5.0, 100.0, 3000)
+    hist[0, 3] = (6.0, 100.0, 3000)
+    hist[0, 4] = (4.7, 100.0, 3000)
+    hist[0, 5] = (0.3, 0.8, 25)          # a few NaN rows, nothing to gain
+    sums = hist[0, :nb].sum(axis=0)
+    # the total as float32 would hand it down: off by under 0.1%
+    total_g, total_h = sums[0] + 2.0, sums[1] - 0.7
+    bs = find_best_split_numerical(
+        jnp.asarray(hist), _meta([nb], missing=[MISSING_NAN]),
+        _params(min_data_in_leaf=20), jnp.float32(total_g),
+        jnp.float32(total_h), jnp.float32(sums[2]), jnp.ones((1,), bool))
+    assert int(bs.threshold) == 1, (int(bs.threshold), float(bs.gain))
+    assert not bool(bs.default_left)     # the NaN rows' sum leans right
+    # both children hold what their bins hold
+    left = hist[0, :2].sum(axis=0)
+    right = hist[0, 2:6].sum(axis=0)
+    np.testing.assert_allclose(
+        [bs.left_sum_grad, bs.left_sum_hess, bs.left_count], left, rtol=1e-6)
+    np.testing.assert_allclose(
+        [bs.right_sum_grad, bs.right_sum_hess, bs.right_count], right,
+        rtol=1e-6)
