@@ -297,13 +297,18 @@ class GBDT:
                                rows=train_data.num_data) as span:
                 self._setup_train(train_data)
                 # which placement the exact grower's tile loop is built
-                # with (0 too where another grower runs)
+                # with, and whether it maps rows to leaves once a tree
+                # through leaf_id_from_partition, which has no gather over
+                # all rows (both 0 where another grower runs; the second 0
+                # too where CEGB keeps the leaf ids split by split)
                 p = self.grow_params
+                exact_part = (p.use_partition and not p.frontier_mode
+                              and p.batch_splits == 0)
                 span.counts["partition_window_placement"] = int(
-                    p.use_partition and not p.frontier_mode
-                    and p.batch_splits == 0
-                    and partition_mod.window_placement(p.hist_impl,
-                                                       p.vmapped_classes))
+                    exact_part and partition_mod.window_placement(
+                        p.hist_impl, p.vmapped_classes))
+                span.counts["leaf_ids_gather_free"] = int(
+                    exact_part and not p.with_cegb_lazy)
                 # what the data made of its columns: those whose split
                 # search prices a missing direction, and those with fewer
                 # bins than max_bin allows

@@ -1136,7 +1136,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         state = lax.fori_loop(0, l - 1, step, state)
     leaf_id_out = state.leaf_id
     if use_partition and not maintain_lid:
-        leaf_id_out = leaf_id_from_partition(state.part, n, l)
+        leaf_id_out = leaf_id_from_partition(state.part, n)
     # the model contract is f32 tree arrays regardless of the histogram
     # accumulation dtype (the reference also stores float leaf values)
     tree_out = jax.tree.map(

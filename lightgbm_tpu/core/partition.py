@@ -21,15 +21,22 @@ from the range start, rights backward from the range end — so one pass
 suffices. ``leaf_id`` is NOT maintained per split: it is reconstructed
 once per tree from the final ranges (leaf_id_from_partition).
 
-What a v5e charges for the tile loop's ops (PERF.md section 5; traced
-iterations at 26.6M x 67, 255 leaves, ~52,000 tiles of 4,096 rows):
+What a v5e charges for these ops (PERF.md sections 5 and 6; traced
+iterations at 26.6M x 67, 255 leaves, ~52,000 tiles of 4,096 rows, and
+PR 30's standalone runs at 26.6M positions):
 
 | op                                                | a call | an element |
 | element scatter of the tile's ids into ``order``  | 187 us | 45 ns      |
 | sort of the tile + two window writes (PR 28)      | 7.8 us | 1.9 ns     |
 | row gather, 4,096 rows x 79 B from 2.1 GB         |  37 us |  9 ns      |
 | histogram kernel on the tile, 67 cols x 6 chans   |  80 us | 20 ns      |
-| full-size scatter / gather, 26.6M elements        | 103 / 129 ms | 3.9 / 4.9 ns |
+| full-size scatter / gather, 26.6M elements        | 220 / 260 ms | 8.3 / 9.8 ns |
+| position -> leaf: 510 marks + one prefix sum (PR 30) | 6.4 ms | 0.24 ns |
+
+(103 / 129 ms until PR 30: a capture shows a gather as two instructions,
+index clamp and gather, a scatter as a sort and the scatter.) A gather costs
+that from a 255-entry table too, so a position finds its leaf by a prefix
+sum over marks, not by a search (_range_owner).
 
 An XLA scatter into HBM costs per ELEMENT, whatever the operand's size, and
 a tile's ids only ever go to two contiguous runs; so where the tile loop is
@@ -297,27 +304,38 @@ def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
     return hist
 
 
-def leaf_id_from_partition(part: RowPartition, num_data: int,
-                           num_leaves: int) -> jnp.ndarray:
+def _range_owner(order: jnp.ndarray, begin: jnp.ndarray, count: jnp.ndarray,
+                 num_data: int) -> jnp.ndarray:
+    """Per row, the index i of the range [begin[i], begin[i] + count[i]) of
+    ``order`` that holds it, -1 for a row in none.
+
+    The ranges are disjoint (DataPartition invariant), so position -> owner
+    is one prefix sum over marks: +(i + 1) at range i's start, -(i + 1) at
+    its end, 2 x len(begin) scalars scattered into zeros. An empty range's
+    two marks cancel wherever its start lies, so it needs no filtering and
+    nothing is sorted. Row -> owner is then one scatter through ``order``.
+    No lookup by a vector index and no search (module docstring).
+    """
+    ids = jnp.arange(1, begin.shape[0] + 1, dtype=jnp.int32)
+    marks = jnp.zeros((num_data + 1,), jnp.int32) \
+        .at[begin].add(ids).at[begin + count].add(-ids)
+    pos_owner = jnp.cumsum(marks[:num_data], dtype=jnp.int32) - 1
+    rows = jnp.minimum(order[:num_data], num_data - 1)
+    return jnp.zeros((num_data,), jnp.int32).at[rows].set(
+        pos_owner, mode="promise_in_bounds")
+
+
+def leaf_id_from_partition(part: RowPartition,
+                           num_data: int) -> jnp.ndarray:
     """Reconstruct the per-row leaf assignment from the final ranges.
 
     The leaf ranges tile [0, num_data) exactly (DataPartition invariant), so
-    position -> leaf is a searchsorted over the count-filtered sorted begins,
-    and row -> leaf is one scatter through ``order`` — O(N log L) dense work
-    once per tree instead of O(N x depth) scattered writes during growth.
+    every row has an owner: O(N) dense work once per tree, whatever the
+    number of leaves, instead of O(N x depth) scattered writes during growth.
     """
     with jax.named_scope("lgbm.leaf_ids"):
-        # empty leaves sort past every real range
-        begins = jnp.where(part.leaf_count > 0, part.leaf_begin,
-                           jnp.int32(num_data + 1))
-        sort_begins, sort_leaf = lax.sort(
-            (begins, jnp.arange(num_leaves, dtype=jnp.int32)), num_keys=1)
-        pos = jnp.arange(num_data, dtype=jnp.int32)
-        block = jnp.searchsorted(sort_begins, pos, side="right") - 1
-        pos_leaf = sort_leaf[jnp.clip(block, 0, num_leaves - 1)]
-        rows = jnp.minimum(part.order[:num_data], num_data - 1)
-        return jnp.zeros((num_data,), jnp.int32).at[rows].set(
-            pos_leaf, mode="promise_in_bounds")
+        return _range_owner(part.order, part.leaf_begin, part.leaf_count,
+                            num_data)
 
 
 def frontier_slots_from_partition(part: RowPartition, leaves: jnp.ndarray,
@@ -329,23 +347,7 @@ def frontier_slots_from_partition(part: RowPartition, leaves: jnp.ndarray,
     histogram.build_histogram_frontier — the partition gives the builder
     the wave's LEAF IDS and the builder sweeps the dataset once for all
     of them, instead of extracting one leaf's row list per histogram.
-    Same searchsorted-over-sorted-begins shape as leaf_id_from_partition,
-    except the selected leaves cover only PART of [0, num_data), so a
-    positional hit also range-checks against the owning leaf's count.
+    The selected leaves are distinct and cover only PART of [0, num_data).
     """
-    k = leaves.shape[0]
-    leaf_begin = part.leaf_begin[leaves]
-    leaf_count = part.leaf_count[leaves]
-    # empty/unselected ranges sort past every real one
-    begins = jnp.where(leaf_count > 0, leaf_begin, jnp.int32(num_data + 1))
-    sort_begins, sort_slot = lax.sort(
-        (begins, jnp.arange(k, dtype=jnp.int32)), num_keys=1)
-    pos = jnp.arange(num_data, dtype=jnp.int32)
-    block = jnp.searchsorted(sort_begins, pos, side="right") - 1
-    cand = sort_slot[jnp.clip(block, 0, k - 1)]
-    inside = ((block >= 0) & (pos >= leaf_begin[cand])
-              & (pos < leaf_begin[cand] + leaf_count[cand]))
-    pos_slot = jnp.where(inside, cand, -1)
-    rows = jnp.minimum(part.order[:num_data], num_data - 1)
-    return jnp.full((num_data,), -1, jnp.int32).at[rows].set(
-        pos_slot, mode="promise_in_bounds")
+    return _range_owner(part.order, part.leaf_begin[leaves],
+                        part.leaf_count[leaves], num_data)

@@ -139,15 +139,18 @@ def test_spans_record_under_observability_none_and_export_nothing(tmp_path):
             if m.name == "lgbm_train_span_seconds"} == reg_before
 
 
+# want: (partition_window_placement, leaf_ids_gather_free)
 @pytest.mark.parametrize("extra,want", [
-    ({}, 0),                                     # the CPU's element scatter
-    ({"tpu_hist_impl": "pallas_interpret"}, 1),  # the chip's tile loop
+    ({}, (0, 1)),                                # the CPU's element scatter
+    ({"tpu_hist_impl": "pallas_interpret"}, (1, 1)),  # the chip's tile loop
     ({"tpu_hist_impl": "pallas_interpret", "objective": "multiclass",
-      "num_class": 3}, 0),                       # vmapped class batching
-    ({"tpu_hist_impl": "pallas_interpret", "tree_growth": "frontier"}, 0),
-], ids=["cpu_default", "pallas", "pallas_vmapped", "pallas_frontier"])
-def test_setup_span_says_which_placement_the_block_was_built_with(extra,
-                                                                  want):
+      "num_class": 3}, (0, 1)),                  # vmapped class batching
+    ({"tpu_hist_impl": "pallas_interpret", "tree_growth": "frontier"},
+     (0, 0)),                                    # no leaf_id_from_partition
+    ({"cegb_penalty_feature_lazy": [0.1] * 4}, (0, 0)),  # ids kept by split
+], ids=["cpu_default", "pallas", "pallas_vmapped", "pallas_frontier",
+        "cegb_lazy"])
+def test_setup_span_says_how_the_block_places_and_maps_rows(extra, want):
     mark = last_id()
     X = np.random.RandomState(0).randn(300, 4)
     y = (X[:, 0] > 0).astype(float) + (X[:, 1] > 0)
@@ -156,7 +159,8 @@ def test_setup_span_says_which_placement_the_block_was_built_with(extra,
     lgb.Booster(dict({"objective": "binary", "num_leaves": 4, "verbose": -1},
                      **extra), lgb.Dataset(X, y))
     (setup,) = spans_named("train.setup", since=mark)
-    assert setup["counts"]["partition_window_placement"] == want
+    assert (setup["counts"]["partition_window_placement"],
+            setup["counts"]["leaf_ids_gather_free"]) == want
     assert setup["counts"]["rows"] == 300
 
 

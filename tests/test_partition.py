@@ -112,8 +112,128 @@ def test_partition_leaf_counts_consistent():
     # reconstruction from ranges matches the maintained assignment
     from lightgbm_tpu.core.partition import leaf_id_from_partition
     lid2 = np.asarray(jax.jit(
-        lambda p: leaf_id_from_partition(p, n, 8))(part))
+        lambda p: leaf_id_from_partition(p, n))(part))
     np.testing.assert_array_equal(lid, lid2)
+
+
+def _ranges(n, num_leaves, ids, counts, chunk=64, garbage=(), seed=0):
+    """(n, [(order, leaf_begin, leaf_count)]) of a partition whose live
+    ranges lie in position order ``ids`` with sizes ``counts``; every other
+    leaf is empty, its start taken in turn from ``garbage`` (else 0)."""
+    assert sum(counts) == n and len(ids) == len(counts)
+    rng = np.random.RandomState(seed)
+    order = np.concatenate([rng.permutation(n),
+                            np.full((chunk,), n)]).astype(np.int32)
+    begin = np.zeros((num_leaves,), np.int32)
+    count = np.zeros((num_leaves,), np.int32)
+    begin[list(ids)] = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    count[list(ids)] = counts
+    empty = [l for l in range(num_leaves) if l not in set(ids)]
+    for l, g in zip(empty, garbage):
+        begin[l] = g
+    return n, [(order, begin, count)]
+
+
+def _case_one_leaf():
+    return _ranges(1000, 8, [0], [1000])
+
+
+def _case_255_leaves_200_live():
+    rng = np.random.RandomState(1)
+    ids = rng.permutation(255)[:200]
+    cuts = np.sort(rng.choice(np.arange(1, 5000), 199, replace=False))
+    counts = np.diff(np.concatenate([[0], cuts, [5000]]))
+    return _ranges(5000, 255, ids, counts,
+                   garbage=rng.randint(0, 5000, 55))
+
+
+def _case_ids_out_of_position_order():
+    # leaf 6's range lies before leaf 0's, leaf 3's between them
+    return _ranges(900, 8, [6, 3, 0, 5], [100, 250, 50, 500])
+
+
+def _case_empty_leaves_with_garbage_starts():
+    # empty leaves 1, 3, 4 start on live leaf 2's start (300), in the
+    # middle of leaf 0's range, and on the last position
+    return _ranges(1000, 6, [0, 2, 5], [300, 450, 250],
+                   garbage=[300, 17, 999])
+
+
+def _case_one_row_leaves_at_both_ends():
+    return _ranges(777, 5, [4, 1, 2], [1, 775, 1], garbage=[0, 776])
+
+
+def _case_ragged_n_with_tail_pad():
+    # 1003 rows in chunks of 128: the pad past order[:n] holds n
+    return _ranges(1003, 4, [2, 0, 3, 1], [400, 3, 500, 100], chunk=128)
+
+
+def _case_vmap_two_classes():
+    # two classes' partitions of the same rows, batched as vmapped
+    # class-batched growth batches them
+    return 900, [_case_ids_out_of_position_order()[1][0],
+                 _ranges(900, 8, [7, 1], [899, 1], garbage=[5, 899, 450],
+                         seed=3)[1][0]]
+
+
+_LEAF_ID_CASES = {f.__name__[len("_case_"):]: f for f in (
+    _case_one_leaf, _case_255_leaves_200_live,
+    _case_ids_out_of_position_order, _case_empty_leaves_with_garbage_starts,
+    _case_one_row_leaves_at_both_ends, _case_ragged_n_with_tail_pad,
+    _case_vmap_two_classes)}
+
+
+def _leaf_ids_ref(order, begin, count, n):
+    lid = np.zeros((n,), np.int32)
+    for l in range(len(begin)):
+        lid[order[begin[l]:begin[l] + count[l]]] = l
+    return lid
+
+
+@pytest.mark.parametrize("case", list(_LEAF_ID_CASES))
+def test_leaf_id_from_partition_equals_the_range_by_range_reference(case):
+    """Element for element: ``lid[order[b:b+c]] = l`` for every leaf."""
+    from lightgbm_tpu.core.partition import (RowPartition,
+                                             leaf_id_from_partition)
+    n, parts = _LEAF_ID_CASES[case]()
+
+    def fn(order, begin, count):
+        return leaf_id_from_partition(RowPartition(order, begin, count), n)
+
+    if len(parts) == 1:
+        got = jax.jit(fn)(*map(jnp.asarray, parts[0]))[None]
+    else:
+        got = jax.jit(jax.vmap(fn))(*map(jnp.asarray, map(np.stack,
+                                                          zip(*parts))))
+    assert got.dtype == jnp.int32
+    for g, p in zip(np.asarray(got), parts):
+        np.testing.assert_array_equal(g, _leaf_ids_ref(*p, n))
+
+
+def test_leaf_ids_are_mapped_without_a_gather_or_a_loop_over_all_rows():
+    """No ``gather`` and no loop with an operand or a result of N
+    elements: on a v5e the searchsorted over the range starts (8 steps,
+    each a gather of N elements from a 255-entry table, and one more for
+    the leaf id) was a quarter of an iteration (PERF.md, PR 30)."""
+    from lightgbm_tpu.core.partition import (init_partition,
+                                             leaf_id_from_partition)
+    n, nl = 10_000, 255
+
+    def full_size(fn, *args):
+        return sorted({e.primitive.name for e in _eqns_under(
+            jax.make_jaxpr(fn)(*args).jaxpr, "")
+            if e.primitive.name in ("gather", "while", "scan")
+            and any(int(np.prod(v.aval.shape)) >= n
+                    for v in list(e.invars) + list(e.outvars)
+                    if hasattr(v.aval, "shape"))})
+
+    assert full_size(lambda p: leaf_id_from_partition(p, n),
+                     init_partition(n, nl, 64)) == []
+    # the walk does see the search it guards against
+    starts = jnp.arange(nl, dtype=jnp.int32)
+    assert full_size(lambda sb: (sb + 1)[jnp.searchsorted(
+        sb, jnp.arange(n, dtype=jnp.int32), side="right") - 1],
+        starts) == ["gather", "scan"]
 
 
 _PN, _PF, _PB = 2000, 3, 8         # the placement cases' rows, columns, bins
