@@ -199,9 +199,7 @@ def _resolve_hist_impl(cfg: Config) -> str:
     DOUBLE-precision histogram accumulation. The Pallas kernels are
     f32-only, so dp routes to the XLA paths (scatter / one-hot matmul),
     which accumulate in the value dtype — f64 once the GBDT driver casts
-    the stacked values (GrowParams.hist_dtype). Users who want the f32
-    Precision.HIGHEST kernel without f64 cost ask for
-    tpu_hist_impl=pallas_highest explicitly."""
+    the stacked values (GrowParams.hist_dtype)."""
     impl = cfg.tpu_hist_impl
     if _hist_dtype(cfg) == "f64":
         if impl == "auto" or impl.startswith("pallas"):
@@ -627,30 +625,6 @@ class GBDT:
         # uncapped pool; vmap remains the CPU default, where it wins.
         vmapped = (self.num_tree_per_iteration > 1 and pool_slots == 0
                    and not partition_mod.tpu_shaped_backend())
-        # partitioned batched growth (core/grow_batched_part.py): a GSPMD
-        # mesh path must keep it off (the per-step permutation would
-        # shuffle rows across devices) — the explicit shard_map
-        # data-parallel learner partitions each LOCAL shard and may use it.
-        part_ok = (batch_splits > 0 and not vmapped
-                   and (self.mesh is None
-                        or (cfg.tree_learner == "data"
-                            and mesh_mod.DATA_AXIS in self.mesh.axis_names)))
-        if cfg.tpu_batched_part in ("true", "1"):
-            if not part_ok and batch_splits > 0:
-                Log.warning("tpu_batched_part=true is unsupported here "
-                            "(vmapped multiclass or GSPMD mesh path); "
-                            "using the unpartitioned batched step")
-            batched_part = part_ok
-        elif cfg.tpu_batched_part in ("false", "0"):
-            batched_part = False
-        else:
-            # auto = OFF: measured on a v5e chip the per-step permutation
-            # (XLA gather ~2.3 GB/s) and per-tile DMA latency make the
-            # partitioned step LOSE to both exact growth and the joint
-            # slot kernel at 1M x 28 (docs/Performance.md round-4 table);
-            # revisit if those two costs change
-            batched_part = False
-
         # explicit shard_map data-parallel learner: every device partitions
         # its local row shard and only child histograms cross the mesh
         # (data_parallel_tree_learner.cpp:146-161). Forced splits rebuild
@@ -735,8 +709,6 @@ class GBDT:
             partition_on_mesh=self._partition_on_mesh,
             vmapped_classes=vmapped,
             batch_splits=batch_splits,
-            batched_pack=(batch_splits > 0 and cfg.tpu_batched_pack),
-            batched_part=batched_part,
             frontier_mode=frontier_mode,
             # reduce-scatter wave histograms (DataRSLearner): resolved at
             # padding time — needs frontier + data learner + a data axis +
@@ -1210,12 +1182,8 @@ class GBDT:
                 from ..core.grow_frontier import \
                     grow_tree_frontier as grow_batched_fn
             elif params.batch_splits > 0:
-                if params.batched_part:
-                    from ..core.grow_batched_part import \
-                        grow_tree_batched_part as grow_batched_fn
-                else:
-                    from ..core.grow_batched import \
-                        grow_tree_batched as grow_batched_fn
+                from ..core.grow_batched import \
+                    grow_tree_batched as grow_batched_fn
 
             if fp_capture is not None:
                 # explicit feature-parallel: one shard_map over the feature
@@ -1779,11 +1747,10 @@ class GBDT:
         """Pre-compile ``build_histogram_frontier`` at every wave-width
         bucket the frontier grower can dispatch (the serving ``warmup()``
         analog for training): one all-inactive-slot call per ladder width
-        on the real data shapes, so standalone probes and eager frontier
-        calls after this never compile — and with ``compile_cache_dir``
-        set, later PROCESSES reload every specialization from disk.
-        Returns per-bucket compile counts + seconds (reported by
-        profiling). No-op unless the booster grows frontier-mode.
+        on the real data shapes, so eager frontier calls after this never
+        compile — and with ``compile_cache_dir`` set, later PROCESSES
+        reload every specialization from disk. Returns per-bucket compile
+        counts + seconds. No-op unless the booster grows frontier-mode.
         """
         from .. import bucketing
         from ..profiling import backend_compile_count, compile_cache_stats
@@ -1852,8 +1819,8 @@ class GBDT:
 
         PULL-based by design: nothing in the training loop calls this,
         so ``observability=none`` runs do zero costmodel work — and with
-        obs off it returns ``{}`` unless ``force=True`` (probes
-        and the perf tools force it).  Arguments are mirrored as
+        obs off it returns ``{}`` unless ``force=True`` (the perf gate
+        forces it).  Arguments are mirrored as
         ``jax.ShapeDtypeStruct`` (sharding preserved), never sampled:
         extraction must not advance ``self._rng`` / ``self._bag_key`` or
         resumed-run byte-identity would break.  AOT lowering shares no

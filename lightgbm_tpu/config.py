@@ -173,8 +173,8 @@ _PARAMS: List[Tuple[str, type, Any, List[str]]] = [
     # ---- TPU-specific extensions (no reference counterpart) ----
     ("tpu_hist_dtype", str, "float32", []),   # histogram accumulation dtype
     # histogram kernel: auto (pallas on TPU, scatter on CPU) | pallas |
-    # pallas_highest (full-f32 MXU contraction, ~2x cost) | matmul |
-    # scatter | pallas_interpret; f64 mode routes off the f32-only pallas
+    # matmul | scatter | pallas_interpret; f64 mode routes off the
+    # f32-only pallas
     # — the GPUTreeLearner device-path dispatch analog (tree_learner.cpp:9-31)
     ("tpu_hist_impl", str, "auto", []),
     # device bin-matrix packing (core/binpack.py; docs/Performance.md
@@ -224,16 +224,6 @@ _PARAMS: List[Tuple[str, type, Any, List[str]]] = [
     # environment overrides this; empty = <checkout>/.jax_cache.
     ("compile_cache_dir", str, "", ["compilation_cache_dir",
                                     "jax_compilation_cache_dir"]),
-    # batched growth: pack active rows so dead row tiles skip the slot
-    # kernel's compute (cost ~ split-leaf rows, not N); opt-in until
-    # measured on chip
-    ("tpu_batched_pack", bool, False, []),
-    # partitioned batched growth (core/grow_batched_part.py): rows kept
-    # physically grouped by leaf so per-step kernel cost tracks the
-    # splitting leaves' rows. auto currently = off — the per-step row
-    # permutation measured slower than the kernel savings on chip
-    # (docs/Performance.md); true forces it on for experiments.
-    ("tpu_batched_part", str, "auto", []),
     # out-of-core streamed training (lightgbm_tpu.stream;
     # docs/OutOfCore.md): > 0 caps the rows of each host-resident binned
     # chunk — the dataset is ingested two-round (sample-based bin
@@ -422,8 +412,7 @@ SERVING_BACKENDS = ("traversal", "replay")
 OBSERVABILITY_LEVELS = ("none", "basic", "full")
 HEALTH_MONITOR_ACTIONS = ("auto", "none", "warn", "abort", "raise")
 OBS_DISTRIBUTED_MODES = ("auto", "on", "off")
-HIST_IMPLS = ("auto", "matmul", "scatter", "pallas", "pallas_highest",
-              "pallas_interpret", "pallas_highest_interpret")
+HIST_IMPLS = ("auto", "matmul", "scatter", "pallas", "pallas_interpret")
 BIN_PACKING_MODES = ("auto", "none", "nibble", "byte")
 
 _CANON: Dict[str, Tuple[type, Any]] = {n: (t, d) for n, t, d, _ in _PARAMS}
@@ -616,10 +605,6 @@ class Config:
                                    self.tpu_bin_packing))
         if self.tree_batch_splits < 1:
             raise LightGBMError("tree_batch_splits should be >= 1")
-        self.tpu_batched_part = str(self.tpu_batched_part).strip().lower()
-        if self.tpu_batched_part not in ("auto", "true", "false", "1", "0"):
-            raise LightGBMError("tpu_batched_part should be auto, true or "
-                                "false, got %s" % self.tpu_batched_part)
         if self.tpu_row_chunk < 0:
             raise LightGBMError("tpu_row_chunk should be >= 0 (0 = auto), "
                                 "got %s" % self.tpu_row_chunk)

@@ -123,17 +123,6 @@ class GrowParams(NamedTuple):
     # of the highest-gain frontier leaves per sequential step instead of
     # exactly one. 0 = exact leaf-wise (the reference's semantics)
     batch_splits: int = 0
-    # pack active rows to the front each batched step so all-inactive row
-    # tiles skip the slot kernel's compute body (tpu_batched_pack; opt-in
-    # until measured on chip)
-    batched_pack: bool = False
-    # partitioned batched growth (core/grow_batched_part.py): rows kept
-    # physically grouped by leaf in tile-aligned segments; per-step
-    # KERNEL cost tracks the splitting leaves' rows with no slot-one-hot
-    # redundancy — but the per-step row permutation (XLA gather) measured
-    # slower than the kernel savings on a v5e chip, so this stays opt-in
-    # (docs/Performance.md round-4 table)
-    batched_part: bool = False
     # frontier-wave growth (core/grow_frontier.py): split EVERY
     # positive-gain frontier leaf per sequential step, with histogram
     # construction batched into one leaf-indexed dataset pass per wave

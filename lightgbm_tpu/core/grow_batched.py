@@ -28,8 +28,10 @@ Design notes (same profiling facts as core/partition.py):
   feature column byte via one take_along_axis, and computes go-left for
   all K splits simultaneously;
 - child histograms for all 2K children come from ONE histogram build over
-  a combined index (child_slot * B + bin) — the multi-leaf analog of the
-  fused partition+histogram pass;
+  the rows — the multi-leaf analog of the fused partition+histogram pass:
+  on the Pallas spellings the parent-slot x 6-channel kernel
+  (histogram_pallas.build_histogram_slots6), on matmul / scatter the
+  leaf-indexed builder over child slots (build_histogram_frontier);
 - tree/leaf bookkeeping writes use scatter-with-drop (invalid lanes route
   to an out-of-bounds index) so masked lanes cannot race resident writes.
 
@@ -219,48 +221,6 @@ class _BatchState(NamedTuple):
     leaf_max: jnp.ndarray     # [L] f32 monotone upper bound
 
 
-def _combined_hist(xb, slot, active, grad, hess, hmask, b, kb, impl,
-                   row_chunk, pack):
-    """All 2K children's [C, B, 3] histograms in one pass over the rows.
-
-    Pallas spellings use the slot-extended digit kernel (the combined
-    slot*B+bin index as a third one-hot factor on the MXU); matmul/scatter
-    delegate to histogram.build_histogram_frontier, the leaf-indexed
-    frontier builder (slot one-hot x bin one-hot), with inactive rows
-    marked slot -1.
-
-    ``pack`` (tpu_batched_pack): gather the ACTIVE rows (those inside a
-    splitting leaf) to the front with a stable cumsum partition before
-    the kernel, and mark everything behind them slot -1 — all-inactive
-    row tiles then skip their compute body (pl.when), so per-step kernel
-    cost tracks the split leaves' rows instead of N. Costs one [N, C]
-    gather + one scatter per step; opt-in until measured on chip.
-    """
-    if impl.startswith("pallas"):
-        from .histogram_pallas import build_histogram_slots
-        if pack:
-            n = slot.shape[0]
-            act32 = active.astype(jnp.int32)
-            na = jnp.cumsum(act32)
-            total = na[-1]
-            pos = jnp.where(active, na - 1,
-                            total + jnp.cumsum(1 - act32) - 1)
-            perm = jnp.zeros((n,), jnp.int32).at[pos].set(
-                jnp.arange(n, dtype=jnp.int32))
-            xb = jnp.take(xb, perm, axis=0)
-            slot = jnp.where(active, slot, -1)[perm]
-            grad, hess, hmask = grad[perm], hess[perm], hmask[perm]
-        vals = jnp.stack([grad * hmask, hess * hmask, hmask], axis=0)
-        out = build_histogram_slots(
-            xb, slot, vals, num_bins=b, n_slots=2 * kb,
-            interpret=impl.endswith("interpret"),
-            highest="highest" in impl)                  # [2K, C, B, 3]
-        return out
-    return build_histogram_frontier(
-        xb, jnp.where(active, slot, -1), grad, hess, hmask,
-        num_bins=b, num_slots=2 * kb, row_chunk=row_chunk, impl=impl)
-
-
 def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                       sample_mask: jnp.ndarray, meta: FeatureMeta,
                       feature_mask: jnp.ndarray, params: GrowParams,
@@ -348,7 +308,7 @@ def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
         # ---- all 2K children's histograms in one combined build ---------
         hmask = sample_mask * active.astype(jnp.float32)
-        if params.hist_impl.startswith("pallas") and not params.batched_pack:
+        if params.hist_impl.startswith("pallas"):
             # parent-slot x 6-channel joint kernel: half the slot one-hot
             # width, double the MXU row utilization (round-4 on-chip fix)
             from .histogram_pallas import build_histogram_slots6
@@ -356,18 +316,20 @@ def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             h6 = psum(build_histogram_slots6(
                 xb, jnp.where(active, rs, -1), go_left.astype(jnp.float32),
                 vals3, num_bins=b, n_slots=kb,
-                interpret=params.hist_impl.endswith("interpret"),
-                highest="highest" in params.hist_impl))   # [K, C, B, 6]
+                interpret=params.hist_impl.endswith("interpret")))
+            # h6: [K, C, B, 6]
             ch_hist = jnp.stack([h6[..., :3], h6[..., 3:]],
                                 axis=1).reshape(2 * kb, ncols, b, 3)
         else:
-            # child slot = 2*rank + side; combined bin index = slot*B + bin
+            # matmul / scatter: the leaf-indexed frontier builder (slot
+            # one-hot x bin one-hot); child slot = 2*rank + side, rows
+            # outside a splitting leaf marked slot -1
             slot = jnp.where(active,
-                             rs * 2 + (~go_left).astype(jnp.int32), 0)
-            ch_hist = psum(_combined_hist(
-                xb, slot, active, grad, hess, hmask, b, kb,
-                params.hist_impl, params.row_chunk,
-                params.batched_pack))                     # [2K, C, B, 3]
+                             rs * 2 + (~go_left).astype(jnp.int32), -1)
+            ch_hist = psum(build_histogram_frontier(
+                xb, slot, grad, hess, hmask,
+                num_bins=b, num_slots=2 * kb, row_chunk=params.row_chunk,
+                impl=params.hist_impl))                   # [2K, C, B, 3]
 
         # ---- tree bookkeeping for up to K splits (Tree::Split, x K) -----
         (tree, leaf_min, leaf_max, safe_leaf,

@@ -70,8 +70,7 @@ def hist_tile_vals(xb_rows: jnp.ndarray, vals: jnp.ndarray, num_bins: int,
             from .histogram_pallas import build_histogram_pallas_vals
             return build_histogram_pallas_vals(
                 xb_rows, vals.T, num_bins,
-                interpret=impl.endswith("interpret"),
-                highest="highest" in impl)
+                interpret=impl.endswith("interpret"))
         if impl == "scatter":
             return _hist_scatter(xb_rows, vals, num_bins)
         return _hist_chunk_matmul(xb_rows, vals, num_bins)
@@ -103,11 +102,10 @@ def build_histogram(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     n = xb.shape[0]
     f = packed_cols or xb.shape[1]
     if impl.startswith("pallas"):
-        # pallas | pallas_highest | pallas_interpret | pallas_highest_interpret
+        # pallas | pallas_interpret
         from .histogram_pallas import build_histogram_pallas
         return build_histogram_pallas(xb, grad, hess, mask, num_bins,
                                       interpret=impl.endswith("interpret"),
-                                      highest="highest" in impl,
                                       packed_cols=packed_cols)
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=-1)  # [N, 3]
     if impl == "scatter" or n <= row_chunk:
@@ -212,7 +210,7 @@ def build_histogram_frontier(xb: jnp.ndarray, slot: jnp.ndarray,
       num_bins, num_slots: static sizes.
       impl: "matmul" ((leaf, bin) one-hot MXU contraction) | "scatter"
         (combined-index scatter-add) | pallas spellings (the slot kernel,
-        histogram_pallas.build_histogram_frontier_pallas).
+        histogram_pallas.build_histogram_slots).
       packed_cols: real column count F when xb is word-packed; 0 = plain.
 
     Returns: [num_slots, F, B, 3] f32 (sum_grad, sum_hess, count).
@@ -220,12 +218,11 @@ def build_histogram_frontier(xb: jnp.ndarray, slot: jnp.ndarray,
     n = xb.shape[0]
     f = packed_cols or xb.shape[1]
     if impl.startswith("pallas"):
-        from .histogram_pallas import build_histogram_frontier_pallas
+        from .histogram_pallas import build_histogram_slots
         vals = jnp.stack([grad * mask, hess * mask, mask], axis=0)  # [3, N]
-        return build_histogram_frontier_pallas(
+        return build_histogram_slots(
             xb, slot, vals, num_bins=num_bins, n_slots=num_slots,
-            interpret=impl.endswith("interpret"),
-            highest="highest" in impl, packed_cols=packed_cols)
+            interpret=impl.endswith("interpret"), packed_cols=packed_cols)
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=-1)     # [N, 3]
     if impl == "scatter":
         if packed_cols:

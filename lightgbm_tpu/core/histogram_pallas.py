@@ -17,13 +17,12 @@ xb (N*F bytes) + vals (12N bytes) + the [3, F, B] output.
 
 Precision: the values operand is split into two bfloat16 terms
 (a = hi16(a) + lo16(a)) and contracted with the exactly-representable
-one-hot in two default-precision MXU passes, at half Precision.HIGHEST's
-cost. Per-ELEMENT error is ~|v|*2^-17; summed over a bin this lands within
-~3e-6 of float64 relative to the bin's sum of |values| (measured), though a
-bin whose gradients nearly cancel can see a larger error relative to its
-small net sum — same caveat as any fixed-precision accumulation, and the
-same stance as the GPU learner's single-precision histograms
-(gpu_tree_learner.h:74-78).
+one-hot in two default-precision MXU passes. Per-ELEMENT error is
+~|v|*2^-17; summed over a bin this lands within ~3e-6 of float64 relative
+to the bin's sum of |values| (measured), though a bin whose gradients
+nearly cancel can see a larger error relative to its small net sum — same
+caveat as any fixed-precision accumulation, and the same stance as the GPU
+learner's single-precision histograms (gpu_tree_learner.h:74-78).
 
 Grid = (feature_tiles, row_tiles); rows are the innermost sequential
 reduction so each feature tile's accumulator stays resident in VMEM across
@@ -70,18 +69,12 @@ def _slot_compiler_params(out_block: tuple, row_tile: int,
     return pltpu.CompilerParams(vmem_limit_bytes=max(need, 16 << 20))
 
 
-def _digit_contract(a, eq, highest: bool):
+def _digit_contract(a, eq):
     """Shared MXU contraction of every digit kernel in this file:
     [M, C] values-by-digit LHS against a [Nw, C] one-hot RHS, contracted
-    over rows. ``highest`` keeps full f32 (the gpu_use_dp analog, ~2x
-    MXU cost); the default splits the values operand into two bfloat16
-    terms — the one-hot side is exactly representable, so two
-    default-precision passes land within ~3e-6 of f32."""
-    if highest:
-        return jax.lax.dot_general(
-            a, eq, (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)
+    over rows. The values operand is split into two bfloat16 terms — the
+    one-hot side is exactly representable, so two default-precision
+    passes land within ~3e-6 of f32."""
     a_top = a.astype(jnp.bfloat16)
     a_rem = (a - a_top.astype(jnp.float32)).astype(jnp.bfloat16)
     eqb = eq.astype(jnp.bfloat16)
@@ -93,17 +86,13 @@ def _digit_contract(a, eq, highest: bool):
         preferred_element_type=jnp.float32)
 
 
-def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int, highest: bool):
+def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int):
     """One (feature_tile, row_tile) grid cell.
 
     xb_ref: [Ft, C] uint8 binned values; vals_ref: [K, C] f32 value
     channels (K = 3: grad*mask, hess*mask, mask; K = 6: the same for both
     children of a fused partition+histogram pass);
     out_ref: [K, Ft, Hi, 16] f32 accumulator.
-
-    ``highest``: contract in full f32 (Precision.HIGHEST) instead of the
-    default two-term bf16 split — ~2x the MXU cost, for users who need the
-    tightest reference parity (the gpu_use_dp analog, config.h:784).
     """
     r = pl.program_id(1)
     xb = xb_ref[...].astype(jnp.int32)                       # [Ft, C]
@@ -128,19 +117,18 @@ def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int, highest: bool):
         # relayout ... vector<16x2048xi1>"; re-tested on a v5e with
         # jax 0.9.0 / libtpu 0.0.34)
         eqlo = jnp.where(lo_eq, 1.0, 0.0)
-        part = _digit_contract(a, eqlo, highest)             # [K*Hi, 16]
+        part = _digit_contract(a, eqlo)                      # [K*Hi, 16]
         out_ref[:, j, :, :] += part.reshape(k, hi_n, 16)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_tile", "feature_tile",
-                                    "interpret", "highest", "packed_cols"))
+                                    "interpret", "packed_cols"))
 def build_histogram_pallas(xb: jnp.ndarray, grad: jnp.ndarray,
                            hess: jnp.ndarray, mask: jnp.ndarray,
                            num_bins: int, row_tile: int = 2048,
                            feature_tile: int = 8,
                            interpret: bool = False,
-                           highest: bool = False,
                            packed_cols: int = 0) -> jnp.ndarray:
     """[N, F] uint8 bins + per-row values -> [F, B, 3] f32 histograms.
 
@@ -151,18 +139,16 @@ def build_histogram_pallas(xb: jnp.ndarray, grad: jnp.ndarray,
     """
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=0)   # [3, N]
     return build_histogram_pallas_vals(xb, vals, num_bins, row_tile,
-                                       feature_tile, interpret, highest,
-                                       packed_cols)
+                                       feature_tile, interpret, packed_cols)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_tile", "feature_tile",
-                                    "interpret", "highest", "packed_cols"))
+                                    "interpret", "packed_cols"))
 def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
                                 num_bins: int, row_tile: int = 2048,
                                 feature_tile: int = 8,
                                 interpret: bool = False,
-                                highest: bool = False,
                                 packed_cols: int = 0) -> jnp.ndarray:
     """Same kernel with pre-stacked value channels: vals [K, N] -> output
     [F, B, K] (K = 3 for one histogram, 6 for a fused two-child pass)."""
@@ -185,7 +171,7 @@ def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
     vals = jnp.pad(vals, ((0, 0), (0, n_pad)))   # padded rows carry mask 0
     fp = f + f_pad
 
-    kernel = functools.partial(_hist_kernel, hi_n=hi_n, highest=highest)
+    kernel = functools.partial(_hist_kernel, hi_n=hi_n)
     out = pl.pallas_call(
         kernel,
         grid=(fp // feature_tile, (n + n_pad) // row_tile),
@@ -203,12 +189,12 @@ def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
 
 
 def _hist_slot6_kernel(xb_ref, slot_ref, sel_ref, vals_ref, out_ref, *,
-                       hi_n: int, n_slots: int, highest: bool):
+                       hi_n: int, n_slots: int):
     """Joint slot kernel, PARENT-slot x 6-channel variant (round-4 MXU
     fix): rows carry their splitting PARENT's rank (n_slots = K) and a
     go-left selector; the kernel routes (g, h, m) into left/right channel
     triples, so both children come out of half the slot one-hot width of
-    the child-slot variant above — 2x fewer MXU column passes AND 2x the
+    the child-slot variant below — 2x fewer MXU column passes AND 2x the
     systolic-row utilization (M = 6*Hi = 96 vs 48).
     """
     r = pl.program_id(1)
@@ -238,19 +224,18 @@ def _hist_slot6_kernel(xb_ref, slot_ref, sel_ref, vals_ref, out_ref, *,
                           0.0).reshape(6 * hi_n, c)          # [6*Hi, C]
             eqj = jnp.where(s_eq[:, None, :] & lo_eq[None, :, :], 1.0,
                             0.0).reshape(n_slots * 16, c)    # [S*16, C]
-            part = _digit_contract(a, eqj, highest)
+            part = _digit_contract(a, eqj)
             out_ref[:, j, :, :] += part.reshape(6, hi_n, n_slots * 16)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "n_slots", "row_tile",
-                                    "feature_tile", "interpret", "highest"))
+                                    "feature_tile", "interpret"))
 def build_histogram_slots6(xb: jnp.ndarray, slot: jnp.ndarray,
                            sel: jnp.ndarray, vals: jnp.ndarray,
                            num_bins: int, n_slots: int,
                            row_tile: int = 2048, feature_tile: int = 8,
-                           interpret: bool = False,
-                           highest: bool = False) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """[N, F] uint8 bins + per-row PARENT-slot ids (-1 = inactive) +
     per-row go-left selector + [3, N] value channels ->
     [n_slots, F, B, 6] f32: channels [g,h,m]*sel then [g,h,m]*(1-sel) —
@@ -269,7 +254,7 @@ def build_histogram_slots6(xb: jnp.ndarray, slot: jnp.ndarray,
     fp = f + f_pad
 
     kernel = functools.partial(_hist_slot6_kernel, hi_n=hi_n,
-                               n_slots=n_slots, highest=highest)
+                               n_slots=n_slots)
     out_block = (6, feature_tile, hi_n, n_slots * 16)
     out = pl.pallas_call(
         kernel,
@@ -293,133 +278,13 @@ def build_histogram_slots6(xb: jnp.ndarray, slot: jnp.ndarray,
     return out[:, :f, :num_bins]
 
 
-def _hist_part_kernel(tile_slot_ref, tile_first_ref, xb_ref, sel_ref,
-                      vals_ref, out_ref, *, hi_n: int, highest: bool):
-    """One (feature_tile, row_tile) grid cell of the PARTITIONED batched
-    kernel (core/grow_batched_part.py): rows arrive physically grouped by
-    leaf into row_tile-ALIGNED segments, so every row tile belongs to at
-    most ONE frontier slot — the tile->slot map rides in scalar-prefetch
-    SMEM and drives the OUTPUT BlockSpec index directly. Unlike the joint
-    slot kernel above, no S-wide one-hot ever materializes: per-row work
-    is the base digit kernel's (the joint kernel pays S x redundant MXU
-    work because each row matches exactly one of its S x 16 columns).
-
-    Six value channels per slot: ``sel`` in {1.0, 0.0} routes each row's
-    (g, h, m) into the first or second channel triple — both children of
-    a splitting leaf (sel = go_left) in ONE pass over the parent's rows,
-    at BETTER MXU utilization than 3 channels (M = 6*Hi = 96 rows of the
-    systolic array instead of 48).
-
-    tile_slot[t] == -1 marks a tile with no frontier rows: its compute
-    body is skipped entirely, so per-step cost tracks the splitting
-    leaves' rows, not N. tile_first[t] == 1 marks the first tile of a
-    slot's run and zero-initializes the accumulator (blocks of slots that
-    never appear keep garbage — callers mask invalid slots after).
-
-    Pallas TPU's pipelined output machinery requires every output block
-    to be visited in ONE contiguous grid run — revisiting a block after
-    visiting others corrupts it via the stale double-buffer (measured on
-    a v5e chip: mapping inactive tiles to slot 0 silently mixed partial
-    sums into slot 0's result). Inactive tiles therefore index a
-    DEDICATED dummy block (slot n_slots) whose garbage content is
-    dropped by the caller; real slots are each one contiguous segment of
-    the layout, so they are never revisited.
-    """
-    r = pl.program_id(1)
-    slot = tile_slot_ref[r]
-
-    @pl.when(tile_first_ref[r] == 1)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(slot >= 0)
-    def _body():
-        xb = xb_ref[...].astype(jnp.int32)                   # [Ft, C]
-        sel = sel_ref[...]                                   # [1, C]
-        v3 = vals_ref[...]                                   # [3, C]
-        ft, c = xb.shape
-        v6 = jnp.concatenate([v3 * sel, v3 * (1.0 - sel)],
-                             axis=0)                         # [6, C]
-        iota_lo = jax.lax.broadcasted_iota(jnp.int32, (16, c), 0)
-        iota_hi = jax.lax.broadcasted_iota(jnp.int32, (hi_n, c), 0)
-        for j in range(ft):
-            x = xb[j:j + 1, :]                               # [1, C]
-            hi_eq = iota_hi == (x >> 4)                      # [Hi, C]
-            lo_eq = iota_lo == (x & 15)                      # [16, C]
-            a = jnp.where(hi_eq[None, :, :], v6[:, None, :],
-                          0.0).reshape(6 * hi_n, c)          # [6*Hi, C]
-            eqlo = jnp.where(lo_eq, 1.0, 0.0)
-            part = _digit_contract(a, eqlo, highest)         # [6*Hi, 16]
-            out_ref[0, :, j, :, :] += part.reshape(6, hi_n, 16)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "n_slots", "row_tile",
-                                    "feature_tile", "interpret", "highest"))
-def build_histogram_part_tiles(xb_fm: jnp.ndarray, sel: jnp.ndarray,
-                               vals: jnp.ndarray, tile_slot: jnp.ndarray,
-                               tile_first: jnp.ndarray, num_bins: int,
-                               n_slots: int, row_tile: int = 2048,
-                               feature_tile: int = 8,
-                               interpret: bool = False,
-                               highest: bool = False) -> jnp.ndarray:
-    """Partitioned-layout histograms: [F, Np] FEATURE-MAJOR uint8 bins
-    (Np a multiple of row_tile, rows grouped into tile-aligned leaf
-    segments) + per-row channel selector + [3, Np] value channels +
-    per-tile slot/first maps -> [n_slots, F, B, 6] f32.
-
-    Channel order per slot: [g*sel, h*sel, m*sel, g*(1-sel), h*(1-sel),
-    m*(1-sel)] — left child then right child when sel = go_left. Rows in
-    tiles with tile_slot == -1 and rows whose value channels are zero
-    (segment padding) contribute nothing. Slots with no tiles keep
-    UNINITIALIZED memory — mask invalid slots downstream.
-    """
-    f, np_ = xb_fm.shape
-    assert np_ % row_tile == 0, "partitioned layout must be tile-aligned"
-    hi_n = max(1, (num_bins + 15) // 16)
-    f_pad = (-f) % feature_tile
-    xb_p = jnp.pad(xb_fm, ((0, f_pad), (0, 0))).astype(jnp.uint8)
-    fp = f + f_pad
-    t = np_ // row_tile
-
-    from jax.experimental.pallas import tpu as pltpu
-    kernel = functools.partial(_hist_part_kernel, hi_n=hi_n,
-                               highest=highest)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(fp // feature_tile, t),
-        in_specs=[
-            pl.BlockSpec((feature_tile, row_tile),
-                         lambda i, r, *_: (i, r)),
-            pl.BlockSpec((1, row_tile), lambda i, r, *_: (0, r)),
-            pl.BlockSpec((3, row_tile), lambda i, r, *_: (0, r)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 6, feature_tile, hi_n, 16),
-            lambda i, r, slot_ref, first_ref: (
-                jnp.where(slot_ref[r] < 0, n_slots, slot_ref[r]),
-                0, i, 0, 0)),
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_slots + 1, 6, fp, hi_n, 16),
-                                       jnp.float32),
-        interpret=interpret,
-    )(tile_slot.astype(jnp.int32), tile_first.astype(jnp.int32),
-      xb_p, sel[None, :], vals)
-    # [S+1, 6, Fp, Hi, 16] -> [S, F, B, 6] (dummy slot dropped)
-    out = out[:n_slots].reshape(n_slots, 6, fp, hi_n * 16)
-    return jnp.transpose(out, (0, 2, 3, 1))[:, :f, :num_bins]
-
-
 def _hist_slot_kernel(xb_ref, slot_ref, vals_ref, out_ref, *, hi_n: int,
-                      n_slots: int, highest: bool):
+                      n_slots: int):
     """One (feature_tile, row_tile) grid cell of the SLOT-EXTENDED digit
-    kernel (batched-frontier growth, core/grow_batched.py): every row
-    carries a slot id in [0, n_slots) — which frontier-leaf child it
-    belongs to this step — and the kernel accumulates a separate [B]
-    histogram per (slot, feature).
+    kernel (frontier-wave growth, core/grow_frontier.py): every row
+    carries a slot id in [0, n_slots) — its leaf's rank in this wave —
+    and the kernel accumulates a separate [B] histogram per (slot,
+    feature).
 
     The combined index slot*B + 16*hi + lo factorizes into THREE one-hots;
     grouping (vals x hi) on the left and (slot x lo) on the right keeps
@@ -432,10 +297,8 @@ def _hist_slot_kernel(xb_ref, slot_ref, vals_ref, out_ref, *, hi_n: int,
     minor so the RHS one-hot needs no in-kernel transpose; the caller
     reorders to [S, F, B, K]).
 
-    A row tile whose slots are ALL -1 skips its entire compute body —
-    with actives packed to the front (grow_batched's tpu_batched_pack),
-    per-step cost becomes proportional to the split leaves' rows instead
-    of N.
+    A row tile whose slots are ALL -1 (a frontier wave marks every row
+    in no splitting leaf so) skips its entire compute body.
     """
     r = pl.program_id(1)
     slot = slot_ref[...].astype(jnp.int32)                   # [1, C]
@@ -451,11 +314,11 @@ def _hist_slot_kernel(xb_ref, slot_ref, vals_ref, out_ref, *, hi_n: int,
     @pl.when(jnp.any(slot >= 0))
     def _body():
         _hist_slot_tile(xb_ref, slot, vals, out_ref, hi_n=hi_n,
-                        n_slots=n_slots, highest=highest, k=k, ft=ft, c=c)
+                        n_slots=n_slots, k=k, ft=ft, c=c)
 
 
-def _hist_slot_tile(xb_ref, slot, vals, out_ref, *, hi_n, n_slots, highest,
-                    k, ft, c):
+def _hist_slot_tile(xb_ref, slot, vals, out_ref, *, hi_n, n_slots, k, ft,
+                    c):
     xb = xb_ref[...].astype(jnp.int32)                       # [Ft, C]
     iota_lo = jax.lax.broadcasted_iota(jnp.int32, (16, c), 0)
     iota_hi = jax.lax.broadcasted_iota(jnp.int32, (hi_n, c), 0)
@@ -470,23 +333,23 @@ def _hist_slot_tile(xb_ref, slot, vals, out_ref, *, hi_n, n_slots, highest,
         # RHS one-hot of (slot, lo) jointly: column index s*16 + lo
         eqj = jnp.where(s_eq[:, None, :] & lo_eq[None, :, :], 1.0,
                         0.0).reshape(n_slots * 16, c)        # [S*16, C]
-        part = _digit_contract(a, eqj, highest)              # [K*Hi, S*16]
+        part = _digit_contract(a, eqj)                       # [K*Hi, S*16]
         out_ref[:, j, :, :] += part.reshape(k, hi_n, n_slots * 16)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "n_slots", "row_tile",
-                                    "feature_tile", "interpret", "highest",
+                                    "feature_tile", "interpret",
                                     "packed_cols"))
 def build_histogram_slots(xb: jnp.ndarray, slot: jnp.ndarray,
                           vals: jnp.ndarray, num_bins: int, n_slots: int,
                           row_tile: int = 2048, feature_tile: int = 8,
                           interpret: bool = False,
-                          highest: bool = False,
                           packed_cols: int = 0) -> jnp.ndarray:
     """[N, F] uint8 bins + per-row slot ids + [K, N] value channels ->
     [n_slots, F, B, K] f32 histograms — every slot's histogram in ONE pass
-    over the rows (the multi-leaf step of batched-frontier growth).
+    over the rows: one frontier wave's histograms, slot = the row's
+    frontier rank (the device path of histogram.build_histogram_frontier).
 
     Rows outside every slot should carry slot -1 (matches no one-hot AND
     lets an all-inactive row tile skip its compute body entirely); zero
@@ -512,7 +375,7 @@ def build_histogram_slots(xb: jnp.ndarray, slot: jnp.ndarray,
     fp = f + f_pad
 
     kernel = functools.partial(_hist_slot_kernel, hi_n=hi_n,
-                               n_slots=n_slots, highest=highest)
+                               n_slots=n_slots)
     out_block = (k, feature_tile, hi_n, n_slots * 16)
     out = pl.pallas_call(
         kernel,
@@ -533,27 +396,3 @@ def build_histogram_slots(xb: jnp.ndarray, slot: jnp.ndarray,
     out = jnp.transpose(out, (3, 1, 2, 4, 0)).reshape(
         n_slots, fp, hi_n * 16, k)
     return out[:, :f, :num_bins]
-
-
-def build_histogram_frontier_pallas(xb: jnp.ndarray, slot: jnp.ndarray,
-                                    vals: jnp.ndarray, num_bins: int,
-                                    n_slots: int, row_tile: int = 2048,
-                                    feature_tile: int = 8,
-                                    interpret: bool = False,
-                                    highest: bool = False,
-                                    packed_cols: int = 0) -> jnp.ndarray:
-    """Frontier-wave entry of the slot kernel: the device path of
-    histogram.build_histogram_frontier.
-
-    One frontier wave's histograms — [n_slots, F, B, K] with slot = the
-    row's frontier rank (-1 = row in no splitting leaf) — ARE the slot
-    kernel's contract, so this is a named alias of build_histogram_slots:
-    the digit-factorized MXU contraction with a per-tile slot one-hot as
-    the third factor, all-inactive row tiles skipping their compute body.
-    Kept as its own entry so the frontier grower's kernel dependency is
-    explicit and its tiling defaults can diverge from the batched grower's
-    without touching that path."""
-    return build_histogram_slots(
-        xb, slot, vals, num_bins=num_bins, n_slots=n_slots,
-        row_tile=row_tile, feature_tile=feature_tile,
-        interpret=interpret, highest=highest, packed_cols=packed_cols)

@@ -336,18 +336,3 @@ def leaf_id_from_partition(part: RowPartition,
     with jax.named_scope("lgbm.leaf_ids"):
         return _range_owner(part.order, part.leaf_begin, part.leaf_count,
                             num_data)
-
-
-def frontier_slots_from_partition(part: RowPartition, leaves: jnp.ndarray,
-                                  num_data: int) -> jnp.ndarray:
-    """Per-row frontier slot from the row partition: rows inside
-    ``leaves[i]``'s range get slot i, every other row -1.
-
-    This is the hand-off from the partition to
-    histogram.build_histogram_frontier — the partition gives the builder
-    the wave's LEAF IDS and the builder sweeps the dataset once for all
-    of them, instead of extracting one leaf's row list per histogram.
-    The selected leaves are distinct and cover only PART of [0, num_data).
-    """
-    return _range_owner(part.order, part.leaf_begin[leaves],
-                        part.leaf_count[leaves], num_data)

@@ -7,7 +7,7 @@ sweep, each serving predict bucket, the materialize flush) is AOT-lowered
 and compiled once, and its static costs — FLOPs, bytes accessed, peak /
 temp / output memory — are read from ``Compiled.cost_analysis()`` +
 ``Compiled.memory_analysis()``.  Combined with measured wall time (span
-summaries from obs/trace.py, or explicit probe timings) that yields
+summaries from obs/trace.py) that yields
 per-phase roofline attribution: achieved FLOP/s, achieved B/s, arithmetic
 intensity, and ``mfu`` / ``membw_util`` against the detected chip's peaks.
 Both GPU GBDT papers (arXiv:1706.08359, 1806.11248) argue from exactly
@@ -361,17 +361,12 @@ def span_wall_times(registry: Optional[MetricsRegistry] = None,
 
 
 def roofline_snapshot(registry: Optional[MetricsRegistry] = None,
-                      cost_model: Optional[CostModel] = None,
-                      extra_wall_times: Optional[
-                          Dict[str, Tuple[float, float]]] = None
+                      cost_model: Optional[CostModel] = None
                       ) -> Dict[str, Any]:
     """The ``GET /roofline`` payload: detected peaks + one attribution
     row per extracted entry point, joined with whatever span wall-times
-    the registry holds.  Entries that have no matching span (probe-only
-    phases like the wave-width buckets) report static costs only, unless
-    the caller supplies their timings via ``extra_wall_times``
-    (``{name: (seconds, calls)}`` — perf_report passes the phase probe's
-    standalone per-call times this way)."""
+    the registry holds.  Entries that have no matching span (the
+    wave-width buckets) report static costs only."""
     peaks = detect_peaks()
     try:
         import jax
@@ -379,10 +374,8 @@ def roofline_snapshot(registry: Optional[MetricsRegistry] = None,
         backend = jax.default_backend()
     except Exception:  # noqa: BLE001 - scrape must answer regardless
         kind, backend = "", ""
-    wall = span_wall_times(registry)
-    if extra_wall_times:
-        wall.update(extra_wall_times)
-    rows = roofline_table(wall, cost_model=cost_model, peaks=peaks)
+    rows = roofline_table(span_wall_times(registry), cost_model=cost_model,
+                          peaks=peaks)
     return {
         "ts": round(time.time(), 3),
         "backend": backend,
@@ -390,28 +383,3 @@ def roofline_snapshot(registry: Optional[MetricsRegistry] = None,
         "peaks": peaks,      # None on CPU: achieved rates only
         "rows": rows,
     }
-
-
-def roofline_markdown(snapshot: Dict[str, Any]) -> str:
-    """Render a roofline snapshot as a markdown table (perf_report)."""
-    lines = ["| phase | calls | seconds | GFLOP/call | MB/call | "
-             "GFLOP/s | GB/s | intensity | mfu | membw_util |",
-             "|---|---|---|---|---|---|---|---|---|---|"]
-    for r in snapshot.get("rows", []):
-        def _g(key, scale, fmt="%.3f"):
-            v = r.get(key)
-            return (fmt % (v / scale)) if isinstance(v, (int, float)) else "-"
-        lines.append("| %s | %d | %s | %s | %s | %s | %s | %s | %s | %s |" % (
-            r.get("phase", "?"), int(r.get("calls", 0)),
-            ("%.4f" % r["seconds"]) if r.get("seconds") else "-",
-            _g("flops_per_call", 1e9), _g("bytes_per_call", 1e6),
-            _g("flops_per_s", 1e9), _g("bytes_per_s", 1e9),
-            ("%.4f" % r["arithmetic_intensity"])
-            if "arithmetic_intensity" in r else "-",
-            ("%.6f" % r["mfu"]) if "mfu" in r else "-",
-            ("%.6f" % r["membw_util"]) if "membw_util" in r else "-"))
-    if snapshot.get("peaks") is None:
-        lines.append("")
-        lines.append("_CPU backend: achieved rates only — no utilization "
-                     "ratio is reported against a TPU peak._")
-    return "\n".join(lines) + "\n"

@@ -1,20 +1,19 @@
-"""Phase timing probes — the TIMETAG analog (serial_tree_learner.cpp:15-43).
+"""Compile accounting and the persistent compile cache, plus two
+deterministic summaries: a latency window's quantiles (serving) and a
+grown tree's frontier-wave counts (the perf gate).
 
-The boosting iteration is one fused jit program, so per-phase time cannot be
-read from inside it; instead each phase's op is re-run standalone on the
-booster's real shapes and timed. The phase list mirrors the reference's
-(init/hist/find-split/split) plus the TPU-specific partition/gather phase.
-``jax.profiler`` traces can be layered on via trace_dir for a full timeline.
+Phase TIMES are not taken here: the iteration names its own phases
+(``jax.named_scope("lgbm.*")``, docs/Observability.md) and
+``obs.trace.capture_phases`` / ``tools/trace_phases.py`` read them from a
+``jax.profiler`` capture of the program as it runs.
 """
 from __future__ import annotations
 
 import os
 import threading
-import time
-from typing import Dict, Optional
+from typing import Dict
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax._src.core import trace_state_clean
 
@@ -166,16 +165,6 @@ def enable_compile_cache(requested: str = "") -> str:
     return cache_dir
 
 
-def _timed(fn, *args, reps=3, **kw) -> float:
-    out = fn(*args, **kw)
-    jax.block_until_ready(out)  # lgbm-lint: disable=LGL103 bench warmup
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        out = fn(*args, **kw)
-    jax.block_until_ready(out)  # lgbm-lint: disable=LGL103 bench barrier
-    return (time.perf_counter() - t0) / reps
-
-
 def latency_summary(samples_ms) -> Dict[str, float]:
     """Quantile summary of a latency sample window (milliseconds) — the
     serving-side SLO view (p50/p90/p99) shared by serving.metrics and any
@@ -191,183 +180,14 @@ def latency_summary(samples_ms) -> Dict[str, float]:
             "max_ms": round(float(a.max()), 4)}
 
 
-def phase_probe(booster, trace_dir: Optional[str] = None) -> Dict[str, float]:
-    """Per-phase seconds for one boosting iteration's building blocks, using
-    the booster's actual data/shapes. Keys: grad, hist_full,
-    partition_hist_fused, hist_leaf_half, find_split,
-    compile_cache_hits/misses, plus frontier_hist / frontier_hist_w<k> /
-    frontier_waves / frontier_sweeps_per_tree / frontier_wave_occupancy /
-    frontier_slot_sweeps_per_tree when the booster grows in frontier mode
-    (docs/Performance.md describes each)."""
-    from .core.histogram import build_histogram
-    from .core.partition import (frontier_slots_from_partition, hist_for_leaf,
-                                 init_partition, make_row_gather,
-                                 partition_and_hist, stack_vals,
-                                 window_placement)
-    from .core.split import find_best_split
-
-    from .obs.trace import perfetto_trace
-
-    xb = booster.xb
-    n = booster.num_data
-    params = booster.grow_params
-    meta = booster.feature_meta
-    out: Dict[str, float] = {}
-
-    # trace_dir rides the shared Perfetto helper (obs/trace.py), which
-    # degrades to a warning when the profiler backend is unavailable or a
-    # capture is already active instead of crashing the probe
-    with perfetto_trace(trace_dir):
-        scores = booster.scores
-        if booster.objective is not None:
-            obj = booster.objective
-            if booster.num_tree_per_iteration == 1:
-                grad_fn = jax.jit(lambda s: obj.get_gradients(s[:, 0]))
-            else:
-                grad_fn = jax.jit(lambda s: obj.get_gradients(s))
-            out["grad"] = _timed(grad_fn, scores)
-            g, h = grad_fn(scores)
-            if g.ndim == 2:           # multiclass: probe class 0's tree
-                g, h = g[:, 0], h[:, 0]
-        else:
-            g = jnp.zeros((n,), jnp.float32)
-            h = jnp.ones((n,), jnp.float32)
-        mask = jnp.ones((n,), jnp.float32)
-
-        packed = int(getattr(params, "word_packed_cols", 0) or 0)
-        out["hist_full"] = _timed(
-            build_histogram, xb, g, h, mask, num_bins=params.num_bins,
-            row_chunk=params.row_chunk, impl=params.hist_impl,
-            packed_cols=packed)
-        hist = build_histogram(xb, g, h, mask, num_bins=params.num_bins,
-                               row_chunk=params.row_chunk,
-                               impl=params.hist_impl, packed_cols=packed)
-
-        part = init_partition(n, params.num_leaves, params.row_chunk)
-        # sized to the partition TILE, not n: the decision closure below
-        # is sliced per row tile, which is row_chunk wide even when the
-        # dataset is smaller
-        half = jnp.asarray(
-            np.arange(max(n, params.row_chunk), dtype=np.int64) % 2 == 0)
-        # probe in f32 regardless of ambient x64: the gather closure owns
-        # the packed bins/values boundary, so dtypes must be consistent
-        # the partition machinery gathers plain uint8 columns — probe it
-        # on a transient unpacked view when the device matrix is
-        # word-packed (the frontier grower routes from words directly;
-        # these two probes price the EXACT grower's phases)
-        if packed:
-            from .core.binpack import unpack_words
-            xb_cols = unpack_words(xb, packed)
-        else:
-            xb_cols = xb
-        gr = make_row_gather(
-            xb_cols, stack_vals(g.astype(jnp.float32),
-                                h.astype(jnp.float32),
-                                mask.astype(jnp.float32)))
-        ncols = xb_cols.shape[1]
-        # the real growth path: one fused pass that partitions the root and
-        # prices both children — same placement selection as grow_tree
-        windows = window_placement(params.hist_impl, params.vmapped_classes)
-        fused = jax.jit(lambda p: partition_and_hist(
-            p, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
-            lambda rows: half[:rows.shape[0]],
-            jnp.asarray(True), params.row_chunk, gr, ncols,
-            params.num_bins, params.hist_impl, windows=windows))
-        out["partition_hist_fused"] = _timed(lambda p: fused(p)[0], part)
-        part2 = fused(part)[0]
-        out["hist_leaf_half"] = _timed(
-            jax.jit(lambda p: hist_for_leaf(
-                p, jnp.int32(0), gr, n, ncols, params.num_bins,
-                params.row_chunk, impl=params.hist_impl)), part2)
-
-        if getattr(params, "frontier_mode", False):
-            from . import bucketing
-            from .core.histogram import build_histogram_frontier
-            # the frontier wave cost: the partition hands the builder the
-            # wave's LEAF IDS and one leaf-indexed sweep prices them all.
-            # kb is the clamped maximum wave width; with bucketing on,
-            # early waves run at the smaller pow-2 ladder widths, so the
-            # per-width probes below show the per-sweep cost the grower
-            # actually pays per wave
-            bucketed = getattr(params, "frontier_bucketing", False)
-            kb = bucketing.frontier_max_width(params.num_leaves,
-                                              params.max_depth)
-            ladder = (bucketing.wave_width_ladder(params.num_leaves,
-                                                  params.max_depth)
-                      if bucketed else [kb])
-            for w in sorted({ladder[0], ladder[len(ladder) // 2],
-                             ladder[-1]}):
-                slots_w = frontier_slots_from_partition(
-                    part2, jnp.arange(w, dtype=jnp.int32), n)
-                t_w = _timed(
-                    build_histogram_frontier, xb, slots_w, g, h, mask,
-                    num_bins=params.num_bins, num_slots=w,
-                    row_chunk=params.row_chunk, impl=params.hist_impl,
-                    packed_cols=packed)
-                out["frontier_hist_w%d" % w] = t_w
-                if w == ladder[-1]:      # full width: the pre-bucketing key
-                    out["frontier_hist"] = t_w
-            # dataset sweeps per tree scale with DEPTH, not leaf count:
-            # wave w splits the leaves created in wave w-1, so waves = max
-            # leaf depth of the grown tree, sweeps = waves + 1 (the root).
-            # An internal node's depth IS the wave that committed it (every
-            # positive-gain leaf splits at the first wave after it
-            # appears), so per-depth internal-node counts reconstruct each
-            # wave's live width exactly.
-            if booster.models:
-                for k, v in frontier_tree_stats(booster.models[0],
-                                                params).items():
-                    out["frontier_" + k] = v
-
-        sum_g = jnp.sum(g)
-        sum_h = jnp.sum(h)
-        cnt = jnp.asarray(float(n), jnp.float32)
-        fmask = jnp.ones((meta.num_bin.shape[0],), bool)
-        split_fn = jax.jit(lambda hh: find_best_split(
-            hh, meta, params.split, sum_g, sum_h, cnt, fmask,
-            with_categorical=params.with_categorical))
-        # find_split works on per-feature views; without EFB hist == view
-        if not params.with_efb:
-            out["find_split"] = _timed(split_fn, hist)
-
-        # persistent-compile-cache accounting (compile_cache_dir): both
-        # stay 0 unless the cache is enabled; a warm cache shows as hits
-        stats = compile_cache_stats()
-        out["compile_cache_hits"] = float(stats["persistent_cache_hits"])
-        out["compile_cache_misses"] = float(stats["persistent_cache_misses"])
-
-        # checkpoint overhead (lightgbm_tpu.checkpoint): one full-state
-        # snapshot save + restore on the booster's real model/shapes, so
-        # the per-period cost shows up next to the phases it competes with
-        out.update(_checkpoint_probe(booster))
-
-        # roofline attribution (obs/costmodel.py): join extracted XLA
-        # per-call costs with this probe's standalone wall times + any
-        # span totals the run accumulated. Best-effort — a probe must
-        # never fail because cost extraction cannot run here.
-        try:
-            from .obs.costmodel import (detect_peaks, roofline_table,
-                                        span_wall_times)
-            booster.extract_cost_model(force=True)
-            wall = span_wall_times()
-            for k, v in out.items():
-                if k.startswith("frontier_hist_w"):
-                    wall[k] = (float(v), 1.0)
-            out["roofline"] = roofline_table(wall, peaks=detect_peaks())
-        except Exception:  # noqa: BLE001
-            pass
-    return {k: (round(v, 5) if isinstance(v, float) else v)
-            for k, v in out.items()}
-
-
 def frontier_tree_stats(tree, params) -> Dict[str, float]:
     """Deterministic per-tree wave accounting from a grown HostTree:
     waves, dataset sweeps, occupancy and slot-sweeps under the
     bucketing ladder. An internal node's depth IS the wave that
     committed it (every positive-gain leaf splits at the first wave
     after it appears), so per-depth internal-node counts reconstruct
-    each wave's live width exactly. Shared by phase_probe and the perf
-    gate (obs/perfgate.py) — semantic counters, no timing."""
+    each wave's live width exactly. Read by the perf gate
+    (obs/perfgate.py) — semantic counters, no timing."""
     from . import bucketing
     bucketed = getattr(params, "frontier_bucketing", False)
     kb = bucketing.frontier_max_width(params.num_leaves, params.max_depth)
@@ -393,32 +213,3 @@ def frontier_tree_stats(tree, params) -> Dict[str, float]:
                                / max(float(sum(paid)), 1.0)),
             "slot_sweeps_per_tree": float(sum(paid)),
             "slot_sweeps_fixed_width": float(waves * kb)}
-
-
-def _checkpoint_probe(booster) -> Dict[str, float]:
-    """checkpoint_save_s / checkpoint_restore_s: wall time of one snapshot
-    write (state npz + manifest + model text) and one verified load back
-    into the same driver. Restoring the state it just saved is a no-op for
-    the booster. Empty dict when the booster has no trained trees yet."""
-    import shutil
-    import tempfile
-    try:
-        if not booster.models:
-            return {}
-        from .checkpoint.manager import CheckpointManager
-        tmp = tempfile.mkdtemp(prefix="lgbm_tpu_ckpt_probe_")
-        try:
-            mgr = CheckpointManager(tmp, keep_last_n=1)
-            t0 = time.perf_counter()
-            mgr.save(booster)
-            save_s = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            handle = mgr.load_latest()
-            booster.load_training_state(handle.meta, handle.arrays)
-            restore_s = time.perf_counter() - t0
-            return {"checkpoint_save_s": save_s,
-                    "checkpoint_restore_s": restore_s}
-        finally:
-            shutil.rmtree(tmp, ignore_errors=True)
-    except Exception:  # noqa: BLE001 - a probe must not kill the caller
-        return {}

@@ -12,7 +12,7 @@ loop actually blocks on an unfinished copy, so
 
 is 1.0 when every transfer finished under the previous sweep and 0.0
 when the loop is pure transfer-bound. Those numbers surface in
-``tools/stream_smoke.py`` and BENCH_r12.
+``tools/stream_smoke.py``.
 
 Chunks are repacked host-side to a UNIFORM ``chunk_rows`` row count
 (last chunk zero-padded): every device buffer then has one shape

@@ -8,11 +8,12 @@ import json
 import os
 import tempfile
 
+import jax
 import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.log import LightGBMError
+from lightgbm_tpu.log import LightGBMError, Log
 
 from conftest import make_binary, make_multiclass
 
@@ -35,7 +36,12 @@ def _forced_file():
 # expectation: "raise" | dict of engagement flags to assert
 #   part_mesh -> _partition_on_mesh, fp -> _explicit_fp,
 #   use_part -> grow_params.use_partition, pool -> grow_params.pool_slots>0,
-#   vmapped -> grow_params.vmapped_classes, batch -> grow_params.batch_splits>0
+#   vmapped -> grow_params.vmapped_classes,
+#   batch -> grow_params.batch_splits>0,
+#   frontier -> grow_params.frontier_mode,
+#   frontier_rs -> grow_params.frontier_rs
+# "WARNS" in the overrides: a warning holding that text must be logged
+_F64_WARNING = "does not support f64 histograms yet; falling back to exact"
 MATRIX = [
     ("serial-plain", {}, dict(use_part=True, part_mesh=False, fp=False)),
     ("serial-forced", {"FORCED": True}, dict(use_part=True)),
@@ -93,6 +99,41 @@ MATRIX = [
      dict(batch=True)),
     ("mc-batched", {"MULTICLASS": True, "tree_growth": "batched"},
      dict(batch=True, vmapped=True)),
+    ("batched-bagging", {"tree_growth": "batched", "bagging_freq": 1,
+                         "bagging_fraction": 0.6},
+     dict(batch=True, frontier=False)),
+    ("batched-f64", {"tree_growth": "batched", "gpu_use_dp": True,
+                     "WARNS": _F64_WARNING},
+     dict(batch=False, frontier=False, use_part=True)),  # exact grower
+    ("serial-frontier", {"tree_growth": "frontier"},
+     dict(frontier=True, frontier_rs=False, batch=False, use_part=True,
+          part_mesh=False)),
+    ("data-frontier", {"tree_learner": "data", "mesh_shape": [8],
+                       "tree_growth": "frontier"},
+     dict(part_mesh=True, frontier=True, frontier_rs=True)),
+    ("data-frontier-psum", {"tree_learner": "data", "mesh_shape": [8],
+                            "tree_growth": "frontier",
+                            "tpu_frontier_rs": False},
+     dict(part_mesh=True, frontier=True, frontier_rs=False)),
+    ("voting-frontier", {"tree_learner": "voting", "mesh_shape": [8],
+                         "top_k": 3, "tree_growth": "frontier"},
+     dict(part_mesh=False, frontier=True, frontier_rs=False)),
+    ("feature-frontier", {"tree_learner": "feature", "mesh_shape": [8],
+                          "tree_growth": "frontier"}, "raise"),
+    ("frontier-forced", {"tree_growth": "frontier", "FORCED": True},
+     "raise"),
+    ("frontier-cegb", {"tree_growth": "frontier", "cegb_tradeoff": 0.5,
+                       "cegb_penalty_split": 1e-4}, "raise"),
+    ("mc-frontier", {"MULTICLASS": True, "tree_growth": "frontier"},
+     dict(vmapped=True, frontier=True)),
+    ("goss-frontier", {"boosting": "goss", "tree_growth": "frontier"},
+     dict(frontier=True, batch=False)),
+    ("rf-frontier", {"boosting": "rf", "tree_growth": "frontier",
+                     "bagging_freq": 1, "bagging_fraction": 0.8},
+     dict(frontier=True, batch=False)),
+    ("frontier-f64", {"tree_growth": "frontier", "gpu_use_dp": True,
+                      "WARNS": _F64_WARNING},
+     dict(frontier=False, batch=False, use_part=True)),  # exact grower
 ]
 
 
@@ -102,15 +143,19 @@ def test_capability_matrix(case, overrides, expect):
     overrides = dict(overrides)
     multiclass = overrides.pop("MULTICLASS", False)
     forced = overrides.pop("FORCED", False)
+    warns = overrides.pop("WARNS", None)
     X, y = _data(multiclass=multiclass)
     params = {"objective": "multiclass" if multiclass else "binary",
-              "num_leaves": 15, "verbosity": -1, "min_data_in_leaf": 5,
+              "num_leaves": 15, "verbosity": 0 if warns else -1,
+              "min_data_in_leaf": 5,
               **({"num_class": 3} if multiclass else {}),
               **overrides}
     path = None
     if forced:
         path = _forced_file()
         params["forcedsplits_filename"] = path
+    logged = []
+    Log.reset_callback(logged.append)
     try:
         if expect == "raise":
             with pytest.raises(LightGBMError):
@@ -124,9 +169,13 @@ def test_capability_matrix(case, overrides, expect):
             use_part=impl.grow_params.use_partition,
             pool=impl.grow_params.pool_slots > 0,
             vmapped=impl.grow_params.vmapped_classes,
-            batch=impl.grow_params.batch_splits > 0)
+            batch=impl.grow_params.batch_splits > 0,
+            frontier=impl.grow_params.frontier_mode,
+            frontier_rs=impl.grow_params.frontier_rs)
         for key, want in expect.items():
             assert flags[key] == want, (case, key, flags)
+        if warns:
+            assert any("Warning" in m and warns in m for m in logged), logged
         # and the model actually learned (no silently-dead path)
         pred = bst.predict(X, raw_score=not multiclass)
         if multiclass:
@@ -137,5 +186,8 @@ def test_capability_matrix(case, overrides, expect):
             auc = roc_auc_score(y, pred)
             assert auc > 0.8, (case, auc)
     finally:
+        Log.reset_callback(None)
+        # gpu_use_dp turns x64 on for the process: not for the next test
+        jax.config.update("jax_enable_x64", False)
         if path:
             os.unlink(path)

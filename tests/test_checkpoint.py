@@ -259,12 +259,3 @@ def test_lossy_init_model_continuation_warns(tmp_path):
     finally:
         Log.reset_callback(None)
     assert any("resume_from" in m for m in msgs)
-
-
-@pytest.mark.slow
-def test_phase_probe_reports_checkpoint_cost(tmp_path):
-    from lightgbm_tpu.profiling import phase_probe
-    bst, _ = _train(_BASE, str(tmp_path), 3)
-    ph = phase_probe(bst._impl)
-    assert ph["checkpoint_save_s"] > 0
-    assert ph["checkpoint_restore_s"] > 0

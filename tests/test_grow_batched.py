@@ -82,10 +82,21 @@ def test_batched_fills_leaf_budget():
     assert b.models[0].num_leaves == 33
 
 
-def test_batched_predict_matches_train_scores():
+@pytest.mark.parametrize("sampling", [
+    {},
+    {"bagging_fraction": 0.6, "bagging_freq": 1},
+    # learning_rate 0.5: GOSS samples from iteration 2 on (1 / rate)
+    {"boosting": "goss", "learning_rate": 0.5},
+], ids=["plain", "bagging", "goss"])
+def test_batched_predict_matches_train_scores(sampling):
+    """Rows a tree was not grown on (out of the bag, dropped by GOSS) still
+    reach the right leaf: the held scores are what predict gives."""
     X, y = make_binary(n=1500)
-    b = _train(X, y, {"objective": "binary", "tree_growth": "batched",
-                      "tree_batch_splits": 8, "verbosity": -1}, rounds=8)
+    b = _train(X, y, dict({"objective": "binary", "tree_growth": "batched",
+                           "tree_batch_splits": 8, "verbosity": -1},
+                          **sampling), rounds=8)
+    assert b.grow_params.batch_splits == 8
+    assert b.grow_params.all_rows_in_bag == (not sampling)
     pred = b.predict(X, raw_score=True)
     np.testing.assert_allclose(pred, np.asarray(b.scores)[:, 0],
                                rtol=1e-4, atol=1e-4)
@@ -159,24 +170,3 @@ def test_batched_slot_kernel_end_to_end():
     ps = bs.predict(X[:300], raw_score=True)
     pp = bp.predict(X[:300], raw_score=True)
     np.testing.assert_allclose(ps, pp, rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.slow
-@pytest.mark.slow
-def test_batched_pack_matches_unpacked():
-    """tpu_batched_pack (active rows packed to the front + tile-skip slot
-    kernel) reorders rows feeding the histogram sums, so models must
-    match to f32 summation-order tolerance. n spans multiple 2048-row
-    kernel tiles so rows actually cross tile boundaries and late steps
-    leave whole tiles inactive (the pl.when skip path)."""
-    X, y = make_binary(n=6000, f=6)
-    base = {"objective": "binary", "num_leaves": 63, "verbosity": -1,
-            "min_data_in_leaf": 5,
-            "tree_growth": "batched", "tree_batch_splits": 4,
-            "tpu_hist_impl": "pallas_interpret"}
-    b0 = _train(X, y, dict(base), rounds=3)
-    b1 = _train(X, y, dict(base, tpu_batched_pack=True), rounds=3)
-    assert b1.grow_params.batched_pack
-    p0 = b0.predict(X[:300], raw_score=True)
-    p1 = b1.predict(X[:300], raw_score=True)
-    np.testing.assert_allclose(p0, p1, rtol=1e-5, atol=1e-5)

@@ -107,20 +107,19 @@ def test_frontier_fills_leaf_budget():
 def test_frontier_sweeps_scale_with_depth():
     """The whole point: dataset sweeps per tree = max leaf depth + 1,
     not num_leaves - 1 (ISSUE 2 acceptance)."""
-    from lightgbm_tpu.profiling import phase_probe
+    from lightgbm_tpu.profiling import frontier_tree_stats
     X, y = make_binary(n=2000)
     b = _train(X, y, {"objective": "binary", "num_leaves": 31,
                       "tree_growth": "frontier", "verbosity": -1},
                rounds=2)
-    phases = phase_probe(b)
-    assert "frontier_hist" in phases and phases["frontier_hist"] > 0
-    waves = phases["frontier_waves"]
+    stats = frontier_tree_stats(b.models[0], b.grow_params)
+    waves = stats["waves"]
     # a 31-leaf tree needs at least ceil(log2(31)) = 5 waves and at most
     # 30 (degenerate chain); on this learnable workload it must be far
     # below the per-leaf sweep count
     assert 5 <= waves <= 30
-    assert phases["frontier_sweeps_per_tree"] == waves + 1
-    assert phases["frontier_sweeps_per_tree"] < b.models[0].num_leaves - 1
+    assert stats["sweeps_per_tree"] == waves + 1
+    assert stats["sweeps_per_tree"] < b.models[0].num_leaves - 1
 
 
 @pytest.mark.slow
@@ -197,6 +196,15 @@ def test_config_validates_growth_and_hist_impl():
         Config({"tree_growth": "levelwise"})
     with pytest.raises(LightGBMError, match="tpu_hist_impl"):
         Config({"tpu_hist_impl": "palas"})
+    # the full-f32 kernel variant went at PR 31: refused by name, with the
+    # spellings that are left (gpu_use_dp is the double-precision route)
+    for suffix in ("highest", "highest_interpret"):
+        gone = "pallas_" + suffix
+        with pytest.raises(
+                LightGBMError,
+                match="tpu_hist_impl should be one of auto/matmul/scatter/"
+                      "pallas/pallas_interpret, got %s$" % gone):
+            Config({"tpu_hist_impl": gone})
     # the alias from the issue spelling resolves to the canonical name
     assert Config({"tree_grow_mode": "frontier"}).tree_growth == "frontier"
     assert Config({"tpu_hist_impl": " Scatter "}).tpu_hist_impl == "scatter"

@@ -129,39 +129,6 @@ def test_pallas_kernel_six_channel_matches_scatter():
     np.testing.assert_allclose(pal, ref, rtol=1e-4, atol=1e-3)
 
 
-def test_pallas_highest_precision_matches_scatter_tighter():
-    """The full-f32 Precision.HIGHEST kernel variant (gpu_use_dp analog,
-    tpu_hist_impl=pallas_highest) must match the scatter reference at least
-    as tightly as the default two-term bf16 kernel — its whole point is
-    users who pay 2x MXU cost for the tightest parity."""
-    import jax.numpy as jnp
-    from lightgbm_tpu.core.histogram import build_histogram, hist_tile_vals
-    r = np.random.RandomState(11)
-    n, f, b = 1200, 7, 256
-    xb = r.randint(0, b, (n, f)).astype(np.uint8)
-    g = r.randn(n).astype(np.float32)
-    h = np.abs(r.randn(n)).astype(np.float32)
-    m = (r.rand(n) > 0.3).astype(np.float32)
-    ref = np.asarray(build_histogram(
-        jnp.asarray(xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-        num_bins=b, impl="scatter"))
-    hi = np.asarray(build_histogram(
-        jnp.asarray(xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-        num_bins=b, impl="pallas_highest_interpret"))
-    lo = np.asarray(build_histogram(
-        jnp.asarray(xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-        num_bins=b, impl="pallas_interpret"))
-    np.testing.assert_allclose(hi, ref, rtol=1e-5, atol=1e-5)
-    assert np.abs(hi - ref).max() <= np.abs(lo - ref).max() + 1e-7
-    # 6-channel (fused two-child) layout too
-    vals6 = r.randn(n, 6).astype(np.float32)
-    ref6 = np.asarray(hist_tile_vals(jnp.asarray(xb), jnp.asarray(vals6),
-                                     b, "scatter"))
-    hi6 = np.asarray(hist_tile_vals(jnp.asarray(xb), jnp.asarray(vals6),
-                                    b, "pallas_highest_interpret"))
-    np.testing.assert_allclose(hi6, ref6, rtol=1e-5, atol=1e-5)
-
-
 @pytest.mark.parametrize("fake_backend", [
     "cpu", "gpu", "METAL", "neuron", "tpu", "tpu_plugin"])
 def test_tpu_shaped_gate_is_allow_list(monkeypatch, fake_backend):
@@ -174,8 +141,7 @@ def test_tpu_shaped_gate_is_allow_list(monkeypatch, fake_backend):
     from lightgbm_tpu.core import partition
     monkeypatch.setattr(jax, "default_backend", lambda: fake_backend)
     assert partition.tpu_shaped_backend() == (fake_backend == "tpu")
-    for impl in ("pallas", "pallas_highest", "pallas_interpret",
-                 "pallas_highest_interpret"):
+    for impl in ("pallas", "pallas_interpret"):
         assert partition.tpu_tiles(impl)
         assert partition.window_placement(impl, vmapped=False)
         # a batched window start would be a scatter again
@@ -187,7 +153,7 @@ def test_tpu_shaped_gate_is_allow_list(monkeypatch, fake_backend):
 
 
 def test_slot_kernel_matches_per_slot_scatter():
-    """The slot-extended digit kernel (batched-frontier growth) must equal
+    """The slot-extended digit kernel (a frontier wave) must equal
     building each slot's histogram separately with the scatter reference."""
     import jax.numpy as jnp
     from lightgbm_tpu.core.histogram import build_histogram
@@ -201,17 +167,16 @@ def test_slot_kernel_matches_per_slot_scatter():
     slot = r.randint(0, s, n).astype(np.int32)
     vals = jnp.stack([jnp.asarray(g * m), jnp.asarray(h * m),
                       jnp.asarray(m)], axis=0)
-    for highest in (False, True):
-        out = np.asarray(build_histogram_slots(
-            jnp.asarray(xb), jnp.asarray(slot), vals, num_bins=b, n_slots=s,
-            interpret=True, highest=highest))
-        assert out.shape == (s, f, b, 3)
-        for si in range(s):
-            msk = m * (slot == si)
-            ref = np.asarray(build_histogram(
-                jnp.asarray(xb), jnp.asarray(g), jnp.asarray(h),
-                jnp.asarray(msk), num_bins=b, impl="scatter"))
-            np.testing.assert_allclose(out[si], ref, rtol=1e-4, atol=1e-3)
+    out = np.asarray(build_histogram_slots(
+        jnp.asarray(xb), jnp.asarray(slot), vals, num_bins=b, n_slots=s,
+        interpret=True))
+    assert out.shape == (s, f, b, 3)
+    for si in range(s):
+        msk = m * (slot == si)
+        ref = np.asarray(build_histogram(
+            jnp.asarray(xb), jnp.asarray(g), jnp.asarray(h),
+            jnp.asarray(msk), num_bins=b, impl="scatter"))
+        np.testing.assert_allclose(out[si], ref, rtol=1e-4, atol=1e-3)
 
 
 def test_slot_kernel_sentinel_rows_skip_and_match():
@@ -226,8 +191,8 @@ def test_slot_kernel_sentinel_rows_skip_and_match():
     xb = r.randint(0, b, (n, f)).astype(np.uint8)
     g = r.randn(n).astype(np.float32)
     h = np.abs(r.randn(n)).astype(np.float32)
-    # actives packed to the front (what tpu_batched_pack produces); the
-    # tail spans multiple whole row tiles of -1
+    # actives at the front, as a frontier wave's -1 slots leave them once
+    # most leaves are done; the tail spans multiple whole row tiles of -1
     n_active = 1500
     slot = np.full(n, -1, np.int32)
     slot[:n_active] = r.randint(0, s, n_active)

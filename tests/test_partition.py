@@ -391,46 +391,3 @@ def test_tpu_tile_loop_places_ids_without_a_scatter_into_order():
     # the walk does see the other placement's scatter into order
     assert ("scatter", part.order.shape) in names(
         window_placement(impl, vmapped=True))
-
-
-def test_frontier_slots_from_partition():
-    """The partition hands the frontier builder LEAF IDS: rows inside
-    leaves[i]'s range get slot i, every other row -1 (ISSUE 2 tentpole
-    hand-off, used by the frontier phase probe)."""
-    from lightgbm_tpu.core.partition import (frontier_slots_from_partition,
-                                             init_partition, make_row_gather,
-                                             partition_and_hist, stack_vals)
-
-    np.random.seed(9)
-    n, chunk = 1000, 128
-    f, b = 3, 8
-    part = init_partition(n, 8, chunk)
-    decision_np = np.random.rand(n) < 0.3
-    xb = np.random.randint(0, b, (n, f)).astype(np.uint8)
-    xb[:, 0] = decision_np.astype(np.uint8)
-    vals = stack_vals(jnp.asarray(np.random.randn(n).astype(np.float32)),
-                      jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32))
-    gr = make_row_gather(jnp.asarray(xb), vals)
-    part = jax.jit(
-        lambda p: partition_and_hist(
-            p, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
-            lambda rows: rows[:, 0] == 1,
-            jnp.asarray(True), chunk, gr, f, b, "scatter"))(part)[0]
-
-    def slots(leaves):
-        return np.asarray(jax.jit(
-            lambda p: frontier_slots_from_partition(
-                p, jnp.asarray(leaves, jnp.int32), n))(part))
-
-    # both leaves selected: slot == leaf id
-    s01 = slots([0, 1])
-    np.testing.assert_array_equal(s01, np.where(decision_np, 0, 1))
-    # slot index follows position IN THE LEAVES LIST, not the leaf id
-    s10 = slots([1, 0])
-    np.testing.assert_array_equal(s10, np.where(decision_np, 1, 0))
-    # unselected leaves' rows are -1
-    s1 = slots([1])
-    np.testing.assert_array_equal(s1, np.where(decision_np, -1, 0))
-    # empty leaves in the list claim no rows
-    s_empty = slots([5, 0])
-    np.testing.assert_array_equal(s_empty, np.where(decision_np, 1, -1))

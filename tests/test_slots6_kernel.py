@@ -96,7 +96,7 @@ def test_batched_routing_on_efb_bundles(seed):
 
 
 @pytest.mark.parametrize("seed", [3, 7])
-def test_exact_and_batched_part_at_an_exact_tie_only(seed):
+def test_exact_and_batched_at_an_exact_tie_only(seed):
     """The tables the test above leaves out: up to the first node the two
     growers split differently the trees are the same, and at that node
     both record the same gain to two float32 ulps: a tie, not another
@@ -122,19 +122,3 @@ def test_exact_and_batched_part_at_an_exact_tie_only(seed):
                                np.asarray(t1.split_gain)[at], rtol=2.4e-7)
     assert np.asarray(t0.internal_count)[at] == \
         np.asarray(t1.internal_count)[at]
-
-
-def test_batched_part_routing_on_efb_bundles():
-    """Same EFB routing contract for the partitioned batched grower
-    (shares route_split_rows, but its own layout maintenance)."""
-    X, y = _exclusive_groups()
-    base = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
-            "min_data_in_leaf": 5, "tpu_hist_impl": "scatter",
-            "tree_growth": "batched", "tree_batch_splits": 4}
-    b0, _ = _train(X, y, dict(base))
-    b1, _ = _train(X, y, dict(base, tpu_batched_part="true"))
-    for t0, t1 in zip(b0.models, b1.models):
-        np.testing.assert_array_equal(np.asarray(t0.split_feature),
-                                      np.asarray(t1.split_feature))
-        np.testing.assert_array_equal(np.asarray(t0.threshold_bin),
-                                      np.asarray(t1.threshold_bin))

@@ -526,7 +526,7 @@ def _run_train_phase(args, check) -> dict:
 
 def _run_stream_phase(args, check) -> dict:
     """Chunks x chips: 2-process sharded-stream training + its
-    1-process weak-scaling baseline, assembled into the BENCH_r15 row."""
+    1-process weak-scaling baseline, assembled into one row."""
     outs = _drain(_spawn_pair(_free_port(), args.workdir, phase="stream"),
                   timeout=480)
     for rank, (rc, so, se) in enumerate(outs):

@@ -18,8 +18,7 @@ pipeline, cross-chunk frontier growth — then verifies from the outside:
   chunks+1 dispatches (the last chunk's sweep fused with the commit).
 
 Exit code 0 = every assertion holds. The summary JSON goes to ``--out``
-(and stdout) so CI uploads it as an artifact; the numbers feed the
-BENCH_r12 streaming section.
+(and stdout) so CI uploads it as an artifact.
 """
 import argparse
 import json
