@@ -295,10 +295,12 @@ class GBDT:
                                rows=train_data.num_data) as span:
                 self._setup_train(train_data)
                 # which placement the exact grower's tile loop is built
-                # with, and whether it maps rows to leaves once a tree
-                # through leaf_id_from_partition, which has no gather over
-                # all rows (both 0 where another grower runs; the second 0
-                # too where CEGB keeps the leaf ids split by split)
+                # with, whether it maps rows to leaves once a tree through
+                # leaf_id_from_partition, which has no gather over all
+                # rows, and whether the kernel sees only the smaller
+                # child's range of a split (all 0 where another grower
+                # runs; the second 0 too where CEGB keeps the leaf ids
+                # split by split)
                 p = self.grow_params
                 exact_part = (p.use_partition and not p.frontier_mode
                               and p.batch_splits == 0)
@@ -307,6 +309,7 @@ class GBDT:
                         p.hist_impl, p.vmapped_classes))
                 span.counts["leaf_ids_gather_free"] = int(
                     exact_part and not p.with_cegb_lazy)
+                span.counts["hist_smaller_child"] = int(exact_part)
                 # what the data made of its columns: those whose split
                 # search prices a missing direction, and those with fewer
                 # bins than max_bin allows
@@ -1219,8 +1222,9 @@ class GBDT:
                     return t, li, None
             elif params.partition_on_mesh or params.voting_top_k > 0:
                 # explicit shard_map learners (mutually exclusive configs):
-                # - data-parallel partition: local fused partition+hist per
-                #   device, psum only on the [F, B, 6] child histograms;
+                # - data-parallel partition: each device partitions its
+                #   local rows and histograms the globally smaller child's,
+                #   psum only on that [F, B, 3] histogram;
                 # - voting-parallel: manual PV-Tree election collectives
                 #   (all_gather of proposals, psum of elected candidates).
                 # check_vma=False: the replicated tree output is

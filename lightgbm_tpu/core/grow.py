@@ -7,16 +7,18 @@ TPU-native re-design of SerialTreeLearner::Train
 - The reference breaks out of the split loop when the best gain <= 0
   (serial_tree_learner.cpp:217-219); under jit the loop runs a fixed
   ``num_leaves - 1`` iterations with *masked no-op* splits instead.
-- Single-device growth keeps rows grouped by leaf (core/partition.py) and
-  fuses DataPartition::Split with ConstructHistograms: one pass over the
-  split leaf's rows partitions the range AND prices both children through
-  six value channels — no histogram pool, nothing to subtract. The final
-  ``leaf_id`` (reconstructed from the ranges) doubles as the score-update
-  fast path (score_updater.hpp:53-117).
-- Mesh paths use masked full-data passes with a per-row ``leaf_id`` vector
-  and keep the histogram-subtraction trick: only the smaller child's
-  histogram is built (serial_tree_learner.cpp:383-397, 547-548); the
-  sibling is parent - child. Dead iterations skip work via lax.cond.
+- Every path keeps the histogram-subtraction trick: only the smaller
+  child's histogram is built (serial_tree_learner.cpp:383-397, 547-548);
+  the sibling is parent - child, the parent read from a per-leaf pool
+  whose slot 0 holds the root's. Dead iterations skip the work.
+- Single-device growth and the shard_map data-parallel learner keep rows
+  grouped by leaf (core/partition.py): one pass over the split leaf's rows
+  partitions its range (DataPartition::Split), a second pass over the
+  smaller child's new range builds its histogram through the kernel. The
+  final ``leaf_id`` (reconstructed from the ranges) doubles as the
+  score-update fast path (score_updater.hpp:53-117).
+- The other mesh paths use masked full-data passes with a per-row
+  ``leaf_id`` vector.
 - Node numbering matches the reference exactly: splitting leaf ``l`` at step
   ``t`` creates internal node ``t``; the left child keeps leaf index ``l``,
   the right child becomes leaf ``t + 1`` (tree.cpp:49-67). Child pointers use
@@ -38,7 +40,7 @@ from jax import lax
 from .histogram import build_histogram
 from .partition import (RowPartition, hist_for_leaf, init_partition,
                         leaf_id_from_partition, make_row_gather,
-                        partition_and_hist, stack_vals, window_placement)
+                        partition_rows, stack_vals, window_placement)
 from .split import (BestSplit, FeatureMeta, SplitParams, K_EPSILON,
                     K_MIN_SCORE, MISSING_NAN, MISSING_NONE, MISSING_ZERO,
                     calculate_leaf_output, find_best_split, leaf_split_gain,
@@ -76,8 +78,9 @@ class GrowParams(NamedTuple):
     # allow the partition path under an explicit shard_map data-parallel
     # learner: every device partitions its LOCAL row shard (trip counts
     # diverge freely — no collective sits inside the chunk loops) and only
-    # the fused [F, B, 6] child histograms are psum-combined, the
-    # ReduceScatter moment of data_parallel_tree_learner.cpp:146-161.
+    # the [F, B, 3] histogram of the GLOBALLY smaller child is
+    # psum-combined, the ReduceScatter moment of
+    # data_parallel_tree_learner.cpp:146-161.
     # GSPMD paths must keep this off (a gather through a sharded order
     # array would shuffle rows across devices).
     partition_on_mesh: bool = False
@@ -117,7 +120,8 @@ class GrowParams(NamedTuple):
     # histogram pool cap (HistogramPool, feature_histogram.hpp:646-820):
     # 0 = one slot per leaf (unlimited); otherwise S < num_leaves slots with
     # LRU eviction, rebuilding an evicted parent histogram from its rows
-    # when that leaf is finally chosen for splitting (the Move/Get dance)
+    # (its range of the row partition, where there is one) when that leaf
+    # is finally chosen for splitting (the Move/Get dance)
     pool_slots: int = 0
     # batched-frontier growth (core/grow_batched.py): split up to this many
     # of the highest-gain frontier leaves per sequential step instead of
@@ -534,9 +538,10 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     # assignment is reconstructed from the final ranges in one dense pass
     maintain_lid = (cegb is not None and params.with_cegb_lazy)
 
-    def hist_for_mask(mask_f32):
+    def hist_for_mask(mask_f32, compensated=False):
         h = build_histogram(xb_hist, grad, hess, mask_f32, num_bins=b,
-                            row_chunk=params.row_chunk, impl=params.hist_impl)
+                            row_chunk=params.row_chunk, impl=params.hist_impl,
+                            compensated=compensated)
         # voting mode keeps histograms LOCAL (the pool then supports local
         # subtraction); only elected candidates are reduced, in voting_best
         return h if voting else psum(h)
@@ -646,7 +651,10 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         root_g = psum(jnp.sum(grad * sample_mask))
         root_h = psum(jnp.sum(hess * sample_mask))
         root_c = psum(jnp.sum(sample_mask))
-        hist_root = hist_for_mask(sample_mask)
+        # over the row partition every other leaf's histogram comes from
+        # this one by subtracting sums that carry their rounding
+        # (hist_for_leaf), so this one carries its own too
+        hist_root = hist_for_mask(sample_mask, compensated=use_partition)
 
     tree = empty_tree(l, hdt)
     tree = tree._replace(
@@ -663,23 +671,19 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     best = jax.tree.map(lambda a, v: a.at[0].set(v), _empty_best(l, hdt),
                         best0)
 
-    capped = (0 < params.pool_slots < l) and not use_partition
+    capped = 0 < params.pool_slots < l
     assert not (capped and axis_name is not None), \
         "histogram_pool_size cap is not supported on sharded learners " \
         "(rebuild-on-miss cannot psum under lax.cond)"
     assert not capped or params.pool_slots >= 2, \
         "a capped histogram pool needs at least 2 slots (both children " \
         "of a split are resident)"
-    # the partition path needs no pool at all: the fused pass prices both
-    # children directly, so there is no parent to subtract from, and forced
-    # splits rebuild any leaf's histogram from its rows
-    num_slots = 1 if use_partition else (params.pool_slots if capped else l)
+    num_slots = params.pool_slots if capped else l
     hist_pool = jnp.zeros((num_slots, ncols_h, b, 3), hdt)
     if voting:
         # the pool holds LOCAL histograms in voting mode -> device-varying
         hist_pool = lax.pcast(hist_pool, (axis_name,), to="varying")
-    if not use_partition:
-        hist_pool = hist_pool.at[0].set(hist_root)
+    hist_pool = hist_pool.at[0].set(hist_root)
     pool_map0 = None
     if capped:
         pool_map0 = PoolMap(
@@ -687,34 +691,18 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             leaf_of_slot=jnp.full((num_slots,), -1, jnp.int32).at[0].set(0),
             last_used=jnp.full((num_slots,), -1, jnp.int32).at[0].set(0))
 
+    def hist_of_range(part, leaf_idx, valid=True):
+        """[C, B, 3] over ``leaf_idx``'s range of the row partition (this
+        device's rows of it)."""
+        return hist_for_leaf(part, leaf_idx, gather_rows, n, ncols, b,
+                             params.row_chunk, valid=valid,
+                             impl=params.hist_impl, val_dtype=hdt)
+
     def leaf_hist(s: _GrowState, leaf_idx, live=True):
         """A leaf's [C, B, 3] histogram: the pool slot when resident, else
         rebuilt from the leaf's rows (HistogramPool::Get miss path). Must
         run BEFORE the step's partition update — the rebuild walks the
         pre-split row partition / leaf_id."""
-        if use_partition:
-            # no pool in partition mode (only forced splits land here)
-            if axis_name is not None:
-                # collectives cannot sit under lax.cond in SPMD code: the
-                # rebuild runs straight-line (valid=live zeroes the trip
-                # count on dead iterations, so they rebuild 0 rows and
-                # psum zeros) — this is what lets forced splits ride the
-                # fused sharded partition path at all
-                return psum(hist_for_leaf(s.part, leaf_idx, gather_rows,
-                                          n, ncols, b,
-                                          params.row_chunk, valid=live,
-                                          impl=params.hist_impl,
-                                          val_dtype=hdt))
-            # single device: dead iterations never pay for a rebuild
-            return lax.cond(
-                live,
-                lambda _: hist_for_leaf(s.part, leaf_idx, gather_rows,
-                                        n, ncols, b,
-                                        params.row_chunk, valid=True,
-                                        impl=params.hist_impl,
-                                        val_dtype=hdt),
-                lambda _: jnp.zeros((ncols_h, b, 3), hdt),
-                operand=None)
         if not capped:
             return s.hist_pool[leaf_idx]
         sl = s.pool_map.slot_of_leaf[leaf_idx]
@@ -723,6 +711,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             return s.hist_pool[jnp.maximum(sl, 0)]
 
         def rebuild(_):
+            if use_partition:
+                return hist_of_range(s.part, leaf_idx)
             m = (s.leaf_id == leaf_idx).astype(hdt) * sample_mask
             return hist_for_mask(m)
 
@@ -861,20 +851,12 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                     split_missing, split_num_bin, split_default_bin,
                     split_is_cat, cur.cat_bitset)
 
-            part, leaf_id, hist_left_d, hist_right_d = partition_and_hist(
+            part, leaf_id = partition_rows(
                 s.part, s.leaf_id, leaf, right_leaf, go_left_rows, valid,
-                params.row_chunk, gather_rows, ncols, b, params.hist_impl,
+                params.row_chunk, gather_rows,
                 maintain_leaf_id=maintain_lid,
                 windows=window_placement(params.hist_impl,
-                                         params.vmapped_classes),
-                val_dtype=hdt)
-            if axis_name is not None:
-                # one collective per split: psum the fused 6-channel
-                # accumulator, not the two child views separately
-                both = psum(jnp.concatenate([hist_left_d, hist_right_d],
-                                            axis=2))
-                hist_left_d = both[:, :, :3]
-                hist_right_d = both[:, :, 3:]
+                                         params.vmapped_classes))
         else:
             part = s.part
             col = jnp.take(xb, stored_col, axis=1)
@@ -947,13 +929,18 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             num_leaves=tree.num_leaves + valid.astype(jnp.int32))
 
         # ---- histograms: build smaller child, subtract for sibling -------
+        # smaller by the split's own counts, which on a mesh are the global
+        # ones: every device builds the same child, whichever is the
+        # smaller among its local rows
         left_smaller = cur.left_count <= cur.right_count
         small_leaf = jnp.where(left_smaller, leaf, right_leaf)
         large_leaf = jnp.where(left_smaller, right_leaf, leaf)
 
         if use_partition:
-            # both children came out of the fused partition pass
-            hist_small = jnp.where(left_smaller, hist_left_d, hist_right_d)
+            # a second pass, over the smaller child's new range only; a dead
+            # iteration walks no tile (and psums zeros on a mesh: one
+            # collective a split, outside every loop)
+            hist_small = psum(hist_of_range(part, small_leaf, valid))
         elif axis_name is None:
             def live_hist(_):
                 m = (leaf_id == small_leaf).astype(hdt) * sample_mask
@@ -970,23 +957,14 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             hist_small = hist_for_mask(
                 (leaf_id == small_leaf).astype(hdt) * sample_mask
                 * valid.astype(hdt))
-        if use_partition:
-            # no subtraction, no pool: the sibling was priced in the same
-            # fused pass
-            hist_large = jnp.where(left_smaller, hist_right_d, hist_left_d)
-            pool_map = s.pool_map
-            hist_pool = s.hist_pool
-        elif not capped:
+        with jax.named_scope("lgbm.hist_subtract"):
             hist_parent = leaf_hist(s, leaf, live=valid)
             hist_large = hist_parent - hist_small
+        if not capped:
+            # one slot a leaf
             pool_map = s.pool_map
-            hist_pool = s.hist_pool.at[small_leaf].set(
-                jnp.where(valid, hist_small, s.hist_pool[small_leaf]))
-            hist_pool = hist_pool.at[large_leaf].set(
-                jnp.where(valid, hist_large, hist_pool[large_leaf]))
+            target_large, target_small = large_leaf, small_leaf
         else:
-            hist_parent = leaf_hist(s, leaf, live=valid)
-            hist_large = hist_parent - hist_small
             # LRU slot allocation (HistogramPool::Move/Get): the larger
             # child reuses the parent's slot when resident; the smaller
             # child takes the least-recently-used other slot. Evicted
@@ -1014,6 +992,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             lu = _masked_set(lu, target_small, stamp, valid)
             pool_map = PoolMap(slot_of_leaf=sol, leaf_of_slot=los,
                                last_used=lu)
+        with jax.named_scope("lgbm.hist_subtract"):
             hist_pool = s.hist_pool.at[target_large].set(
                 jnp.where(valid, hist_large, s.hist_pool[target_large]))
             hist_pool = hist_pool.at[target_small].set(
@@ -1067,8 +1046,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 return bl, br
             # both children's split searches are independent — one vmapped
             # call instead of two sequential ones halves the small-op chain
-            # (the scalar-heavy bin scans dominate per-split latency once
-            # histogram building is fused into the partition pass)
+            # of the scalar-heavy bin scans
             hist2 = jnp.stack([hist_left, hist_right])
             sg2 = jnp.stack([cur.left_sum_grad, cur.right_sum_grad])
             sh2 = jnp.stack([cur.left_sum_hess, cur.right_sum_hess])

@@ -59,6 +59,27 @@ def _hist_scatter(xb: jnp.ndarray, vals: jnp.ndarray, num_bins: int) -> jnp.ndar
     return hist.reshape(f, num_bins, vals.shape[-1])
 
 
+def compensated_add(total: jnp.ndarray, lost: jnp.ndarray,
+                    term: jnp.ndarray):
+    """One Kahan step of a long sum of histograms: ``total + term`` with
+    the add's rounding carried in ``lost`` to the next step, so that the
+    sum over thousands of tiles keeps the accuracy of its terms and not
+    that of a running total thousands of times their size. The sum so far
+    is ``total - lost``. A leaf's sibling is parent - smaller, so a small
+    leaf's bins inherit the ABSOLUTE error of every larger ancestor's. Two
+    sums carry their rounding for that: the root's pass over the row
+    partition, in blocks (build_histogram ``compensated``), and the tiles
+    of a smaller child's range (partition.hist_for_leaf). On the chip at
+    26.6M rows the worst split gain of two trees read 0.0236 off the
+    float64 reference's with neither, 0.0225 with the tiles alone, 0.0011
+    with the root alone, 0.000045 with both; the six-channel pass that
+    subtracted nothing read 0.0001-0.00025 (PERF.md section 6, PR 32;
+    tools/compensation_lab.py)."""
+    y = term - lost
+    t = total + y
+    return t, (t - total) - y
+
+
 def hist_tile_vals(xb_rows: jnp.ndarray, vals: jnp.ndarray, num_bins: int,
                    impl: str) -> jnp.ndarray:
     """One fixed-size row tile with pre-stacked [rows, 3] values
@@ -77,11 +98,12 @@ def hist_tile_vals(xb_rows: jnp.ndarray, vals: jnp.ndarray, num_bins: int,
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins", "row_chunk", "impl",
-                                             "packed_cols"))
+                                             "packed_cols", "compensated"))
 def build_histogram(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                     mask: jnp.ndarray, num_bins: int,
                     row_chunk: int = 16384, impl: str = "matmul",
-                    packed_cols: int = 0) -> jnp.ndarray:
+                    packed_cols: int = 0,
+                    compensated: bool = False) -> jnp.ndarray:
     """Build (grad, hess, count) histograms for every feature.
 
     Args:
@@ -96,6 +118,10 @@ def build_histogram(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
       impl: "matmul" (MXU one-hot) or "scatter" (XLA scatter-add).
       packed_cols: the real column count F when xb is word-packed; 0 =
         xb is the plain uint8 matrix.
+      compensated: the pallas impls sum the pass in blocks of rows with the
+        rounding carried (compensated_add): for the exact grower's root,
+        which every other leaf's histogram is subtracted from. The masked
+        passes of the other learners stay one call.
 
     Returns: [F, B, 3] f32.
     """
@@ -103,10 +129,11 @@ def build_histogram(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     f = packed_cols or xb.shape[1]
     if impl.startswith("pallas"):
         # pallas | pallas_interpret
-        from .histogram_pallas import build_histogram_pallas
+        from .histogram_pallas import ROW_BLOCK, build_histogram_pallas
         return build_histogram_pallas(xb, grad, hess, mask, num_bins,
                                       interpret=impl.endswith("interpret"),
-                                      packed_cols=packed_cols)
+                                      packed_cols=packed_cols,
+                                      row_block=ROW_BLOCK * compensated)
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=-1)  # [N, 3]
     if impl == "scatter" or n <= row_chunk:
         if packed_cols:

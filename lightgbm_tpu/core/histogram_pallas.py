@@ -40,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .binpack import unpack_words
+from .histogram import compensated_add
 
 # The joint (slot, lo) kernels materialize a [n_slots*16, row_tile] f32
 # one-hot per feature. At the 2048-row tile that is 16 MiB at 128 slots
@@ -48,6 +49,13 @@ from .binpack import unpack_words
 # counted. So the row tile shrinks with the slot count to hold the
 # one-hot at this budget, and the call states the VMEM it needs.
 _ONE_HOT_BUDGET = 4 << 20
+
+
+# Rows a call of the root kernel takes where a pass is cut in blocks
+# (build_histogram_pallas ``row_block``). A call adds its row tiles' partial
+# histograms into one float32 accumulator, each add rounding at the running
+# total's size: 12,970 such adds a bin over 26.6M rows in one call.
+ROW_BLOCK = 1 << 15
 
 
 def _slot_row_tile(row_tile: int, n_slots: int) -> int:
@@ -90,8 +98,7 @@ def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int):
     """One (feature_tile, row_tile) grid cell.
 
     xb_ref: [Ft, C] uint8 binned values; vals_ref: [K, C] f32 value
-    channels (K = 3: grad*mask, hess*mask, mask; K = 6: the same for both
-    children of a fused partition+histogram pass);
+    channels (K = 3: grad*mask, hess*mask, mask; the cost is linear in K);
     out_ref: [K, Ft, Hi, 16] f32 accumulator.
     """
     r = pl.program_id(1)
@@ -123,23 +130,53 @@ def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "row_tile", "feature_tile",
-                                    "interpret", "packed_cols"))
+                                    "interpret", "packed_cols", "row_block"))
 def build_histogram_pallas(xb: jnp.ndarray, grad: jnp.ndarray,
                            hess: jnp.ndarray, mask: jnp.ndarray,
                            num_bins: int, row_tile: int = 2048,
                            feature_tile: int = 8,
                            interpret: bool = False,
-                           packed_cols: int = 0) -> jnp.ndarray:
+                           packed_cols: int = 0,
+                           row_block: int = 0) -> jnp.ndarray:
     """[N, F] uint8 bins + per-row values -> [F, B, 3] f32 histograms.
 
     Same contract as histogram.build_histogram (incl. int32-word-packed
-    xb via ``packed_cols``). The feature-major transpose of ``xb`` is
-    loop-invariant across the splits of one tree, so XLA hoists it out of
-    the growth loop.
+    xb via ``packed_cols``). In one call (``row_block`` 0: every masked
+    pass) the feature-major transpose of ``xb`` is loop-invariant across
+    the splits of one tree, so XLA hoists it out of the growth loop.
+
+    ``row_block`` > 0 (build_histogram ``compensated``: a pass made once a
+    tree, which every leaf's histogram is subtracted from) cuts a longer
+    pass into calls of that many rows, so that a call's accumulator stays
+    small, and sums their results with the rounding carried
+    (histogram.compensated_add), as the exact grower sums a leaf's tiles.
     """
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=0)   # [3, N]
-    return build_histogram_pallas_vals(xb, vals, num_bins, row_tile,
-                                       feature_tile, interpret, packed_cols)
+    call = functools.partial(
+        build_histogram_pallas_vals, num_bins=num_bins, row_tile=row_tile,
+        feature_tile=feature_tile, interpret=interpret,
+        packed_cols=packed_cols)
+    n = xb.shape[0]
+    if row_block <= 0 or n <= row_block:
+        return call(xb, vals)
+
+    last = n - row_block
+
+    def body(i, c):
+        # the last block is moved back to end on the last row; the rows it
+        # shares with the block before it count there and not here
+        first = i * row_block
+        start = jnp.minimum(first, last)
+        fresh = start + jax.lax.iota(jnp.int32, row_block) >= first
+        v = jax.lax.dynamic_slice_in_dim(vals, start, row_block, axis=1)
+        return compensated_add(*c, call(
+            jax.lax.dynamic_slice_in_dim(xb, start, row_block, axis=0),
+            v * fresh[None, :].astype(v.dtype)))
+
+    zero = jnp.zeros((packed_cols or xb.shape[1], num_bins, 3), jnp.float32)
+    total, lost = jax.lax.fori_loop(0, -(-n // row_block), body,
+                                    (zero, zero))
+    return total - lost
 
 
 @functools.partial(jax.jit,
@@ -151,7 +188,8 @@ def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
                                 interpret: bool = False,
                                 packed_cols: int = 0) -> jnp.ndarray:
     """Same kernel with pre-stacked value channels: vals [K, N] -> output
-    [F, B, K] (K = 3 for one histogram, 6 for a fused two-child pass)."""
+    [F, B, K] (K = 3 for one histogram: root, masked pass, a leaf's
+    tile)."""
     if packed_cols:
         # unpack int32 words straight to int32 lanes (the kernels cast to
         # int32 anyway and Mosaic has no uint8 casts, so the word layout

@@ -12,26 +12,42 @@ same invariant is maintained functionally:
 - ``leaf_begin`` / ``leaf_count`` [L] int32 — each leaf's contiguous range.
 
 Both maintenance and consumption are chunked ``lax.while_loop``s whose trip
-count is data-dependent (ceil(count / chunk)). A split is ONE pass over the
-leaf's tiles: each tile's row ids come from a contiguous slice of ``order``,
-its rows from one gather through them (the analog of the reference's
-ordered-gradient gather, dataset.cpp ConstructHistograms), the histogram
-kernel prices both children, and the tile's ids are placed — lefts forward
-from the range start, rights backward from the range end — so one pass
-suffices. ``leaf_id`` is NOT maintained per split: it is reconstructed
-once per tree from the final ranges (leaf_id_from_partition).
+count is data-dependent (ceil(count / chunk)). A split is TWO passes. The
+first (partition_rows) walks the split leaf's tiles: each tile's row ids
+come from a contiguous slice of ``order``, its rows from one gather through
+them (the analog of the reference's ordered-gradient gather, dataset.cpp
+ConstructHistograms), the split decision from the gathered bytes, and the
+tile's ids are placed — lefts forward from the range start, rights backward
+from the range end — so one pass suffices. The second (hist_for_leaf) walks
+the SMALLER child's new range and runs the histogram kernel on its tiles;
+the sibling is parent - smaller, from the grower's per-leaf pool
+(serial_tree_learner.cpp:383-397). ``leaf_id`` is NOT maintained per split:
+it is reconstructed once per tree from the final ranges
+(leaf_id_from_partition).
 
 What a v5e charges for these ops (PERF.md sections 5 and 6; traced
-iterations at 26.6M x 67, 255 leaves, ~52,000 tiles of 4,096 rows, and
-PR 30's standalone runs at 26.6M positions):
+iterations at 26.6M x 67, 255 leaves, ~52,000 tiles of 4,096 rows a tree
+of which the smaller children hold ~21,000, PR 30's standalone runs at
+26.6M positions and PR 32's lab):
 
 | op                                                | a call | an element |
 | element scatter of the tile's ids into ``order``  | 187 us | 45 ns      |
 | sort of the tile + two window writes (PR 28)      | 7.8 us | 1.9 ns     |
 | row gather, 4,096 rows x 79 B from 2.1 GB         |  37 us |  9 ns      |
-| histogram kernel on the tile, 67 cols x 6 chans   |  80 us | 20 ns      |
+| histogram kernel on the tile, 67 cols x 3 chans   |  43 us | 10.5 ns    |
+| the same at 6 channels (both children, until PR 32) |  83 us | 20 ns      |
 | full-size scatter / gather, 26.6M elements        | 220 / 260 ms | 8.3 / 9.8 ns |
 | position -> leaf: 510 marks + one prefix sum (PR 30) | 6.4 ms | 0.24 ns |
+
+The kernel is linear in its value channels (43.1 and 82.9 us a call
+standalone: a call's fixed cost is ~3 us), so what it costs is rows x
+channels. Until PR 32 ONE fused pass priced both children of a split
+through six channels, on every row of the parent: it saved the second
+pass's gather (37 us) and paid 83 us of kernel on 2.5-4 times the rows the
+smaller child holds (over one recorded tree's splits: 7.22 s fused, 5.31 s
+fused at three channels, 4.81 s this way; 5.80, 4.31, 3.33 s on a click-log
+tree). Now the kernel sees min(left, right) rows of a split at three
+channels, and the partition pass's gather serves the routing column alone.
 
 (103 / 129 ms until PR 30: a capture shows a gather as two instructions,
 index clamp and gather, a scatter as a sort and the scatter.) A gather costs
@@ -53,7 +69,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .histogram import hist_tile_vals
+from .histogram import compensated_add, hist_tile_vals
 
 
 class RowPartition(NamedTuple):
@@ -132,7 +148,7 @@ def tpu_tiles(hist_impl: str) -> bool:
 
 
 def window_placement(hist_impl: str, vmapped: bool) -> bool:
-    """Which of partition_and_hist's two placements the tile loop is built
+    """Which of partition_rows' two placements its tile loop is built
     with: the contiguous windows where the loop is TPU-shaped, the element
     scatter elsewhere — and always under vmapped class batching, where a
     batched start index turns each window write back into a scatter."""
@@ -149,26 +165,21 @@ def _write_window(order, packed, k, start):
     return lax.dynamic_update_slice(order, w, (start,))
 
 
-def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
-                       go_left_from_rows, valid, chunk: int,
-                       gather_rows, num_cols: int, num_bins: int,
-                       impl: str, maintain_leaf_id: bool = False,
-                       windows: bool = False, val_dtype=jnp.float32):
-    """One pass over ``leaf``'s rows that BOTH partitions the range and
-    builds both children's [F, B, 3] histograms.
-
-    This fuses DataPartition::Split with ConstructHistograms and replaces
-    the histogram-subtraction dance (serial_tree_learner.cpp:383-397): with
-    the parent's rows already gathered for the partition decision, weighting
-    them into six value channels (3 per child) prices both children at one
-    row visit — fewer total rows touched than smaller-child + subtraction
-    (P vs 1.5P per split).
+def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
+                   go_left_from_rows, valid, chunk: int, gather_rows,
+                   maintain_leaf_id: bool = False, windows: bool = False):
+    """One pass over ``leaf``'s rows that splits its range of ``order`` in
+    two (DataPartition::Split): the left child keeps the front of the range
+    and ``leaf``'s id, ``right_leaf`` takes the back. No histogram is built
+    here: the grower prices the SMALLER child from its new range with
+    hist_for_leaf and takes the sibling as parent - smaller
+    (serial_tree_learner.cpp:383-397), so the kernel sees min(left, right)
+    rows of a split and not all of them.
 
     ``go_left_from_rows(rows[chunk, F]) -> bool[chunk]`` evaluates the split
     decision directly on the gathered feature bytes. ``gather_rows`` is a
-    make_row_gather() closure owning the bins+values layout (packed:
-    ONE row gather per tile serves both the routing bytes and the value
-    channels).
+    make_row_gather() closure owning the bins+values layout; the values it
+    returns are not read here.
 
     A tile's lefts go forward from the left cursor in tile order, its
     rights backward from the right cursor; ``windows`` (window_placement)
@@ -185,10 +196,9 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
       and ``order`` needs no front pad. The masks leave the neighbours'
       ranges and the tail pad as they were.
 
-    Returns (new_part, new_leaf_id, hist_left, hist_right).
+    Returns (new_part, new_leaf_id).
     """
     n_rows = leaf_id.shape[0]
-    f = num_cols
     trash = part.order.shape[0] - 1        # never inside any leaf range
     beg = part.leaf_begin[leaf]
     cnt = jnp.where(valid, part.leaf_count[leaf], 0)
@@ -198,7 +208,7 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
         return i * chunk < cnt
 
     def body(c):
-        i, nl, nr, order_new, lid, acc = c
+        i, nl, nr, order_new, lid = c
         j = jnp.arange(chunk, dtype=jnp.int32)
         # ahead of the gather, where the audited jaxpr has it
         with jax.named_scope("lgbm.route_rows"):
@@ -206,17 +216,11 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
         with jax.named_scope("lgbm.row_gather"):
             idx = lax.dynamic_slice(part.order, (beg + i * chunk,), (chunk,))
             idx_safe = jnp.minimum(idx, n_rows - 1)
-            rows, v = gather_rows(idx_safe)                    # [chunk, F/3]
+            rows, _ = gather_rows(idx_safe)                    # [chunk, F]
         with jax.named_scope("lgbm.route_rows"):
-            v = v * in_range[:, None].astype(v.dtype)
             go_left = go_left_from_rows(rows)
             is_l = go_left & in_range
             is_r = (~go_left) & in_range
-            v6 = jnp.concatenate([v * is_l[:, None].astype(v.dtype),
-                                  v * is_r[:, None].astype(v.dtype)],
-                                 axis=1)                       # [chunk, 6]
-        with jax.named_scope("lgbm.hist_tile"):
-            acc = acc + hist_tile_vals(rows, v6, num_bins, impl)
         with jax.named_scope("lgbm.partition_scatter"):
             if windows:
                 kl = jnp.sum(is_l.astype(jnp.int32), dtype=jnp.int32)
@@ -251,12 +255,10 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
             with jax.named_scope("lgbm.leaf_ids"):
                 val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
                 lid = lid.at[idx_safe].max(val, mode="promise_in_bounds")
-        return (i + 1, nl + kl, nr + kr, order_new, lid, acc)
+        return (i + 1, nl + kl, nr + kr, order_new, lid)
 
-    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), part.order,
-            leaf_id, jnp.zeros((f, num_bins, 6), val_dtype))
-    _, n_left, n_right, order_new, leaf_id, acc6 = lax.while_loop(
-        cond, body, init)
+    init = (jnp.int32(0), jnp.int32(0), jnp.int32(0), part.order, leaf_id)
+    _, n_left, n_right, order_new, leaf_id = lax.while_loop(cond, body, init)
 
     leaf_begin = part.leaf_begin.at[right_leaf].set(
         jnp.where(valid, beg + n_left, part.leaf_begin[right_leaf]))
@@ -264,8 +266,7 @@ def partition_and_hist(part: RowPartition, leaf_id, leaf, right_leaf,
         jnp.where(valid, n_left, part.leaf_count[leaf]))
     leaf_count = leaf_count.at[right_leaf].set(
         jnp.where(valid, n_right, leaf_count[right_leaf]))
-    return (RowPartition(order_new, leaf_begin, leaf_count), leaf_id,
-            acc6[:, :, :3], acc6[:, :, 3:])
+    return RowPartition(order_new, leaf_begin, leaf_count), leaf_id
 
 
 def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
@@ -276,18 +277,19 @@ def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
 
     Touches ceil(leaf_count / chunk) fixed-size tiles: row ids come from a
     contiguous slice of ``order``; ``gather_rows`` (make_row_gather) loads
-    each tile's bins+values — one gather when packed.
+    each tile's bins+values — one gather when packed. The tiles' histograms
+    are summed with the rounding carried (compensated_add): siblings are
+    taken from this one by subtraction.
     """
     f = num_cols
     beg = part.leaf_begin[leaf]
     cnt = jnp.where(valid, part.leaf_count[leaf], 0)
 
     def cond(c):
-        i, _ = c
-        return i * chunk < cnt
+        return c[0] * chunk < cnt
 
     def body(c):
-        i, acc = c
+        i, acc, lost = c
         start = beg + i * chunk
         with jax.named_scope("lgbm.row_gather"):
             idx = lax.dynamic_slice(part.order, (start,), (chunk,))
@@ -296,12 +298,15 @@ def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
             idx_safe = jnp.minimum(jnp.where(in_range, idx, 0),
                                    num_rows - 1)
             rows, v = gather_rows(idx_safe)                    # [chunk, F/3]
-        v = v * in_range[:, None].astype(v.dtype)
-        return i + 1, acc + hist_tile_vals(rows, v, num_bins, impl)
+        with jax.named_scope("lgbm.hist_tile"):
+            v = v * in_range[:, None].astype(v.dtype)
+            acc, lost = compensated_add(
+                acc, lost, hist_tile_vals(rows, v, num_bins, impl))
+        return i + 1, acc, lost
 
-    _, hist = lax.while_loop(
-        cond, body, (jnp.int32(0), jnp.zeros((f, num_bins, 3), val_dtype)))
-    return hist
+    zero = jnp.zeros((f, num_bins, 3), val_dtype)
+    _, hist, lost = lax.while_loop(cond, body, (jnp.int32(0), zero, zero))
+    return hist - lost
 
 
 def _range_owner(order: jnp.ndarray, begin: jnp.ndarray, count: jnp.ndarray,

@@ -112,21 +112,82 @@ def test_pallas_kernel_matches_scatter():
         np.testing.assert_allclose(pal, ref, rtol=1e-4, atol=1e-3)
 
 
-def test_pallas_kernel_six_channel_matches_scatter():
-    """The K=6 fused two-child channel layout (partition_and_hist) must
-    come back in the right channel order from the digit-factorized kernel."""
+def test_pallas_long_pass_runs_in_row_blocks():
+    """``compensated`` (the exact grower's root over the row partition): a
+    pass longer than one row block makes one kernel call a block, the last
+    block moved back to end on the last row with the rows it shares masked,
+    the blocks summed with the rounding carried. Every row counts once: the
+    count channel is exact. Without it (every masked pass) the pass stays
+    one call on the whole matrix."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.core import histogram_pallas
+    from lightgbm_tpu.core.histogram import build_histogram
+    r = np.random.RandomState(5)
+    n, f, b = 2 * histogram_pallas.ROW_BLOCK + 4321, 3, 16
+    xb = r.randint(0, b, (n, f)).astype(np.uint8)
+    g = r.randn(n).astype(np.float32)
+    h = np.abs(r.randn(n)).astype(np.float32)
+    m = (r.rand(n) > 0.4).astype(np.float32)
+    args = (jnp.asarray(xb), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m))
+    ref = np.zeros((f, b, 3))
+    for j in range(f):
+        np.add.at(ref[j], xb[:, j], np.stack([g * m, h * m, m], 1)
+                  .astype(np.float64))
+    for compensated in (True, False):
+        fn = lambda *a: build_histogram(                        # noqa: E731
+            *a, num_bins=b, impl="pallas_interpret", compensated=compensated)
+        pal = np.asarray(fn(*args))
+        np.testing.assert_array_equal(pal[:, :, 2], ref[:, :, 2])
+        np.testing.assert_allclose(pal, ref, rtol=2e-5, atol=2e-3)
+        text = str(jax.make_jaxpr(fn)(*args))
+        assert ("dynamic_slice" in text) == compensated
+
+
+def test_compensated_add_keeps_the_terms_accuracy():
+    """4,000 histograms of one size summed in float32: the plain running
+    total drifts by thousands of its last place, the compensated one stays
+    within a few (what a sibling taken by subtraction inherits)."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.core.histogram import compensated_add
+    terms = jnp.asarray(np.random.RandomState(1).rand(4000, 64)
+                        .astype(np.float32) + 0.5)
+
+    def step(c, t):
+        total, lost, plain = c
+        total, lost = compensated_add(total, lost, t)
+        return (total, lost, plain + t), None
+
+    zero = jnp.zeros((64,), jnp.float32)
+    (total, lost, plain), _ = jax.jit(lambda ts: jax.lax.scan(
+        step, (zero, zero, zero), ts))(terms)
+    want = np.asarray(terms, np.float64).sum(axis=0)
+    ulp = np.spacing(want.astype(np.float32)).astype(np.float64)
+    assert np.abs(np.asarray(total - lost, np.float64) - want).max() \
+        <= ulp.max()
+    assert np.abs(np.asarray(plain, np.float64) - want).max() > 8 * ulp.max()
+
+
+def test_pallas_kernel_tile_matches_scatter():
+    """One 4,096-row tile of pre-stacked (grad, hess, count) values, as the
+    exact grower's second pass feeds the kernel (partition.hist_for_leaf):
+    the channels come back in order from the digit-factorized kernel, and
+    the count channel, integers in float32, exactly."""
     import jax.numpy as jnp
     from lightgbm_tpu.core.histogram import hist_tile_vals
     r = np.random.RandomState(7)
-    n, f, b = 900, 9, 256
+    n, f, b = 4096, 9, 256
     xb = r.randint(0, b, (n, f)).astype(np.uint8)
-    vals6 = r.randn(n, 6).astype(np.float32)
-    ref = np.asarray(hist_tile_vals(jnp.asarray(xb), jnp.asarray(vals6),
+    vals = r.randn(n, 3).astype(np.float32)
+    vals[:, 2] = r.rand(n) > 0.3
+    ref = np.asarray(hist_tile_vals(jnp.asarray(xb), jnp.asarray(vals),
                                     b, "scatter"))
-    pal = np.asarray(hist_tile_vals(jnp.asarray(xb), jnp.asarray(vals6),
+    pal = np.asarray(hist_tile_vals(jnp.asarray(xb), jnp.asarray(vals),
                                     b, "pallas_interpret"))
-    assert pal.shape == (f, b, 6)
+    assert pal.shape == (f, b, 3)
     np.testing.assert_allclose(pal, ref, rtol=1e-4, atol=1e-3)
+    np.testing.assert_array_equal(pal[:, :, 2], ref[:, :, 2])
 
 
 @pytest.mark.parametrize("fake_backend", [

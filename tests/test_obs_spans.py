@@ -33,7 +33,8 @@ from lightgbm_tpu.obs.distributed import FlightRecorder
 
 SCOPES = ("lgbm.gradients", "lgbm.root_hist", "lgbm.row_gather",
           "lgbm.route_rows",
-          "lgbm.hist_tile", "lgbm.partition_scatter", "lgbm.split_search",
+          "lgbm.hist_tile", "lgbm.hist_subtract", "lgbm.partition_scatter",
+          "lgbm.split_search",
           "lgbm.leaf_ids", "lgbm.score_update", "lgbm.tree_pack")
 
 
@@ -139,17 +140,19 @@ def test_spans_record_under_observability_none_and_export_nothing(tmp_path):
             if m.name == "lgbm_train_span_seconds"} == reg_before
 
 
-# want: (partition_window_placement, leaf_ids_gather_free)
+# want: (partition_window_placement, leaf_ids_gather_free,
+#        hist_smaller_child)
 @pytest.mark.parametrize("extra,want", [
-    ({}, (0, 1)),                                # the CPU's element scatter
-    ({"tpu_hist_impl": "pallas_interpret"}, (1, 1)),  # the chip's tile loop
+    ({}, (0, 1, 1)),                             # the CPU's element scatter
+    ({"tpu_hist_impl": "pallas_interpret"}, (1, 1, 1)),  # the chip's loop
     ({"tpu_hist_impl": "pallas_interpret", "objective": "multiclass",
-      "num_class": 3}, (0, 1)),                  # vmapped class batching
+      "num_class": 3}, (0, 1, 1)),               # vmapped class batching
     ({"tpu_hist_impl": "pallas_interpret", "tree_growth": "frontier"},
-     (0, 0)),                                    # no leaf_id_from_partition
-    ({"cegb_penalty_feature_lazy": [0.1] * 4}, (0, 0)),  # ids kept by split
+     (0, 0, 0)),                                 # another grower
+    ({"tree_growth": "batched"}, (0, 0, 0)),
+    ({"cegb_penalty_feature_lazy": [0.1] * 4}, (0, 0, 1)),  # ids by split
 ], ids=["cpu_default", "pallas", "pallas_vmapped", "pallas_frontier",
-        "cegb_lazy"])
+        "batched", "cegb_lazy"])
 def test_setup_span_says_how_the_block_places_and_maps_rows(extra, want):
     mark = last_id()
     X = np.random.RandomState(0).randn(300, 4)
@@ -160,7 +163,8 @@ def test_setup_span_says_how_the_block_places_and_maps_rows(extra, want):
                      **extra), lgb.Dataset(X, y))
     (setup,) = spans_named("train.setup", since=mark)
     assert (setup["counts"]["partition_window_placement"],
-            setup["counts"]["leaf_ids_gather_free"]) == want
+            setup["counts"]["leaf_ids_gather_free"],
+            setup["counts"]["hist_smaller_child"]) == want
     assert setup["counts"]["rows"] == 300
 
 

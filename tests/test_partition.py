@@ -72,8 +72,9 @@ def test_partition_matches_masked(num_leaves, chunk):
 def test_partition_leaf_counts_consistent():
     """Partition bookkeeping: leaf ranges tile [0, N) and counts match the
     per-row leaf_id assignment."""
-    from lightgbm_tpu.core.partition import (init_partition, make_row_gather,
-                                             partition_and_hist, stack_vals)
+    from lightgbm_tpu.core.partition import (hist_for_leaf, init_partition,
+                                             make_row_gather, partition_rows,
+                                             stack_vals)
 
     np.random.seed(4)
     n, chunk = 1000, 128
@@ -89,13 +90,17 @@ def test_partition_leaf_counts_consistent():
                       jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32))
     gr = make_row_gather(jnp.asarray(xb), vals)
 
-    part, leaf_id, hl, hr = jax.jit(
-        lambda p, l: partition_and_hist(
-            p, l, jnp.int32(0), jnp.int32(1),
-            lambda rows: rows[:, 0] == 1,
-            jnp.asarray(True), chunk, gr, f, b,
-            "scatter", maintain_leaf_id=True))(part, leaf_id)
-    # the fused histograms cover exactly each child's rows
+    def split(p, l):
+        p, l = partition_rows(p, l, jnp.int32(0), jnp.int32(1),
+                              lambda rows: rows[:, 0] == 1,
+                              jnp.asarray(True), chunk, gr,
+                              maintain_leaf_id=True)
+        return (p, l) + tuple(
+            hist_for_leaf(p, jnp.int32(child), gr, n, f, b, chunk,
+                          impl="scatter") for child in (0, 1))
+
+    part, leaf_id, hl, hr = jax.jit(split)(part, leaf_id)
+    # a child's histogram, built from its new range, covers exactly its rows
     assert int(np.asarray(hl)[0, 1, 2]) == int(decision_np.sum())
     assert int(np.asarray(hr)[0, 0, 2]) == int((~decision_np).sum())
     lid = np.asarray(leaf_id)
@@ -257,8 +262,9 @@ def _placement_problem(windows, chunk):
     """One compiled split per (placement, chunk); the leaf, its range and
     the threshold are arguments. Integer-valued gradients: every f32 sum is
     exact, so the histograms compare with array_equal."""
-    from lightgbm_tpu.core.partition import (RowPartition, make_row_gather,
-                                             partition_and_hist, stack_vals)
+    from lightgbm_tpu.core.partition import (RowPartition, hist_for_leaf,
+                                             make_row_gather, partition_rows,
+                                             stack_vals)
     r = np.random.RandomState(28)
     xb = r.randint(0, _PB, (_PN, _PF)).astype(np.uint8)
     vals = np.stack([r.randint(-4, 5, _PN), r.randint(1, 4, _PN),
@@ -272,10 +278,15 @@ def _placement_problem(windows, chunk):
     @jax.jit
     def split(begin, count, valid, thr):
         part = RowPartition(jnp.asarray(order), begin, count)
-        part, _, hl, hr = partition_and_hist(
+        part, _ = partition_rows(
             part, jnp.zeros((_PN,), jnp.int32), jnp.int32(1), jnp.int32(3),
             lambda rows: rows[:, 0].astype(jnp.int32) <= thr, valid, chunk,
-            gr, _PF, _PB, "scatter", windows=windows)
+            gr, windows=windows)
+        # either child's histogram from its new range, as the grower
+        # builds the smaller one's (a dead split builds none)
+        hl, hr = (hist_for_leaf(part, jnp.int32(child), gr, _PN, _PF, _PB,
+                                chunk, valid=valid, impl="scatter")
+                  for child in (1, 3))
         return part, hl, hr
     # what stack_vals feeds the histograms: (g*m, h*m, m), m in {0, 1}
     return xb, vals * vals[:, 2:], order, split
@@ -365,7 +376,7 @@ def test_tpu_tile_loop_places_ids_without_a_scatter_into_order():
     scatter at all, so none whose operand has order's length (on a v5e such
     a scatter cost 187 us a tile, half an iteration; PERF.md, PR 28)."""
     from lightgbm_tpu.core.partition import (init_partition, make_row_gather,
-                                             partition_and_hist, stack_vals,
+                                             partition_rows, stack_vals,
                                              window_placement)
     n, chunk, f, b = 1000, 128, 3, 8
     impl = "pallas_interpret"
@@ -375,10 +386,10 @@ def test_tpu_tile_loop_places_ids_without_a_scatter_into_order():
     part = init_partition(n, 8, chunk)
 
     def names(windows):
-        jaxpr = jax.make_jaxpr(lambda p: partition_and_hist(
+        jaxpr = jax.make_jaxpr(lambda p: partition_rows(
             p, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
-            lambda rows: rows[:, 0] == 1, jnp.asarray(True), chunk, gr, f,
-            b, impl, windows=windows))(part)
+            lambda rows: rows[:, 0] == 1, jnp.asarray(True), chunk, gr,
+            windows=windows))(part)
         return [(e.primitive.name, e.invars[0].aval.shape)
                 for e in _eqns_under(jaxpr.jaxpr, "lgbm.partition_scatter")
                 if e.invars]
@@ -391,3 +402,124 @@ def test_tpu_tile_loop_places_ids_without_a_scatter_into_order():
     # the walk does see the other placement's scatter into order
     assert ("scatter", part.order.shape) in names(
         window_placement(impl, vmapped=True))
+
+
+@pytest.mark.parametrize("impl", ["scatter", "pallas_interpret"])
+def test_smaller_child_plus_sibling_is_the_parent(impl):
+    """The identity the exact grower leans on: the two children's
+    histograms, each built from its new range, add up to the parent's built
+    from the old one — within float32 rounding in the gradient and hessian
+    channels, and EXACTLY in the count channel (integers in float32), so
+    parent - smaller is the sibling and its counts are the partition's."""
+    from lightgbm_tpu.core.partition import (hist_for_leaf, init_partition,
+                                             make_row_gather, partition_rows,
+                                             stack_vals, window_placement)
+    r = np.random.RandomState(32)
+    n, chunk, f, b = 5000, 512, 5, 64
+    xb = r.randint(0, b, (n, f)).astype(np.uint8)
+    gr = make_row_gather(jnp.asarray(xb), stack_vals(
+        jnp.asarray(r.randn(n).astype(np.float32)),
+        jnp.asarray((r.rand(n) + 0.5).astype(np.float32)),
+        jnp.asarray((r.rand(n) < 0.8).astype(np.float32))))
+
+    @jax.jit
+    def split(part):
+        hist = functools.partial(hist_for_leaf, gather_rows=gr, num_rows=n,
+                                 num_cols=f, num_bins=b, chunk=chunk,
+                                 impl=impl)
+        parent = hist(part, jnp.int32(0))
+        part, _ = partition_rows(
+            part, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
+            lambda rows: rows[:, 2] < 20, jnp.asarray(True), chunk, gr,
+            windows=window_placement(impl, vmapped=False))
+        return parent, hist(part, jnp.int32(0)), hist(part, jnp.int32(1))
+
+    parent, left, right = map(np.asarray,
+                              split(init_partition(n, 4, chunk)))
+    n_left = int((xb[:, 2] < 20).sum())
+    assert 0 < n_left < n - n_left                  # left is the smaller
+    np.testing.assert_array_equal(left[:, :, 2] + right[:, :, 2],
+                                  parent[:, :, 2])
+    np.testing.assert_array_equal(parent[:, :, 2] - left[:, :, 2],
+                                  right[:, :, 2])
+    np.testing.assert_allclose(parent - left, right, rtol=1e-5, atol=2e-4)
+
+
+def _pallas_channels(jaxpr):
+    """The value-channel count K of every pallas_call under ``jaxpr``: the
+    leading axis of the digit kernel's [K, F, Hi, 16] output."""
+    return [e.outvars[0].aval.shape[0] for e in _eqns_under(jaxpr, "")
+            if e.primitive.name == "pallas_call"]
+
+
+def test_exact_grower_calls_the_kernel_with_three_channels_only():
+    """Root and tile passes alike feed the kernel (grad, hess, count) of
+    ONE leaf: no call prices two children through six channels (80 us a
+    4,096-row tile on a v5e against 40 at three; PERF.md, PR 32)."""
+    from lightgbm_tpu.core.histogram import hist_tile_vals
+    n, f, b = 600, 4, 16
+    p = GrowParams(num_leaves=7, num_bins=b, max_depth=-1,
+                   split=_split_params(), row_chunk=256,
+                   hist_impl="pallas_interpret", use_partition=True)
+    jaxpr = jax.make_jaxpr(functools.partial(grow_tree, params=p))(
+        jnp.zeros((n, f), jnp.uint8), jnp.zeros((n,), jnp.float32),
+        jnp.ones((n,), jnp.float32), jnp.ones((n,), jnp.float32),
+        _meta(f, b), jnp.ones((f,), bool))
+    got = _pallas_channels(jaxpr.jaxpr)
+    assert len(got) >= 2 and set(got) == {3}, got
+    # the walk does see a six-channel call
+    six = jax.make_jaxpr(lambda x, v: hist_tile_vals(
+        x, v, b, "pallas_interpret"))(jnp.zeros((256, f), jnp.uint8),
+                                      jnp.zeros((256, 6), jnp.float32))
+    assert _pallas_channels(six.jaxpr) == [6]
+
+
+def test_mesh_builds_the_globally_smaller_child_on_every_device():
+    """Two devices whose LOCAL smaller child of the root's split differs:
+    device 0 holds few of the left child's rows, device 1 most of them,
+    and over both the left child is the smaller. Each device must
+    histogram the globally smaller child (the psum adds like to like) —
+    the grown tree is the single-device one."""
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+    r = np.random.RandomState(5)
+    n, f, b = 4000, 4, 16
+    half = n // 2
+    xb = r.randint(0, b, (n, f)).astype(np.uint8)
+    # column 0 decides: bins 0..7 go left; 5% of device 0's rows and 85% of
+    # device 1's, 45% of all
+    left = np.concatenate([r.rand(half) < 0.05, r.rand(half) < 0.85])
+    xb[:, 0] = np.where(left, r.randint(0, 8, n), r.randint(8, b, n))
+    g = (np.where(left, -1.0, 1.0) + 0.1 * r.randn(n)).astype(np.float32)
+    h = np.ones(n, np.float32)
+    meta, fm = _meta(f, b), jnp.ones((f,), bool)
+    p = GrowParams(num_leaves=15, num_bins=b, max_depth=-1,
+                   split=_split_params(), row_chunk=256,
+                   hist_impl="scatter", use_partition=True)
+
+    tree_ref, leaf_ref = jax.jit(lambda *a: grow_tree(
+        *a, meta, fm, p)[:2])(xb, g, h, h)
+    assert int(tree_ref.split_feature[0]) == 0
+    assert int(tree_ref.threshold_bin[0]) == 7
+    assert left[:half].sum() < half - left[:half].sum()       # device 0
+    assert left[half:].sum() > half - left[half:].sum()       # device 1
+    assert left.sum() < n - left.sum()                        # both
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
+    fn = shard_map(
+        lambda *a: grow_tree(*a, meta, fm,
+                             p._replace(partition_on_mesh=True),
+                             axis_name="data")[:2],
+        mesh=mesh, in_specs=(P("data"),) * 4,
+        out_specs=(jax.tree.map(lambda _: P(), tree_ref), P("data")),
+        check_vma=False)
+    tree_dp, leaf_dp = jax.jit(fn)(xb, g, h, h)
+    for name in ("split_feature", "threshold_bin", "leaf_count",
+                 "internal_count", "split_leaf"):
+        np.testing.assert_array_equal(np.asarray(getattr(tree_dp, name)),
+                                      np.asarray(getattr(tree_ref, name)),
+                                      err_msg=name)
+    np.testing.assert_array_equal(np.asarray(leaf_dp), np.asarray(leaf_ref))
+    np.testing.assert_allclose(np.asarray(tree_dp.leaf_value),
+                               np.asarray(tree_ref.leaf_value),
+                               rtol=1e-4, atol=1e-5)
