@@ -8,6 +8,10 @@ columns, num_leaves=255, max_bin=255) on seeded synthetic rows:
                      tpu_hist_impl=auto, which must resolve to pallas)
   kernel_parity      Pallas histograms against the f32 scatter histogram
   train_frontier     the same call with tree_growth=frontier
+  train_goss         the same call with boosting=goss at learning_rate 0.5,
+                     so that three of the five trees are sampled: they are
+                     grown on a bag of 30% of the rows, counted in its
+                     integers, and every row still takes their score
   predict_and_serve  bst.predict, save_model, then the task=serve path over
                      HTTP in this process, zero compiles after warm-up
   mesh4              tree_learner=data over four devices (only when the
@@ -65,6 +69,10 @@ FRONTIER_AUC_BAND = 0.02
 PARITY_ROWS = 65536
 PARITY_TOL = 1e-3
 SERVE_TOL = 1e-6          # tools/serve_smoke.py's tolerance
+# GOSS samples from iteration 1 / learning_rate on: 2 of the 5 rounds stay
+# unsampled. Held scores are a float32 running sum of five leaf values
+GOSS_RATE = 0.5
+GOSS_SCORE_TOL = 1e-5
 # four-device model against the single-device one: tests/test_parallel.py
 # holds predictions to 1e-3; at 255 leaves a near-tied split may flip under
 # f32 summation order and move one leaf's rows, so a small share may exceed it
@@ -183,6 +191,30 @@ class Smoke:
         gp = self.bst_exact._impl.grow_params
         return {"hist_impl": gp.hist_impl, "tree_growth": "exact",
                 "train_auc": round(self.auc_exact, 4)}
+
+    def train_goss(self):
+        bst = self.train(boosting="goss", learning_rate=GOSS_RATE)
+        gbdt = bst._impl
+        check(gbdt._goss_bag, "boosting=goss on the serial exact grower did "
+              "not start its row partition from the bag")
+        bag = int(self.rows * 0.2) + int(self.rows * 0.1)
+        roots = [int(t.internal_count[0]) for t in gbdt.models]
+        unsampled = int(1 / GOSS_RATE)
+        check(roots == [self.rows] * unsampled + [bag] * (ROUNDS - unsampled),
+              "root counts %s: expected %d unsampled trees on %d rows, then "
+              "bags of %d" % (roots, unsampled, self.rows, bag))
+        # rows out of the last bags were routed all the same: what the
+        # booster holds for a row is what its trees give that row
+        held = np.asarray(gbdt.scores)[:self.eval_rows, 0]
+        raw = bst.predict(self.X[:self.eval_rows], raw_score=True)
+        worst = float(np.abs(raw - held).max())
+        check(worst <= GOSS_SCORE_TOL, "held scores vs the trees' leaves "
+              "maxdiff %.3g > %.0g" % (worst, GOSS_SCORE_TOL))
+        a = self.train_auc(bst)
+        check(a > MIN_TRAIN_AUC, "GOSS train AUC %.4f <= %.2f"
+              % (a, MIN_TRAIN_AUC))
+        return {"boosting": "goss", "bag_rows": bag, "train_auc": round(a, 4),
+                "held_vs_leaves_maxdiff": worst}
 
     def kernel_parity(self):
         r = np.random.RandomState(7)
@@ -387,6 +419,7 @@ def main():
     smoke.phase("train_exact", smoke.train_exact)
     smoke.phase("kernel_parity", smoke.kernel_parity)
     smoke.phase("train_frontier", smoke.train_frontier)
+    smoke.phase("train_goss", smoke.train_goss)
     smoke.phase("predict_and_serve", smoke.predict_and_serve)
     if len(devices) >= 4:
         smoke.phase("mesh4", smoke.mesh4)

@@ -44,7 +44,7 @@ from ..core import tree as tree_mod
 from ..objectives import ObjectiveFunction
 from ..metrics import Metric
 from ..resilience import faults as _faults
-from ..obs.trace import recorder
+from ..obs.trace import record_span, recorder
 
 
 class HostTree:
@@ -310,6 +310,15 @@ class GBDT:
                 span.counts["leaf_ids_gather_free"] = int(
                     exact_part and not p.with_cegb_lazy)
                 span.counts["hist_smaller_child"] = int(exact_part)
+                # GOSS: whether the row partition starts from the bag, and
+                # the bag's static size (the others' draw is exact, so its
+                # capacity is its count)
+                span.counts["goss_bag_partition"] = int(self._goss_bag)
+                if self._goss_counts is not None:
+                    top_cnt, other_cnt, _ = self._goss_counts
+                    span.counts["bag_rows"] = top_cnt + other_cnt
+                    span.counts["bag_top_rows"] = top_cnt
+                    span.counts["bag_other_capacity"] = other_cnt
                 # what the data made of its columns: those whose split
                 # search prices a missing direction, and those with fewer
                 # bins than max_bin allows
@@ -638,6 +647,22 @@ class GBDT:
             self.mesh is not None
             and cfg.tree_learner == "data"
             and mesh_mod.DATA_AXIS in self.mesh.axis_names)
+        # GOSS where the exact grower runs over the row partition on one
+        # device: the sampler makes a bag and the partition starts from it
+        # (boosting/goss.py). Everywhere else it stays a multiplier.
+        self._goss_bag = (
+            self.boosting_type == "goss" and self.mesh is None
+            and not streamed and not frontier_mode and batch_splits == 0
+            and not vmapped and self._cegb_state is None)
+        # (top_cnt, other_cnt, the others' multiplier) from the REAL row
+        # count, not the mesh-padding-inflated one — padded rows carry
+        # |g·h| = 0 and sort last, so top-k over the padded array with real
+        # counts is exact (goss.hpp:87-135)
+        self._goss_counts = None
+        if self.boosting_type == "goss":
+            from .goss import bag_counts
+            self._goss_counts = bag_counts(
+                self.num_data_orig, cfg.top_rate, cfg.other_rate)
 
         # observability: built before grow_params so the device-side
         # health piggy-back (GrowParams.obs_health) keys off the resolved
@@ -706,7 +731,8 @@ class GBDT:
                                   .any()),
             all_rows_in_bag=(
                 not (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0)
-                and self.boosting_type not in ("goss", "rf")
+                and self.boosting_type != "rf"
+                and (self.boosting_type != "goss" or self._goss_bag)
                 and row_valid is None and not streamed),
             use_partition=(self.mesh is None or self._partition_on_mesh),
             partition_on_mesh=self._partition_on_mesh,
@@ -835,6 +861,18 @@ class GBDT:
         self._compiled_iter = None
         self._iter_core = None
         self._compiled_block = None
+        # under a GOSS bag the three above are the CURRENT regime's (the
+        # unsampled iterations' or the sampled ones'); the other regime's
+        # wait in _programs (_enter_regime)
+        self._sampled_regime = False
+        # (iteration, uint8 [N] of goss.OUT_OF_BAG / BAG_TOP / BAG_OTHER):
+        # the newest bag a sampled iteration grew its tree on, on the device
+        self.last_bag: Optional[Tuple[int, jnp.ndarray]] = None
+        self._programs: Dict[bool, Tuple[Any, Any, Any]] = {}
+        self._goss_t0: Optional[float] = None
+        # blocks compiled ahead of their first dispatch (compile_block),
+        # by (regime, block length)
+        self._aot_blocks: Dict[Tuple[bool, int], Any] = {}
         self._ladder_warmup: Optional[Dict[str, Any]] = None
         # shape bookkeeping for PULL-based cost-model extraction
         # (extract_cost_model): what the last fused block / flush looked
@@ -1111,15 +1149,19 @@ class GBDT:
         # plus the grower's aux accumulator. Off: the step returns a
         # constant zero vector and no health compute enters the program.
         health_on = self.obs.health_enabled
-        is_goss = self.boosting_type == "goss"
-        if is_goss:
-            # counts from the REAL row count, not the mesh-padding-inflated
-            # one — padded rows carry |g·h| = 0 and sort last, so top-k over
-            # the padded array with real counts is exact (goss.hpp:87-135)
+        # which GOSS sampler this program holds. None: no GOSS, or under a
+        # bag (_goss_bag) the unsampled iterations' program, which is
+        # boosting=gbdt's; "bag": the sampled iterations' program under a
+        # bag; "mask": both regimes in one program under a lax.cond
+        goss_mode = None
+        if self.boosting_type == "goss":
+            from .goss import BAG_OTHER, BAG_TOP, sample_bag
+            if not self._goss_bag:
+                goss_mode = "mask"
+            elif self._sampled_regime:
+                goss_mode = "bag"
             n_real = self.num_data_orig
-            top_cnt = max(1, int(n_real * self.config.top_rate))
-            other_cnt = max(1, int(n_real * self.config.other_rate))
-            goss_multiply = float(n_real - top_cnt) / other_cnt
+            top_cnt, other_cnt, goss_multiply = self._goss_counts
 
         forced_splits = self._forced_splits
         # RenewTreeOutput objectives (L1/Quantile/MAPE): leaf refit runs
@@ -1154,12 +1196,14 @@ class GBDT:
                 else:
                     g, h = grad_in, hess_in
 
-                if is_goss:
-                    # GOSS one-side sampling on device (goss.hpp:87-135):
-                    # keep all of the top |g*h| rows, sample the rest,
-                    # amplify their grad/hess by (n - top)/other so
-                    # expectations are unbiased. Warmup iterations
-                    # (goss_active == 0) skip the sort entirely.
+                if goss_mode == "mask":
+                    # GOSS one-side sampling as a multiplier
+                    # (goss.hpp:87-135): keep all of the top |g*h| rows,
+                    # Bernoulli-sample the rest, amplify their grad/hess by
+                    # (n - top)/other so expectations are unbiased; every
+                    # row stays in the grower's passes, the dropped ones at
+                    # weight 0. Warmup iterations (goss_active == 0) skip
+                    # the sort entirely.
                     def goss_mult(_):
                         gh = jnp.sum(jnp.abs(g * h), axis=1)
                         thr = jax.lax.top_k(gh, top_cnt)[0][-1]
@@ -1177,6 +1221,26 @@ class GBDT:
                     g = g * mult[:, None]
                     h = h * mult[:, None]
                     sample_mask = sample_mask * (mult > 0).astype(jnp.float32)
+
+            bag = bag_code = None
+            if goss_mode == "bag":
+                # GOSS one-side sampling as a bag (boosting/goss.py): a
+                # code a row (the top at weight 1, exactly other_cnt of
+                # the rest at the multiplier, the others out), and the row
+                # partition the tree starts from, the bag's rows at its
+                # front. No bagging under GOSS and no padding off a mesh:
+                # the mask that comes in is all ones
+                with jax.named_scope("lgbm.goss_sample"):
+                    bag_code = sample_bag(jnp.sum(jnp.abs(g * h), axis=1),
+                                          goss_key, top_cnt, other_cnt)
+                    mult = jnp.where(
+                        bag_code == BAG_TOP, 1.0,
+                        jnp.where(bag_code == BAG_OTHER, goss_multiply, 0.0))
+                    g = g * mult[:, None]
+                    h = h * mult[:, None]
+                    sample_mask = (bag_code > 0).astype(jnp.float32)
+                bag = partition_mod.bag_partition(bag_code > 0,
+                                                  params.row_chunk)
 
             # one place decides which wave-batched grower runs (the
             # shard_map and single-device branches below both use it)
@@ -1298,7 +1362,7 @@ class GBDT:
                 def grow_one(gk, hk, cs):
                     return grow_tree(xb, gk, hk, sample_mask, meta,
                                      feature_mask, params,
-                                     forced=forced_splits, cegb=cs)
+                                     forced=forced_splits, cegb=cs, bag=bag)
 
             # class batching: k == 1 calls directly; multiclass maps
             # classes sequentially when (a) the pool is capped — vmap
@@ -1349,6 +1413,12 @@ class GBDT:
                 grower_health, grower_mstats = aux
             elif params.frontier_mode and params.obs_health:
                 grower_health, cegb_out = cegb_out, None
+            # grown on a bag (no CEGB there), it is the rows whose bins
+            # entered a histogram kernel call, a class tree. With the bag's
+            # codes they are what an iteration on a bag hands out besides
+            bag_aux = None
+            if bag is not None:
+                bag_aux, cegb_out = (jnp.sum(cegb_out), bag_code), None
             if cegb_state is not None:
                 # classes train from the iteration-start state; acquisitions
                 # merge across class trees for the next iteration (the
@@ -1404,11 +1474,12 @@ class GBDT:
                 health = health_vec(g, h, any_split, grower_health)
             else:
                 health = jnp.zeros((4,), jnp.float32)
-            # grower_mstats is None unless obs_modelstats: a None output
-            # is an empty pytree leaf, so the compiled program (and every
-            # jaxpr fingerprint) is unchanged when the feature is off
+            # grower_mstats is None unless obs_modelstats, bag_aux unless
+            # the tree grew on a bag: a None output is an empty pytree
+            # leaf, so the compiled program (and every jaxpr fingerprint)
+            # is unchanged when the feature is off
             return pack_trees(trees), leaf_ids, new_scores, cegb_new, \
-                stopped_out, health, grower_mstats
+                stopped_out, health, grower_mstats, bag_aux
 
         self._iter_core = run_iter   # unjitted: train_many scans over it
         return jax.jit(run_iter)
@@ -1445,9 +1516,7 @@ class GBDT:
         is_goss = self.boosting_type == "goss"
         if is_goss:
             n_real = self.num_data_orig
-            top_cnt = max(1, int(n_real * self.config.top_rate))
-            other_cnt = max(1, int(n_real * self.config.other_rate))
-            goss_multiply = float(n_real - top_cnt) / other_cnt
+            top_cnt, other_cnt, goss_multiply = self._goss_counts
         row_valid = self._row_valid
         renew_alpha = None
         renew_w_attr = None
@@ -1555,6 +1624,7 @@ class GBDT:
         tspan = obs.trace_iter(iter_idx)
         with obs.span("train.block", start_iter=iter_idx,
                       count=1) as block_span:
+            self._count_goss(block_span, iter_idx, 1)
             with obs.span("train.block_prepare"):
                 sample_mask = self._sample_bagging_mask(iter_idx)
                 feature_mask = self._sample_feature_mask()
@@ -1657,6 +1727,7 @@ class GBDT:
         row_valid = self._row_valid
         row_group = self._row_group          # group-aware bagging (ranking)
         num_groups = getattr(self, "_num_groups", 0)
+        goss_bag_sampled = self._goss_bag and self._sampled_regime
 
         def run_block(xb, obj_rows, fp_capture, meta, scores, feature_masks,
                       goss_actives, iter_idxs, keys, bag_mask0, cegb_state,
@@ -1665,7 +1736,7 @@ class GBDT:
             h0 = jnp.ones((n, k), jnp.float32)
 
             def step(carry, xs):
-                sc, bag_mask, cegb, stopped = carry
+                sc, bag_mask, cegb, stopped, _ = carry
                 fm, ga, it, key = xs
                 bkey, gkey = jax.random.split(key)
                 if bag_enabled:
@@ -1681,22 +1752,27 @@ class GBDT:
                         new_mask = (u < frac).astype(jnp.float32)
                         bag_mask = jnp.where(refresh, new_mask, bag_mask)
                 sm = bag_mask if row_valid is None else bag_mask * row_valid
-                packed, _leaf_ids, sc2, cegb2, stopped2, health, ms = core(
-                    xb, obj_rows, fp_capture, meta, sc, sm, fm, g0, h0, lr, ga,
-                    gkey, cegb, stopped)
-                return (sc2, bag_mask, cegb2, stopped2), (packed, health, ms)
+                packed, _leaf_ids, sc2, cegb2, stopped2, health, ms, aux = \
+                    core(xb, obj_rows, fp_capture, meta, sc, sm, fm, g0, h0,
+                         lr, ga, gkey, cegb, stopped)
+                hr, code = aux if aux is not None else (None, None)
+                return (sc2, bag_mask, cegb2, stopped2, code), \
+                    (packed, health, ms, hr)
 
-            carry, (packs, healths, mstats) = lax.scan(
-                step, (scores, bag_mask0, cegb_state, stopped_in),
+            code0 = jnp.zeros((n,), jnp.uint8) if goss_bag_sampled else None
+            carry, (packs, healths, mstats, hist_rows) = lax.scan(
+                step, (scores, bag_mask0, cegb_state, stopped_in, code0),
                 (feature_masks, goss_actives, iter_idxs, keys))
-            new_scores, bag_mask, cegb_out, stopped_out = carry
+            new_scores, bag_mask, cegb_out, stopped_out, last_code = carry
             # healths: [block, 4] per-iteration health vectors (zeros when
             # monitoring is off) — one tiny transfer per block, not per
             # iter. mstats: [block, K, F, MS_WIDTH] per-iteration model
             # statistics with obs_modelstats, else None (invisible in the
-            # compiled program)
+            # compiled program); under a GOSS bag (hist_rows [block], the
+            # last iteration's bag codes [N]), else None likewise
+            bag_aux = ((hist_rows, last_code) if goss_bag_sampled else None)
             return packs, healths, new_scores, bag_mask, cegb_out, \
-                stopped_out, mstats
+                stopped_out, mstats, bag_aux
 
         return run_block
 
@@ -1926,18 +2002,10 @@ class GBDT:
 
         self._boost_from_average()
         self._maybe_warm_ladder()
-        if self._iter_core is None or self._compiled_block is None:
-            with self.obs.span("train.make_block_fn"):
-                if self._iter_core is None:
-                    self._compiled_iter = self._make_train_iter_fn()
-                if self._compiled_block is None:
-                    # one jitted scan; jax caches a compilation per block
-                    # length
-                    self._compiled_block = self._make_train_block_fn()
 
         done = 0
         while done < num_iters and not self._stopped:
-            block = min(num_iters - done, 64)
+            block = self._ready_block(num_iters - done)
             # train_dispatch seam (docs/Resilience.md): fires before the
             # block is dispatched; iteration = block start, round = the
             # per-point block ordinal. Two attribute checks when inert.
@@ -1950,11 +2018,13 @@ class GBDT:
             obs.perfetto_step(self.iter_, self.iter_ + block)
             with obs.span("train.block", start_iter=self.iter_,
                           count=block) as block_span:
+                self._count_goss(block_span, self.iter_, block)
                 # feature sampling and the bag keys are host-side work: with
                 # the dispatch they are the block's busy_s in the distributed
                 # per-block comm/compute split
                 with obs.span("train.block_prepare"):
-                    fn = self._compiled_block
+                    fn = self._aot_blocks.get(
+                        (self._sampled_regime, block), self._compiled_block)
                     fmasks = jnp.stack([self._sample_feature_mask()
                                         for _ in range(block)])
                     gactive = jnp.asarray(
@@ -1972,7 +2042,8 @@ class GBDT:
                 # first block: tracing, lowering, the compile or cache load
                 with obs.span("train.block_dispatch") as dispatch_span:
                     packs, healths, self.scores, self._bag_mask, \
-                        self._cegb_state, self._stopped_dev, mstats = fn(
+                        self._cegb_state, self._stopped_dev, mstats, \
+                        bag_aux = fn(
                             *self._iter_capture,
                             self.scores, fmasks, gactive, idxs, all_keys[1:],
                             self._bag_mask, self._cegb_state,
@@ -1986,7 +2057,9 @@ class GBDT:
             self._pending.append({"packed": packs,
                                   "shrinkage": self.shrinkage_rate,
                                   "count": block,
-                                  "mstats": mstats})
+                                  "mstats": mstats,
+                                  "span": block_span})
+            self._keep_bag(bag_aux, self.iter_ + block - 1)
             self.iter_ += block
             done += block
             if obs.enabled:
@@ -2009,6 +2082,80 @@ class GBDT:
 
     def _goss_active(self, iter_idx: int) -> float:
         return 0.0
+
+    def _enter_regime(self, iter_idx: int) -> None:
+        """Under a GOSS bag the unsampled and the sampled iterations are
+        two device programs of different shapes: make current the one that
+        iteration ``iter_idx`` runs. A no-op everywhere else."""
+        if not self._goss_bag:
+            return
+        now = time.perf_counter()
+        if self._goss_t0 is None:
+            self._goss_t0 = now
+        sampled = self._goss_active(iter_idx) > 0
+        if sampled == self._sampled_regime:
+            return
+        if sampled and iter_idx == self._goss_warmup():
+            # what every GOSS job pays before its first sampled tree:
+            # from its first block made ready to the switch
+            record_span("train.goss_warmup", now - self._goss_t0,
+                        iterations=iter_idx)
+        self._programs[self._sampled_regime] = (
+            self._compiled_iter, self._iter_core, self._compiled_block)
+        self._compiled_iter, self._iter_core, self._compiled_block = \
+            self._programs.pop(sampled, (None, None, None))
+        self._sampled_regime = sampled
+
+    def _ready_block(self, remaining: int) -> int:
+        """Make current, and build if need be, the program the next block
+        runs; return that block's length: at most 64 iterations, and none
+        across a GOSS bag's switch from unsampled to sampled."""
+        self._enter_regime(self.iter_)
+        if self._iter_core is None or self._compiled_block is None:
+            with self.obs.span("train.make_block_fn"):
+                if self._iter_core is None:
+                    self._compiled_iter = self._make_train_iter_fn()
+                if self._compiled_block is None:
+                    # one jitted scan; jax caches a compilation per block
+                    # length
+                    self._compiled_block = self._make_train_block_fn()
+        block = min(remaining, 64)
+        if self._goss_bag and not self._sampled_regime:
+            block = min(block, self._goss_warmup() - self.iter_)
+        return block
+
+    def compile_block(self, num_iters: int) -> None:
+        """Trace, lower and compile (or load from the persistent cache)
+        the block the next ``train_many(num_iters)`` dispatches first, and
+        run nothing: a caller that times its blocks pays the compile when
+        it chooses to, as a GOSS job about to cross from its unsampled
+        iterations to the sampled ones, whose program is another."""
+        self._boost_from_average()
+        block = self._ready_block(num_iters)
+        with self.obs.span("train.compile_block", count=block):
+            self._aot_blocks[(self._sampled_regime, block)] = \
+                self._compiled_block.lower(
+                    *self.train_block_sds(block)).compile()
+
+    def _keep_bag(self, bag_aux, iteration: int) -> None:
+        """What a block on a GOSS bag hands out besides its trees: the
+        rows its histogram passes saw, for the block's span once the host
+        fetches the trees, and its last iteration's bag, which stays on
+        the device as ``last_bag``."""
+        if bag_aux is not None:
+            self._pending[-1]["hist_rows"], code = bag_aux
+            self.last_bag = (iteration, code)
+
+    def _count_goss(self, block_span, start_iter: int, count: int) -> None:
+        """The GOSS counts of a ``train.block`` span: whether its
+        iterations sample and, where they do, the others drawn an
+        iteration (the draw is exact: the count is other_cnt)."""
+        if self.boosting_type != "goss":
+            return
+        active = self._goss_active(start_iter + count - 1) > 0
+        block_span.counts["goss_active"] = int(active)
+        if active:
+            block_span.counts["bag_other_rows"] = self._goss_counts[1]
 
     @property
     def models(self) -> List[HostTree]:
@@ -2201,6 +2348,8 @@ class GBDT:
         self._compiled_iter = None
         self._iter_core = None
         self._compiled_block = None
+        self._programs.clear()
+        self._aot_blocks.clear()
         if getattr(self, "grow_params", None) is not None \
                 and self.grow_params.frontier_mode \
                 and not self._partition_on_mesh:
@@ -2228,6 +2377,7 @@ class GBDT:
         _faults.inject("train_dispatch", iteration=self.iter_)
         self._boost_from_average()
         self._maybe_warm_ladder()
+        self._enter_regime(self.iter_)
         if self._compiled_iter is None:
             with self.obs.span("train.make_block_fn"):
                 self._compiled_iter = self._make_train_iter_fn()
@@ -2239,6 +2389,7 @@ class GBDT:
         # as train_many's
         with obs.span("train.block", start_iter=iter_idx,
                       count=1) as block_span:
+            self._count_goss(block_span, iter_idx, 1)
             with obs.span("train.block_prepare"):
                 sample_mask = self._sample_bagging_mask(iter_idx)
                 feature_mask = self._sample_feature_mask()
@@ -2262,7 +2413,7 @@ class GBDT:
                 self._bag_key, goss_key = jax.random.split(self._bag_key)
             with obs.span("train.block_dispatch") as dispatch_span:
                 packed, leaf_ids, new_scores, cegb_new, self._stopped_dev, \
-                    health, mstats = self._compiled_iter(
+                    health, mstats, bag_aux = self._compiled_iter(
                         *self._iter_capture,
                         self.scores, sample_mask, feature_mask, g_in, h_in,
                         jnp.float32(self.shrinkage_rate),
@@ -2281,8 +2432,10 @@ class GBDT:
                                 "shrinkage": self.shrinkage_rate,
                                 "count": 1,
                                 "mstats": (mstats[None]
-                                           if mstats is not None else None)}
+                                           if mstats is not None else None),
+                                "span": block_span}
         self._pending.append(pend)
+        self._keep_bag(bag_aux, iter_idx)
         self.iter_ += 1
         if obs.enabled:
             hrow = np.asarray(health)[None]
@@ -2321,6 +2474,12 @@ class GBDT:
         with self.obs.span("materialize", blocks=len(pend)):
             buf = np.asarray(jnp.concatenate([p["packed"] for p in pend],
                                              axis=0))  # [sum(B_i), K, T]
+        for p in pend:
+            # a span never waits for the device: the count its block made
+            # there joins it here, where the host fetches the trees
+            if p.get("hist_rows") is not None:
+                p["span"].counts["hist_rows"] = int(
+                    np.asarray(p["hist_rows"], np.int64).sum())
         row = 0
         for p in pend:
             if self._stopped:

@@ -38,9 +38,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from .histogram import build_histogram
-from .partition import (RowPartition, hist_for_leaf, init_partition,
-                        leaf_id_from_partition, make_row_gather,
-                        partition_rows, stack_vals, window_placement)
+from .partition import (OutOfBag, RowPartition, hist_for_leaf,
+                        init_partition, leaf_id_from_partition,
+                        make_row_gather, partition_rows, stack_vals,
+                        window_placement)
 from .split import (BestSplit, FeatureMeta, SplitParams, K_EPSILON,
                     K_MIN_SCORE, MISSING_NAN, MISSING_NONE, MISSING_ZERO,
                     calculate_leaf_output, find_best_split, leaf_split_gain,
@@ -66,10 +67,11 @@ class GrowParams(NamedTuple):
     # dataset has categorical features -> run the categorical split finder
     # alongside the numerical one (FindBestThreshold dispatch)
     with_categorical: bool = False
-    # no bagging, no GOSS, no padded rows: every row carries sample weight
-    # 1, so the row partition's integer counts ARE the leaves' counts. The
-    # exact grower then records those; a float32 histogram count cannot
-    # hold an odd number past 2**24 rows
+    # the row partition holds exactly the counted rows: no bagging, no
+    # padded rows, and GOSS only where the partition starts from its bag
+    # (grow_tree's ``bag``), so the partition's integer counts ARE the
+    # leaves' counts. The exact grower then records those; a float32
+    # histogram count cannot hold an odd number past 2**24 rows
     all_rows_in_bag: bool = False
     # row-partition mode (DataPartition analog, core/partition.py): keep rows
     # grouped by leaf and build each histogram only over the leaf's rows —
@@ -272,6 +274,10 @@ class _GrowState(NamedTuple):
     #                               remaining forced steps fall back to
     #                               best-first (aborted_last_force_split)
     pool_map: Optional[PoolMap]   # LRU slot map (None = uncapped)
+    oob: Optional[OutOfBag] = None   # the leaves' ranges out of the bag
+    hist_rows: Optional[jnp.ndarray] = None   # with a bag: rows whose bins
+    #                                           entered a kernel call
+
 
 
 def _empty_best(num_leaves: int, dtype=jnp.float32) -> BestSplit:
@@ -476,6 +482,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               forced: Optional[ForcedSplits] = None,
               cegb: Optional[CegbState] = None,
               fp: Optional[FeatureParallelCtx] = None,
+              bag: Optional[RowPartition] = None,
               ) -> Tuple[TreeArrays, jnp.ndarray, Optional[CegbState]]:
     """Grow one leaf-wise tree; returns (tree, final per-row leaf_id,
     updated CEGB state or None).
@@ -492,6 +499,14 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     argmax-allreduced as a struct (sync_best_split) — row partitioning is
     then computed locally and identically on every device from the
     replicated xb.
+
+    With ``bag`` set (partition.bag_partition; the single-device partition
+    path only): the tree is grown on the bag's rows. ``sample_mask`` is 1
+    on them and 0 elsewhere; the row partition starts from the bag, so the
+    root's pass, every smaller child's pass and the tree's counts see its
+    rows alone, and the rows out of it keep a second range a leaf
+    (partition.OutOfBag) that every split routes and nothing else reads:
+    the returned leaf_id covers ALL rows.
     """
     n, ncols = xb.shape                 # stored columns (== F without EFB)
     f = meta.num_bin.shape[0]           # logical features
@@ -645,16 +660,40 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                        if use_partition else None)
     # the tree's counts come from the partition's integers where those
     # count what the histogram's count channel counts
-    exact_counts = (params.all_rows_in_bag and use_partition
-                    and axis_name is None)
+    assert bag is None or (use_partition and axis_name is None), \
+        "a bag is the single-device row partition's"
+    exact_counts = ((params.all_rows_in_bag or bag is not None)
+                    and use_partition and axis_name is None)
+    part0 = oob0 = None
+    if bag is not None:
+        zeros_l = jnp.zeros((l,), jnp.int32)
+        part0 = RowPartition(bag.order, zeros_l,
+                             zeros_l.at[0].set(bag.leaf_count[0]))
+        oob0 = OutOfBag(zeros_l.at[0].set(bag.leaf_begin[1]),
+                        zeros_l.at[0].set(bag.leaf_count[1]))
+    elif use_partition:
+        part0 = init_partition(n, l, params.row_chunk)
+
+    def hist_of_range(part, leaf_idx, valid=True, scope=None):
+        """[C, B, 3] over ``leaf_idx``'s range of the row partition (this
+        device's rows of it)."""
+        return hist_for_leaf(part, leaf_idx, gather_rows, n, ncols, b,
+                             params.row_chunk, valid=valid,
+                             impl=params.hist_impl, val_dtype=hdt,
+                             scope=scope)
+
     with jax.named_scope("lgbm.root_hist"):
         root_g = psum(jnp.sum(grad * sample_mask))
         root_h = psum(jnp.sum(hess * sample_mask))
-        root_c = psum(jnp.sum(sample_mask))
         # over the row partition every other leaf's histogram comes from
         # this one by subtracting sums that carry their rounding
         # (hist_for_leaf), so this one carries its own too
-        hist_root = hist_for_mask(sample_mask, compensated=use_partition)
+        if bag is None:
+            root_c = psum(jnp.sum(sample_mask))
+            hist_root = hist_for_mask(sample_mask, compensated=use_partition)
+        else:
+            root_c = bag.leaf_count[0].astype(hdt)
+            hist_root = hist_of_range(part0, 0, scope="lgbm.root_hist")
 
     tree = empty_tree(l, hdt)
     tree = tree._replace(
@@ -663,7 +702,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                   sp.max_delta_step)),
         leaf_weight=tree.leaf_weight.at[0].set(root_h),
         leaf_count=tree.leaf_count.at[0].set(
-            jnp.int32(n) if exact_counts else count_i32(root_c)))
+            (jnp.int32(n) if bag is None else bag.leaf_count[0])
+            if exact_counts else count_i32(root_c)))
 
     root_pen = cegb_gain_penalty(cegb, root_c, sample_mask)
     best0 = best_for(hist_root, root_g, root_h, root_c, True,
@@ -691,13 +731,6 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             leaf_of_slot=jnp.full((num_slots,), -1, jnp.int32).at[0].set(0),
             last_used=jnp.full((num_slots,), -1, jnp.int32).at[0].set(0))
 
-    def hist_of_range(part, leaf_idx, valid=True):
-        """[C, B, 3] over ``leaf_idx``'s range of the row partition (this
-        device's rows of it)."""
-        return hist_for_leaf(part, leaf_idx, gather_rows, n, ncols, b,
-                             params.row_chunk, valid=valid,
-                             impl=params.hist_impl, val_dtype=hdt)
-
     def leaf_hist(s: _GrowState, leaf_idx, live=True):
         """A leaf's [C, B, 3] histogram: the pool slot when resident, else
         rebuilt from the leaf's rows (HistogramPool::Get miss path). Must
@@ -724,7 +757,6 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # under shard_map the carry must be marked device-varying up front:
         # it starts as a constant but becomes a function of the sharded rows
         leaf_id0 = lax.pcast(leaf_id0, (axis_name,), to="varying")
-    part0 = init_partition(n, l, params.row_chunk) if use_partition else None
     if part0 is not None and axis_name is not None:
         # same pcast story as leaf_id0: starts constant, becomes a function
         # of the device-local rows
@@ -736,7 +768,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                        leaf_max=jnp.full((l,), jnp.inf, hdt),
                        part=part0, cegb=cegb,
                        force_aborted=jnp.asarray(False),
-                       pool_map=pool_map0)
+                       pool_map=pool_map0, oob=oob0,
+                       hist_rows=None if bag is None else bag.leaf_count[0])
 
     def forced_split_info(s: _GrowState, t: jnp.ndarray, in_phase):
         """Evaluate the step-t forced (leaf, feature, threshold) from the
@@ -851,14 +884,26 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                     split_missing, split_num_bin, split_default_bin,
                     split_is_cat, cur.cat_bitset)
 
+            windows = window_placement(params.hist_impl,
+                                       params.vmapped_classes)
             part, leaf_id = partition_rows(
                 s.part, s.leaf_id, leaf, right_leaf, go_left_rows, valid,
                 params.row_chunk, gather_rows,
-                maintain_leaf_id=maintain_lid,
-                windows=window_placement(params.hist_impl,
-                                         params.vmapped_classes))
+                maintain_leaf_id=maintain_lid, windows=windows)
+            oob = s.oob
+            if oob is not None:
+                # the leaf's rows out of the bag follow the same split
+                # through the same ``order``: routed, and no more
+                routed, leaf_id = partition_rows(
+                    RowPartition(part.order, oob.begin, oob.count), leaf_id,
+                    leaf, right_leaf, go_left_rows, valid, params.row_chunk,
+                    gather_rows, maintain_leaf_id=maintain_lid,
+                    windows=windows, scope="lgbm.route_only")
+                part = part._replace(order=routed.order)
+                oob = OutOfBag(routed.leaf_begin, routed.leaf_count)
         else:
             part = s.part
+            oob = None
             col = jnp.take(xb, stored_col, axis=1)
             go_left = _bin_go_left(
                 to_feat_bin(col), cur.threshold, cur.default_left,
@@ -933,6 +978,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # ones: every device builds the same child, whichever is the
         # smaller among its local rows
         left_smaller = cur.left_count <= cur.right_count
+        hist_rows = s.hist_rows
         small_leaf = jnp.where(left_smaller, leaf, right_leaf)
         large_leaf = jnp.where(left_smaller, right_leaf, leaf)
 
@@ -941,6 +987,9 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # iteration walks no tile (and psums zeros on a mesh: one
             # collective a split, outside every loop)
             hist_small = psum(hist_of_range(part, small_leaf, valid))
+            if hist_rows is not None:
+                hist_rows = hist_rows + jnp.where(
+                    valid, part.leaf_count[small_leaf], 0)
         elif axis_name is None:
             def live_hist(_):
                 m = (leaf_id == small_leaf).astype(hdt) * sample_mask
@@ -1092,7 +1141,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                           best=best, tree=tree,
                           leaf_min=leaf_min, leaf_max=leaf_max, part=part,
                           cegb=cegb_state, force_aborted=force_aborted,
-                          pool_map=pool_map)
+                          pool_map=pool_map, oob=oob, hist_rows=hist_rows)
 
     if params.num_forced > 0 and forced is not None:
         nf = min(params.num_forced, l - 1)
@@ -1103,11 +1152,14 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         state = lax.fori_loop(0, l - 1, step, state)
     leaf_id_out = state.leaf_id
     if use_partition and not maintain_lid:
-        leaf_id_out = leaf_id_from_partition(state.part, n)
+        leaf_id_out = leaf_id_from_partition(state.part, n, state.oob)
     # the model contract is f32 tree arrays regardless of the histogram
     # accumulation dtype (the reference also stores float leaf values)
     tree_out = jax.tree.map(
         # lgbm-lint: disable=LGL105 downcast guard: removes f64, never adds
         lambda a: a.astype(jnp.float32) if a.dtype == jnp.float64 else a,
         state.tree)
-    return tree_out, leaf_id_out, state.cegb
+    # grown on a bag (which takes no CEGB) the third is the count of rows
+    # the histogram kernel saw, as the frontier grower's is its obs aux
+    return tree_out, leaf_id_out, \
+        (state.cegb if bag is None else state.hist_rows)

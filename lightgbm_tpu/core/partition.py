@@ -63,7 +63,7 @@ window back into a scatter.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +86,32 @@ def init_partition(num_data: int, num_leaves: int, chunk: int) -> RowPartition:
     leaf_count = jnp.zeros((num_leaves,), jnp.int32) \
         .at[0].set(jnp.int32(num_data))
     return RowPartition(order, leaf_begin, leaf_count)
+
+
+class OutOfBag(NamedTuple):
+    """A leaf's second range of ``order``: its rows out of the bag. They
+    are routed with the leaf's split and never gathered for a histogram,
+    never counted."""
+    begin: jnp.ndarray       # [L] int32
+    count: jnp.ndarray       # [L] int32
+
+
+def bag_partition(in_bag: jnp.ndarray, chunk: int) -> RowPartition:
+    """The row partition a bagged tree starts from: ``order`` holds the
+    rows of ``in_bag`` (bool [N]) at its front, then every other row, both
+    ascending. Two ranges: 0 the bag, 1 the rest. One stable two-key sort
+    (72.6 ms at 26.6M rows on a v5e; partition_rows' tile loop over the
+    identity order 77.6, a prefix sum and a full-size scatter 225.9:
+    PERF.md section 6, PR 33)."""
+    n = in_bag.shape[0]
+    with jax.named_scope("lgbm.bag_compact"):
+        _, rows = lax.sort(((~in_bag).astype(jnp.int32),
+                            jnp.arange(n, dtype=jnp.int32)),
+                           num_keys=1, is_stable=True)
+        n_bag = jnp.sum(in_bag.astype(jnp.int32), dtype=jnp.int32)
+        order = jnp.concatenate([rows, jnp.full((chunk,), n, jnp.int32)])
+        return RowPartition(order, jnp.stack([jnp.int32(0), n_bag]),
+                            jnp.stack([n_bag, n - n_bag]))
 
 
 def stack_vals(grad: jnp.ndarray, hess: jnp.ndarray,
@@ -167,7 +193,8 @@ def _write_window(order, packed, k, start):
 
 def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
                    go_left_from_rows, valid, chunk: int, gather_rows,
-                   maintain_leaf_id: bool = False, windows: bool = False):
+                   maintain_leaf_id: bool = False, windows: bool = False,
+                   scope: Optional[str] = None):
     """One pass over ``leaf``'s rows that splits its range of ``order`` in
     two (DataPartition::Split): the left child keeps the front of the range
     and ``leaf``'s id, ``right_leaf`` takes the back. No histogram is built
@@ -196,8 +223,15 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
       and ``order`` needs no front pad. The masks leave the neighbours'
       ranges and the tail pad as they were.
 
+    ``scope`` puts the whole pass under one named scope in place of the
+    four phase scopes: the pass over a leaf's rows out of the bag reads
+    apart from the split's own in a trace.
+
     Returns (new_part, new_leaf_id).
     """
+    def phase(name):
+        return jax.named_scope(scope or name)
+
     n_rows = leaf_id.shape[0]
     trash = part.order.shape[0] - 1        # never inside any leaf range
     beg = part.leaf_begin[leaf]
@@ -211,17 +245,17 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
         i, nl, nr, order_new, lid = c
         j = jnp.arange(chunk, dtype=jnp.int32)
         # ahead of the gather, where the audited jaxpr has it
-        with jax.named_scope("lgbm.route_rows"):
+        with phase("lgbm.route_rows"):
             in_range = (i * chunk + j) < cnt
-        with jax.named_scope("lgbm.row_gather"):
+        with phase("lgbm.row_gather"):
             idx = lax.dynamic_slice(part.order, (beg + i * chunk,), (chunk,))
             idx_safe = jnp.minimum(idx, n_rows - 1)
             rows, _ = gather_rows(idx_safe)                    # [chunk, F]
-        with jax.named_scope("lgbm.route_rows"):
+        with phase("lgbm.route_rows"):
             go_left = go_left_from_rows(rows)
             is_l = go_left & in_range
             is_r = (~go_left) & in_range
-        with jax.named_scope("lgbm.partition_scatter"):
+        with phase("lgbm.partition_scatter"):
             if windows:
                 kl = jnp.sum(is_l.astype(jnp.int32), dtype=jnp.int32)
                 kr = jnp.sum(is_r.astype(jnp.int32), dtype=jnp.int32)
@@ -252,7 +286,7 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
         if maintain_leaf_id:
             # max-scatter: right_leaf exceeds every id assigned so far; left
             # rows keep their id; padded/OOB duplicates contribute 0
-            with jax.named_scope("lgbm.leaf_ids"):
+            with phase("lgbm.leaf_ids"):
                 val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
                 lid = lid.at[idx_safe].max(val, mode="promise_in_bounds")
         return (i + 1, nl + kl, nr + kr, order_new, lid)
@@ -272,14 +306,16 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
 def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
                   num_cols: int, num_bins: int, chunk: int, valid=True,
                   impl: str = "matmul",
-                  val_dtype=jnp.float32) -> jnp.ndarray:
+                  val_dtype=jnp.float32,
+                  scope: Optional[str] = None) -> jnp.ndarray:
     """Build [F, B, 3] (grad, hess, count) histograms over one leaf's rows.
 
     Touches ceil(leaf_count / chunk) fixed-size tiles: row ids come from a
     contiguous slice of ``order``; ``gather_rows`` (make_row_gather) loads
     each tile's bins+values — one gather when packed. The tiles' histograms
     are summed with the rounding carried (compensated_add): siblings are
-    taken from this one by subtraction.
+    taken from this one by subtraction. ``scope`` names the whole pass (a
+    bag's root), in place of the two tile scopes.
     """
     f = num_cols
     beg = part.leaf_begin[leaf]
@@ -291,14 +327,14 @@ def hist_for_leaf(part: RowPartition, leaf, gather_rows, num_rows: int,
     def body(c):
         i, acc, lost = c
         start = beg + i * chunk
-        with jax.named_scope("lgbm.row_gather"):
+        with jax.named_scope(scope or "lgbm.row_gather"):
             idx = lax.dynamic_slice(part.order, (start,), (chunk,))
             j = jnp.arange(chunk, dtype=jnp.int32)
             in_range = (i * chunk + j) < cnt
             idx_safe = jnp.minimum(jnp.where(in_range, idx, 0),
                                    num_rows - 1)
             rows, v = gather_rows(idx_safe)                    # [chunk, F/3]
-        with jax.named_scope("lgbm.hist_tile"):
+        with jax.named_scope(scope or "lgbm.hist_tile"):
             v = v * in_range[:, None].astype(v.dtype)
             acc, lost = compensated_add(
                 acc, lost, hist_tile_vals(rows, v, num_bins, impl))
@@ -330,14 +366,21 @@ def _range_owner(order: jnp.ndarray, begin: jnp.ndarray, count: jnp.ndarray,
         pos_owner, mode="promise_in_bounds")
 
 
-def leaf_id_from_partition(part: RowPartition,
-                           num_data: int) -> jnp.ndarray:
+def leaf_id_from_partition(part: RowPartition, num_data: int,
+                           oob: Optional[OutOfBag] = None) -> jnp.ndarray:
     """Reconstruct the per-row leaf assignment from the final ranges.
 
-    The leaf ranges tile [0, num_data) exactly (DataPartition invariant), so
-    every row has an owner: O(N) dense work once per tree, whatever the
-    number of leaves, instead of O(N x depth) scattered writes during growth.
+    The leaf ranges (with ``oob``, a leaf's two) tile [0, num_data) exactly
+    (DataPartition invariant), so every row has an owner: O(N) dense work
+    once per tree, whatever the number of leaves, instead of O(N x depth)
+    scattered writes during growth.
     """
     with jax.named_scope("lgbm.leaf_ids"):
-        return _range_owner(part.order, part.leaf_begin, part.leaf_count,
-                            num_data)
+        if oob is None:
+            return _range_owner(part.order, part.leaf_begin, part.leaf_count,
+                                num_data)
+        num_leaves = part.leaf_begin.shape[0]
+        owner = _range_owner(
+            part.order, jnp.concatenate([part.leaf_begin, oob.begin]),
+            jnp.concatenate([part.leaf_count, oob.count]), num_data)
+        return jnp.where(owner >= num_leaves, owner - num_leaves, owner)

@@ -40,6 +40,7 @@ def _forced_file():
 #   batch -> grow_params.batch_splits>0,
 #   frontier -> grow_params.frontier_mode,
 #   frontier_rs -> grow_params.frontier_rs
+#   bag -> _goss_bag (GOSS grows its sampled trees on a bag partition)
 # "WARNS" in the overrides: a warning holding that text must be logged
 _F64_WARNING = "does not support f64 histograms yet; falling back to exact"
 MATRIX = [
@@ -90,8 +91,28 @@ MATRIX = [
     ("mc-vmap", {"MULTICLASS": True}, dict(vmapped=True)),
     ("mc-pool-seq", {"MULTICLASS": True, "histogram_pool_size": 1e-4},
      dict(vmapped=False, pool=True)),
+    # GOSS: the row partition starts from the bag where the exact grower
+    # runs over it on one device; everywhere else the sampler is a mask
+    ("goss-serial", {"boosting": "goss", "learning_rate": 0.5},
+     dict(bag=True, use_part=True, batch=False, frontier=False)),
+    ("goss-forced", {"boosting": "goss", "learning_rate": 0.5,
+                     "FORCED": True}, dict(bag=True, use_part=True)),
+    ("goss-pool", {"boosting": "goss", "learning_rate": 0.5,
+                   "histogram_pool_size": 1e-4}, dict(bag=True, pool=True)),
+    ("goss-cegb", {"boosting": "goss", "learning_rate": 0.5,
+                   "cegb_tradeoff": 0.5, "cegb_penalty_split": 1e-4},
+     dict(bag=False, use_part=True)),
+    ("goss-mc-vmap", {"boosting": "goss", "learning_rate": 0.5,
+                      "MULTICLASS": True}, dict(bag=False, vmapped=True)),
+    ("goss-data", {"boosting": "goss", "learning_rate": 0.5,
+                   "tree_learner": "data", "mesh_shape": [8]},
+     dict(bag=False, part_mesh=True)),
+    ("goss-stream", {"boosting": "goss", "learning_rate": 0.5,
+                     "tree_growth": "frontier",
+                     "data_stream_chunk_rows": 256},
+     dict(bag=False, frontier=True)),
     ("goss-batched", {"boosting": "goss", "tree_growth": "batched"},
-     dict(batch=True)),
+     dict(batch=True, bag=False)),
     ("dart-batched", {"boosting": "dart", "tree_growth": "batched"},
      dict(batch=True)),
     ("rf-batched", {"boosting": "rf", "tree_growth": "batched",
@@ -127,7 +148,7 @@ MATRIX = [
     ("mc-frontier", {"MULTICLASS": True, "tree_growth": "frontier"},
      dict(vmapped=True, frontier=True)),
     ("goss-frontier", {"boosting": "goss", "tree_growth": "frontier"},
-     dict(frontier=True, batch=False)),
+     dict(frontier=True, batch=False, bag=False)),
     ("rf-frontier", {"boosting": "rf", "tree_growth": "frontier",
                      "bagging_freq": 1, "bagging_fraction": 0.8},
      dict(frontier=True, batch=False)),
@@ -171,7 +192,8 @@ def test_capability_matrix(case, overrides, expect):
             vmapped=impl.grow_params.vmapped_classes,
             batch=impl.grow_params.batch_splits > 0,
             frontier=impl.grow_params.frontier_mode,
-            frontier_rs=impl.grow_params.frontier_rs)
+            frontier_rs=impl.grow_params.frontier_rs,
+            bag=impl._goss_bag)
         for key, want in expect.items():
             assert flags[key] == want, (case, key, flags)
         if warns:

@@ -100,7 +100,7 @@ def test_chip_smoke_rehearsal_passes_and_a_broken_phase_fails():
     assert last == {"ok": True, "rehearsal": True,
                     "device": {"platform": "cpu", "kind": "cpu", "count": 8}}
     for phase in ("train_exact", "kernel_parity", "train_frontier",
-                  "predict_and_serve", "mesh4"):
+                  "train_goss", "predict_and_serve", "mesh4"):
         assert "[%s] PASSED" % phase in r.stdout
     # an impossible tolerance: the phase raises, nothing carries on
     broken = subprocess.run(
