@@ -314,6 +314,12 @@ class GBDT:
                 # the bag's static size (the others' draw is exact, so its
                 # capacity is its count)
                 span.counts["goss_bag_partition"] = int(self._goss_bag)
+                if self._bins_by_col is not None:
+                    # a tree on a bag routes all rows in row space, by
+                    # the feature-major copy of the bins made for it
+                    span.counts["goss_rowspace_routing"] = 1
+                    span.counts["rowspace_bins_bytes"] = \
+                        self._bins_by_col.nbytes
                 if self._goss_counts is not None:
                     top_cnt, other_cnt, _ = self._goss_counts
                     span.counts["bag_rows"] = top_cnt + other_cnt
@@ -654,6 +660,12 @@ class GBDT:
             self.boosting_type == "goss" and self.mesh is None
             and not streamed and not frontier_mode and batch_splits == 0
             and not vmapped and self._cegb_state is None)
+        # the bins feature-major, made on the device: a tree on a bag
+        # routes every row by one contiguous column a split
+        # (partition.route_in_row_space). An argument of the sampled
+        # iterations' block, as xb is of every block
+        self._bins_by_col = (jax.jit(partition_mod.bins_by_column)(self.xb)
+                             if self._goss_bag else None)
         # (top_cnt, other_cnt, the others' multiplier) from the REAL row
         # count, not the mesh-padding-inflated one — padded rows carry
         # |g·h| = 0 and sort last, so top-k over the padded array with real
@@ -1177,7 +1189,7 @@ class GBDT:
 
         def run_iter(xb, obj_rows, fp_capture, meta, scores, sample_mask,
                      feature_mask, grad_in, hess_in, lr, goss_active,
-                     goss_key, cegb_state, stopped_in):
+                     goss_key, cegb_state, stopped_in, bins_by_col):
             with jax.named_scope("lgbm.gradients"):
                 # gradients: objective or custom (grad_in) (gbdt.cpp:333-347)
                 if not use_input:
@@ -1362,7 +1374,8 @@ class GBDT:
                 def grow_one(gk, hk, cs):
                     return grow_tree(xb, gk, hk, sample_mask, meta,
                                      feature_mask, params,
-                                     forced=forced_splits, cegb=cs, bag=bag)
+                                     forced=forced_splits, cegb=cs, bag=bag,
+                                     bins_by_col=bins_by_col)
 
             # class batching: k == 1 calls directly; multiclass maps
             # classes sequentially when (a) the pool is capped — vmap
@@ -1731,7 +1744,7 @@ class GBDT:
 
         def run_block(xb, obj_rows, fp_capture, meta, scores, feature_masks,
                       goss_actives, iter_idxs, keys, bag_mask0, cegb_state,
-                      stopped_in, lr):
+                      stopped_in, lr, bins_by_col):
             g0 = jnp.zeros((n, k), jnp.float32)
             h0 = jnp.ones((n, k), jnp.float32)
 
@@ -1754,7 +1767,7 @@ class GBDT:
                 sm = bag_mask if row_valid is None else bag_mask * row_valid
                 packed, _leaf_ids, sc2, cegb2, stopped2, health, ms, aux = \
                     core(xb, obj_rows, fp_capture, meta, sc, sm, fm, g0, h0,
-                         lr, ga, gkey, cegb, stopped)
+                         lr, ga, gkey, cegb, stopped, bins_by_col)
                 hr, code = aux if aux is not None else (None, None)
                 return (sc2, bag_mask, cegb2, stopped2, code), \
                     (packed, health, ms, hr)
@@ -1821,6 +1834,7 @@ class GBDT:
             mirror(self._cegb_state),
             mirror(self._stopped_dev),
             sds((), jnp.float32),                   # lr
+            mirror(self._rowspace_bins()),
         )
 
     def warmup_wave_ladder(self) -> Dict[str, Any]:
@@ -2048,7 +2062,8 @@ class GBDT:
                             self.scores, fmasks, gactive, idxs, all_keys[1:],
                             self._bag_mask, self._cegb_state,
                             self._stopped_dev,
-                            jnp.float32(self.shrinkage_rate))
+                            jnp.float32(self.shrinkage_rate),
+                            self._rowspace_bins())
                 if obs.enabled:
                     # basic mode's only added barrier, and the block
                     # boundary already is one for the flush cadence
@@ -2105,6 +2120,12 @@ class GBDT:
         self._compiled_iter, self._iter_core, self._compiled_block = \
             self._programs.pop(sampled, (None, None, None))
         self._sampled_regime = sampled
+
+    def _rowspace_bins(self) -> Optional[jnp.ndarray]:
+        """The last argument of a block: the feature-major bins where its
+        trees grow on a bag, else None (an empty pytree: the unsampled
+        iterations' program stays boosting=gbdt's)."""
+        return self._bins_by_col if self._sampled_regime else None
 
     def _ready_block(self, remaining: int) -> int:
         """Make current, and build if need be, the program the next block
@@ -2418,7 +2439,8 @@ class GBDT:
                         self.scores, sample_mask, feature_mask, g_in, h_in,
                         jnp.float32(self.shrinkage_rate),
                         jnp.float32(self._goss_active(iter_idx)), goss_key,
-                        self._cegb_state, self._stopped_dev)
+                        self._cegb_state, self._stopped_dev,
+                        self._rowspace_bins())
             if obs.enabled:
                 # the per-iteration path is already the slow
                 # (full/host-logic) path, so one barrier per iteration is

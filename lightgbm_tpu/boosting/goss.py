@@ -22,8 +22,8 @@ Where the exact grower runs over the row partition on one device
 (``GBDT._goss_bag``) the bag IS the partition: ``sample_bag``'s rows go to
 the front of ``order`` (partition.bag_partition), the histogram passes, the
 smaller-child choice and the tree's counts see the bag's rows only, and the
-rows out of the bag ride a second, route-only range a leaf so that they
-still get the tree's score. The unsampled and the sampled iterations are
+rows out of the bag are routed in row space (partition.route_in_row_space)
+and still get the tree's score. The unsampled and the sampled iterations are
 then two device programs, and the first is ``boosting=gbdt``'s. Everywhere
 else (vmapped multiclass, the ``batched`` / ``frontier`` growers, streaming,
 every mesh learner, CEGB) the sampler stays a multiplier on grad, hess and

@@ -38,9 +38,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from .histogram import build_histogram
-from .partition import (OutOfBag, RowPartition, hist_for_leaf,
-                        init_partition, leaf_id_from_partition,
-                        make_row_gather, partition_rows, stack_vals,
+from .partition import (RowPartition, hist_for_leaf, init_partition,
+                        leaf_id_from_partition, make_row_gather,
+                        partition_rows, route_in_row_space,
+                        row_space_leaf_ids, row_space_leaf_ids0, stack_vals,
                         window_placement)
 from .split import (BestSplit, FeatureMeta, SplitParams, K_EPSILON,
                     K_MIN_SCORE, MISSING_NAN, MISSING_NONE, MISSING_ZERO,
@@ -261,7 +262,8 @@ class PoolMap(NamedTuple):
 
 
 class _GrowState(NamedTuple):
-    leaf_id: jnp.ndarray      # [N] int32
+    leaf_id: jnp.ndarray      # [N] int32; grown on a bag, the row-space
+    #                           ids in bins_by_col's row shape
     hist_pool: jnp.ndarray    # [S, F, B, 3] f32 histogram slots (S = L
     #                           uncapped, or pool_slots under the LRU cap)
     best: BestSplit           # per-leaf best split, fields [L]
@@ -274,7 +276,6 @@ class _GrowState(NamedTuple):
     #                               remaining forced steps fall back to
     #                               best-first (aborted_last_force_split)
     pool_map: Optional[PoolMap]   # LRU slot map (None = uncapped)
-    oob: Optional[OutOfBag] = None   # the leaves' ranges out of the bag
     hist_rows: Optional[jnp.ndarray] = None   # with a bag: rows whose bins
     #                                           entered a kernel call
 
@@ -483,6 +484,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               cegb: Optional[CegbState] = None,
               fp: Optional[FeatureParallelCtx] = None,
               bag: Optional[RowPartition] = None,
+              bins_by_col: Optional[jnp.ndarray] = None,
               ) -> Tuple[TreeArrays, jnp.ndarray, Optional[CegbState]]:
     """Grow one leaf-wise tree; returns (tree, final per-row leaf_id,
     updated CEGB state or None).
@@ -502,11 +504,13 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
     With ``bag`` set (partition.bag_partition; the single-device partition
     path only): the tree is grown on the bag's rows. ``sample_mask`` is 1
-    on them and 0 elsewhere; the row partition starts from the bag, so the
+    on them and 0 elsewhere; the row partition holds the bag alone, so the
     root's pass, every smaller child's pass and the tree's counts see its
-    rows alone, and the rows out of it keep a second range a leaf
-    (partition.OutOfBag) that every split routes and nothing else reads:
-    the returned leaf_id covers ALL rows.
+    rows alone. The rows out of it have no place in ``order``: every split
+    routes ALL rows in row space, one streaming pass over the split column
+    of ``bins_by_col`` (partition.bins_by_column(xb), made once by the
+    caller), so the returned leaf_id covers all rows and
+    leaf_id_from_partition is not run.
     """
     n, ncols = xb.shape                 # stored columns (== F without EFB)
     f = meta.num_bin.shape[0]           # logical features
@@ -660,17 +664,17 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                        if use_partition else None)
     # the tree's counts come from the partition's integers where those
     # count what the histogram's count channel counts
-    assert bag is None or (use_partition and axis_name is None), \
-        "a bag is the single-device row partition's"
+    assert bag is None or (use_partition and axis_name is None
+                           and not maintain_lid
+                           and bins_by_col is not None), \
+        "a bag is the single-device row partition's, routed in row space"
     exact_counts = ((params.all_rows_in_bag or bag is not None)
                     and use_partition and axis_name is None)
-    part0 = oob0 = None
+    part0 = None
     if bag is not None:
         zeros_l = jnp.zeros((l,), jnp.int32)
         part0 = RowPartition(bag.order, zeros_l,
                              zeros_l.at[0].set(bag.leaf_count[0]))
-        oob0 = OutOfBag(zeros_l.at[0].set(bag.leaf_begin[1]),
-                        zeros_l.at[0].set(bag.leaf_count[1]))
     elif use_partition:
         part0 = init_partition(n, l, params.row_chunk)
 
@@ -753,6 +757,8 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         return lax.cond((sl < 0) & live, rebuild, read, operand=None)
 
     leaf_id0 = jnp.zeros((n,), jnp.int32)
+    if bag is not None:
+        leaf_id0 = row_space_leaf_ids0(bins_by_col, l)
     if axis_name is not None:
         # under shard_map the carry must be marked device-varying up front:
         # it starts as a constant but becomes a function of the sharded rows
@@ -768,7 +774,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                        leaf_max=jnp.full((l,), jnp.inf, hdt),
                        part=part0, cegb=cegb,
                        force_aborted=jnp.asarray(False),
-                       pool_map=pool_map0, oob=oob0,
+                       pool_map=pool_map0,
                        hist_rows=None if bag is None else bag.leaf_count[0])
 
     def forced_split_info(s: _GrowState, t: jnp.ndarray, in_phase):
@@ -870,6 +876,13 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # category bitset (as the wave growers do)
         split_is_cat = cur.is_categorical if params.with_categorical else None
 
+        def go_left_bins(colv):
+            """The split's decision from its stored column's values."""
+            return _bin_go_left(
+                to_feat_bin(colv), cur.threshold, cur.default_left,
+                split_missing, split_num_bin, split_default_bin,
+                split_is_cat, cur.cat_bitset)
+
         if use_partition:
             def go_left_rows(rows):
                 # dynamic-column extract as a one-hot matvec — bin bytes
@@ -877,38 +890,25 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 # avoids another indexed gather
                 onehot_col = (jnp.arange(ncols, dtype=jnp.int32)
                               == stored_col).astype(jnp.float32)
-                colv = jnp.einsum("rc,c->r", rows.astype(jnp.float32),
-                                  onehot_col).astype(jnp.int32)
-                return _bin_go_left(
-                    to_feat_bin(colv), cur.threshold, cur.default_left,
-                    split_missing, split_num_bin, split_default_bin,
-                    split_is_cat, cur.cat_bitset)
+                return go_left_bins(
+                    jnp.einsum("rc,c->r", rows.astype(jnp.float32),
+                               onehot_col).astype(jnp.int32))
 
-            windows = window_placement(params.hist_impl,
-                                       params.vmapped_classes)
             part, leaf_id = partition_rows(
                 s.part, s.leaf_id, leaf, right_leaf, go_left_rows, valid,
                 params.row_chunk, gather_rows,
-                maintain_leaf_id=maintain_lid, windows=windows)
-            oob = s.oob
-            if oob is not None:
-                # the leaf's rows out of the bag follow the same split
-                # through the same ``order``: routed, and no more
-                routed, leaf_id = partition_rows(
-                    RowPartition(part.order, oob.begin, oob.count), leaf_id,
-                    leaf, right_leaf, go_left_rows, valid, params.row_chunk,
-                    gather_rows, maintain_leaf_id=maintain_lid,
-                    windows=windows, scope="lgbm.route_only")
-                part = part._replace(order=routed.order)
-                oob = OutOfBag(routed.leaf_begin, routed.leaf_count)
+                maintain_leaf_id=maintain_lid,
+                windows=window_placement(params.hist_impl,
+                                         params.vmapped_classes))
+            if bag is not None:
+                # every row, in the bag or out, takes the split's side in
+                # row space; ``order`` lists the bag's rows alone
+                leaf_id = route_in_row_space(
+                    leaf_id, bins_by_col, stored_col, go_left_bins, leaf,
+                    right_leaf, valid)
         else:
             part = s.part
-            oob = None
-            col = jnp.take(xb, stored_col, axis=1)
-            go_left = _bin_go_left(
-                to_feat_bin(col), cur.threshold, cur.default_left,
-                split_missing, split_num_bin, split_default_bin,
-                split_is_cat, cur.cat_bitset)
+            go_left = go_left_bins(jnp.take(xb, stored_col, axis=1))
             in_leaf = s.leaf_id == leaf
             leaf_id = jnp.where(valid & in_leaf & ~go_left, right_leaf,
                                 s.leaf_id)
@@ -1141,7 +1141,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                           best=best, tree=tree,
                           leaf_min=leaf_min, leaf_max=leaf_max, part=part,
                           cegb=cegb_state, force_aborted=force_aborted,
-                          pool_map=pool_map, oob=oob, hist_rows=hist_rows)
+                          pool_map=pool_map, hist_rows=hist_rows)
 
     if params.num_forced > 0 and forced is not None:
         nf = min(params.num_forced, l - 1)
@@ -1151,8 +1151,10 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     else:
         state = lax.fori_loop(0, l - 1, step, state)
     leaf_id_out = state.leaf_id
-    if use_partition and not maintain_lid:
-        leaf_id_out = leaf_id_from_partition(state.part, n, state.oob)
+    if bag is not None:
+        leaf_id_out = row_space_leaf_ids(state.leaf_id, n)
+    elif use_partition and not maintain_lid:
+        leaf_id_out = leaf_id_from_partition(state.part, n)
     # the model contract is f32 tree arrays regardless of the histogram
     # accumulation dtype (the reference also stores float leaf values)
     tree_out = jax.tree.map(

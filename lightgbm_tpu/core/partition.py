@@ -25,6 +25,17 @@ the sibling is parent - smaller, from the grower's per-leaf pool
 it is reconstructed once per tree from the final ranges
 (leaf_id_from_partition).
 
+A tree grown on a bag (bag_partition: GOSS on the single-device exact
+grower) is the other way round for the rows OUT of the bag: no histogram
+ever reads them, so they have no place in ``order`` and all they need is a
+leaf. There ``order`` lists the bag alone, the two passes above run over
+its ranges, and a THIRD pass a split routes all N rows in row space
+(route_in_row_space): the split column read contiguously from a
+feature-major copy of the bins (bins_by_column), the leaf ids in and out,
+one fused elementwise op and no index. Until PR 34 those rows kept a
+second range a leaf and walked the tile loop again: 79 B gathered to read
+one routing byte, 1.95 s of a 4.15-s iteration against 0.03 s this way.
+
 What a v5e charges for these ops (PERF.md sections 5 and 6; traced
 iterations at 26.6M x 67, 255 leaves, ~52,000 tiles of 4,096 rows a tree
 of which the smaller children hold ~21,000, PR 30's standalone runs at
@@ -38,6 +49,8 @@ of which the smaller children hold ~21,000, PR 30's standalone runs at
 | the same at 6 channels (both children, until PR 32) |  83 us | 20 ns      |
 | full-size scatter / gather, 26.6M elements        | 220 / 260 ms | 8.3 / 9.8 ns |
 | position -> leaf: 510 marks + one prefix sum (PR 30) | 6.4 ms | 0.24 ns |
+| row-space routing pass, 26.6M rows, uint8 ids (PR 34) | 0.127 ms a split | 0.005 ns |
+| the same on int32 ids / column from [C, N] or xb[:, c] | 0.373 / 1.52 ms | 0.014 / 0.057 ns |
 
 The kernel is linear in its value channels (43.1 and 82.9 us a call
 standalone: a call's fixed cost is ~3 us), so what it costs is rows x
@@ -88,21 +101,14 @@ def init_partition(num_data: int, num_leaves: int, chunk: int) -> RowPartition:
     return RowPartition(order, leaf_begin, leaf_count)
 
 
-class OutOfBag(NamedTuple):
-    """A leaf's second range of ``order``: its rows out of the bag. They
-    are routed with the leaf's split and never gathered for a histogram,
-    never counted."""
-    begin: jnp.ndarray       # [L] int32
-    count: jnp.ndarray       # [L] int32
-
-
 def bag_partition(in_bag: jnp.ndarray, chunk: int) -> RowPartition:
     """The row partition a bagged tree starts from: ``order`` holds the
     rows of ``in_bag`` (bool [N]) at its front, then every other row, both
-    ascending. Two ranges: 0 the bag, 1 the rest. One stable two-key sort
-    (72.6 ms at 26.6M rows on a v5e; partition_rows' tile loop over the
-    identity order 77.6, a prefix sum and a full-size scatter 225.9:
-    PERF.md section 6, PR 33)."""
+    ascending. Two ranges: 0 the bag, 1 the rest, which the grower never
+    reads again (its rows are routed in row space: route_in_row_space).
+    One stable two-key sort (72.6 ms at 26.6M rows on a v5e;
+    partition_rows' tile loop over the identity order 77.6, a prefix sum
+    and a full-size scatter 225.9: PERF.md section 6, PR 33)."""
     n = in_bag.shape[0]
     with jax.named_scope("lgbm.bag_compact"):
         _, rows = lax.sort(((~in_bag).astype(jnp.int32),
@@ -112,6 +118,79 @@ def bag_partition(in_bag: jnp.ndarray, chunk: int) -> RowPartition:
         order = jnp.concatenate([rows, jnp.full((chunk,), n, jnp.int32)])
         return RowPartition(order, jnp.stack([jnp.int32(0), n_bag]),
                             jnp.stack([n_bag, n - n_bag]))
+
+
+ROUTE_LANES = 1024
+
+
+def bins_by_column(xb: jnp.ndarray) -> jnp.ndarray:
+    """[N, C] bins -> [C, ceil(N / ROUTE_LANES), ROUTE_LANES]: the stored
+    columns as ``xb`` holds them, feature-major, so that one column is read
+    contiguously. A v5e tiles a uint8 array (32, 128) over its last two
+    dimensions: a row of a [C, N] copy is fetched with the 31 beside it
+    (1.52 ms a split at 26.6M rows, what ``xb[:, c]`` costs from the
+    row-major table), a [rows / 1024, 1024] slab is whole tiles of its own
+    (0.127 ms; lanes of 128 read the same). The tail pad is routed like any
+    row and never read back (row_space_leaf_ids).
+
+    One column a step, not ``pad(xb.T).reshape``: that one transpose of
+    26.6M x 67 runs in 49 ms and takes the v5e's compiler 1,000 s (PERF.md
+    section 6, PR 34); this loop compiles in 13 s."""
+    n, c = xb.shape
+    m = -(-n // ROUTE_LANES)
+
+    def column(j):
+        col = lax.dynamic_index_in_dim(xb, j, 1, keepdims=False)
+        return jnp.pad(col, (0, m * ROUTE_LANES - n)).reshape(m, ROUTE_LANES)
+
+    return lax.map(column, jnp.arange(c, dtype=jnp.int32))
+
+
+def row_space_leaf_ids0(bins_by_col: jnp.ndarray,
+                        num_leaves: int) -> jnp.ndarray:
+    """Every row at the root, in ``bins_by_col``'s row shape and the
+    narrowest of uint8 / int32 that holds a leaf: the pass is bound by its
+    bytes, 0.127 ms a split on bytes against 0.373 on words."""
+    return jnp.zeros(bins_by_col.shape[1:],
+                     jnp.uint8 if num_leaves <= 256 else jnp.int32)
+
+
+def route_in_row_space(leaf_id, bins_by_col, stored_col, go_left_from_bins,
+                       leaf, right_leaf, valid):
+    """One split over ALL rows, in row space: a row of ``leaf`` that does
+    not go left takes ``right_leaf``. ``leaf_id`` has ``bins_by_col``'s row
+    shape (row_space_leaf_ids0); ``go_left_from_bins`` maps the split
+    column's stored bins to the decision, as the tile loop's routing does.
+
+    This is how a tree grown on a bag routes its rows: those out of the
+    bag are never listed for a histogram, so they need no place in
+    ``order``, only a leaf; and with every row's leaf known when the last
+    split is done, leaf_id_from_partition is not run. One fused elementwise
+    pass a split, O(N) and streaming: 0.127 ms at 26.6M rows on a v5e =
+    0.005 ns a row, against 11.5 ns a row of the split leaf through the
+    tile loop (gather, sort, windows). A tree pays (L - 1) x N x 0.005 ns
+    here (0.014 on int32 ids) and the sum of its out-of-bag internal
+    counts x 11.5 ns there: 1.2 against ~90 ns a row at 255 leaves, 57
+    against 150-180 at 4,095, so row space wins wherever a bag exists and
+    there is one path (PERF.md section 6, PR 34). A dead split costs
+    nothing."""
+    def route(lid):
+        # the branch is traced apart: it names its own scope
+        with jax.named_scope("lgbm.route_only"):
+            col = lax.dynamic_index_in_dim(bins_by_col, stored_col, 0,
+                                           keepdims=False)
+            return jnp.where((lid == leaf.astype(lid.dtype))
+                             & ~go_left_from_bins(col),
+                             right_leaf.astype(lid.dtype), lid)
+
+    with jax.named_scope("lgbm.route_only"):
+        return lax.cond(valid, route, lambda lid: lid, leaf_id)
+
+
+def row_space_leaf_ids(leaf_id: jnp.ndarray, num_data: int) -> jnp.ndarray:
+    """The int32 [N] leaf assignment from route_in_row_space's state."""
+    with jax.named_scope("lgbm.leaf_ids"):
+        return leaf_id.reshape(-1)[:num_data].astype(jnp.int32)
 
 
 def stack_vals(grad: jnp.ndarray, hess: jnp.ndarray,
@@ -193,8 +272,7 @@ def _write_window(order, packed, k, start):
 
 def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
                    go_left_from_rows, valid, chunk: int, gather_rows,
-                   maintain_leaf_id: bool = False, windows: bool = False,
-                   scope: Optional[str] = None):
+                   maintain_leaf_id: bool = False, windows: bool = False):
     """One pass over ``leaf``'s rows that splits its range of ``order`` in
     two (DataPartition::Split): the left child keeps the front of the range
     and ``leaf``'s id, ``right_leaf`` takes the back. No histogram is built
@@ -223,16 +301,10 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
       and ``order`` needs no front pad. The masks leave the neighbours'
       ranges and the tail pad as they were.
 
-    ``scope`` puts the whole pass under one named scope in place of the
-    four phase scopes: the pass over a leaf's rows out of the bag reads
-    apart from the split's own in a trace.
-
-    Returns (new_part, new_leaf_id).
+    Returns (new_part, new_leaf_id); ``leaf_id`` is touched only with
+    ``maintain_leaf_id``.
     """
-    def phase(name):
-        return jax.named_scope(scope or name)
-
-    n_rows = leaf_id.shape[0]
+    n_rows = part.order.shape[0] - chunk
     trash = part.order.shape[0] - 1        # never inside any leaf range
     beg = part.leaf_begin[leaf]
     cnt = jnp.where(valid, part.leaf_count[leaf], 0)
@@ -245,17 +317,17 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
         i, nl, nr, order_new, lid = c
         j = jnp.arange(chunk, dtype=jnp.int32)
         # ahead of the gather, where the audited jaxpr has it
-        with phase("lgbm.route_rows"):
+        with jax.named_scope("lgbm.route_rows"):
             in_range = (i * chunk + j) < cnt
-        with phase("lgbm.row_gather"):
+        with jax.named_scope("lgbm.row_gather"):
             idx = lax.dynamic_slice(part.order, (beg + i * chunk,), (chunk,))
             idx_safe = jnp.minimum(idx, n_rows - 1)
             rows, _ = gather_rows(idx_safe)                    # [chunk, F]
-        with phase("lgbm.route_rows"):
+        with jax.named_scope("lgbm.route_rows"):
             go_left = go_left_from_rows(rows)
             is_l = go_left & in_range
             is_r = (~go_left) & in_range
-        with phase("lgbm.partition_scatter"):
+        with jax.named_scope("lgbm.partition_scatter"):
             if windows:
                 kl = jnp.sum(is_l.astype(jnp.int32), dtype=jnp.int32)
                 kr = jnp.sum(is_r.astype(jnp.int32), dtype=jnp.int32)
@@ -286,7 +358,7 @@ def partition_rows(part: RowPartition, leaf_id, leaf, right_leaf,
         if maintain_leaf_id:
             # max-scatter: right_leaf exceeds every id assigned so far; left
             # rows keep their id; padded/OOB duplicates contribute 0
-            with phase("lgbm.leaf_ids"):
+            with jax.named_scope("lgbm.leaf_ids"):
                 val = jnp.where(is_r, right_leaf, 0).astype(lid.dtype)
                 lid = lid.at[idx_safe].max(val, mode="promise_in_bounds")
         return (i + 1, nl + kl, nr + kr, order_new, lid)
@@ -366,21 +438,14 @@ def _range_owner(order: jnp.ndarray, begin: jnp.ndarray, count: jnp.ndarray,
         pos_owner, mode="promise_in_bounds")
 
 
-def leaf_id_from_partition(part: RowPartition, num_data: int,
-                           oob: Optional[OutOfBag] = None) -> jnp.ndarray:
+def leaf_id_from_partition(part: RowPartition, num_data: int) -> jnp.ndarray:
     """Reconstruct the per-row leaf assignment from the final ranges.
 
-    The leaf ranges (with ``oob``, a leaf's two) tile [0, num_data) exactly
-    (DataPartition invariant), so every row has an owner: O(N) dense work
-    once per tree, whatever the number of leaves, instead of O(N x depth)
-    scattered writes during growth.
+    The leaf ranges tile [0, num_data) exactly (DataPartition invariant),
+    so every row has an owner: O(N) dense work once per tree, whatever the
+    number of leaves, instead of O(N x depth) scattered writes during
+    growth.
     """
     with jax.named_scope("lgbm.leaf_ids"):
-        if oob is None:
-            return _range_owner(part.order, part.leaf_begin, part.leaf_count,
-                                num_data)
-        num_leaves = part.leaf_begin.shape[0]
-        owner = _range_owner(
-            part.order, jnp.concatenate([part.leaf_begin, oob.begin]),
-            jnp.concatenate([part.leaf_count, oob.count]), num_data)
-        return jnp.where(owner >= num_leaves, owner - num_leaves, owner)
+        return _range_owner(part.order, part.leaf_begin, part.leaf_count,
+                            num_data)

@@ -523,3 +523,73 @@ def test_mesh_builds_the_globally_smaller_child_on_every_device():
     np.testing.assert_allclose(np.asarray(tree_dp.leaf_value),
                                np.asarray(tree_ref.leaf_value),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("ids_dtype", ["int32", "uint8"])
+def test_row_space_ids_agree_with_the_bags_ranges_of_order(ids_dtype):
+    """A bag's rows are split twice over: by partition_rows in their ranges
+    of ``order`` (for the histogram passes) and, with every other row, by
+    route_in_row_space. After a few splits, one of them dead, an in-bag
+    row's row-space id is the leaf whose range holds it, a row out of the
+    bag has the leaf its column sends it to, and the pad is never read."""
+    from lightgbm_tpu.core.partition import (
+        ROUTE_LANES, RowPartition, _range_owner, bag_partition,
+        bins_by_column, make_row_gather, partition_rows, route_in_row_space,
+        row_space_leaf_ids, stack_vals)
+    rng = np.random.default_rng(34)
+    n, f, chunk, nl = 2 * ROUTE_LANES + 77, 3, 128, 8
+    xb = jnp.asarray(rng.integers(0, 16, (n, f)).astype(np.uint8))
+    in_bag = jnp.asarray(rng.random(n) < 0.3)
+    ones = jnp.ones((n,), jnp.float32)
+    gr = make_row_gather(xb, stack_vals(ones, ones, ones))
+    # (leaf, column, threshold, valid): leaf 1 exists once split 0 is made
+    splits = [(0, 0, 7, True), (1, 1, 4, True), (0, 2, 9, False),
+              (0, 2, 11, True)]
+
+    @jax.jit
+    def run(xb, in_bag):
+        bag = bag_partition(in_bag, chunk)
+        zeros = jnp.zeros((nl,), jnp.int32)
+        part = RowPartition(bag.order, zeros,
+                            zeros.at[0].set(bag.leaf_count[0]))
+        cols = bins_by_column(xb)
+        lid = jnp.zeros(cols.shape[1:], ids_dtype)
+        for t, (leaf, col, thr, valid) in enumerate(splits):
+            leaf, right, valid = jnp.int32(leaf), jnp.int32(t + 1), \
+                jnp.asarray(valid)
+            part, _ = partition_rows(
+                part, None, leaf, right, lambda r: r[:, col] <= thr, valid,
+                chunk, gr)
+            lid = route_in_row_space(lid, cols, jnp.int32(col),
+                                     lambda c: c <= thr, leaf, right, valid)
+        return part, lid, row_space_leaf_ids(lid, n)
+
+    part, lid, ids = jax.tree.map(np.asarray, run(xb, in_bag))
+    assert lid.shape == (3, ROUTE_LANES) and lid.dtype == ids_dtype
+    assert ids.shape == (n,) and ids.dtype == np.int32
+    x, bag = np.asarray(xb), np.asarray(in_bag)
+    want = np.zeros(n, np.int32)
+    for t, (leaf, col, thr, valid) in enumerate(splits):
+        if valid:
+            want[(want == leaf) & (x[:, col] > thr)] = t + 1
+    np.testing.assert_array_equal(ids, want)
+    assert sorted(np.unique(ids)) == [0, 1, 2, 4]
+    owner = np.asarray(_range_owner(
+        jnp.asarray(part.order), jnp.asarray(part.leaf_begin),
+        jnp.asarray(part.leaf_count), n))
+    np.testing.assert_array_equal(owner[bag], ids[bag])
+    assert (owner[~bag] == -1).all()       # no range holds a row out of it
+    np.testing.assert_array_equal(part.leaf_count,
+                                  np.bincount(ids[bag], minlength=nl))
+
+
+def test_bins_by_column_holds_the_stored_columns_in_whole_lanes():
+    from lightgbm_tpu.core.partition import ROUTE_LANES, bins_by_column
+    rng = np.random.default_rng(35)
+    for n in (5, ROUTE_LANES, 3 * ROUTE_LANES + 1):
+        xb = rng.integers(0, 255, (n, 4)).astype(np.uint8)
+        cols = np.asarray(jax.jit(bins_by_column)(jnp.asarray(xb)))
+        assert cols.dtype == np.uint8
+        assert cols.shape == (4, -(-n // ROUTE_LANES), ROUTE_LANES)
+        np.testing.assert_array_equal(cols.reshape(4, -1)[:, :n], xb.T)
+        assert not cols.reshape(4, -1)[:, n:].any()

@@ -39,8 +39,8 @@ partition on one device (`tree_learner=serial`, `tree_growth=exact`, no
 CEGB; multiclass where the classes grow in sequence, which is the TPU's
 way) the bag IS the partition: the rows out of it cost no histogram pass,
 a sampled tree's `leaf_count` / `internal_count` are integer counts of
-in-bag rows, and every row still takes every tree's score (a second,
-route-only range a leaf). The unsampled and the sampled iterations are two
+in-bag rows, and every row still takes every tree's score (all rows are
+routed in row space). The unsampled and the sampled iterations are two
 device programs there, the first being `boosting=gbdt`'s;
 `GBDT.compile_block(n)` readies the next one without running it, and
 `GBDT.last_bag` holds the newest bag on the device. Everywhere else
