@@ -14,6 +14,12 @@ columns, num_leaves=255, max_bin=255) on seeded synthetic rows:
                      integers, and every row still takes their score
   predict_and_serve  bst.predict, save_model, then the task=serve path over
                      HTTP in this process, zero compiles after warm-up
+  train_categorical  the same call on 39 columns, 8 of them categorical (3
+                     to 100,000 categories, with NaN and negative ids): the
+                     share of categorical splits, the kernel against the
+                     scatter histogram at 39 columns, held scores equal to
+                     the trees' leaves by RAW category, and the model served
+                     over HTTP on ids it kept, never saw, NaN and negatives
   mesh4              tree_learner=data over four devices (only when the
                      machine shows four or more; then it must pass)
 
@@ -79,6 +85,16 @@ GOSS_SCORE_TOL = 1e-5
 MESH_PRED_TOL = 1e-3
 MESH_MOVED_FRAC = 0.01
 MESH_AUC_GAP = 1e-3
+# the categorical phase: 31 numerical columns and one categorical column
+# of each of these cardinalities (the rehearsal caps them at its rows / 8)
+CAT_FEATURES = 39
+CAT_CARDINALITIES = (3, 10, 27, 300, 2000, 5000, 20000, 100000)
+CAT_SCORE_TOL = 1e-5      # a float32 running sum of five leaf values
+# the kernel against the scatter histogram on the TRAINED table's bins: a
+# categorical column's largest bin holds half the rows, so one sum is tens of
+# thousands of values and an absolute bar means nothing (0.059 of 26,000 on
+# the chip, PR 35); the two-term bfloat16 split lands within ~3e-6 of float32
+CAT_PARITY_REL_TOL = 2e-5
 SERVE_REQUEST_ROWS = (1, 3, 16, 17, 100, 255, 256, 1000, 2048, 4096)
 SERVE_REQUESTS = 40
 
@@ -89,6 +105,28 @@ def synth(rows, seed=0):
     y = ((X[:, 0] + X[:, 1] * X[:, 2] + 0.5 * np.sin(X[:, 3] * 3)
           + 0.3 * r.randn(rows)) > 0).astype(np.float32)
     return X, y
+
+
+def synth_categorical(rows, seed=1):
+    """39 columns: synth's rule over the first four, then one categorical
+    column a cardinality (Zipf ranks as ids, 2% NaN, 1% negative), five of
+    which move the label by an effect a category."""
+    r = np.random.RandomState(seed)
+    X = r.randn(rows, CAT_FEATURES).astype(np.float32)
+    t = (X[:, 0] + X[:, 1] * X[:, 2] + 0.5 * np.sin(X[:, 3] * 3)
+         + 0.3 * r.randn(rows))
+    first = CAT_FEATURES - len(CAT_CARDINALITIES)
+    for i, k in enumerate(CAT_CARDINALITIES):
+        k = max(3, min(k, rows // 8))
+        ids = np.minimum((k + 1.0) ** r.rand(rows) - 1, k - 1).astype(np.int64)
+        if i % 2 == 0:
+            t += 0.8 * r.randn(k)[ids]
+        col = ids.astype(np.float32)
+        u = r.rand(rows)
+        col[u < 0.02] = np.nan
+        col[u > 0.99] = -1.0
+        X[:, first + i] = col
+    return X, (t > 0).astype(np.float32), list(range(first, CAT_FEATURES))
 
 
 def auc(y, score):
@@ -282,13 +320,19 @@ class Smoke:
                 "train_auc": round(a, 4)}
 
     def predict_and_serve(self):
-        bst = self.bst_exact
         r = np.random.RandomState(3)
         sizes = [SERVE_REQUEST_ROWS[i % len(SERVE_REQUEST_ROWS)]
                  for i in range(SERVE_REQUESTS)]
+        return self.serve(self.bst_exact,
+                          [r.randn(n, FEATURES).astype(np.float32)
+                           for n in sizes])
+
+    def serve(self, bst, queries):
+        """The model saved, loaded by the task=serve path and asked over
+        HTTP: every reply against ``bst.predict``, no compile after
+        warm-up."""
         # references BEFORE warm-up, so that the reference path's own
         # compilations do not count against the served path
-        queries = [r.randn(n, FEATURES).astype(np.float32) for n in sizes]
         refs = [bst.predict(q) for q in queries]
         with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
             path = os.path.join(tmp, "model.txt")
@@ -307,7 +351,10 @@ class Smoke:
             worst = 0.0
             try:
                 for q, ref in zip(queries, refs):
-                    body = json.dumps({"data": q.tolist()}).encode()
+                    # a NaN travels as null
+                    rows = [[None if v != v else v for v in row]
+                            for row in q.tolist()]
+                    body = json.dumps({"data": rows}).encode()
                     rep = json.loads(urllib.request.urlopen(
                         urllib.request.Request(
                             "http://127.0.0.1:%d/predict" % port, data=body),
@@ -327,9 +374,62 @@ class Smoke:
         recompiles = app.engine.metrics.recompiles_after_warmup()
         check(recompiles == 0,
               "%d backend compiles after serve warm-up" % recompiles)
-        return {"requests": len(sizes), "buckets_warmed": warmed,
+        return {"requests": len(queries), "buckets_warmed": warmed,
                 "served_maxdiff": worst, "recompiles_after_warmup": 0,
                 "cache_dir_after_warmup": cache_dir}
+
+    def train_categorical(self):
+        X, y, cats = synth_categorical(self.rows)
+        params = dict(self.params,
+                      categorical_feature=",".join(str(c) for c in cats))
+        ds = lgb.Dataset(X, y, params=dict(params)).construct()
+        bst = lgb.train(params, ds, num_boost_round=ROUNDS)
+        gbdt = bst._impl
+        jax.block_until_ready(gbdt.scores)
+        gp = gbdt.grow_params
+        check(gp.hist_impl == self.want_impl and gp.with_categorical
+              == len(cats), "hist_impl %r, %d categorical features"
+              % (gp.hist_impl, gp.with_categorical))
+        nodes = sum(t.num_leaves_actual - 1 for t in gbdt.models)
+        cat_nodes = sum(int(t.is_categorical[:t.num_leaves_actual - 1].sum())
+                        for t in gbdt.models)
+        check(cat_nodes > 0, "no split on a categorical column")
+        # bins routed the rows while training; raw ids route them here
+        held = np.asarray(gbdt.scores)[:self.eval_rows, 0]
+        raw = bst.predict(X[:self.eval_rows], raw_score=True)
+        worst = float(np.abs(raw - held).max())
+        check(worst <= CAT_SCORE_TOL, "held scores vs the trees' leaves by "
+              "raw category maxdiff %.3g > %.0g" % (worst, CAT_SCORE_TOL))
+        a = auc(y[:self.eval_rows], bst.predict(X[:self.eval_rows]))
+        check(a > MIN_TRAIN_AUC, "categorical train AUC %.4f <= %.2f"
+              % (a, MIN_TRAIN_AUC))
+        # the kernel at 39 columns, a shape no other phase gives it
+        n = min(self.rows, 4096 if self.rehearsal else PARITY_ROWS)
+        r = np.random.RandomState(11)
+        b = self.params["max_bin"]
+        xb = gbdt.xb[:n]
+        g = jnp.asarray(r.randn(n).astype(np.float32))
+        h = jnp.asarray(np.abs(r.randn(n)).astype(np.float32))
+        m = jnp.asarray((r.rand(n) > 0.3).astype(np.float32))
+        ref = np.asarray(build_histogram(xb, g, h, m, num_bins=b,
+                                         impl="scatter"))
+        k3 = float(np.abs(np.asarray(build_histogram(
+            xb, g, h, m, num_bins=b, impl=self.want_impl)) - ref).max()
+            / np.abs(ref).max())
+        check(k3 <= CAT_PARITY_REL_TOL, "k3 at %d columns maxdiff %.3g of "
+              "the largest sum > %.0g" % (xb.shape[1], k3, CAT_PARITY_REL_TOL))
+        # served on ids the model kept, ids it never saw, NaN and negatives
+        queries = []
+        for size in (1, 17, 256, 1000):
+            q = X[r.randint(0, self.rows, size)].copy()
+            q[:, cats[-1]] = r.randint(0, 10 ** 7, size)    # mostly unseen
+            queries.append(q)
+        served = self.serve(bst, queries)
+        return {"hist_impl": gp.hist_impl, "columns": int(xb.shape[1]),
+                "cat_splits": cat_nodes, "splits": nodes,
+                "train_auc": round(a, 4), "held_vs_leaves_maxdiff": worst,
+                "k3_maxdiff_rel": k3,
+                "served_maxdiff": served["served_maxdiff"]}
 
     def mesh4(self):
         out = {}
@@ -421,6 +521,7 @@ def main():
     smoke.phase("train_frontier", smoke.train_frontier)
     smoke.phase("train_goss", smoke.train_goss)
     smoke.phase("predict_and_serve", smoke.predict_and_serve)
+    smoke.phase("train_categorical", smoke.train_categorical)
     if len(devices) >= 4:
         smoke.phase("mesh4", smoke.mesh4)
     else:

@@ -334,6 +334,14 @@ class GBDT:
                     np.count_nonzero(np.asarray(m.missing_type)[:f]))
                 span.counts["features_short"] = int(np.count_nonzero(
                     np.asarray(m.num_bin)[:f] < self.config.max_bin))
+                # categorical columns, and whether the grower tests a
+                # split's category set without a gather over the rows it
+                # routes (the exact grower's one-split form; the wave
+                # growers' per-row sets keep their take_along_axis)
+                span.counts["features_categorical"] = p.with_categorical
+                span.counts["cat_route_gather_free"] = int(
+                    p.with_categorical > 0 and not p.frontier_mode
+                    and p.batch_splits == 0)
 
     # ------------------------------------------------------------ setup
     def _setup_stream_mesh(self, ds) -> np.ndarray:
@@ -739,8 +747,8 @@ class GBDT:
             hist_dtype=_hist_dtype(cfg),
             voting_top_k=(cfg.top_k if cfg.tree_learner == "voting"
                           and self.mesh is not None else 0),
-            with_categorical=bool(np.asarray(self.feature_meta.is_categorical)
-                                  .any()),
+            with_categorical=int(np.count_nonzero(
+                np.asarray(self.feature_meta.is_categorical))),
             all_rows_in_bag=(
                 not (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0)
                 and self.boosting_type != "rf"
@@ -2503,6 +2511,7 @@ class GBDT:
                 p["span"].counts["hist_rows"] = int(
                     np.asarray(p["hist_rows"], np.int64).sum())
         row = 0
+        with_cat = self.grow_params.with_categorical > 0
         for p in pend:
             if self._stopped:
                 break
@@ -2515,6 +2524,14 @@ class GBDT:
                     if ht.num_leaves_actual > 1:
                         any_split = True
                     host_trees.append(ht)
+                    if with_cat and p.get("span") is not None:
+                        # the block's splits on a categorical column
+                        # join its span here, as hist_rows does above
+                        counts = p["span"].counts
+                        counts["cat_splits"] = counts.get(
+                            "cat_splits", 0) + int(np.count_nonzero(
+                                ht.is_categorical[
+                                    :max(ht.num_leaves_actual - 1, 0)]))
                 row += 1
                 if not any_split:
                     Log.warning("Stopped training because there are no "
@@ -2590,25 +2607,33 @@ class GBDT:
         ht.split_gain[:nn] = t.split_gain[:nn]
         ht.threshold_bin[:nn] = t.threshold_bin[:nn]
         # raw-value bitsets are variable-width (Tree cat_threshold_,
-        # tree.h:276-291): wide enough for the largest category value of any
-        # categorical feature in this dataset
-        max_cat_val = max(
-            (max(m.bin_2_categorical) for m in ds.bin_mappers
-             if m.bin_type == BinType.CATEGORICAL and m.bin_2_categorical),
-            default=0)
+        # tree.h:276-291): wide enough for the largest category value that
+        # goes LEFT at any node of this tree (not the dataset's largest id:
+        # at ids up to 10M that is 300 MB a tree, and a category that goes
+        # right needs no bit)
+        left_vals = {}
+        for i in range(nn):
+            if bool(t.is_categorical[i]):
+                mapper = ds.bin_mappers[int(ht.split_feature[i])]
+                words = np.asarray(t.cat_bitset[i], np.uint32)
+                in_set = ((words[:, None] >> np.arange(32, dtype=np.uint32))
+                          & 1).astype(bool).reshape(-1)[1:mapper.num_bin]
+                left_vals[i] = np.asarray(mapper.bin_2_categorical,
+                                          np.int64)[:len(in_set)][in_set]
+        max_cat_val = max((int(v.max()) for v in left_vals.values()
+                           if len(v)), default=0)
         cat_words = max(8, (max_cat_val + 32) // 32)
         ht.cat_bitset = np.zeros((max(nn, 1), cat_words), np.uint32)
         for i in range(nn):
             mapper = ds.bin_mappers[int(ht.split_feature[i])]
             if bool(t.is_categorical[i]):
                 ht.threshold[i] = 0.0
-                # translate the bin-space bitset into raw category values for
-                # raw-input prediction and model serialization (the reference
-                # stores cat_threshold in value space, tree.cpp)
-                for b in range(1, mapper.num_bin):
-                    if (int(t.cat_bitset[i][b >> 5]) >> (b & 31)) & 1:
-                        v = mapper.bin_2_categorical[b - 1]
-                        ht.cat_bitset[i][v >> 5] |= np.uint32(1 << (v & 31))
+                # the bin-space bitset as raw category values, for
+                # raw-input prediction and model serialization (the
+                # reference stores cat_threshold in value space, tree.cpp)
+                v = left_vals[i]
+                np.bitwise_or.at(ht.cat_bitset[i], v >> 5,
+                                 np.uint32(1) << (v & 31).astype(np.uint32))
             else:
                 tb = int(t.threshold_bin[i])
                 # the last numeric bin of a column with a NaN bin is

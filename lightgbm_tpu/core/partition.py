@@ -202,13 +202,23 @@ def stack_vals(grad: jnp.ndarray, hess: jnp.ndarray,
     return jnp.stack([grad * m, hess * m, m], axis=1)
 
 
+# The packed row's least width in bytes. The v5e's compiler emits a gather
+# of rows narrower than about 57 bytes through another path (its result
+# column-major, five times the scoped memory), which costs four times as
+# much a row: 148 us against 37 us a 4,096-row tile at 39 columns + 12 value
+# bytes (PERF.md section 6, PR 35). A table of 52 columns or more is wider
+# than this already, and its packed rows are left as they are.
+MIN_PACKED_WIDTH = 64
+
+
 def make_row_gather(xb: jnp.ndarray, vals: jnp.ndarray,
                     packed: bool = True):
     """Build the per-tile ``gather_rows(idx_safe) -> (rows, v)`` closure
     the partition loops use, owning the bins/values layout in ONE place.
 
     packed=True bit-packs [N, C] uint8 bins and [N, 3] float values side
-    by side into one [N, C + 3*itemsize] uint8 array, so a histogram
+    by side into one [N, C + 3*itemsize] uint8 array (filled with zero
+    bytes up to MIN_PACKED_WIDTH where it is narrower), so a histogram
     trip does ONE row gather instead of two; the per-tile unpack is a
     free bitcast. packed=False keeps
     two gathers — required under vmapped class-batched growth, where
@@ -223,14 +233,17 @@ def make_row_gather(xb: jnp.ndarray, vals: jnp.ndarray,
     n, c = xb.shape
     nbytes = jnp.dtype(vals.dtype).itemsize
     vb = lax.bitcast_convert_type(vals, jnp.uint8).reshape(n, -1)
-    xv = jnp.concatenate([xb, vb], axis=1)
+    spare = max(MIN_PACKED_WIDTH - c - vb.shape[1], 0)
+    xv = jnp.concatenate(
+        [xb, vb] + ([jnp.zeros((n, spare), jnp.uint8)] if spare else []),
+        axis=1)
     val_dtype = vals.dtype
 
     def gather_rows(idx_safe):
         p = xv.at[idx_safe].get(mode="promise_in_bounds")
         rows = p[:, :c]
         v = lax.bitcast_convert_type(
-            p[:, c:].reshape(p.shape[0], 3, nbytes), val_dtype)
+            p[:, c:c + 3 * nbytes].reshape(p.shape[0], 3, nbytes), val_dtype)
         return rows, v
     return gather_rows
 

@@ -388,6 +388,7 @@ def per_feature_split_categorical(
                                  sp.lambda_l2, sp.max_delta_step)
     min_gain_shift = gain_shift + sp.min_gain_to_split
     l2_cat = sp.lambda_l2 + sp.cat_l2
+    steps = min(b, max(int(sp.max_cat_threshold), 1))
 
     def one_feature(hist_f, num_bin):
         is_real = (bins >= 1) & (bins < num_bin)
@@ -419,12 +420,16 @@ def per_feature_split_categorical(
         max_num_cat = jnp.minimum(sp.max_cat_threshold, (n_elig + 1) // 2)
 
         def one_direction(key):
-            order = jnp.argsort(key)
-            gs, hs, cs = g[order], h[order], c[order]
+            # one stable sort carries the sums along (jnp.argsort's order
+            # and tie-breaks); only the first ``steps`` sorted categories
+            # can enter a subset (in_range), so nothing past them is read
+            _, order, gs, hs, cs = jax.lax.sort(
+                (key, bins, g, h, c), num_keys=1, is_stable=True)
+            order, gs, hs, cs = (a[:steps] for a in (order, gs, hs, cs))
             pg = jnp.cumsum(gs)
             ph = jnp.cumsum(hs) + K_EPSILON
             pc = jnp.cumsum(cs)
-            i = jnp.arange(b, dtype=jnp.int32)
+            i = jnp.arange(steps, dtype=jnp.int32)
             in_range = (i < max_num_cat) & (i < n_elig)
             left_ok = (pc >= sp.min_data_in_leaf) \
                 & (ph >= sp.min_sum_hessian_in_leaf)
@@ -443,23 +448,28 @@ def per_feature_split_categorical(
                 do_eval = can_i & (cnt_group >= sp.min_data_per_group)
                 return jnp.where(do_eval, 0.0, cnt_group), do_eval
 
-            _, do_eval = jax.lax.scan(gstep, jnp.asarray(0.0), (cs, can))
+            # the group counter resets where it fires, so it is a chain:
+            # unrolled, its ``steps`` links are one fused elementwise op
+            _, do_eval = jax.lax.scan(gstep, jnp.asarray(0.0), (cs, can),
+                                      unroll=True)
             gain2, lo2, ro2 = _split_gains_l2(
                 pg, ph, sum_grad - pg, sum_hess - ph, sp, l2_cat,
                 min_constraint, max_constraint)
             gain2 = jnp.where(do_eval & (gain2 > min_gain_shift), gain2,
                               K_MIN_SCORE)
             ib = jnp.argmax(gain2)
-            inv_rank = jnp.argsort(order)
-            member = (inv_rank <= ib) & elig
+            # a bin goes left where it sorted at or before the best prefix
+            member = jnp.any((order[None, :] == bins[:, None])
+                             & (i[None, :] <= ib), axis=1) & elig
             return dict(gain=gain2[ib], lg=pg[ib], lh=ph[ib] - K_EPSILON,
                         lc=pc[ib], lo=lo2[ib], ro=ro2[ib], member=member)
 
-        asc = one_direction(jnp.where(elig, ctr, jnp.inf))
-        desc = one_direction(jnp.where(elig, -ctr, jnp.inf))
+        # both directions in one batched pass
+        both = jax.vmap(one_direction)(jnp.stack(
+            [jnp.where(elig, ctr, jnp.inf), jnp.where(elig, -ctr, jnp.inf)]))
         sorted_best = jax.tree.map(
-            lambda a_, d_: jnp.where(asc["gain"] >= desc["gain"], a_, d_),
-            asc, desc)
+            lambda a: jnp.where(both["gain"][0] >= both["gain"][1],
+                                a[0], a[1]), both)
 
         use_onehot = num_bin <= sp.max_cat_to_onehot
         return jax.tree.map(
@@ -543,18 +553,38 @@ def per_feature_split_merged(
         with_categorical: bool = False,
 ) -> Tuple[PerFeatureSplit, jnp.ndarray]:
     """Per-feature best splits, each feature using its own finder
-    (FindBestThreshold dispatch, feature_histogram.hpp:68-108)."""
+    (FindBestThreshold dispatch, feature_histogram.hpp:68-108).
+
+    ``with_categorical``: False or 0 = no categorical column; an int n =
+    the data set's n categorical columns (static a data set; WHICH they
+    are is read from ``meta``, an argument of the compiled block) are
+    taken out and searched alone; True = the caller does not know the
+    number (a device's share of the columns): every column goes through
+    the categorical finder and the numerical ones are masked afterwards.
+    """
     f = hist.shape[0]
     pf = per_feature_split_numerical(
         hist, meta, params, sum_grad, sum_hess, num_data, feature_mask,
         None, min_constraint, max_constraint)
     if not with_categorical:
         return pf, jnp.zeros((f, 8), jnp.uint32)
-    pfc, bitsets = per_feature_split_categorical(
-        hist, meta, params, sum_grad, sum_hess, num_data, feature_mask,
-        min_constraint, max_constraint)
     is_cat = meta.is_categorical
-    merged = PerFeatureSplit(*[
-        jnp.where(is_cat, cv, nv) for nv, cv in zip(pf, pfc)])
-    bitsets = jnp.where(is_cat[:, None], bitsets, 0).astype(jnp.uint32)
+    with jax.named_scope("lgbm.split_search_cat"):
+        if with_categorical is True or with_categorical >= f:
+            cols = jnp.arange(f, dtype=jnp.int32)
+        else:
+            cols = jnp.nonzero(is_cat, size=int(with_categorical),
+                               fill_value=0)[0]
+        meta_c = FeatureMeta(*[None if a is None else a[cols]
+                               for a in meta])
+        pfc, bits_c = per_feature_split_categorical(
+            hist[cols], meta_c, params, sum_grad, sum_hess, num_data,
+            feature_mask[cols], min_constraint, max_constraint)
+        # a column taken that is not categorical keeps its numerical split
+        took = is_cat[cols]
+        merged = PerFeatureSplit(*[
+            nv.at[cols].set(jnp.where(took, cv, nv[cols]))
+            for nv, cv in zip(pf, pfc)])
+        bitsets = jnp.zeros((f, 8), jnp.uint32).at[cols].set(
+            jnp.where(took[:, None], bits_c, 0).astype(jnp.uint32))
     return merged, bitsets

@@ -163,6 +163,8 @@ class BinMapper:
         self.min_val: float = 0.0
         self.max_val: float = 0.0
         self.default_bin: int = 0
+        # distinct categories among the sampled values (categorical only)
+        self.categories_seen: int = 0
 
     # ------------------------------------------------------------------ fit
     def find_bin(self, values: np.ndarray, total_sample_cnt: int, max_bin: int,
@@ -282,19 +284,20 @@ class BinMapper:
                               total_sample_cnt: int, na_cnt: int,
                               min_data_in_bin: int) -> None:
         """Categorical path of FindBin (bin.cpp:300-360)."""
-        dvi: List[int] = []
-        cti: List[int] = []
-        for v, c in zip(dv, ct):
-            iv = int(v)
-            if iv < 0:
-                na_cnt += int(c)
-                Log.warning("Met negative value in categorical features, "
-                            "will convert it to NaN")
-            elif dvi and iv == dvi[-1]:
-                cti[-1] += int(c)
-            else:
-                dvi.append(iv)
-                cti.append(int(c))
+        # distinct values as upstream's static_cast<int> reads them; ``dv``
+        # ascends, so equal ints are neighbours and their counts add up
+        iv = np.asarray(dv, np.float64).astype(np.int64)
+        ct = np.asarray(ct, np.int64)
+        if len(iv) and iv[0] < 0:
+            na_cnt += int(ct[iv < 0].sum())
+            Log.warning("Met negative value in categorical features, "
+                        "will convert it to NaN")
+            iv, ct = iv[iv >= 0], ct[iv >= 0]
+        starts = np.flatnonzero(np.append(True, iv[1:] != iv[:-1])) \
+            if len(iv) else np.zeros(0, np.int64)
+        dvi = iv[starts].tolist()
+        cti = np.add.reduceat(ct, starts).tolist() if len(iv) else []
+        self.categories_seen = len(dvi)
         self.num_bin = 0
         rest_cnt = total_sample_cnt - na_cnt
         self.categorical_2_bin = {}
@@ -358,17 +361,18 @@ class BinMapper:
         """Vectorized ValueToBin over a column."""
         values = np.asarray(values, dtype=np.float64)
         if self.bin_type == BinType.CATEGORICAL:
-            out = np.zeros(len(values), dtype=np.int32)
-            if self.categorical_2_bin:
-                keys = np.fromiter(self.categorical_2_bin.keys(), dtype=np.int64)
-                vals = np.fromiter(self.categorical_2_bin.values(), dtype=np.int32)
-                iv = np.where(np.isfinite(values), values, -1).astype(np.int64)
-                sorter = np.argsort(keys)
-                pos = np.searchsorted(keys[sorter], iv)
-                pos = np.clip(pos, 0, len(keys) - 1)
-                hit = keys[sorter[pos]] == iv
-                out = np.where(hit, vals[sorter[pos]], 0).astype(np.int32)
-            return out
+            if not self.categorical_2_bin:
+                return np.zeros(len(values), dtype=np.int32)
+            keys = np.fromiter(self.categorical_2_bin.keys(), dtype=np.int64)
+            vals = np.fromiter(self.categorical_2_bin.values(), dtype=np.int32)
+            sorter = np.argsort(keys)
+            keys, vals = keys[sorter], vals[sorter]
+            if len(values) >= 65536:
+                from ..native import bin_categorical_native
+                nb = bin_categorical_native(values, keys, vals)
+                if nb is not None:
+                    return nb
+            return self._categorical_bins_numpy(values, keys, vals)
         has_nan_bin = self.missing_type == MissingType.NAN
         n_numeric = self.num_bin - (1 if has_nan_bin else 0)
         bounds = self.bin_upper_bound[:max(n_numeric - 1, 0)]
@@ -385,6 +389,17 @@ class BinMapper:
         if has_nan_bin:
             bins = np.where(nan_mask, self.num_bin - 1, bins)
         return bins
+
+    @staticmethod
+    def _categorical_bins_numpy(values: np.ndarray, keys: np.ndarray,
+                                vals: np.ndarray) -> np.ndarray:
+        """The categorical map without the native library: ``keys``
+        ascending, ``vals`` their bins, everything else bin 0."""
+        with np.errstate(invalid="ignore"):
+            ok = (values > -1.0) & (values < 9.2e18)    # False for a NaN
+        iv = np.where(ok, values, -1.0).astype(np.int64)
+        pos = np.clip(np.searchsorted(keys, iv), 0, len(keys) - 1)
+        return np.where(ok & (keys[pos] == iv), vals[pos], 0).astype(np.int32)
 
     def bin_to_value(self, bin_idx: int) -> float:
         """BinToValue: representative (upper bound) of a bin."""

@@ -29,7 +29,7 @@ import numpy as np
 
 from ..config import Config
 from ..log import Log, LightGBMError, check
-from ..obs.trace import recorder
+from ..obs.trace import record_span, recorder
 from .binning import BinMapper, BinType, MissingType
 from .bundle import bundle_offsets, find_bundles
 
@@ -350,6 +350,8 @@ class BinnedDataset:
                 span.counts["bundles"] = len(self.col_features)
 
         # ---- build the stored uint8 columns ------------------------------
+        cat_s = [0.0, 0]    # seconds and columns of categorical binning
+
         def full_bin_column(j):
             m = self.bin_mappers[j]
             if sparse:
@@ -359,7 +361,12 @@ class BinnedDataset:
                 if len(rows):
                     colb[rows] = m.values_to_bins(vals).astype(np.uint8)
                 return colb
-            return m.values_to_bins(data64[:, j]).astype(np.uint8)
+            t_col = time.perf_counter()
+            colb = m.values_to_bins(data64[:, j]).astype(np.uint8)
+            if m.bin_type == BinType.CATEGORICAL:
+                cat_s[0] += time.perf_counter() - t_col
+                cat_s[1] += 1
+            return colb
 
         cols = []
         with recorder.span("ingest.bin_columns",
@@ -384,6 +391,17 @@ class BinnedDataset:
                         colb[rows[sel]] = (off
                                            + bins[sel]).astype(np.uint8)
                 cols.append(colb)
+            if cat_s[1]:
+                # the dense categorical columns' share of the span above:
+                # id -> bin through the kept categories (native binner)
+                cat_maps = [m for m in self.bin_mappers
+                            if m.bin_type == BinType.CATEGORICAL]
+                record_span(
+                    "ingest.bin_categorical", cat_s[0], columns=cat_s[1],
+                    values=n * cat_s[1],
+                    categories_kept=sum(len(m.bin_2_categorical)
+                                        for m in cat_maps),
+                    categories_seen=sum(m.categories_seen for m in cat_maps))
         with recorder.span("ingest.stack") as span:
             self.X_binned = (np.stack(cols, axis=1) if cols
                              else np.zeros((n, 0), dtype=np.uint8))

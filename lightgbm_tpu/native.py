@@ -92,6 +92,12 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_double), ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_double), ctypes.c_int32,
                 ctypes.c_int32, ctypes.POINTER(ctypes.c_int32)]
+            lib.LGBMT_BinCategorical.restype = None
+            lib.LGBMT_BinCategorical.argtypes = [
+                ctypes.POINTER(ctypes.c_double), ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int32), ctypes.c_int32,
+                ctypes.POINTER(ctypes.c_int32)]
             _lib = lib
         except (OSError, AttributeError) as e:
             # AttributeError: a stale prebuilt .so from before a symbol was
@@ -155,5 +161,34 @@ def bin_numeric_native(values: np.ndarray, bounds: np.ndarray,
         ctypes.c_int64(len(values)),
         bounds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
         ctypes.c_int32(len(bounds)), ctypes.c_int32(nan_bin),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+    return out
+
+
+def bin_categorical_native(values: np.ndarray, keys: np.ndarray,
+                           bins: np.ndarray) -> Optional[np.ndarray]:
+    """Assign bins for a categorical column with the OpenMP binner
+    (native/src/binning.cpp); None when the library is unavailable.
+
+    ``keys`` are the kept category ids in ascending order, ``bins`` their
+    bins; every other value (NaN, negative, not kept) lands in bin 0.
+    Matches BinMapper.values_to_bins' numpy path value for value.
+    """
+    lib = get_lib()
+    if lib is None:
+        return None
+    values = np.ascontiguousarray(values, dtype=np.float64)
+    keys = np.ascontiguousarray(keys, dtype=np.int64)
+    bins = np.ascontiguousarray(bins, dtype=np.int32)
+    if len(keys) != len(bins) or np.any(keys[1:] <= keys[:-1]):
+        raise ValueError("bin_categorical_native: one bin for each of the "
+                         "ascending keys")
+    out = np.empty(len(values), dtype=np.int32)
+    lib.LGBMT_BinCategorical(
+        values.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+        ctypes.c_int64(len(values)),
+        keys.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        bins.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        ctypes.c_int32(len(keys)),
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
     return out

@@ -152,9 +152,11 @@ class DataRSLearner:
     def _search(self, hist_local, sum_g, sum_h, cnt, mn, mx,
                 base, meta_l, fmask_l):
         p = self.params
+        # a device's slice of the columns holds an unknown number of the
+        # categorical ones: every local column through the finder
         bs = find_best_split(hist_local, meta_l, p.split, sum_g, sum_h, cnt,
                              fmask_l, min_constraint=mn, max_constraint=mx,
-                             with_categorical=p.with_categorical)
+                             with_categorical=bool(p.with_categorical))
         return bs._replace(feature=base + bs.feature)
 
     def best_root(self, hist, sum_g, sum_h, cnt):

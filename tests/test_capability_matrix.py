@@ -14,6 +14,7 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.log import LightGBMError, Log
+from lightgbm_tpu.obs.trace import recorded_spans
 
 from conftest import make_binary, make_multiclass
 
@@ -41,10 +42,27 @@ def _forced_file():
 #   frontier -> grow_params.frontier_mode,
 #   frontier_rs -> grow_params.frontier_rs
 #   bag -> _goss_bag (GOSS grows its sampled trees on a bag partition)
+#   cat_free -> train.setup's cat_route_gather_free (a categorical split
+#     routes its rows without a gather over them: the exact grower's
+#     one-split form; the wave growers test per-row sets through
+#     take_along_axis). "CATEGORICAL" makes the last column one
 # "WARNS" in the overrides: a warning holding that text must be logged
 _F64_WARNING = "does not support f64 histograms yet; falling back to exact"
 MATRIX = [
-    ("serial-plain", {}, dict(use_part=True, part_mesh=False, fp=False)),
+    ("serial-plain", {}, dict(use_part=True, part_mesh=False, fp=False,
+                              cat_free=False)),
+    ("serial-categorical", {"CATEGORICAL": True},
+     dict(use_part=True, cat_free=True)),
+    ("serial-categorical-goss", {"CATEGORICAL": True, "boosting": "goss"},
+     dict(use_part=True, bag=True, cat_free=True)),
+    ("data-categorical", {"CATEGORICAL": True, "tree_learner": "data",
+                          "mesh_shape": [8]},
+     dict(part_mesh=True, cat_free=True)),
+    ("batched-categorical", {"CATEGORICAL": True, "tree_growth": "batched"},
+     dict(batch=True, cat_free=False)),
+    ("frontier-categorical", {"CATEGORICAL": True,
+                              "tree_growth": "frontier"},
+     dict(frontier=True, cat_free=False)),
     ("serial-forced", {"FORCED": True}, dict(use_part=True)),
     ("serial-cegb", {"cegb_tradeoff": 0.5,
                      "cegb_penalty_split": 1e-4}, dict(use_part=True)),
@@ -166,6 +184,10 @@ def test_capability_matrix(case, overrides, expect):
     forced = overrides.pop("FORCED", False)
     warns = overrides.pop("WARNS", None)
     X, y = _data(multiclass=multiclass)
+    if overrides.pop("CATEGORICAL", False):
+        X = X.copy()
+        X[:, -1] = np.digitize(X[:, -1], [-1.0, -0.3, 0.2, 0.9, 1.5])
+        overrides["categorical_feature"] = str(X.shape[1] - 1)
     params = {"objective": "multiclass" if multiclass else "binary",
               "num_leaves": 15, "verbosity": 0 if warns else -1,
               "min_data_in_leaf": 5,
@@ -193,7 +215,10 @@ def test_capability_matrix(case, overrides, expect):
             batch=impl.grow_params.batch_splits > 0,
             frontier=impl.grow_params.frontier_mode,
             frontier_rs=impl.grow_params.frontier_rs,
-            bag=impl._goss_bag)
+            bag=impl._goss_bag,
+            cat_free=bool([s for s in recorded_spans()
+                           if s["name"] == "train.setup"][-1]["counts"]
+                          .get("cat_route_gather_free")))
         for key, want in expect.items():
             assert flags[key] == want, (case, key, flags)
         if warns:

@@ -49,6 +49,25 @@ learner, CEGB) the sampler stays a multiplier on gradient, hessian and
 sample mask inside one program: a `lax.top_k` threshold and a Bernoulli
 draw of the rest at `other_cnt / (N - top_cnt)`, every row in every pass,
 counts summed in float32. No option selects between the two.
+
+**`categorical_feature`** (`max_cat_threshold`, `cat_l2`, `cat_smooth`,
+`max_cat_to_onehot`, `min_data_per_group`; `core/split.py`
+`per_feature_split_categorical`). Upstream's candidates, order and
+tie-breaks: one-vs-rest where a column has at most `max_cat_to_onehot`
+bins, else the categories with at least `cat_smooth` rows sorted by
+`sum_gradient / (sum_hessian + cat_smooth)` and searched from both ends;
+the children of a sorted-subset split are valued under
+`lambda_l2 + cat_l2`. A column keeps its `max_bin - 1` most frequent
+categories of the sampled rows (99% coverage); bin 0 is the catch-all of
+every other id, NaN and negatives, and always goes right. The finder runs
+over the categorical columns alone (their number is static a data set,
+which they are is data). On the chip path (`tree_growth=exact` over the
+row partition: serial, a GOSS bag, `tree_learner=data`) a split's category
+set is tested by selects on its eight words, no gather over the routed
+rows; `tree_growth=batched|frontier` test per-row sets through a
+`take_along_axis`, and `tree_learner=feature` sends a device's whole slice
+of the columns through the finder. A node's raw-value set in the model
+text is as wide as the largest id going left: label-encode sparse ids.
 """
 
 
