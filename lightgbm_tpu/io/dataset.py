@@ -30,7 +30,7 @@ import numpy as np
 from ..config import Config
 from ..log import Log, LightGBMError, check
 from ..obs.trace import record_span, recorder
-from .binning import BinMapper, BinType, MissingType
+from .binning import K_ZERO_THRESHOLD, BinMapper, BinType, MissingType
 from .bundle import bundle_offsets, find_bundles
 
 
@@ -120,6 +120,15 @@ def _parse_categorical(categorical_feature, feature_names: List[str]) -> List[in
         else:
             out.append(int(c))
     return sorted(set(out))
+
+
+def _nonzeros(col: np.ndarray):
+    """(positions ascending, values) of the entries of a float64 column
+    that lie outside [-1e-35, 1e-35]: what feeds FindBin, like the
+    reference's sampler (a NaN fails both comparisons and is kept)."""
+    at = np.flatnonzero(~((col >= -K_ZERO_THRESHOLD)
+                          & (col <= K_ZERO_THRESHOLD)))
+    return at, col[at]
 
 
 def bytes_copied(src, out: np.ndarray) -> int:
@@ -217,9 +226,7 @@ class BinnedDataset:
             if sparse:
                 sl = slice(csc.indptr[j], csc.indptr[j + 1])
                 return csc.indices[sl], np.asarray(csc.data[sl], np.float64)
-            col = data64[:, j]
-            rows = np.flatnonzero(~((col >= -1e-35) & (col <= 1e-35)))
-            return rows, col[rows]
+            return _nonzeros(data64[:, j])
 
         if reference is not None:
             # validation set: reuse the reference's bin mappers / layout
@@ -243,12 +250,29 @@ class BinnedDataset:
                     rng = np.random.RandomState(config.data_random_seed)
                     sample_rows = np.sort(rng.choice(n, sample_cnt,
                                                      replace=False))
-                    # row id -> sample position (-1 = not sampled)
+                else:
+                    sample_rows = None      # the table is its own sample
+                if sparse and sample_rows is not None:
+                    # row id -> sample position (-1 = not sampled), for the
+                    # stored entries; a dense column needs no such table
                     sample_pos = np.full(n, -1, np.int64)
                     sample_pos[sample_rows] = np.arange(sample_cnt)
-                else:
-                    sample_rows = None
-                    sample_pos = None
+
+            def sampled_nonzeros(j):
+                """(sample positions ascending, values, values read) of
+                column j's non-zero entries among the sampled rows."""
+                if sample_rows is not None and not sparse:
+                    # a dense table larger than the sample: the sampled
+                    # rows' values first, the zero test on those alone
+                    pos, vals = _nonzeros(data64[sample_rows, j])
+                    return pos, vals, sample_cnt
+                rows, vals = column_nonzeros(j)
+                read = len(rows) if sparse else n
+                if sample_rows is None:
+                    return rows, vals, read
+                pos = sample_pos[rows]
+                keep = pos >= 0
+                return pos[keep], vals[keep], read
 
             self.bin_mappers = []
             nz_sample: List[np.ndarray] = []   # per feature, sample positions
@@ -256,15 +280,11 @@ class BinnedDataset:
                                sample_rows=sample_cnt) as span:
                 scan_s = find_s = 0.0
                 nan_values = zero_values = 0   # of the sampled rows
+                values_scanned = 0             # read by the zero test
                 t = time.perf_counter()
                 for j in range(f):
-                    rows, vals = column_nonzeros(j)
-                    if sample_pos is not None:
-                        pos = sample_pos[rows]
-                        keep = pos >= 0
-                        rows_s, vals_s = pos[keep], vals[keep]
-                    else:
-                        rows_s, vals_s = rows, vals
+                    rows_s, vals_s, scanned = sampled_nonzeros(j)
+                    values_scanned += scanned
                     nz_sample.append(rows_s.astype(np.int64))
                     nan_values += int(np.isnan(vals_s).sum())
                     zero_values += sample_cnt - len(vals_s)
@@ -287,7 +307,8 @@ class BinnedDataset:
                     find_s += t - t_scan
                 span.counts.update(nonzero_scan_s=scan_s, find_bin_s=find_s,
                                    nan_values=nan_values,
-                                   zero_values=zero_values)
+                                   zero_values=zero_values,
+                                   values_scanned=values_scanned)
             self.used_features = [j for j in range(f)
                                   if not self.bin_mappers[j].is_trivial]
             if not self.used_features:

@@ -44,8 +44,9 @@ def spans_named(*names, since=0):
 
 
 def last_id():
-    spans = trace.recorded_spans()
-    return spans[-1]["id"] if spans else 0
+    # the ring is in order of closing: an outer span closes after the spans
+    # it holds and has the smaller id
+    return max((s["id"] for s in trace.recorded_spans()), default=0)
 
 
 # ------------------------------------------------------------ the record
@@ -138,6 +139,37 @@ def test_spans_record_under_observability_none_and_export_nothing(tmp_path):
     assert not events.exists()
     assert {m.name for m in lgb.obs.get_registry().metrics()
             if m.name == "lgbm_train_span_seconds"} == reg_before
+
+
+@pytest.mark.parametrize("sparse,sample_cnt", [
+    (False, 250),     # dense and larger than the sample: the sampled rows
+    (False, 1000),    # the table is its own sample: every value
+    (True, 250),      # sparse: the stored entries
+], ids=["dense_sampled", "dense_own_sample", "sparse_stored"])
+def test_find_bins_span_counts_the_values_its_zero_test_read(sparse,
+                                                             sample_cnt):
+    sparse_mod = pytest.importorskip("scipy.sparse")
+    rng = np.random.RandomState(37)
+    n, f = 1000, 6
+    X = rng.randn(n, f)
+    X[rng.rand(n, f) < 0.3] = 0.0
+    X[rng.rand(n, f) < 0.1] = np.nan
+    data = sparse_mod.csr_matrix(X) if sparse else X
+    mark = last_id()
+    lgb.Dataset(data, (X[:, 0] > 0).astype(float),
+                params={"verbose": -1,
+                        "bin_construct_sample_cnt": sample_cnt}).construct()
+    (span,) = spans_named("ingest.find_bins", since=mark)
+    counts = span["counts"]
+    assert counts["columns"] == f and counts["sample_rows"] == sample_cnt
+    assert counts["values_scanned"] == (data.nnz if sparse
+                                        else sample_cnt * f)
+    # of the sampled rows' values, as before the zero test moved to them
+    rows = np.arange(n) if sample_cnt == n else np.sort(
+        np.random.RandomState(1).choice(n, sample_cnt, replace=False))
+    assert counts["nan_values"] == int(np.isnan(X[rows]).sum())
+    assert counts["zero_values"] == int((X[rows] == 0).sum())
+    assert counts["nonzero_scan_s"] >= 0 and counts["find_bin_s"] > 0
 
 
 # want: (partition_window_placement, leaf_ids_gather_free,
