@@ -37,7 +37,8 @@ from ..log import Log, LightGBMError, check
 from ..io.dataset import BinnedDataset
 from ..io.binning import BinType, MissingType as BinMissingType
 from ..core.split import FeatureMeta, SplitParams
-from ..core.grow import GrowParams, TreeArrays, empty_tree, grow_tree
+from ..core.grow import (WORK_COUNTS, WORK_LIMB, GrowParams, TreeArrays,
+                         empty_tree, grow_tree)
 from ..core import partition as partition_mod
 from ..core.pack import pack_trees, unpack_tree
 from ..core import tree as tree_mod
@@ -1303,7 +1304,7 @@ class GBDT:
                 def grow_one(gk, hk, cs):
                     t, li = grow_fp(xb, xb_cols, meta_loc, gofl, gk, hk,
                                     sample_mask, meta, feature_mask)
-                    return t, li, None
+                    return t, li, None, None
             elif params.partition_on_mesh or params.voting_top_k > 0:
                 # explicit shard_map learners (mutually exclusive configs):
                 # - data-parallel partition: each device partitions its
@@ -1339,7 +1340,7 @@ class GBDT:
                     def _grow_core_cegb(xbj, gj, hj, mj, mt, fm, cs):
                         return grow_tree(xbj, gj, hj, mj, mt, fm, params,
                                          axis_name=DATA_AXIS,
-                                         forced=forced_splits, cegb=cs)
+                                         forced=forced_splits, cegb=cs)[:3]
                     # acquisition state: per-feature fields replicated,
                     # lazy per-row accounting sharded with the rows
                     cegb_specs = CegbState(
@@ -1355,7 +1356,7 @@ class GBDT:
 
                     def grow_one(gk, hk, cs):
                         return grow_cegb(xb, gk, hk, sample_mask, meta,
-                                         feature_mask, cs)
+                                         feature_mask, cs) + (None,)
                 else:
                     def _grow_core(xbj, gj, hj, mj, mt, fm):
                         return grow_tree(xbj, gj, hj, mj, mt, fm, params,
@@ -1373,11 +1374,11 @@ class GBDT:
                     def grow_one(gk, hk, cs):
                         t, li = grow_sharded(xb, gk, hk, sample_mask, meta,
                                              feature_mask)
-                        return t, li, None
+                        return t, li, None, None
             elif grow_batched_fn is not None:
                 def grow_one(gk, hk, cs):
                     return grow_batched_fn(xb, gk, hk, sample_mask, meta,
-                                           feature_mask, params)
+                                           feature_mask, params) + (None,)
             else:
                 def grow_one(gk, hk, cs):
                     return grow_tree(xb, gk, hk, sample_mask, meta,
@@ -1395,12 +1396,15 @@ class GBDT:
             # params.vmapped_classes is the ONE predicate: grow_tree keys
             # its placement/pool decisions off the same flag this
             # dispatch uses, so the two can never disagree.
+            # every grow_one returns (tree, leaf ids, the grower's third
+            # output, the tree's work counts or None: grow.Grown)
             if k == 1:
-                t1, li1, cb1 = grow_one(g[:, 0], h[:, 0], cegb_state)
+                t1, li1, cb1, wk1 = grow_one(g[:, 0], h[:, 0], cegb_state)
                 trees = jax.tree.map(lambda a: a[None], t1)
                 leaf_ids = li1[None]
                 cegb_out = (jax.tree.map(lambda a: a[None], cb1)
                             if cb1 is not None else None)
+                work = wk1[None] if wk1 is not None else None
             elif params.vmapped_classes:
                 if params.frontier_mode and fp_capture is None \
                         and not params.partition_on_mesh \
@@ -1415,11 +1419,12 @@ class GBDT:
                     trees, leaf_ids, cegb_out = grow_tree_frontier_classes(
                         xb, g.T, h.T, sample_mask, meta, feature_mask,
                         params)
+                    work = None
                 else:
-                    trees, leaf_ids, cegb_out = jax.vmap(
+                    trees, leaf_ids, cegb_out, work = jax.vmap(
                         grow_one, in_axes=(1, 1, None))(g, h, cegb_state)
             else:
-                trees, leaf_ids, cegb_out = lax.map(
+                trees, leaf_ids, cegb_out, work = lax.map(
                     lambda gh: grow_one(gh[0], gh[1], cegb_state),
                     (g.T, h.T))
             # the grower's third output is CEGB state on the exact path
@@ -1434,12 +1439,6 @@ class GBDT:
                 grower_health, grower_mstats = aux
             elif params.frontier_mode and params.obs_health:
                 grower_health, cegb_out = cegb_out, None
-            # grown on a bag (no CEGB there), it is the rows whose bins
-            # entered a histogram kernel call, a class tree. With the bag's
-            # codes they are what an iteration on a bag hands out besides
-            bag_aux = None
-            if bag is not None:
-                bag_aux, cegb_out = (jnp.sum(cegb_out), bag_code), None
             if cegb_state is not None:
                 # classes train from the iteration-start state; acquisitions
                 # merge across class trees for the next iteration (the
@@ -1495,12 +1494,14 @@ class GBDT:
                 health = health_vec(g, h, any_split, grower_health)
             else:
                 health = jnp.zeros((4,), jnp.float32)
-            # grower_mstats is None unless obs_modelstats, bag_aux unless
-            # the tree grew on a bag: a None output is an empty pytree
-            # leaf, so the compiled program (and every jaxpr fingerprint)
-            # is unchanged when the feature is off
+            # grower_mstats is None unless obs_modelstats, the class
+            # trees' work counts ([K, 2, W]: grow.Grown) unless they grew
+            # over the single-device row partition, the bag's codes unless
+            # on a bag: a None output is an empty pytree leaf, so the
+            # compiled program (and every jaxpr fingerprint) is unchanged
+            # when the feature is off
             return pack_trees(trees), leaf_ids, new_scores, cegb_new, \
-                stopped_out, health, grower_mstats, bag_aux
+                stopped_out, health, grower_mstats, (work, bag_code)
 
         self._iter_core = run_iter   # unjitted: train_many scans over it
         return jax.jit(run_iter)
@@ -1773,15 +1774,15 @@ class GBDT:
                         new_mask = (u < frac).astype(jnp.float32)
                         bag_mask = jnp.where(refresh, new_mask, bag_mask)
                 sm = bag_mask if row_valid is None else bag_mask * row_valid
-                packed, _leaf_ids, sc2, cegb2, stopped2, health, ms, aux = \
-                    core(xb, obj_rows, fp_capture, meta, sc, sm, fm, g0, h0,
-                         lr, ga, gkey, cegb, stopped, bins_by_col)
-                hr, code = aux if aux is not None else (None, None)
+                packed, _leaf_ids, sc2, cegb2, stopped2, health, ms, \
+                    (work, code) = core(
+                        xb, obj_rows, fp_capture, meta, sc, sm, fm, g0, h0,
+                        lr, ga, gkey, cegb, stopped, bins_by_col)
                 return (sc2, bag_mask, cegb2, stopped2, code), \
-                    (packed, health, ms, hr)
+                    (packed, health, ms, work)
 
             code0 = jnp.zeros((n,), jnp.uint8) if goss_bag_sampled else None
-            carry, (packs, healths, mstats, hist_rows) = lax.scan(
+            carry, (packs, healths, mstats, works) = lax.scan(
                 step, (scores, bag_mask0, cegb_state, stopped_in, code0),
                 (feature_masks, goss_actives, iter_idxs, keys))
             new_scores, bag_mask, cegb_out, stopped_out, last_code = carry
@@ -1789,11 +1790,12 @@ class GBDT:
             # monitoring is off) — one tiny transfer per block, not per
             # iter. mstats: [block, K, F, MS_WIDTH] per-iteration model
             # statistics with obs_modelstats, else None (invisible in the
-            # compiled program); under a GOSS bag (hist_rows [block], the
-            # last iteration's bag codes [N]), else None likewise
-            bag_aux = ((hist_rows, last_code) if goss_bag_sampled else None)
+            # compiled program); works: [block, K, 2, W] work counts of
+            # the trees grown over the single-device row partition, and
+            # under a GOSS bag the last iteration's bag codes [N], else
+            # None likewise
             return packs, healths, new_scores, bag_mask, cegb_out, \
-                stopped_out, mstats, bag_aux
+                stopped_out, mstats, (works, last_code)
 
         return run_block
 
@@ -2065,7 +2067,7 @@ class GBDT:
                 with obs.span("train.block_dispatch") as dispatch_span:
                     packs, healths, self.scores, self._bag_mask, \
                         self._cegb_state, self._stopped_dev, mstats, \
-                        bag_aux = fn(
+                        grow_aux = fn(
                             *self._iter_capture,
                             self.scores, fmasks, gactive, idxs, all_keys[1:],
                             self._bag_mask, self._cegb_state,
@@ -2082,7 +2084,7 @@ class GBDT:
                                   "count": block,
                                   "mstats": mstats,
                                   "span": block_span})
-            self._keep_bag(bag_aux, self.iter_ + block - 1)
+            self._keep_aux(grow_aux, self.iter_ + block - 1)
             self.iter_ += block
             done += block
             if obs.enabled:
@@ -2166,13 +2168,15 @@ class GBDT:
                 self._compiled_block.lower(
                     *self.train_block_sds(block)).compile()
 
-    def _keep_bag(self, bag_aux, iteration: int) -> None:
-        """What a block on a GOSS bag hands out besides its trees: the
-        rows its histogram passes saw, for the block's span once the host
-        fetches the trees, and its last iteration's bag, which stays on
-        the device as ``last_bag``."""
-        if bag_aux is not None:
-            self._pending[-1]["hist_rows"], code = bag_aux
+    def _keep_aux(self, grow_aux, iteration: int) -> None:
+        """What a block hands out besides its trees: their work counts
+        (grow.Grown), which stay on the device until the host fetches the
+        trees and join the block's span there, and on a GOSS bag its last
+        iteration's bag, which stays on the device as ``last_bag``."""
+        work, code = grow_aux
+        if work is not None:
+            self._pending[-1]["work"] = work
+        if code is not None:
             self.last_bag = (iteration, code)
 
     def _count_goss(self, block_span, start_iter: int, count: int) -> None:
@@ -2442,7 +2446,7 @@ class GBDT:
                 self._bag_key, goss_key = jax.random.split(self._bag_key)
             with obs.span("train.block_dispatch") as dispatch_span:
                 packed, leaf_ids, new_scores, cegb_new, self._stopped_dev, \
-                    health, mstats, bag_aux = self._compiled_iter(
+                    health, mstats, grow_aux = self._compiled_iter(
                         *self._iter_capture,
                         self.scores, sample_mask, feature_mask, g_in, h_in,
                         jnp.float32(self.shrinkage_rate),
@@ -2465,7 +2469,7 @@ class GBDT:
                                            if mstats is not None else None),
                                 "span": block_span}
         self._pending.append(pend)
-        self._keep_bag(bag_aux, iter_idx)
+        self._keep_aux(grow_aux, iter_idx)
         self.iter_ += 1
         if obs.enabled:
             hrow = np.asarray(health)[None]
@@ -2505,11 +2509,13 @@ class GBDT:
             buf = np.asarray(jnp.concatenate([p["packed"] for p in pend],
                                              axis=0))  # [sum(B_i), K, T]
         for p in pend:
-            # a span never waits for the device: the count its block made
-            # there joins it here, where the host fetches the trees
-            if p.get("hist_rows") is not None:
-                p["span"].counts["hist_rows"] = int(
-                    np.asarray(p["hist_rows"], np.int64).sum())
+            # a span never waits for the device: the counts its block made
+            # there join it here, where the host fetches the trees
+            if p.get("work") is not None:
+                limbs = np.asarray(p["work"], np.int64).reshape(
+                    -1, 2, len(WORK_COUNTS)).sum(axis=0)
+                p["span"].counts.update(zip(
+                    WORK_COUNTS, (limbs[1] * WORK_LIMB + limbs[0]).tolist()))
         row = 0
         with_cat = self.grow_params.with_categorical > 0
         for p in pend:
@@ -2524,14 +2530,8 @@ class GBDT:
                     if ht.num_leaves_actual > 1:
                         any_split = True
                     host_trees.append(ht)
-                    if with_cat and p.get("span") is not None:
-                        # the block's splits on a categorical column
-                        # join its span here, as hist_rows does above
-                        counts = p["span"].counts
-                        counts["cat_splits"] = counts.get(
-                            "cat_splits", 0) + int(np.count_nonzero(
-                                ht.is_categorical[
-                                    :max(ht.num_leaves_actual - 1, 0)]))
+                    if p.get("span") is not None:
+                        self._count_tree(p, ht, with_cat)
                 row += 1
                 if not any_split:
                     Log.warning("Stopped training because there are no "
@@ -2569,6 +2569,24 @@ class GBDT:
                         host_trees, len(self._models) // max(k, 1) - 1,
                         device_rows=dev_rows)
         return self._stopped
+
+    @staticmethod
+    def _count_tree(pend: Dict[str, Any], ht: HostTree,
+                    with_cat: bool) -> None:
+        """What a fetched tree adds to its block's span, joined here as the
+        device's work counts are: its splits; under a grower with no tile
+        (no work counts from the device) the rows they split, as the tree
+        says them; on a table with categorical columns the splits on
+        one."""
+        counts = pend["span"].counts
+        nn = max(ht.num_leaves_actual - 1, 0)
+        counts["splits"] = counts.get("splits", 0) + nn
+        if pend.get("work") is None:
+            counts["split_rows"] = counts.get("split_rows", 0) + int(
+                ht.internal_count[:nn].sum(dtype=np.int64))
+        if with_cat:
+            counts["cat_splits"] = counts.get("cat_splits", 0) + int(
+                np.count_nonzero(ht.is_categorical[:nn]))
 
     def _store_host_trees(self, host_trees: List[HostTree],
                           pend: Dict[str, Any]) -> None:

@@ -279,9 +279,46 @@ class _GrowState(NamedTuple):
     #                               remaining forced steps fall back to
     #                               best-first (aborted_last_force_split)
     pool_map: Optional[PoolMap]   # LRU slot map (None = uncapped)
-    hist_rows: Optional[jnp.ndarray] = None   # with a bag: rows whose bins
-    #                                           entered a kernel call
+    work: Optional[jnp.ndarray] = None  # [2, W] int32 work counts (Grown.work)
 
+
+class Grown(NamedTuple):
+    """What grow_tree returns. ``work`` is the tree's work counts where it
+    grew over the single-device row partition, else None: an int32
+    [2, len(WORK_COUNTS)] array, a count being
+    ``work[1] * WORK_LIMB + work[0]`` (two limbs: a lopsided tree splits
+    (L - 1) x N rows, 9e9 at 35.4M rows and 255 leaves, and int32 is what
+    the device has)."""
+    tree: TreeArrays
+    leaf_id: jnp.ndarray
+    cegb: Optional[CegbState]
+    work: Optional[jnp.ndarray]
+
+
+_LIMB_BITS = 20
+WORK_LIMB = 1 << _LIMB_BITS
+
+# The work a tree's growth over the row partition adds up, once a split and
+# from counts the loop holds anyway (no op inside a tile loop):
+# ``split_rows``, the rows of the leaves split (the partition pass's rows)
+# in ``partition_tiles`` tiles of ``row_chunk``; ``hist_rows``, the rows
+# whose bins entered a histogram kernel call: the root's pass (every row, or
+# the bag), then in ``hist_tiles`` tiles each smaller child's and, under a
+# capped pool, each leaf's whose histogram a miss built again. A count a
+# grower has no meaning for is absent, never 0.
+WORK_COUNTS = ("split_rows", "partition_tiles", "hist_rows", "hist_tiles")
+
+
+def _tiles(rows, chunk: int):
+    return (rows + (chunk - 1)) // chunk
+
+
+def _add_work(work: jnp.ndarray, counts) -> jnp.ndarray:
+    """``work`` + a vector of non-negative int32 ``counts``, limb by limb."""
+    add = jnp.stack([jnp.asarray(c, jnp.int32) for c in counts])
+    low = work[0] + (add & (WORK_LIMB - 1))
+    return jnp.stack([low & (WORK_LIMB - 1),
+                      work[1] + (add >> _LIMB_BITS) + (low >> _LIMB_BITS)])
 
 
 def _empty_best(num_leaves: int, dtype=jnp.float32) -> BestSplit:
@@ -504,9 +541,9 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
               fp: Optional[FeatureParallelCtx] = None,
               bag: Optional[RowPartition] = None,
               bins_by_col: Optional[jnp.ndarray] = None,
-              ) -> Tuple[TreeArrays, jnp.ndarray, Optional[CegbState]]:
+              ) -> Grown:
     """Grow one leaf-wise tree; returns (tree, final per-row leaf_id,
-    updated CEGB state or None).
+    updated CEGB state or None, the tree's work counts or None).
 
     xb [N, F] uint8 binned features; grad/hess [N] f32 (objective-weighted);
     sample_mask [N] f32 bagging inclusion. With ``axis_name`` set, rows are
@@ -776,6 +813,20 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # dead iterations (live=False) never pay for a rebuild
         return lax.cond((sl < 0) & live, rebuild, read, operand=None)
 
+    def rebuilt_rows(s: _GrowState, leaf_idx, live):
+        """The rows leaf_hist walks again for ``leaf_idx``: its range's on a
+        pool miss, 0 on a hit."""
+        missed = (s.pool_map.slot_of_leaf[leaf_idx] < 0) & live
+        return jnp.where(missed, s.part.leaf_count[leaf_idx], 0)
+
+    # the work counts (WORK_COUNTS) start from the root's pass: every row,
+    # or the bag
+    work0 = None
+    if use_partition and axis_name is None:
+        root_rows = n if bag is None else bag.leaf_count[0]
+        work0 = _add_work(jnp.zeros((2, len(WORK_COUNTS)), jnp.int32),
+                          (0, 0, root_rows, 0))
+
     leaf_id0 = jnp.zeros((n,), jnp.int32)
     if bag is not None:
         leaf_id0 = row_space_leaf_ids0(bins_by_col, l)
@@ -794,8 +845,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                        leaf_max=jnp.full((l,), jnp.inf, hdt),
                        part=part0, cegb=cegb,
                        force_aborted=jnp.asarray(False),
-                       pool_map=pool_map0,
-                       hist_rows=None if bag is None else bag.leaf_count[0])
+                       pool_map=pool_map0, work=work0)
 
     def forced_split_info(s: _GrowState, t: jnp.ndarray, in_phase):
         """Evaluate the step-t forced (leaf, feature, threshold) from the
@@ -998,7 +1048,6 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # ones: every device builds the same child, whichever is the
         # smaller among its local rows
         left_smaller = cur.left_count <= cur.right_count
-        hist_rows = s.hist_rows
         small_leaf = jnp.where(left_smaller, leaf, right_leaf)
         large_leaf = jnp.where(left_smaller, right_leaf, leaf)
 
@@ -1007,9 +1056,6 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             # iteration walks no tile (and psums zeros on a mesh: one
             # collective a split, outside every loop)
             hist_small = psum(hist_of_range(part, small_leaf, valid))
-            if hist_rows is not None:
-                hist_rows = hist_rows + jnp.where(
-                    valid, part.leaf_count[small_leaf], 0)
         elif axis_name is None:
             def live_hist(_):
                 m = (leaf_id == small_leaf).astype(hdt) * sample_mask
@@ -1026,6 +1072,21 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             hist_small = hist_for_mask(
                 (leaf_id == small_leaf).astype(hdt) * sample_mask
                 * valid.astype(hdt))
+        work = s.work
+        if work is not None:
+            # what this split's two tile loops walked, from the ranges' own
+            # counts; under a capped pool also what a miss walks again: the
+            # parent's range below, the forced leaf's in forced_split_info
+            split_rows = jnp.where(valid, s.part.leaf_count[leaf], 0)
+            walked = [jnp.where(valid, part.leaf_count[small_leaf], 0)]
+            if capped:
+                walked.append(rebuilt_rows(s, leaf, valid))
+                if with_forced:
+                    walked.append(rebuilt_rows(s, fleaf, in_phase))
+            work = _add_work(work, (
+                split_rows, _tiles(split_rows, params.row_chunk),
+                sum(walked),
+                sum(_tiles(r, params.row_chunk) for r in walked)))
         with jax.named_scope("lgbm.hist_subtract"):
             hist_parent = leaf_hist(s, leaf, live=valid)
             hist_large = hist_parent - hist_small
@@ -1161,7 +1222,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                           best=best, tree=tree,
                           leaf_min=leaf_min, leaf_max=leaf_max, part=part,
                           cegb=cegb_state, force_aborted=force_aborted,
-                          pool_map=pool_map, hist_rows=hist_rows)
+                          pool_map=pool_map, work=work)
 
     if params.num_forced > 0 and forced is not None:
         nf = min(params.num_forced, l - 1)
@@ -1181,7 +1242,4 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         # lgbm-lint: disable=LGL105 downcast guard: removes f64, never adds
         lambda a: a.astype(jnp.float32) if a.dtype == jnp.float64 else a,
         state.tree)
-    # grown on a bag (which takes no CEGB) the third is the count of rows
-    # the histogram kernel saw, as the frontier grower's is its obs aux
-    return tree_out, leaf_id_out, \
-        (state.cegb if bag is None else state.hist_rows)
+    return Grown(tree_out, leaf_id_out, state.cegb, state.work)

@@ -17,6 +17,7 @@ from . import callback
 from .basic import Booster, Dataset, _InnerPredictor
 from .config import Config
 from .log import Log, LightGBMError
+from .obs.trace import recorder
 
 
 def train(params: Dict[str, Any], train_set: Dataset,
@@ -52,17 +53,22 @@ def train(params: Dict[str, Any], train_set: Dataset,
         raw = (params or {}).get("supervise",
                                  (params or {}).get("supervised", False))
         supervise = str(raw).strip().lower() in ("true", "1", "yes", "+")
-    if supervise:
-        return _train_supervised(
+    # the entry layer's span: its own cost is its duration less its
+    # children's (train.booster_init, train.make_block_fn, train.block,
+    # materialize)
+    with recorder.span("train.engine"):
+        if supervise:
+            return _train_supervised(
+                params, train_set, num_boost_round, valid_sets, valid_names,
+                fobj, feval, init_model, feature_name, categorical_feature,
+                early_stopping_rounds, evals_result, verbose_eval,
+                learning_rates, keep_training_booster, callbacks,
+                resume_from)
+        return _train_once(
             params, train_set, num_boost_round, valid_sets, valid_names,
             fobj, feval, init_model, feature_name, categorical_feature,
             early_stopping_rounds, evals_result, verbose_eval,
             learning_rates, keep_training_booster, callbacks, resume_from)
-    return _train_once(
-        params, train_set, num_boost_round, valid_sets, valid_names, fobj,
-        feval, init_model, feature_name, categorical_feature,
-        early_stopping_rounds, evals_result, verbose_eval, learning_rates,
-        keep_training_booster, callbacks, resume_from)
 
 
 def _train_supervised(params, train_set, num_boost_round, valid_sets,
@@ -140,7 +146,8 @@ def _train_once(params: Dict[str, Any], train_set: Dataset,
 
     if not train_set.params:
         train_set.params = params
-    booster = Booster(params=params, train_set=train_set)
+    with recorder.span("train.booster_init"):
+        booster = Booster(params=params, train_set=train_set)
     is_valid_contain_train = False
     train_data_name = "training"
     if valid_sets is not None:

@@ -21,8 +21,26 @@ trace lands under ``<dir>/plugins/profile/...`` and loads in
 ui.perfetto.dev or TensorBoard.  Capture is process-global in jax, so
 the helper refuses to nest instead of crashing mid-train.
 ``capture_phases(dir)`` reduces such a capture to device seconds by
-``lgbm.*`` scope and idle seconds by host span (``tools/trace_phases.py``
+``lgbm.*`` scope and idle seconds by host span, and says what the rest is:
+leaf events by scope, each device gap by the scope of the op that ended
+before it, and the ten largest ops under no scope (``tools/trace_phases.py``
 prints it).
+
+What a ``train.block`` span counts of the DEVICE's work (``split_rows``,
+``partition_tiles``, ``hist_rows``, ``hist_tiles``: core/grow.py
+``WORK_COUNTS``) is added up in the split loop's state and stays on the
+device; it joins the span when the host fetches the block's trees
+(``materialize``), so a span still never waits. Set-up is under spans from
+the process's start: ``runtime.before_import`` (recorded by
+``lightgbm_tpu/__init__.py`` at the first import of ``basic`` or
+``engine``, ending where the package's own import began),
+``import.basic`` and ``import.engine`` for those two lazy imports, and
+``train.engine`` around ``engine.train`` with
+``train.booster_init`` for the booster's construction. (The backend's own
+start-up has no span: ``jax.monitoring`` of jax 0.9.0 reports the three
+compile phases and no backend-initialisation duration, so it lies inside
+``runtime.before_import`` where a caller touched the device first, else
+inside the first span that does.)
 """
 from __future__ import annotations
 
@@ -218,12 +236,13 @@ def _span_fields(s: _Span) -> Dict:
                 dur_s=round(s.duration_s, 6), failed=s.failed)
 
 
-def record_span(name: str, duration_s: float, **counts) -> None:
-    """Record a span that has already happened and ends now (the compile
-    hook's: jax reports a duration after the fact). Its parent is the span
-    open on this thread."""
+def record_span(name: str, duration_s: float, ended_ago_s: float = 0.0,
+                **counts) -> None:
+    """Record a span that has already happened and ended ``ended_ago_s``
+    ago: now for the compile hook's (jax reports a duration after the
+    fact). Its parent is the span open on this thread."""
     s = _Span(recorder, name, counts)
-    s.end_ns = time.perf_counter_ns()
+    s.end_ns = time.perf_counter_ns() - int(ended_ago_s * 1e9)
     s.start_ns = s.end_ns - int(duration_s * 1e9)
     stack = _stack()
     if stack:
@@ -435,8 +454,10 @@ def load_capture(trace_dir: str) -> Dict[str, list]:
     """{"devices": [[(op_name, start_ns, dur_ns), ...] per chip],
     "host": [(span name, start_ns, dur_ns), ...]} from the newest
     ``.xplane.pb`` under ``trace_dir``. ``op_name`` is the scoped name the
-    compiled module gives the event's instruction, and the event's own
-    short name (``fusion.243``) where the module gives none."""
+    compiled module gives the event's instruction; where that name holds no
+    ``lgbm.`` scope, the event's own short name before it (``copy.478
+    jit(run_block)/while/body/copy``: the instruction and where it sits),
+    and the short name alone where the module gives none."""
     import bisect
     import os
     from jax.profiler import ProfileData
@@ -467,8 +488,12 @@ def load_capture(trace_dir: str) -> Dict[str, list]:
                 at = bisect.bisect_right(starts, e.start_ns) - 1
                 names = op_names.get(modules[at][2], {}) \
                     if at >= 0 and e.start_ns < modules[at][1] else {}
-                ops.append((names.get(short, short), e.start_ns,
-                            e.duration_ns))
+                full = names.get(short)
+                if full is None:
+                    full = short
+                elif scope_of(full) is None:
+                    full = "%s %s" % (short, full)
+                ops.append((full, e.start_ns, e.duration_ns))
             devices.append(ops)
         elif plane.name == _HOST_PLANE:
             for line in plane.lines:
@@ -497,39 +522,64 @@ def reduce_phases(events: Dict[str, list]) -> Optional[Dict]:
     over the chips in the capture. None when no operation ran on a device
     or NO op carries an ``lgbm.`` scope at all (an executable compiled by
     a build without scopes and loaded from the compile cache shows none):
-    nothing to read is never read as 0."""
+    nothing to read is never read as 0.
+
+    What the remainder is: ``events_by_scope`` counts the leaf events
+    under each scope (seconds divide into calls); ``idle_after_scope`` puts
+    each device gap down to the scope of the op that ended before it
+    (``unscoped`` where it has none: a gap of the device program's own is
+    named by the phase that left it, whatever number the compiler gave its
+    fusion); ``unscoped_ops`` lists the ten largest ops under no scope as
+    [name, seconds, events]."""
     chips = [_leaf_events(dev) for dev in events["devices"]]
     chips = [leaves for leaves in chips if leaves]
     if not chips:
         return None
     busy_ns, unscoped_ns = 0, 0
     scope_ns: Dict[str, int] = {}
+    scope_events: Dict[str, int] = {}
     idle_ns: Dict[str, int] = {}
+    after_ns: Dict[str, int] = {}
+    bare: Dict[str, List[int]] = {}        # unscoped op -> [ns, events]
     for leaves in chips:
-        end = None
+        end, ended = None, None            # ended: scope of the op at `end`
         for op_name, start, dur in leaves:
             scope = scope_of(op_name)
             if scope is None:
                 unscoped_ns += dur
+                seen = bare.setdefault(op_name, [0, 0])
+                seen[0] += dur
+                seen[1] += 1
             else:
                 scope_ns[scope] = scope_ns.get(scope, 0) + dur
+                scope_events[scope] = scope_events.get(scope, 0) + 1
             if end is None or start >= end:
                 if end is not None and start > end:
                     span = _innermost_span(events["host"],
                                            end + (start - end) // 2)
                     idle_ns[span] = idle_ns.get(span, 0) + start - end
+                    after = ended or "unscoped"
+                    after_ns[after] = after_ns.get(after, 0) + start - end
                 busy_ns += dur
-                end = start + dur
+                end, ended = start + dur, scope
             elif start + dur > end:
                 busy_ns += start + dur - end
-                end = start + dur
+                end, ended = start + dur, scope
     if not scope_ns:
         return None
-    per = 1e9 * len(chips)
+    n = len(chips)
+    per = 1e9 * n
     by_size = lambda d: dict(sorted(((k, v / per) for k, v in d.items()),
                                     key=lambda kv: -kv[1]))
+    a_chip = lambda count: count // n if count % n == 0 else count / n
+    largest = sorted(bare.items(), key=lambda kv: -kv[1][0])[:10]
     return {"busy_s": busy_ns / per, "by_scope": by_size(scope_ns),
-            "unscoped_s": unscoped_ns / per, "idle_by_span": by_size(idle_ns)}
+            "unscoped_s": unscoped_ns / per, "idle_by_span": by_size(idle_ns),
+            "events_by_scope": {k: a_chip(scope_events[k])
+                                for k in by_size(scope_ns)},
+            "idle_after_scope": by_size(after_ns),
+            "unscoped_ops": [[name, ns / per, a_chip(count)]
+                             for name, (ns, count) in largest]}
 
 
 def _innermost_span(host: Iterable[Event], t: int) -> str:
@@ -545,5 +595,7 @@ def capture_phases(trace_dir: str) -> Optional[Dict]:
     """One ``jax.profiler`` capture as a table: ``busy_s``, ``by_scope``
     (device seconds under each ``lgbm.*`` scope), ``unscoped_s`` and
     ``idle_by_span`` (device gaps by the innermost ``lgbm.*`` host span
-    their middle falls in)."""
+    their middle falls in); and, of what those leave over,
+    ``events_by_scope``, ``idle_after_scope`` and ``unscoped_ops``
+    (reduce_phases)."""
     return reduce_phases(load_capture(trace_dir))

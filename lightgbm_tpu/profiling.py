@@ -71,7 +71,10 @@ _c_cache_miss = get_registry().counter(
 
 # event -> (span name, seconds counter): each becomes a recorded span under
 # the program span open on the compiling thread, with jax's ``fun_name``
-# kept, so a compile has a name and a block
+# kept, so a compile has a name and a block. These three are every duration
+# jax 0.9.0 reports besides the cache's two (looked for at PR 38: no
+# backend-initialisation event exists, so the chip runtime's start-up has
+# no span of its own; a later jax that reports one gets a row here)
 _COMPILE_PHASES = {
     _TRACE_EVENT: ("jax.trace", _c_trace_secs),
     _LOWER_EVENT: ("jax.lower", _c_lower_secs),

@@ -73,9 +73,11 @@ def grow(X, y, params, rounds, weight=None):
             it, code = gbdt.last_bag
             bags[it] = np.asarray(code)
     trees = ref.parse_trees(bst.model_to_string())
+    # every block counts its trees' work; the sampled ones' are read here
     hist_rows = {s["counts"]["start_iter"]: s["counts"]["hist_rows"]
                  for s in trace.recorded_spans()[-4 * rounds:]
-                 if s["name"] == "train.block" and "hist_rows" in s["counts"]}
+                 if s["name"] == "train.block"
+                 and s["counts"].get("goss_active") == 1}
     return bst, trees, bags, hist_rows
 
 

@@ -321,6 +321,79 @@ def test_reduce_phases_on_a_synthetic_event_list():
     assert two["by_scope"] == pytest.approx(got["by_scope"])
 
 
+# what the parent of PR 38 reduced REMAINDER_EVENTS to, key for key: the
+# reduction gained three tables and the four it had read as they did
+REMAINDER_BEFORE = (
+    '{"busy_s": 1.63e-06, "by_scope": {"lgbm.score_update": 5e-07, '
+    '"lgbm.partition_scatter": 4e-07, "lgbm.hist_tile": 3e-07, '
+    '"lgbm.row_gather": 1e-07, "lgbm.leaf_ids": 7e-08, '
+    '"lgbm.exchange": 5e-08, "lgbm.split_search": 5e-08}, '
+    '"unscoped_s": 1.6e-07, "idle_by_span": {"train.block_dispatch": 5e-07, '
+    '"train.block": 7e-08}}')
+REMAINDER_EVENTS = {"devices": [[
+    ("jit(run_block)/while", 0, 1000),
+    ("jit(run_block)/while/body/lgbm.row_gather/gather", 0, 100),
+    ("jit(run_block)/while/body/lgbm.hist_tile/pallas_call", 100, 300),
+    ("jit(run_block)/while/body/lgbm.split_search/lgbm.exchange/psum",
+     400, 50),
+    ("jit(run_block)/while/body/vmap(lgbm.split_search)/reduce_max", 450, 50),
+    ("jit(run_block)/while/body/lgbm.partition_scatter/scatter", 500, 400),
+    ("fusion.7", 900, 100),               # no scope, and the module has none
+    # a gap of 500 after it, then an op that leaves a gap of 10 behind
+    ("jit(run_block)/lgbm.score_update/add", 1500, 500),
+    # no scope, as load_capture names it: the instruction and where it sits
+    ("copy.478 jit(run_block)/while/body/copy", 2010, 30),
+    ("copy.478 jit(run_block)/while/body/copy", 2100, 30),
+    ("jit(run_block)/lgbm.leaf_ids/scatter", 2130, 70)]],
+    "host": [("train.block", 0, 5000), ("train.block_dispatch", 900, 800)]}
+
+
+def test_reduce_phases_says_what_the_remainder_is():
+    got = trace.reduce_phases(REMAINDER_EVENTS)
+    before = json.loads(REMAINDER_BEFORE)
+    assert json.dumps({k: got[k] for k in before}) == REMAINDER_BEFORE
+    assert sorted(set(got) - set(before)) == [
+        "events_by_scope", "idle_after_scope", "unscoped_ops"]
+    # leaf events, so seconds divide into calls; in by_scope's order
+    assert got["events_by_scope"] == {s: 1 for s in got["by_scope"]}
+    assert list(got["events_by_scope"]) == list(got["by_scope"])
+    # a gap goes to the scope of the op that ended before it
+    assert got["idle_after_scope"] == pytest.approx(
+        {"unscoped": 560e-9, "lgbm.score_update": 10e-9})
+    assert sum(got["idle_after_scope"].values()) == pytest.approx(
+        sum(got["idle_by_span"].values()))
+    assert got["unscoped_ops"] == [
+        ["fusion.7", pytest.approx(100e-9), 1],
+        ["copy.478 jit(run_block)/while/body/copy", pytest.approx(60e-9), 2]]
+    # two chips: seconds and events are a chip's average
+    two = trace.reduce_phases(dict(REMAINDER_EVENTS,
+                                   devices=REMAINDER_EVENTS["devices"] * 2))
+    assert two["events_by_scope"] == got["events_by_scope"]
+    assert two["idle_after_scope"] == pytest.approx(got["idle_after_scope"])
+    assert two["unscoped_ops"][1][1:] == [pytest.approx(60e-9), 2]
+    # only the ten largest unscoped ops are listed
+    many = [("fusion.%d" % i, 100 * i, 10 + i) for i in range(40)] \
+        + [("jit(f)/lgbm.leaf_ids/scatter", 9000, 5)]
+    top = trace.reduce_phases({"devices": [many], "host": []})["unscoped_ops"]
+    assert [name for name, _, _ in top] == [
+        "fusion.%d" % i for i in range(39, 29, -1)]
+
+
+def test_trace_phases_tool_prints_the_three_new_tables():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "trace_phases.py")
+    spec = importlib.util.spec_from_file_location("trace_phases_tool", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    text = tool.table(trace.reduce_phases(REMAINDER_EVENTS))
+    assert "idle after an op of scope" in text
+    assert "copy.478 jit(run_block)/while/body/copy" in text
+    row = [ln for ln in text.splitlines() if ln.startswith("lgbm.hist_tile")]
+    assert row and row[0].split()[-1] == "1"       # its events
+
+
 def test_reduce_phases_reads_no_scope_at_all_as_nothing():
     bare = [("fusion.243", 0, 100), ("fusion.236", 100, 50)]
     assert trace.reduce_phases({"devices": [bare], "host": []}) is None

@@ -54,7 +54,7 @@ def test_partition_matches_masked(num_leaves, chunk):
         p = GrowParams(num_leaves=num_leaves, num_bins=b, max_depth=-1,
                        split=_split_params(), row_chunk=chunk,
                        hist_impl="scatter", use_partition=mode)
-        t, li, _ = jax.jit(functools.partial(grow_tree, params=p))(
+        t, li = jax.jit(lambda *a: grow_tree(*a, params=p)[:2])(
             jnp.asarray(xb), jnp.asarray(grad), jnp.asarray(hess),
             jnp.asarray(mask), meta, fm)
         out[mode] = (jax.tree.map(np.asarray, t), np.asarray(li))
@@ -348,7 +348,7 @@ def test_partition_window_placement_matches_scatter_path():
         p = GrowParams(num_leaves=15, num_bins=b, max_depth=-1,
                        split=_split_params(), row_chunk=1024,
                        hist_impl=impl, use_partition=True)
-        t_, li, _ = jax.jit(functools.partial(grow_tree, params=p))(
+        t_, li = jax.jit(lambda *a: grow_tree(*a, params=p)[:2])(
             jnp.asarray(xb), jnp.asarray(grad), jnp.asarray(hess),
             jnp.asarray(mask), meta, fm)
         out[impl] = (jax.tree.map(np.asarray, t_), np.asarray(li))

@@ -7,7 +7,9 @@
 read by ``lightgbm_tpu.obs.trace.capture_phases``. Device busy time is the
 union of the leaf events of ``XLA Ops``; each op counts under the LAST
 ``lgbm.`` component of its scoped name; an idle gap counts under the
-innermost ``lgbm.*`` host span its middle falls in. A capture in which no op
+innermost ``lgbm.*`` host span its middle falls in, and again under the
+scope of the op that ended before it; the ten largest ops under no scope
+are listed by instruction and by where they sit. A capture in which no op
 carries a scope (a CPU capture, or an executable that a build without scopes
 compiled and the compile cache handed back) prints that, and exits 1.
 """
@@ -21,18 +23,25 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def table(phases) -> str:
     busy = phases["busy_s"]
-    rows = [("scope", "device_s", "% of busy")]
-    rows += [(k, "%.6f" % v, "%.2f" % (100.0 * v / busy))
+    events = phases["events_by_scope"]
+    rows = [("scope", "device_s", "% of busy", "events")]
+    rows += [(k, "%.6f" % v, "%.2f" % (100.0 * v / busy), "%g" % events[k])
              for k, v in phases["by_scope"].items()]
     rows.append(("(unscoped)", "%.6f" % phases["unscoped_s"],
-                 "%.2f" % (100.0 * phases["unscoped_s"] / busy)))
-    rows.append(("busy (union)", "%.6f" % busy, "100.00"))
-    rows.append(("", "", ""))
-    rows.append(("idle under host span", "idle_s", ""))
-    rows += [(k, "%.6f" % v, "") for k, v in phases["idle_by_span"].items()]
-    width = [max(len(r[i]) for r in rows) for i in range(3)]
-    return "\n".join("  ".join(c.ljust(w) for c, w in zip(r, width)).rstrip()
-                     for r in rows)
+                 "%.2f" % (100.0 * phases["unscoped_s"] / busy), ""))
+    rows.append(("busy (union)", "%.6f" % busy, "100.00", ""))
+    for title, key in (("idle under host span", "idle_by_span"),
+                       ("idle after an op of scope", "idle_after_scope")):
+        rows += [("", "", "", ""), (title, "idle_s", "", "")]
+        rows += [(k, "%.6f" % v, "", "") for k, v in phases[key].items()]
+    width = [max(len(r[i]) for r in rows) for i in range(4)]
+    lines = ["  ".join(c.ljust(w) for c, w in zip(r, width)).rstrip()
+             for r in rows]
+    # an unscoped op's name is its instruction and where it sits: long
+    lines += ["", "largest ops under no scope: device_s, events, name"]
+    lines += ["%.6f  %6g  %s" % (v, count, name)
+              for name, v, count in phases["unscoped_ops"]]
+    return "\n".join(lines)
 
 
 def main(argv=None) -> int:
