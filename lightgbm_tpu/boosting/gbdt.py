@@ -36,8 +36,8 @@ from ..log import Log, LightGBMError, check
 from ..io.dataset import BinnedDataset
 from ..io.binning import BinType, MissingType as BinMissingType
 from ..core.split import FeatureMeta, SplitParams
-from ..core.grow import (WORK_COUNTS, WORK_LIMB, GrowParams, TreeArrays,
-                         empty_tree, grow_tree)
+from ..core.grow import (EXCHANGE_COUNTS, WORK_COUNTS, WORK_LIMB,
+                         GrowParams, TreeArrays, empty_tree, grow_tree)
 from ..core import partition as partition_mod
 from ..core.pack import pack_trees, unpack_tree
 from ..core import replay as replay_mod
@@ -46,6 +46,20 @@ from ..objectives import ObjectiveFunction
 from ..metrics import Metric
 from ..resilience import faults as _faults
 from ..obs.trace import record_span, recorder
+
+
+def _work_counts(work) -> Dict[str, int]:
+    """A block's work counts (grow.Grown ``work``: [..., 2 x chips, width]
+    int32 limbs, a mesh's chips stacked) by name: the rows and tiles summed
+    over the chips, the exchange as one chip issued it (every chip issues
+    the same collectives)."""
+    a = np.asarray(work, np.int64)
+    limbs = a.reshape(-1, a.shape[-2] // 2, 2, a.shape[-1]).sum(axis=0)
+    counts = limbs[:, 1] * WORK_LIMB + limbs[:, 0]    # [chips, width]
+    w = len(WORK_COUNTS)
+    out = dict(zip(WORK_COUNTS, counts[:, :w].sum(axis=0).tolist()))
+    out.update(zip(EXCHANGE_COUNTS, counts[0, w:].tolist()))
+    return out
 
 
 class HostTree:
@@ -325,6 +339,14 @@ class GBDT:
                 span.counts["score_update_gather_free"] = int(
                     p.num_leaves <= tree_mod.SELECT_LOOKUP_MAX)
                 span.counts["hist_smaller_child"] = int(exact_part)
+                # the chips of the mesh, and whether the exact grower's
+                # counts over them are the row partition's integers
+                # (psummed as int32) and not a float32 histogram's
+                span.counts["mesh_chips"] = (
+                    0 if self.mesh is None else int(self.mesh.devices.size))
+                span.counts["mesh_exact_counts"] = int(
+                    exact_part and p.partition_on_mesh
+                    and p.all_rows_in_bag)
                 # GOSS: whether the row partition starts from the bag, and
                 # the bag's static size (the others' draw is exact, so its
                 # capacity is its count)
@@ -577,17 +599,21 @@ class GBDT:
         # the host side of the transfer: no barrier waits for the device
         with recorder.span("train.device_put_bins",
                            bytes=0 if streamed else xb_np.nbytes):
-            self.xb = None if streamed else jnp.asarray(xb_np)
             self._fp_capture = None
+            if streamed:
+                self.xb = None
+            elif self.mesh is not None and not self._explicit_fp:
+                # each chip's rows go from the host straight to that chip
+                self.xb = jax.device_put(
+                    xb_np, mesh_mod.feature_sharding(self.mesh))
+            else:
+                self.xb = jnp.asarray(xb_np)
             if self._explicit_fp:
                 # xb stays replicated (every FP worker holds the full data,
                 # like the reference's feature-parallel machines); each
                 # device additionally gets its own column slice for
                 # histogram work
                 self._fp_capture = self._setup_feature_parallel(xb_np)
-            elif self.mesh is not None and self.xb is not None:
-                self.xb = jax.device_put(
-                    self.xb, mesh_mod.feature_sharding(self.mesh))
         with recorder.span("train.objective_init"):
             if self.objective is not None:
                 self.objective.init(ds.metadata, ds.num_data)
@@ -883,6 +909,13 @@ class GBDT:
         self._rng = np.random.RandomState(cfg.feature_fraction_seed)
         self._bag_key = jax.random.PRNGKey(cfg.bagging_seed)
         self._bag_mask = jnp.ones((n,), jnp.float32)
+        if self.mesh is not None and not streamed:
+            # where the block hands them back (rows over the data axis, the
+            # latch replicated), or its second call compiles it again
+            self._bag_mask = jax.device_put(
+                self._bag_mask, mesh_mod.row_sharding(self.mesh))
+            self._stopped_dev = jax.device_put(
+                self._stopped_dev, mesh_mod.replicated(self.mesh))
         # group-aware bagging: under a ranking objective, bagging samples
         # whole QUERY GROUPS — one uniform per query broadcast to its rows
         # — never fractions of a query (a partial query corrupts every
@@ -1360,11 +1393,13 @@ class GBDT:
                     from ..core.grow import CegbState
 
                     def _grow_core_cegb(xbj, gj, hj, mj, mt, fm, cs):
-                        return grow_tree(xbj, gj, hj, mj, mt, fm, params,
-                                         axis_name=DATA_AXIS,
-                                         forced=forced_splits, cegb=cs)[:3]
+                        return tuple(grow_tree(xbj, gj, hj, mj, mt, fm,
+                                               params, axis_name=DATA_AXIS,
+                                               forced=forced_splits,
+                                               cegb=cs))
                     # acquisition state: per-feature fields replicated,
-                    # lazy per-row accounting sharded with the rows
+                    # lazy per-row accounting sharded with the rows; each
+                    # chip's work counts stacked over the data axis
                     cegb_specs = CegbState(
                         coupled_penalty=P(), lazy_penalty=P(),
                         feature_used=P(), row_used=P(None, DATA_AXIS))
@@ -1373,30 +1408,39 @@ class GBDT:
                         mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS),
                                              P(DATA_AXIS), P(DATA_AXIS),
                                              meta_specs, P(), cegb_specs),
-                        out_specs=(tree_spec, P(DATA_AXIS), cegb_specs),
+                        out_specs=(tree_spec, P(DATA_AXIS), cegb_specs,
+                                   P(DATA_AXIS)),
                         check_vma=False)
 
                     def grow_one(gk, hk, cs):
                         return grow_cegb(xb, gk, hk, sample_mask, meta,
-                                         feature_mask, cs) + (None,)
+                                         feature_mask, cs)
                 else:
+                    # over each chip's row partition the tree's work counts
+                    # leave beside it, a chip's a row
                     def _grow_core(xbj, gj, hj, mj, mt, fm):
-                        return grow_tree(xbj, gj, hj, mj, mt, fm, params,
-                                         axis_name=DATA_AXIS,
-                                         forced=forced_splits)[:2]
+                        grown = grow_tree(xbj, gj, hj, mj, mt, fm, params,
+                                          axis_name=DATA_AXIS,
+                                          forced=forced_splits)
+                        return grown[:2] + ((grown.work,) if with_work
+                                            else ())
                 if not has_cegb:
+                    with_work = (params.partition_on_mesh
+                                 and grow_batched_fn is None)
                     grow_sharded = shard_map(
                         _grow_core,
                         mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS),
                                              P(DATA_AXIS), P(DATA_AXIS),
                                              meta_specs, P()),
-                        out_specs=(tree_spec, P(DATA_AXIS)),
+                        out_specs=(tree_spec, P(DATA_AXIS))
+                        + ((P(DATA_AXIS),) if with_work else ()),
                         check_vma=False)
 
                     def grow_one(gk, hk, cs):
-                        t, li = grow_sharded(xb, gk, hk, sample_mask, meta,
-                                             feature_mask)
-                        return t, li, None, None
+                        out = grow_sharded(xb, gk, hk, sample_mask, meta,
+                                           feature_mask)
+                        return out[0], out[1], None, \
+                            out[2] if with_work else None
             elif grow_batched_fn is not None:
                 def grow_one(gk, hk, cs):
                     return grow_batched_fn(xb, gk, hk, sample_mask, meta,
@@ -2556,10 +2600,7 @@ class GBDT:
             # a span never waits for the device: the counts its block made
             # there join it here, where the host fetches the trees
             if p.get("work") is not None:
-                limbs = np.asarray(p["work"], np.int64).reshape(
-                    -1, 2, len(WORK_COUNTS)).sum(axis=0)
-                p["span"].counts.update(zip(
-                    WORK_COUNTS, (limbs[1] * WORK_LIMB + limbs[0]).tolist()))
+                p["span"].counts.update(_work_counts(p["work"]))
         row = 0
         with_cat = self.grow_params.with_categorical > 0
         for p in pend:

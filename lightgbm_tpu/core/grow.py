@@ -26,7 +26,8 @@ TPU-native re-design of SerialTreeLearner::Train
 - Data-parallel training (data_parallel_tree_learner.cpp:146-245) falls out
   of the same code: when ``axis_name`` is set, histograms and root sums are
   psum-reduced over the mesh axis — the ReduceScatter+best-split-sync dance
-  collapses into XLA collectives.
+  collapses into XLA collectives. Over the row partition a leaf's count is
+  the int32 psum of the chips' partition counts.
 """
 from __future__ import annotations
 
@@ -284,11 +285,13 @@ class _GrowState(NamedTuple):
 
 class Grown(NamedTuple):
     """What grow_tree returns. ``work`` is the tree's work counts where it
-    grew over the single-device row partition, else None: an int32
+    grew over the row partition, else None: an int32
     [2, len(WORK_COUNTS)] array, a count being
     ``work[1] * WORK_LIMB + work[0]`` (two limbs: a lopsided tree splits
     (L - 1) x N rows, 9e9 at 35.4M rows and 255 leaves, and int32 is what
-    the device has)."""
+    the device has). On a mesh it is this chip's, [2, len(WORK_COUNTS) +
+    len(EXCHANGE_COUNTS)]: its own rows and tiles, and the exchange, which
+    every chip issues alike."""
     tree: TreeArrays
     leaf_id: jnp.ndarray
     cegb: Optional[CegbState]
@@ -307,6 +310,11 @@ WORK_LIMB = 1 << _LIMB_BITS
 # capped pool, each leaf's whose histogram a miss built again. A count a
 # grower has no meaning for is absent, never 0.
 WORK_COUNTS = ("split_rows", "partition_tiles", "hist_rows", "hist_tiles")
+# Over the row partition on a mesh, what a chip adds to the exchange, counted
+# as the collectives are traced (a split issues the same ones, live or
+# dead): ``exchanges``, the collectives issued; ``exchange_bytes``, the
+# payload a chip puts into them, an all-reduce's operand.
+EXCHANGE_COUNTS = ("exchanges", "exchange_bytes")
 
 
 def _tiles(rows, chunk: int):
@@ -601,11 +609,16 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         fmask_local = jnp.where(
             gofl >= 0, feature_mask[jnp.maximum(gofl, 0)], False)
 
+    # the payload bytes of each collective psum issues, as it is traced:
+    # the split loop's state counts them (EXCHANGE_COUNTS)
+    exchanged = []
+
     def psum(x):
         # fp mode: histograms are per-device partial WORK, not partial
         # sums — nothing to reduce (rows are replicated)
         if fp_mode or axis_name is None:
             return x
+        exchanged.append(x.size * x.dtype.itemsize)
         return _psum(x, axis_name)
 
     # CEGB's lazy acquisition accounting reads leaf_id during growth; only
@@ -725,8 +738,10 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                            and not maintain_lid
                            and bins_by_col is not None), \
         "a bag is the single-device row partition's, routed in row space"
+    # (on a mesh: each chip's, psummed as int32)
     exact_counts = ((params.all_rows_in_bag or bag is not None)
-                    and use_partition and axis_name is None)
+                    and use_partition)
+    chips = 1 if axis_name is None else lax.psum(1, axis_name)   # static
     part0 = None
     if bag is not None:
         zeros_l = jnp.zeros((l,), jnp.int32)
@@ -763,7 +778,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                   sp.max_delta_step)),
         leaf_weight=tree.leaf_weight.at[0].set(root_h),
         leaf_count=tree.leaf_count.at[0].set(
-            (jnp.int32(n) if bag is None else bag.leaf_count[0])
+            (jnp.int32(n * chips) if bag is None else bag.leaf_count[0])
             if exact_counts else count_i32(root_c)))
 
     root_pen = cegb_gain_penalty(cegb, root_c, sample_mask)
@@ -820,12 +835,14 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         return jnp.where(missed, s.part.leaf_count[leaf_idx], 0)
 
     # the work counts (WORK_COUNTS) start from the root's pass: every row,
-    # or the bag
+    # or the bag; on a mesh this chip's rows and the root's exchange
     work0 = None
-    if use_partition and axis_name is None:
+    if use_partition:
         root_rows = n if bag is None else bag.leaf_count[0]
-        work0 = _add_work(jnp.zeros((2, len(WORK_COUNTS)), jnp.int32),
-                          (0, 0, root_rows, 0))
+        counts = (0, 0, root_rows, 0)
+        if axis_name is not None:
+            counts += (len(exchanged), sum(exchanged))
+        work0 = _add_work(jnp.zeros((2, len(counts)), jnp.int32), counts)
 
     leaf_id0 = jnp.zeros((n,), jnp.int32)
     if bag is not None:
@@ -837,8 +854,9 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     if part0 is not None and axis_name is not None:
         # same pcast story as leaf_id0: starts constant, becomes a function
         # of the device-local rows
-        part0 = jax.tree.map(
-            lambda a: lax.pcast(a, (axis_name,), to="varying"), part0)
+        part0, work0 = jax.tree.map(
+            lambda a: lax.pcast(a, (axis_name,), to="varying"),
+            (part0, work0))
     state = _GrowState(leaf_id=leaf_id0, hist_pool=hist_pool,
                        best=best, tree=tree,
                        leaf_min=jnp.full((l,), -jnp.inf, hdt),
@@ -898,6 +916,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
     def step(t: jnp.ndarray, s: _GrowState,
              with_forced: bool = False) -> _GrowState:
+        step_exchanges = len(exchanged)
         tree = s.tree
         leaf = jnp.argmax(s.best.gain).astype(jnp.int32)
         cur = jax.tree.map(lambda a: a[leaf], s.best)
@@ -999,6 +1018,9 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         if exact_counts:
             left_count = part.leaf_count[leaf]
             right_count = part.leaf_count[right_leaf]
+            if axis_name is not None:
+                both = psum(jnp.stack([left_count, right_count]))
+                left_count, right_count = both[0], both[1]
         else:
             left_count = count_i32(cur.left_count)
             right_count = count_i32(cur.right_count)
@@ -1083,10 +1105,12 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 walked.append(rebuilt_rows(s, leaf, valid))
                 if with_forced:
                     walked.append(rebuilt_rows(s, fleaf, in_phase))
-            work = _add_work(work, (
+            split_work = (
                 split_rows, _tiles(split_rows, params.row_chunk),
                 sum(walked),
-                sum(_tiles(r, params.row_chunk) for r in walked)))
+                sum(_tiles(r, params.row_chunk) for r in walked))
+            if axis_name is None:
+                work = _add_work(work, split_work)
         with jax.named_scope("lgbm.hist_subtract"):
             hist_parent = leaf_hist(s, leaf, live=valid)
             hist_large = hist_parent - hist_small
@@ -1217,6 +1241,11 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             lambda arr, vl, vr: _masked_set(_masked_set(arr, leaf, vl, valid),
                                             right_leaf, vr, valid),
             s.best, bl, br)
+        if work is not None and axis_name is not None:
+            # the step's whole exchange, counted where it was traced
+            step_bytes = exchanged[step_exchanges:]
+            work = _add_work(work, split_work + (len(step_bytes),
+                                                 sum(step_bytes)))
 
         return _GrowState(leaf_id=leaf_id, hist_pool=hist_pool,
                           best=best, tree=tree,
