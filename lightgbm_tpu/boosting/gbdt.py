@@ -339,6 +339,11 @@ class GBDT:
                 span.counts["score_update_gather_free"] = int(
                     p.num_leaves <= tree_mod.SELECT_LOOKUP_MAX)
                 span.counts["hist_smaller_child"] = int(exact_part)
+                # whether the histograms go through the Pallas kernels,
+                # whose tile / root kernel contracts a feature in one MXU
+                # pass (histogram_pallas._hist_kernel)
+                span.counts["hist_one_pass"] = int(
+                    p.hist_impl.startswith("pallas"))
                 # the chips of the mesh, and whether the exact grower's
                 # counts over them are the row partition's integers
                 # (psummed as int32) and not a float32 histogram's

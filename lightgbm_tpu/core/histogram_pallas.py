@@ -8,21 +8,26 @@ digits, b = 16*hi + lo. The [B]-wide one-hot comparison then factorizes into
 two 16-wide one-hots whose *outer product* is the full one-hot — and the
 outer-product contraction over rows is exactly a matmul:
 
-    hist[k, hi, lo] = sum_c (vals[k, c] * eqhi[hi, c]) * eqlo[c, lo]
+    hist[hi, (k, lo)] = sum_c eqhi[hi, c] * (vals[k, c] * eqlo[lo, c])
 
-so the bin axis is materialized by the MXU as a [3*Hi, C] @ [C, 16] product
-instead of by N*F*B vector comparisons; the VPU only builds N*F*(Hi+16)
-comparisons. All intermediates live in VMEM: per-pass HBM traffic is just
-xb (N*F bytes) + vals (12N bytes) + the [3, F, B] output.
+so the bin axis is materialized by the MXU instead of by N*F*B vector
+comparisons. The tile / root kernel (_hist_kernel) streams the Hi-row hi
+one-hot against the values on the wide side, [Hi, C] @ [C, 2*K*16]: one
+MXU pass a feature. All intermediates live in VMEM: per-pass HBM traffic
+is just xb (N*F bytes) + the value words (4*K*N bytes) + the output.
 
-Precision: the values operand is split into two bfloat16 terms
-(a = hi16(a) + lo16(a)) and contracted with the exactly-representable
-one-hot in two default-precision MXU passes. Per-ELEMENT error is
-~|v|*2^-17; summed over a bin this lands within ~3e-6 of float64 relative
-to the bin's sum of |values| (measured), though a bin whose gradients
-nearly cancel can see a larger error relative to its small net sum — same
-caveat as any fixed-precision accumulation, and the same stance as the GPU
-learner's single-precision histograms (gpu_tree_learner.h:74-78).
+Precision: each value is split into two bfloat16 terms
+(v = top + rem, split_words: once a row, not once a feature) and both
+terms are contracted against the exactly-representable one-hots in
+default precision, their two float32 sums then added. Per-ELEMENT error
+is ~|v|*2^-17; summed over a bin this lands within ~3e-6 of float64
+relative to the bin's sum of |values| (measured), though a bin whose
+gradients nearly cancel can see a larger error relative to its small net
+sum — same caveat as any fixed-precision accumulation, and the same
+stance as the GPU learner's single-precision histograms
+(gpu_tree_learner.h:74-78). The slot kernels keep the values on the
+streamed side and contract the two terms in two passes
+(_digit_contract).
 
 Grid = (feature_tiles, row_tiles); rows are the innermost sequential
 reduction so each feature tile's accumulator stays resident in VMEM across
@@ -78,11 +83,11 @@ def _slot_compiler_params(out_block: tuple, row_tile: int,
 
 
 def _digit_contract(a, eq):
-    """Shared MXU contraction of every digit kernel in this file:
-    [M, C] values-by-digit LHS against a [Nw, C] one-hot RHS, contracted
-    over rows. The values operand is split into two bfloat16 terms — the
-    one-hot side is exactly representable, so two default-precision
-    passes land within ~3e-6 of f32."""
+    """MXU contraction of the slot kernels: [M, C] values-by-digit LHS
+    against a [Nw, C] one-hot RHS, contracted over rows. The values
+    operand is split into two bfloat16 terms — the one-hot side is
+    exactly representable, so two default-precision passes land within
+    ~3e-6 of f32."""
     a_top = a.astype(jnp.bfloat16)
     a_rem = (a - a_top.astype(jnp.float32)).astype(jnp.bfloat16)
     eqb = eq.astype(jnp.bfloat16)
@@ -94,18 +99,53 @@ def _digit_contract(a, eq):
         preferred_element_type=jnp.float32)
 
 
-def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int):
-    """One (feature_tile, row_tile) grid cell.
+def _bf16_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 whose high half is bf16(x) (round to nearest
+    even) and whose low half is zero, by integer arithmetic. A convert
+    to bfloat16 and back inside one XLA:TPU fusion may keep the float32
+    value (excess precision), which made every rem term 0 on the chip."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return (b + 0x7FFF + (b >> 16 & 1)) & jnp.uint32(0xFFFF0000)
 
-    xb_ref: [Ft, C] uint8 binned values; vals_ref: [K, C] f32 value
-    channels (K = 3: grad*mask, hess*mask, mask; the cost is linear in K);
-    out_ref: [K, Ft, Hi, 16] f32 accumulator.
+
+def split_words(vals: jnp.ndarray) -> jnp.ndarray:
+    """[K, N] f32 -> [K, N] int32: each value's two bfloat16 terms in one
+    word, ``top = bf16(v)`` in the high half and ``rem = bf16(v - top)``
+    in the low half: the split _digit_contract makes, made once a row and
+    not once a feature."""
+    top = _bf16_bits(vals)
+    rem = _bf16_bits(vals - jax.lax.bitcast_convert_type(top, jnp.float32))
+    return jax.lax.bitcast_convert_type(top | rem >> 16, jnp.int32)
+
+
+def _word_half(words: jnp.ndarray) -> jnp.ndarray:
+    """int32 words whose low half is zero -> the bfloat16 they hold."""
+    return jax.lax.bitcast_convert_type(words, jnp.float32).astype(
+        jnp.bfloat16)
+
+
+def _hist_kernel(xb_ref, w_ref, out_ref, *, hi_n: int):
+    """One (feature_tile, row_tile) grid cell, one MXU pass a feature.
+
+    xb_ref: [Ft, C] uint8 binned values; w_ref: [K, C] int32 value words
+    (split_words; K = 3: grad*mask, hess*mask, mask, and the cost is
+    linear in K); out_ref: [Ft, K*16, Hi] f32 accumulator, rows (k, lo).
+
+    The Hi-row hi-digit one-hot is the streamed operand; the values go on
+    the wide side, masked by the lo digit as whole words (one int32
+    select), each word's halves then widened to the float32 they are and
+    converted to bfloat16 exactly, the tops' K*16 rows over the rems':
+
+        hist[hi, (t, k, lo)] = sum_c eqhi[hi, c] * (v_t[k, c] * eqlo[lo, c])
+
+    The top and rem halves of the [Hi, 2*K*16] product are then added in
+    float32, as the two-pass contraction adds its two products.
     """
     r = pl.program_id(1)
     xb = xb_ref[...].astype(jnp.int32)                       # [Ft, C]
-    vals = vals_ref[...]                                     # [K, C]
+    w = w_ref[...]                                           # [K, C]
     ft, c = xb.shape
-    k = vals.shape[0]
+    m = 16 * w.shape[0]
 
     @pl.when(r == 0)
     def _init():
@@ -115,17 +155,19 @@ def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int):
     iota_hi = jax.lax.broadcasted_iota(jnp.int32, (hi_n, c), 0)
     for j in range(ft):
         x = xb[j:j + 1, :]                                   # [1, C]
-        hi_eq = iota_hi == (x >> 4)                          # [Hi, C]
-        lo_eq = iota_lo == (x & 15)                          # [16, C]
-        a = jnp.where(hi_eq[None, :, :], vals[:, None, :],
-                      0.0).reshape(k * hi_n, c)              # [K*Hi, C]
-        # NB: build the one-hot in f32 and let _digit_contract downcast —
-        # a direct bf16 select on the i1 mask fails in Mosaic ("Invalid
-        # relayout ... vector<16x2048xi1>"; re-tested on a v5e with
-        # jax 0.9.0 / libtpu 0.0.34)
-        eqlo = jnp.where(lo_eq, 1.0, 0.0)
-        part = _digit_contract(a, eqlo)                      # [K*Hi, 16]
-        out_ref[:, j, :, :] += part.reshape(k, hi_n, 16)
+        # NB: a direct bf16 select on the i1 mask fails in Mosaic
+        # ("Invalid relayout ... vector<16x2048xi1>"), so the one-hot is
+        # selected in f32 and converted, exactly
+        eqhi = jnp.where(iota_hi == (x >> 4), 1.0, 0.0).astype(
+            jnp.bfloat16)                                    # [Hi, C]
+        wide = jnp.where((iota_lo == (x & 15))[None], w[:, None, :],
+                         0).reshape(m, c)                    # [K*16, C]
+        wide = jnp.concatenate([_word_half(wide & -65536),
+                                _word_half(wide << 16)])     # [2*K*16, C]
+        part = jax.lax.dot_general(
+            eqhi, wide, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).T            # [2*K*16, Hi]
+        out_ref[j] += part[:m] + part[m:]
 
 
 @functools.partial(jax.jit,
@@ -133,7 +175,7 @@ def _hist_kernel(xb_ref, vals_ref, out_ref, *, hi_n: int):
                                     "interpret", "packed_cols", "row_block"))
 def build_histogram_pallas(xb: jnp.ndarray, grad: jnp.ndarray,
                            hess: jnp.ndarray, mask: jnp.ndarray,
-                           num_bins: int, row_tile: int = 2048,
+                           num_bins: int, row_tile: int = 4096,
                            feature_tile: int = 8,
                            interpret: bool = False,
                            packed_cols: int = 0,
@@ -183,7 +225,7 @@ def build_histogram_pallas(xb: jnp.ndarray, grad: jnp.ndarray,
                    static_argnames=("num_bins", "row_tile", "feature_tile",
                                     "interpret", "packed_cols"))
 def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
-                                num_bins: int, row_tile: int = 2048,
+                                num_bins: int, row_tile: int = 4096,
                                 feature_tile: int = 8,
                                 interpret: bool = False,
                                 packed_cols: int = 0) -> jnp.ndarray:
@@ -197,8 +239,14 @@ def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
         xb = unpack_words(xb, packed_cols, dtype=jnp.int32)
     n, f = xb.shape
     k = vals.shape[0]
-    hi_n = max(1, (num_bins + 15) // 16)   # bins above num_bins stay zero
+    # bins above num_bins stay zero. At least two streamed rows: a one-row
+    # product is a matrix-vector product, which XLA:CPU (the interpreter)
+    # sums in another order than the two-pass contraction's matrix
+    # product; on the chip a row costs what eight do
+    hi_n = max(2, (num_bins + 15) // 16)
 
+    # a pass shorter than a tile pads to 128 rows, not to the tile
+    row_tile = min(row_tile, -(-n // 128) * 128)
     f_pad = (-f) % feature_tile
     n_pad = (-n) % row_tile
     # NB: uint8, not int8 — bins >= 128 must not wrap negative (packed
@@ -206,7 +254,8 @@ def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
     xb_t = jnp.pad(xb.T, ((0, f_pad), (0, n_pad)))
     if not packed_cols:
         xb_t = xb_t.astype(jnp.uint8)
-    vals = jnp.pad(vals, ((0, 0), (0, n_pad)))   # padded rows carry mask 0
+    # padded rows carry the word 0: both terms +0
+    words = jnp.pad(split_words(vals), ((0, 0), (0, n_pad)))
     fp = f + f_pad
 
     kernel = functools.partial(_hist_kernel, hi_n=hi_n)
@@ -217,13 +266,14 @@ def build_histogram_pallas_vals(xb: jnp.ndarray, vals: jnp.ndarray,
             pl.BlockSpec((feature_tile, row_tile), lambda i, r: (i, r)),
             pl.BlockSpec((k, row_tile), lambda i, r: (0, r)),
         ],
-        out_specs=pl.BlockSpec((k, feature_tile, hi_n, 16),
-                               lambda i, r: (0, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, fp, hi_n, 16), jnp.float32),
+        out_specs=pl.BlockSpec((feature_tile, k * 16, hi_n),
+                               lambda i, r: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((fp, k * 16, hi_n), jnp.float32),
         interpret=interpret,
-    )(xb_t, vals)
-    out = out.reshape(k, fp, hi_n * 16)
-    return jnp.moveaxis(out, 0, -1)[:f, :num_bins]           # [F, B, 3]
+    )(xb_t, words)
+    # [F, (k, lo), hi] -> [F, (hi, lo), K]
+    out = jnp.transpose(out.reshape(fp, k, 16, hi_n), (0, 3, 2, 1))
+    return out.reshape(fp, hi_n * 16, k)[:f, :num_bins]
 
 
 def _hist_slot6_kernel(xb_ref, slot_ref, sel_ref, vals_ref, out_ref, *,

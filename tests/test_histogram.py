@@ -190,6 +190,174 @@ def test_pallas_kernel_tile_matches_scatter():
     np.testing.assert_array_equal(pal[:, :, 2], ref[:, :, 2])
 
 
+# The tile / root kernel contracts each feature in one MXU pass. The
+# two-pass formulation it replaced is kept here as the reference: each
+# feature's [K*Hi, C] values-by-hi-digit operand, split into two bfloat16
+# terms in the kernel, contracted against the [16, C] lo one-hot twice.
+def _two_pass_kernel(xb_ref, vals_ref, out_ref, *, hi_n):
+    import jax
+    from jax.experimental import pallas as pl
+    r = pl.program_id(1)
+    xb = xb_ref[...].astype(jnp.int32)
+    vals = vals_ref[...]
+    ft, c = xb.shape
+    k = vals.shape[0]
+
+    @pl.when(r == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (16, c), 0)
+    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (hi_n, c), 0)
+    dims = (((1,), (1,)), ((), ()))
+    for j in range(ft):
+        x = xb[j:j + 1, :]
+        a = jnp.where((iota_hi == (x >> 4))[None], vals[:, None, :],
+                      0.0).reshape(k * hi_n, c)
+        if k * hi_n == 1:
+            # at least two rows a product, as the one-pass kernel streams
+            # (histogram_pallas.build_histogram_pallas_vals): XLA:CPU sums
+            # a matrix-vector product in another order than a matrix one
+            a = jnp.pad(a, ((0, 1), (0, 0)))
+        top = a.astype(jnp.bfloat16)
+        rem = (a - top.astype(jnp.float32)).astype(jnp.bfloat16)
+        eqlo = jnp.where(iota_lo == (x & 15), 1.0, 0.0).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(top, eqlo, dims,
+                                   preferred_element_type=jnp.float32)
+        part = part + jax.lax.dot_general(rem, eqlo, dims,
+                                          preferred_element_type=jnp.float32)
+        out_ref[:, j, :, :] += part[:k * hi_n].reshape(k, hi_n, 16)
+
+
+def _two_pass_hist(xb, vals, num_bins, row_tile, feature_tile):
+    """[N, F] uint8 bins, [K, N] f32 values -> [F, B, K], interpreted."""
+    import functools
+    import jax
+    from jax.experimental import pallas as pl
+    n, f = xb.shape
+    k = vals.shape[0]
+    hi_n = max(1, (num_bins + 15) // 16)
+    f_pad, n_pad = (-f) % feature_tile, (-n) % row_tile
+    xb_t = jnp.pad(jnp.asarray(xb).T, ((0, f_pad), (0, n_pad)))
+    vals = jnp.pad(jnp.asarray(vals), ((0, 0), (0, n_pad)))
+    fp = f + f_pad
+    out = pl.pallas_call(
+        functools.partial(_two_pass_kernel, hi_n=hi_n),
+        grid=(fp // feature_tile, (n + n_pad) // row_tile),
+        in_specs=[pl.BlockSpec((feature_tile, row_tile), lambda i, r: (i, r)),
+                  pl.BlockSpec((k, row_tile), lambda i, r: (0, r))],
+        out_specs=pl.BlockSpec((k, feature_tile, hi_n, 16),
+                               lambda i, r: (0, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, fp, hi_n, 16), jnp.float32),
+        interpret=True)(xb_t, vals)
+    out = jnp.moveaxis(out.reshape(k, fp, hi_n * 16), 0, -1)
+    return np.asarray(out[:f, :num_bins])
+
+
+def _awkward_values(r, k, n):
+    """[K, N] f32 that holds zeros (+0 and -0), negatives, subnormals and
+    values >= 2^20 beside ordinary ones."""
+    v = (r.randn(k, n) * np.exp(2 * r.randn(k, n))).astype(np.float32)
+    v[:, 0::7] = 0.0
+    v[:, 5::19] = -0.0
+    sub = v[:, 1::11]
+    v[:, 1::11] = np.float32(3e-39) * r.randn(*sub.shape)
+    big = v[:, 2::13]
+    v[:, 2::13] = np.float32(2.0 ** 20) * (1 + 4 * r.rand(*big.shape))
+    v[:, 3::17] *= -1
+    return v
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.int32)
+
+
+@pytest.mark.parametrize("k", [1, 3, 6])
+@pytest.mark.parametrize("num_bins", [2, 16, 17, 40, 255, 256])
+def test_one_pass_kernel_equals_two_pass_bit_for_bit(num_bins, k):
+    """1,100 rows (not a multiple of the 512-row tile) x 11 columns (not a
+    multiple of the 8-column feature tile): the one-pass kernel's
+    histograms carry the two-pass formulation's bits, every bin of every
+    channel, on values that are zero, negative, subnormal or >= 2^20.
+    Both sum a bin's terms in the order of the dot's contraction, which
+    the interpreter keeps the same for both where each product has two
+    rows or more."""
+    from lightgbm_tpu.core.histogram_pallas import build_histogram_pallas_vals
+    r = np.random.RandomState(num_bins * 10 + k)
+    n, f = 1100, 11
+    xb = r.randint(0, num_bins, (n, f)).astype(np.uint8)
+    vals = _awkward_values(r, k, n)
+    want = _two_pass_hist(xb, vals, num_bins, 512, 8)
+    got = build_histogram_pallas_vals(
+        jnp.asarray(xb), jnp.asarray(vals), num_bins, row_tile=512,
+        feature_tile=8, interpret=True)
+    assert got.shape == (f, num_bins, k)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("num_bins", [40, 256])
+def test_one_pass_kernel_packed_cols_equals_two_pass(num_bins):
+    """The int32-word-packed bins (core/binpack.py, ``packed_cols``) give
+    the plain bins' histograms, bit for bit against the two-pass
+    formulation on the unpacked matrix."""
+    from lightgbm_tpu.core.binpack import pack_words_np
+    from lightgbm_tpu.core.histogram_pallas import build_histogram_pallas_vals
+    r = np.random.RandomState(num_bins)
+    n, f = 1300, 10
+    xb = r.randint(0, num_bins, (n, f)).astype(np.uint8)
+    vals = _awkward_values(r, 3, n)
+    want = _two_pass_hist(xb, vals, num_bins, 512, 8)
+    got = build_histogram_pallas_vals(
+        jnp.asarray(pack_words_np(xb)), jnp.asarray(vals), num_bins,
+        row_tile=512, feature_tile=8, interpret=True, packed_cols=f)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+def test_split_words_holds_both_bfloat16_terms():
+    """split_words puts bf16(v) (round to nearest even, as ml_dtypes
+    rounds) in a word's high half and bf16(v - top) in its low half, for
+    zeros, negatives and values >= 2^20 alike; each half read back as the
+    kernel reads it (the word masked, or shifted up, then taken as
+    float32) is that term exactly. (XLA flushes a subnormal input to zero
+    where numpy keeps it; the kernel tests above hold those rows to the
+    two-pass formulation.)"""
+    import jax
+    import ml_dtypes
+    from lightgbm_tpu.core.histogram_pallas import split_words
+    v = _awkward_values(np.random.RandomState(0), 8, 128)
+    normal = (v == 0) | (np.abs(v) >= np.finfo(np.float32).tiny)
+    top = v.astype(ml_dtypes.bfloat16)
+    rem = (v - top.astype(np.float32)).astype(ml_dtypes.bfloat16)
+    w = np.asarray(split_words(jnp.asarray(v))).view(np.uint32)
+    np.testing.assert_array_equal((w >> 16)[normal],
+                                  top.view(np.uint16)[normal])
+    np.testing.assert_array_equal((w & 0xFFFF)[normal],
+                                  rem.view(np.uint16)[normal])
+    as_f32 = jax.jit(lambda x: (
+        jax.lax.bitcast_convert_type(x & -65536, jnp.float32),
+        jax.lax.bitcast_convert_type(x << 16, jnp.float32)))
+    hi, lo = map(np.asarray, as_f32(jnp.asarray(w.view(np.int32))))
+    np.testing.assert_array_equal(hi.view(np.uint32), w & 0xFFFF0000)
+    np.testing.assert_array_equal(lo.view(np.uint32), w << 16)
+
+
+@pytest.mark.parametrize("impl,want", [
+    ("scatter", 0), ("matmul", 0), ("auto", 0), ("pallas_interpret", 1)])
+def test_setup_span_counts_hist_one_pass(impl, want):
+    """train.setup's hist_one_pass: 1 where the booster's histograms go
+    through the Pallas kernels, 0 on the XLA paths (``auto`` is the
+    scatter on the CPU)."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs import trace
+    X = np.random.RandomState(0).randn(300, 4)
+    y = (X[:, 0] > 0).astype(float)
+    lgb.Booster({"objective": "binary", "num_leaves": 4, "verbose": -1,
+                 "tpu_hist_impl": impl}, lgb.Dataset(X, y))
+    setup = [s for s in trace.recorded_spans()
+             if s["name"] == "train.setup"][-1]
+    assert setup["counts"]["hist_one_pass"] == want
+
+
 @pytest.mark.parametrize("fake_backend", [
     "cpu", "gpu", "METAL", "neuron", "tpu", "tpu_plugin"])
 def test_tpu_shaped_gate_is_allow_list(monkeypatch, fake_backend):

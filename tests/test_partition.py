@@ -447,8 +447,8 @@ def test_smaller_child_plus_sibling_is_the_parent(impl):
 
 def _pallas_channels(jaxpr):
     """The value-channel count K of every pallas_call under ``jaxpr``: the
-    leading axis of the digit kernel's [K, F, Hi, 16] output."""
-    return [e.outvars[0].aval.shape[0] for e in _eqns_under(jaxpr, "")
+    leading axis of the digit kernel's [K, N] value input."""
+    return [e.invars[1].aval.shape[0] for e in _eqns_under(jaxpr, "")
             if e.primitive.name == "pallas_call"]
 
 
