@@ -319,12 +319,12 @@ class GBDT:
                                rows=train_data.num_data) as span:
                 self._setup_train(train_data)
                 # which placement the exact grower's tile loop is built
-                # with, whether it maps rows to leaves once a tree through
-                # leaf_id_from_partition, which has no gather over all
-                # rows, and whether the kernel sees only the smaller
-                # child's range of a split (all 0 where another grower
-                # runs; the second 0 too where CEGB keeps the leaf ids
-                # split by split)
+                # with, whether it maps rows to leaves with no gather over
+                # all rows (in row space, or once a tree through
+                # leaf_id_from_partition), and whether the kernel sees
+                # only the smaller child's range of a split (all 0 where
+                # another grower runs; the second 0 too where CEGB keeps
+                # the leaf ids split by split)
                 p = self.grow_params
                 exact_part = (p.use_partition and not p.frontier_mode
                               and p.batch_splits == 0)
@@ -357,12 +357,17 @@ class GBDT:
                 # capacity is its count)
                 span.counts["goss_bag_partition"] = int(self._goss_bag)
                 span.counts["replay_kernel"] = int(self._replay_kernel())
+                # whether the exact grower over all rows keeps every row's
+                # leaf id in row space, a routing pass a split
+                span.counts["leaf_ids_row_space"] = int(
+                    self._leaf_ids_row_space)
                 if self._bins_by_col is not None:
-                    # a tree on a bag routes all rows, and a replay of stored
-                    # trees its columns, by the feature-major copy of the bins
+                    # a tree on a bag or over all rows routes all rows, and
+                    # a replay of stored trees its columns, by the
+                    # feature-major copy of the bins
                     if self._goss_bag:
                         span.counts["goss_rowspace_routing"] = 1
-                    else:
+                    elif self.boosting_type == "dart":
                         span.counts["replay_feature_major"] = 1
                     span.counts["rowspace_bins_bytes"] = \
                         self._bins_by_col.nbytes
@@ -724,10 +729,22 @@ class GBDT:
         # iterations' block, as xb is of every block. DART replays its
         # drop set over every row twice an iteration, one contiguous column
         # a node (core/replay.py), from the same copy
+        dart_copy = (self.boosting_type == "dart" and self.mesh is None
+                     and not streamed)
+        # the exact grower over all rows on one device keeps its leaf ids
+        # in row space too, where the copy exists or fits beside the table
+        # (partition.row_space_fits): a routing pass a split, not a scatter
+        # through ``order`` once a tree. A GOSS job's unsampled iterations
+        # stay boosting=gbdt's program; CEGB's lazy cost keeps its ids
+        # split by split
+        self._leaf_ids_row_space = (
+            self.mesh is None and not streamed and not frontier_mode
+            and batch_splits == 0 and not self._goss_bag
+            and not cfg.cegb_penalty_feature_lazy and self.xb.shape[1] > 0
+            and (dart_copy or partition_mod.row_space_fits(self.xb)))
         self._bins_by_col = (
             jax.jit(partition_mod.bins_by_column)(self.xb)
-            if (self._goss_bag or (self.boosting_type == "dart"
-                                   and self.mesh is None and not streamed))
+            if (self._goss_bag or dart_copy or self._leaf_ids_row_space)
             and self.xb.shape[1] > 0 else None)
         # (top_cnt, other_cnt, the others' multiplier) from the REAL row
         # count, not the mesh-padding-inflated one — padded rows carry
@@ -2214,9 +2231,12 @@ class GBDT:
 
     def _rowspace_bins(self) -> Optional[jnp.ndarray]:
         """The last argument of a block: the feature-major bins where its
-        trees grow on a bag, else None (an empty pytree: the unsampled
-        iterations' program stays boosting=gbdt's)."""
-        return self._bins_by_col if self._sampled_regime else None
+        trees route every row in row space (on a bag, or over all rows
+        with ``_leaf_ids_row_space``), else None (an empty pytree: a GOSS
+        job's unsampled iterations keep the row partition's leaf ids)."""
+        if self._sampled_regime or self._leaf_ids_row_space:
+            return self._bins_by_col
+        return None
 
     def _ready_block(self, remaining: int) -> int:
         """Make current, and build if need be, the program the next block

@@ -15,8 +15,9 @@ TPU-native re-design of SerialTreeLearner::Train
   grouped by leaf (core/partition.py): one pass over the split leaf's rows
   partitions its range (DataPartition::Split), a second pass over the
   smaller child's new range builds its histogram through the kernel. The
-  final ``leaf_id`` (reconstructed from the ranges) doubles as the
-  score-update fast path (score_updater.hpp:53-117).
+  final ``leaf_id`` (routed in row space split by split on one device,
+  else reconstructed from the ranges) doubles as the score-update fast
+  path (score_updater.hpp:53-117).
 - The other mesh paths use masked full-data passes with a per-row
   ``leaf_id`` vector.
 - Node numbering matches the reference exactly: splitting leaf ``l`` at step
@@ -266,7 +267,7 @@ class PoolMap(NamedTuple):
 
 
 class _GrowState(NamedTuple):
-    leaf_id: jnp.ndarray      # [N] int32; grown on a bag, the row-space
+    leaf_id: jnp.ndarray      # [N] int32; routed in row space, the
     #                           ids in bins_by_col's row shape
     hist_pool: jnp.ndarray    # [S, F, B, 3] f32 histogram slots (S = L
     #                           uncapped, or pool_slots under the LRU cap)
@@ -566,15 +567,17 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     then computed locally and identically on every device from the
     replicated xb.
 
-    With ``bag`` set (partition.bag_partition; the single-device partition
-    path only): the tree is grown on the bag's rows. ``sample_mask`` is 1
-    on them and 0 elsewhere; the row partition holds the bag alone, so the
-    root's pass, every smaller child's pass and the tree's counts see its
-    rows alone. The rows out of it have no place in ``order``: every split
-    routes ALL rows in row space, one streaming pass over the split column
-    of ``bins_by_col`` (partition.bins_by_column(xb), made once by the
-    caller), so the returned leaf_id covers all rows and
-    leaf_id_from_partition is not run.
+    With ``bins_by_col`` set (partition.bins_by_column(xb), made once by
+    the caller; the single-device partition path only, without CEGB's
+    per-split ids): every split routes ALL rows in row space, one
+    streaming pass over the split column of the copy, so the returned
+    leaf_id covers all rows and leaf_id_from_partition is not run.
+
+    With ``bag`` set (partition.bag_partition; needs ``bins_by_col``): the
+    tree is grown on the bag's rows. ``sample_mask`` is 1 on them and 0
+    elsewhere; the row partition holds the bag alone, so the root's pass,
+    every smaller child's pass and the tree's counts see its rows alone.
+    The rows out of it have no place in ``order``: row space routes them.
     """
     n, ncols = xb.shape                 # stored columns (== F without EFB)
     f = meta.num_bin.shape[0]           # logical features
@@ -623,7 +626,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
     # CEGB's lazy acquisition accounting reads leaf_id during growth; only
     # then is the per-split leaf_id scatter worth its cost — otherwise the
-    # assignment is reconstructed from the final ranges in one dense pass
+    # ids are routed in row space or reconstructed from the final ranges
     maintain_lid = (cegb is not None and params.with_cegb_lazy)
 
     def hist_for_mask(mask_f32, compensated=False):
@@ -732,12 +735,14 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                                        stack_vals(grad, hess, sample_mask),
                                        packed=not params.vmapped_classes)
                        if use_partition else None)
+    # the copy routes the row partition's trees (masked passes keep their
+    # own ids a row)
+    row_space = bins_by_col is not None and use_partition
+    assert not row_space or (axis_name is None and not maintain_lid), \
+        "row space routes the single-device row partition's trees"
+    assert bag is None or row_space, "a bag's rows are routed in row space"
     # the tree's counts come from the partition's integers where those
     # count what the histogram's count channel counts
-    assert bag is None or (use_partition and axis_name is None
-                           and not maintain_lid
-                           and bins_by_col is not None), \
-        "a bag is the single-device row partition's, routed in row space"
     # (on a mesh: each chip's, psummed as int32)
     exact_counts = ((params.all_rows_in_bag or bag is not None)
                     and use_partition)
@@ -845,7 +850,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         work0 = _add_work(jnp.zeros((2, len(counts)), jnp.int32), counts)
 
     leaf_id0 = jnp.zeros((n,), jnp.int32)
-    if bag is not None:
+    if row_space:
         leaf_id0 = row_space_leaf_ids0(bins_by_col, l)
     if axis_name is not None:
         # under shard_map the carry must be marked device-varying up front:
@@ -989,9 +994,9 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 maintain_leaf_id=maintain_lid,
                 windows=window_placement(params.hist_impl,
                                          params.vmapped_classes))
-            if bag is not None:
-                # every row, in the bag or out, takes the split's side in
-                # row space; ``order`` lists the bag's rows alone
+            if row_space:
+                # every row, in a bag or out, takes the split's side in
+                # row space; ``order`` serves the two tile loops alone
                 leaf_id = route_in_row_space(
                     leaf_id, bins_by_col, stored_col, go_left_bins, leaf,
                     right_leaf, valid)
@@ -1184,12 +1189,17 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
         def child_bests(_):
             lp = rp = None
             if cegb_state is not None:
+                def lazy_mask(lf):
+                    # the lazy cost's rows alone read the ids a row
+                    # (maintain_lid: never in row space)
+                    if not params.with_cegb_lazy:
+                        return None
+                    return (leaf_id == lf).astype(jnp.float32) * sample_mask
+
                 lp = cegb_gain_penalty(cegb_state, cur.left_count,
-                                       (leaf_id == leaf)
-                                       .astype(jnp.float32) * sample_mask)
+                                       lazy_mask(leaf))
                 rp = cegb_gain_penalty(cegb_state, cur.right_count,
-                                       (leaf_id == right_leaf)
-                                       .astype(jnp.float32) * sample_mask)
+                                       lazy_mask(right_leaf))
             if voting:
                 bl = best_for(hist_left, cur.left_sum_grad,
                               cur.left_sum_hess, cur.left_count, depth_ok,
@@ -1261,7 +1271,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     else:
         state = lax.fori_loop(0, l - 1, step, state)
     leaf_id_out = state.leaf_id
-    if bag is not None:
+    if row_space:
         leaf_id_out = row_space_leaf_ids(state.leaf_id, n)
     elif use_partition and not maintain_lid:
         leaf_id_out = leaf_id_from_partition(state.part, n)

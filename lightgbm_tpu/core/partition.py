@@ -21,18 +21,23 @@ tile's ids are placed — lefts forward from the range start, rights backward
 from the range end — so one pass suffices. The second (hist_for_leaf) walks
 the SMALLER child's new range and runs the histogram kernel on its tiles;
 the sibling is parent - smaller, from the grower's per-leaf pool
-(serial_tree_learner.cpp:383-397). ``leaf_id`` is NOT maintained per split:
-it is reconstructed once per tree from the final ranges
-(leaf_id_from_partition).
+(serial_tree_learner.cpp:383-397).
+
+Every row's leaf comes one of two ways. On one device, where a
+feature-major copy of the bins (bins_by_column) fits beside the table
+(row_space_fits), a THIRD pass a split routes all N rows in row space
+(route_in_row_space): the split column read contiguously from the copy,
+the leaf ids in and out, one fused elementwise op and no index, 0.127 ms
+a split at 26.6M rows. Elsewhere (a mesh, or a table too large for its
+copy) ``leaf_id`` is reconstructed once per tree from the final ranges
+(leaf_id_from_partition), one scatter through ``order``: 0.22 s a tree at
+26.6M rows, which row space replaces by ~32 ms at 255 leaves.
 
 A tree grown on a bag (bag_partition: GOSS on the single-device exact
-grower) is the other way round for the rows OUT of the bag: no histogram
-ever reads them, so they have no place in ``order`` and all they need is a
-leaf. There ``order`` lists the bag alone, the two passes above run over
-its ranges, and a THIRD pass a split routes all N rows in row space
-(route_in_row_space): the split column read contiguously from a
-feature-major copy of the bins (bins_by_column), the leaf ids in and out,
-one fused elementwise op and no index. Until PR 34 those rows kept a
+grower) always routes in row space, for the rows OUT of the bag: no
+histogram ever reads them, so they have no place in ``order`` and all
+they need is a leaf. There ``order`` lists the bag alone and the two
+passes above run over its ranges. Before row space those rows kept a
 second range a leaf and walked the tile loop again: 79 B gathered to read
 one routing byte, 1.95 s of a 4.15-s iteration against 0.03 s this way.
 
@@ -146,6 +151,42 @@ def bins_by_column(xb: jnp.ndarray) -> jnp.ndarray:
     return lax.map(column, jnp.arange(c, dtype=jnp.int32))
 
 
+# What a tree over all rows holds on the device a row besides the table
+# and its copy: the packed rows the tile loops gather from
+# (make_row_gather) and the table they are made from, each a row of
+# uint8 lanes that XLA:TPU pads to a multiple of 128, and the per-row
+# state (scores, gradients, ``order``, leaf ids, masks). Read from the
+# v5e compiler's memory analysis of the categorical cell's block (35.4M x
+# 39, 12.72 GB with the copy; PERF.md section 6); a tenth of the device
+# is left to what else the process holds.
+ROW_STATE_BYTES = 32
+
+
+def device_bytes_limit(x: jnp.ndarray) -> Optional[int]:
+    """The memory limit of the device that holds ``x``, or None where the
+    backend reports none (the CPU)."""
+    stats = next(iter(x.devices())).memory_stats()
+    return None if not stats else stats.get("bytes_limit")
+
+
+def row_space_bytes(xb: jnp.ndarray) -> int:
+    """The device bytes a tree over all rows holds with the feature-major
+    copy of ``xb`` (bins_by_column): the table, the copy, and a row's
+    lanes of packed rows, of their source and of per-row state."""
+    n, c = xb.shape
+    lanes = -(-max(MIN_PACKED_WIDTH, c + 12) // 128) * 128
+    return 2 * xb.nbytes + n * (2 * lanes + ROW_STATE_BYTES)
+
+
+def row_space_fits(xb: jnp.ndarray) -> bool:
+    """Whether a tree over all rows may keep its leaf ids in row space:
+    what it holds with the copy (row_space_bytes) fits the device's limit
+    with room to spare. Where the backend gives no limit, yes, so that the
+    CPU runs the chip's path."""
+    limit = device_bytes_limit(xb)
+    return limit is None or row_space_bytes(xb) <= limit - limit // 10
+
+
 def row_space_leaf_ids0(bins_by_col: jnp.ndarray,
                         num_leaves: int) -> jnp.ndarray:
     """Every row at the root, in ``bins_by_col``'s row shape and the
@@ -164,16 +205,20 @@ def route_in_row_space(leaf_id, bins_by_col, stored_col, go_left_from_bins,
 
     This is how a tree grown on a bag routes its rows: those out of the
     bag are never listed for a histogram, so they need no place in
-    ``order``, only a leaf; and with every row's leaf known when the last
-    split is done, leaf_id_from_partition is not run. One fused elementwise
-    pass a split, O(N) and streaming: 0.127 ms at 26.6M rows on a v5e =
-    0.005 ns a row, against 11.5 ns a row of the split leaf through the
-    tile loop (gather, sort, windows). A tree pays (L - 1) x N x 0.005 ns
-    here (0.014 on int32 ids) and the sum of its out-of-bag internal
-    counts x 11.5 ns there: 1.2 against ~90 ns a row at 255 leaves, 57
-    against 150-180 at 4,095, so row space wins wherever a bag exists and
-    there is one path (PERF.md section 6, PR 34). A dead split costs
-    nothing."""
+    ``order``, only a leaf. A tree over all rows on one device routes
+    them so too, where the copy fits (row_space_fits): ``order`` then
+    serves the two tile loops alone. With every row's leaf known when the
+    last split is done, leaf_id_from_partition is not run. One fused
+    elementwise pass a split, O(N) and streaming: 0.127 ms at 26.6M rows
+    on a v5e = 0.005 ns a row, against 11.5 ns a row of the split leaf
+    through the tile loop (gather, sort, windows). A tree pays
+    (L - 1) x N x 0.005 ns here (0.014 on int32 ids) and the sum of its
+    out-of-bag internal counts x 11.5 ns there: 1.2 against ~90 ns a row
+    at 255 leaves, 57 against 150-180 at 4,095, so row space wins
+    wherever a bag exists and there is one path (PERF.md section 6).
+    Over all rows it replaces leaf_id_from_partition's scatter through
+    ``order`` (~0.22 s a tree at 26.6M rows) by (L - 1) such passes
+    (~32 ms at 255 leaves). A dead split costs nothing."""
     def route(lid):
         # the branch is traced apart: it names its own scope
         with jax.named_scope("lgbm.route_only"):
@@ -219,10 +264,10 @@ def make_row_gather(xb: jnp.ndarray, vals: jnp.ndarray,
     packed=True bit-packs [N, C] uint8 bins and [N, 3] float values side
     by side into one [N, C + 3*itemsize] uint8 array (filled with zero
     bytes up to MIN_PACKED_WIDTH where it is narrower), so a histogram
-    trip does ONE row gather instead of two; the per-tile unpack is a
-    free bitcast. packed=False keeps
+    trip does ONE row gather instead of two; the per-tile unpack shifts
+    the value bytes back into words. packed=False keeps
     two gathers — required under vmapped class-batched growth, where
-    the concat would materialize a PER-CLASS copy of the shared bin
+    the packing would materialize a PER-CLASS copy of the shared bin
     matrix."""
     if not packed:
         def gather_rows(idx_safe):
@@ -230,20 +275,33 @@ def make_row_gather(xb: jnp.ndarray, vals: jnp.ndarray,
             v = vals.at[idx_safe].get(mode="promise_in_bounds")
             return rows, v
         return gather_rows
-    n, c = xb.shape
+    c = xb.shape[1]
     nbytes = jnp.dtype(vals.dtype).itemsize
-    vb = lax.bitcast_convert_type(vals, jnp.uint8).reshape(n, -1)
-    spare = max(MIN_PACKED_WIDTH - c - vb.shape[1], 0)
-    xv = jnp.concatenate(
-        [xb, vb] + ([jnp.zeros((n, spare), jnp.uint8)] if spare else []),
-        axis=1)
+    word = jnp.dtype("uint%d" % (8 * nbytes))
+    width = max(MIN_PACKED_WIDTH, c + 3 * nbytes)
+    # byte b of a value word at shift 8 b, both ways: one select a packed
+    # byte over the lane-padded table and the value words, so the values'
+    # bytes are never an [N, 3 * itemsize] array of their own (XLA:TPU pads
+    # such an array to 128 lanes: 4.2 GB at 35.4M rows, PERF.md section 6)
+    j = jnp.arange(width, dtype=jnp.int32)
+    k = jnp.clip((j - c) // nbytes, 0, 2)
+    shift = (jnp.clip(j - c, 0, 3 * nbytes - 1) % nbytes).astype(word) * 8
+    words = lax.bitcast_convert_type(vals, word)                 # [N, 3]
+    by_byte = jnp.where(k == 0, words[:, 0:1],
+                        jnp.where(k == 1, words[:, 1:2], words[:, 2:3]))
+    xv = jnp.where((j >= c) & (j < c + 3 * nbytes),
+                   ((by_byte >> shift) & 0xFF).astype(jnp.uint8),
+                   jnp.pad(xb, ((0, 0), (0, width - c))))
     val_dtype = vals.dtype
+    shifts = jnp.arange(nbytes, dtype=word) * 8
 
     def gather_rows(idx_safe):
         p = xv.at[idx_safe].get(mode="promise_in_bounds")
         rows = p[:, :c]
+        packed_bytes = p[:, c:c + 3 * nbytes].astype(word).reshape(
+            p.shape[0], 3, nbytes)
         v = lax.bitcast_convert_type(
-            p[:, c:c + 3 * nbytes].reshape(p.shape[0], 3, nbytes), val_dtype)
+            jnp.sum(packed_bytes << shifts, axis=2, dtype=word), val_dtype)
         return rows, v
     return gather_rows
 
@@ -457,7 +515,9 @@ def leaf_id_from_partition(part: RowPartition, num_data: int) -> jnp.ndarray:
     The leaf ranges tile [0, num_data) exactly (DataPartition invariant),
     so every row has an owner: O(N) dense work once per tree, whatever the
     number of leaves, instead of O(N x depth) scattered writes during
-    growth.
+    growth. Run where the grower does not route in row space
+    (route_in_row_space): on a mesh, and where the feature-major copy
+    does not fit (row_space_fits).
     """
     with jax.named_scope("lgbm.leaf_ids"):
         return _range_owner(part.order, part.leaf_begin, part.leaf_count,

@@ -178,13 +178,15 @@ def test_the_mesh_block_psums_counts_as_int32_and_histograms_as_float32():
     assert not [o for p, o in ops if p == "all_gather" and hist in o]
 
 
-# the one-chip exact train block's structural fingerprint, measured on
-# the program before the mesh's counts became integers: the one-chip
-# program does not move
+# the one-chip exact train block's structural fingerprint: the mesh's
+# integer counts do not move it. Measured anew when its leaf ids moved to
+# row space and its packed rows to one select a byte (4042c53b... /
+# 18602e2b... until then): the one-chip program routes every row a split,
+# the mesh's maps ranges to rows once a tree
 ONE_CHIP_BLOCK = {
-    "exact": "4042c53b8a5e5dea8d93ea5b4e7587452c1b26d411b2191dd21b0612b34e41b3",
+    "exact": "14c64dc8d77422ae83e9fde4495cf5007daff2807de8992eb6cc08ba6b889085",
     "exact_cat":
-        "18602e2bb9443927a8453f763a1cfa4e85b885ab6e13db5abb7bc78ed1bb8e0f",
+        "3dd561c87f3df7d44e9eeac25593c0a0d1f8b450f72ab0e4b1dff05b9f65c033",
 }
 
 

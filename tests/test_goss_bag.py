@@ -385,9 +385,17 @@ def test_rowspace_routing_is_counted_where_the_bag_is_and_nowhere_else():
             # one byte a stored bin, rows padded to whole lanes
             assert counts["rowspace_bins_bytes"] == 6 * 2 * 1024
             assert gbdt._rowspace_bins() is None     # unsampled so far
+            assert counts["leaf_ids_row_space"] == 0
         else:
             assert not gbdt._goss_bag
             assert "goss_rowspace_routing" not in counts
+        if boosting == "gbdt":
+            # over all rows the ids live in row space, by the same copy
+            assert counts["leaf_ids_row_space"] == 1
+            assert counts["rowspace_bins_bytes"] == 6 * 2 * 1024
+            assert gbdt._rowspace_bins() is gbdt._bins_by_col
+        elif extra:
+            assert counts["leaf_ids_row_space"] == 0
             assert "rowspace_bins_bytes" not in counts
             assert gbdt._bins_by_col is None
 

@@ -34,7 +34,7 @@ from lightgbm_tpu.obs.distributed import FlightRecorder
 SCOPES = ("lgbm.gradients", "lgbm.root_hist", "lgbm.row_gather",
           "lgbm.route_rows",
           "lgbm.hist_tile", "lgbm.hist_subtract", "lgbm.partition_scatter",
-          "lgbm.split_search",
+          "lgbm.split_search", "lgbm.route_only",
           "lgbm.leaf_ids", "lgbm.score_update", "lgbm.tree_pack")
 
 
@@ -173,16 +173,16 @@ def test_find_bins_span_counts_the_values_its_zero_test_read(sparse,
 
 
 # want: (partition_window_placement, leaf_ids_gather_free,
-#        hist_smaller_child)
+#        hist_smaller_child, leaf_ids_row_space)
 @pytest.mark.parametrize("extra,want", [
-    ({}, (0, 1, 1)),                             # the CPU's element scatter
-    ({"tpu_hist_impl": "pallas_interpret"}, (1, 1, 1)),  # the chip's loop
+    ({}, (0, 1, 1, 1)),                          # the CPU's element scatter
+    ({"tpu_hist_impl": "pallas_interpret"}, (1, 1, 1, 1)),  # the chip's loop
     ({"tpu_hist_impl": "pallas_interpret", "objective": "multiclass",
-      "num_class": 3}, (0, 1, 1)),               # vmapped class batching
+      "num_class": 3}, (0, 1, 1, 1)),            # vmapped class batching
     ({"tpu_hist_impl": "pallas_interpret", "tree_growth": "frontier"},
-     (0, 0, 0)),                                 # another grower
-    ({"tree_growth": "batched"}, (0, 0, 0)),
-    ({"cegb_penalty_feature_lazy": [0.1] * 4}, (0, 0, 1)),  # ids by split
+     (0, 0, 0, 0)),                              # another grower
+    ({"tree_growth": "batched"}, (0, 0, 0, 0)),
+    ({"cegb_penalty_feature_lazy": [0.1] * 4}, (0, 0, 1, 0)),  # ids by split
 ], ids=["cpu_default", "pallas", "pallas_vmapped", "pallas_frontier",
         "batched", "cegb_lazy"])
 def test_setup_span_says_how_the_block_places_and_maps_rows(extra, want):
@@ -196,7 +196,8 @@ def test_setup_span_says_how_the_block_places_and_maps_rows(extra, want):
     (setup,) = spans_named("train.setup", since=mark)
     assert (setup["counts"]["partition_window_placement"],
             setup["counts"]["leaf_ids_gather_free"],
-            setup["counts"]["hist_smaller_child"]) == want
+            setup["counts"]["hist_smaller_child"],
+            setup["counts"]["leaf_ids_row_space"]) == want
     assert setup["counts"]["rows"] == 300
 
 

@@ -164,7 +164,13 @@ PATHS = {
     # (extra params, table, the kernel engages)
     "plain": ({}, lambda x: x, True),
     "cpu-scatter": ({"tpu_hist_impl": "scatter"}, lambda x: x, False),
-    "gbdt": ({"boosting": "gbdt"}, lambda x: x, False),
+    # boosting=gbdt holds the copy for its leaf ids in row space
+    # (GBDT._leaf_ids_row_space), and a replay over the table reads it
+    "gbdt": ({"boosting": "gbdt"}, lambda x: x, True),
+    # no copy where CEGB's lazy cost keeps the ids split by split
+    "gbdt-no-copy": ({"boosting": "gbdt",
+                      "cegb_penalty_feature_lazy": [0.1] * 4},
+                     lambda x: x, False),
     "pairs": ({"tree_growth": "frontier", "tpu_bin_packing": "nibble"},
               lambda x: np.concatenate(
                   [x, np.random.RandomState(2).randint(0, 6, (len(x), 2))],
