@@ -182,7 +182,7 @@ class DART(GBDT):
             "dart_replay_rows": len(dropped) * self.num_data,
             "dart_replay_nodes": sum(max(t.num_leaves_actual - 1, 0)
                                      for t in dropped),
-            "dart_replay_kernel_trees": len(dropped) * self._replay_kernel()}
+            "dart_replay_kernel_trees": len(dropped) * self.plan.replay_kernel}
 
         # remove dropped trees from train/valid scores (DroppingTrees
         # :125-131): one program a table, one summed output kept

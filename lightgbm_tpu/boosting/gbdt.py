@@ -22,7 +22,7 @@ Key mappings:
 """
 from __future__ import annotations
 
-import math
+import copy
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -42,6 +42,7 @@ from ..core import partition as partition_mod
 from ..core.pack import pack_trees, unpack_tree
 from ..core import replay as replay_mod
 from ..core import tree as tree_mod
+from . import plan as plan_mod
 from ..objectives import ObjectiveFunction
 from ..metrics import Metric
 from ..resilience import faults as _faults
@@ -192,43 +193,126 @@ def _host_device_split(block_span, dispatch_span):
             (block_span.end_ns - dispatch_span.end_ns) / 1e9)
 
 
-def _hist_dtype(cfg: Config) -> str:
-    """Histogram accumulation dtype: tpu_hist_dtype is the explicit knob,
-    gpu_use_dp (config.h:784) the reference-compatible alias for f64."""
-    spelled = str(cfg.tpu_hist_dtype).strip().lower()
-    if spelled in ("float64", "f64", "double"):
-        return "f64"
-    if spelled not in ("float32", "f32", "single", ""):
-        raise LightGBMError("unknown tpu_hist_dtype %r "
-                            "(use float32 or float64)" % cfg.tpu_hist_dtype)
-    return "f64" if cfg.gpu_use_dp else "f32"
+def _obj_row_names(obj, n: int, n0: int) -> Tuple[str, ...]:
+    """The objective's per-row device arrays (label, weights, onehot, ...)
+    by name: anything get_gradients reads, handed to a step as arguments."""
+    return tuple(sorted(
+        nm for nm, v in (obj.__dict__.items() if obj is not None else ())
+        if isinstance(v, jnp.ndarray) and v.ndim >= 1
+        and v.shape[0] in (n, n0)))
 
 
-def _resolve_hist_impl(cfg: Config) -> str:
-    """Histogram-kernel dispatch (the GPUTreeLearner device-path analog,
-    tree_learner.cpp:9-31): CPU -> XLA scatter-add; device -> the Pallas
-    VMEM-accumulator kernel. ``auto`` resolves from the backend alone and
-    says so in the log — never by catching a failed compile: a kernel
-    that does not compile raises, whether it was named or resolved.
-    gpu_use_dp (config.h:784) means what it means in the reference:
-    DOUBLE-precision histogram accumulation. The Pallas kernels are
-    f32-only, so dp routes to the XLA paths (scatter / one-hot matmul),
-    which accumulate in the value dtype — f64 once the GBDT driver casts
-    the stacked values (GrowParams.hist_dtype)."""
-    impl = cfg.tpu_hist_impl
-    if _hist_dtype(cfg) == "f64":
-        if impl == "auto" or impl.startswith("pallas"):
-            if impl.startswith("pallas"):
-                Log.warning("f64 histograms: the f32-only Pallas kernel "
-                            "%s is replaced by the f64 XLA path" % impl)
-            return ("scatter" if jax.default_backend() == "cpu"
-                    else "matmul")
-        return impl
-    if impl == "auto":
-        impl = ("scatter" if jax.default_backend() == "cpu" else "pallas")
-        Log.info("tpu_hist_impl=auto resolved to %s (backend %s)",
-                 impl, jax.default_backend())
-    return impl
+def _bind(obj, names, rows):
+    """A shallow copy of ``obj`` with the argument arrays bound: the
+    traced values, not the captured originals, feed get_gradients."""
+    o = copy.copy(obj)
+    for nm, v in zip(names, rows):
+        setattr(o, nm, v)
+    return o
+
+
+def _gradients(o, scores):
+    """The objective's [N, K] gradients and hessians (gbdt.cpp:333-347)."""
+    if scores.shape[1] == 1:
+        g, h = o.get_gradients(scores[:, 0])
+        return g[:, None], h[:, None]
+    return o.get_gradients(scores)
+
+
+def _renew_of(obj):
+    """(alpha, weight attribute) of an objective's in-graph leaf refit
+    (RenewTreeOutput: L1/Quantile/MAPE, core/renew.py), else None."""
+    if obj is None or getattr(obj, "renew_percentile", None) is None:
+        return None
+    return (float(obj.renew_percentile()),
+            "label_weight" if obj.name == "mape" else "weights")
+
+
+def _goss_as_multiplier(g, h, sample_mask, goss_active, key, counts,
+                        n_real, row_valid=None):
+    """GOSS one-side sampling as a multiplier (goss.hpp:87-135): keep all
+    of the top |g*h| rows, Bernoulli-sample the rest, amplify their
+    grad/hess by (n - top)/other so expectations are unbiased; every row
+    stays in the grower's passes, the dropped ones at weight 0. Warmup
+    iterations (goss_active == 0) skip the sort entirely. ``row_valid``
+    masks padded rows out of the ranking where their |g*h| is not zero."""
+    n = g.shape[0]
+    top_cnt, other_cnt, goss_multiply = counts
+
+    def goss_mult(_):
+        gh = jnp.sum(jnp.abs(g * h), axis=1)
+        if row_valid is not None:
+            gh = gh * row_valid
+        thr = jax.lax.top_k(gh, top_cnt)[0][-1]
+        is_top = gh >= thr
+        u = jax.random.uniform(key, (n,))
+        p_rest = other_cnt / max(n_real - top_cnt, 1)
+        keep_other = (~is_top) & (u < p_rest)
+        return jnp.where(is_top, 1.0,
+                         jnp.where(keep_other, goss_multiply, 0.0))
+
+    mult = jax.lax.cond(goss_active > 0, goss_mult,
+                        lambda _: jnp.ones((n,), jnp.float32), operand=None)
+    return (g * mult[:, None], h * mult[:, None],
+            sample_mask * (mult > 0).astype(jnp.float32))
+
+
+def _add_trees(o, renew, trees, leaf_ids, scores, sample_mask, lr,
+               stopped_in, num_leaves):
+    """(trees, new scores, stop latch, any class tree split). Renewed
+    leaves (RenewTreeOutput, serial_tree_learner.cpp:850-928) refit to the
+    residuals against the PRE-update scores; each row adds its leaf's
+    value x lr. A fully-stumped iteration means convergence (gbdt.cpp:
+    379-396): the latch accumulates ON DEVICE, so every later dispatched
+    iteration freezes the scores too and the async driver discards the
+    overshoot trees at the next flush without rewinding anything."""
+    if renew is not None:
+        from ..core.renew import renew_leaf_values
+        alpha, w_attr = renew
+        rw = getattr(o, w_attr, None)
+        if rw is None:
+            rw = jnp.ones_like(o.label)
+
+        def renew_one(t, li, sc_col):
+            # scores live in the (possibly reg_sqrt-transformed) label
+            # space the gradients were computed in
+            lab = getattr(o, "trans_label", None)
+            lab = o.label if lab is None else lab
+            new_lv = renew_leaf_values(lab - sc_col, rw, li, sample_mask,
+                                       num_leaves, alpha, t.leaf_value)
+            return t._replace(leaf_value=new_lv)
+
+        trees = jax.vmap(renew_one, in_axes=(0, 0, 1))(
+            trees, leaf_ids, scores)
+    deltas = jax.vmap(
+        lambda t, li: tree_mod.leaf_value_of(t.leaf_value, li) * lr)(
+            trees, leaf_ids)                                    # [K, N]
+    any_split = jnp.any(trees.num_leaves > 1)
+    stopped_out = stopped_in | ~any_split
+    apply = (any_split & ~stopped_in).astype(jnp.float32)
+    return trees, scores + deltas.T * apply, stopped_out, any_split
+
+
+def _grower_aux(plan, health_on: bool, aux):
+    """(health, model statistics, CEGB state) of the growers' third output,
+    stacked over the classes: on the frontier path the obs aux, the [K, 2]
+    health accumulator with obs_health or the (health_or_None,
+    [K, F, MS_WIDTH] mstats) tuple with obs_modelstats; CEGB state on the
+    exact path (the frontier and CEGB paths are config-exclusive)."""
+    if plan.obs_modelstats:
+        return aux[0], aux[1], None
+    if plan.obs_aux and health_on:
+        return aux, None, None
+    return None, None, aux
+
+
+def _health(on: bool, g, h, any_split, grower_health):
+    """The device-side health flags (lightgbm_tpu.obs) from what the step
+    holds; off, a constant zero vector and no health compute."""
+    if not on:
+        return jnp.zeros((4,), jnp.float32)
+    from ..obs.health import health_vec
+    return health_vec(g, h, any_split, grower_health)
 
 
 class GBDT:
@@ -256,7 +340,8 @@ class GBDT:
         # (same shapes, same jax) then compile nothing
         from ..profiling import enable_compile_cache
         enable_compile_cache(config.compile_cache_dir)
-        if _hist_dtype(config) == "f64" and not jax.config.jax_enable_x64:
+        if plan_mod.hist_dtype(config) == "f64" \
+                and not jax.config.jax_enable_x64:
             # reference gpu_use_dp = double-precision histograms
             # (config.h:784); jax needs x64 enabled for f64 to exist at
             # trace time. Process-wide, explicit user opt-in.
@@ -291,7 +376,8 @@ class GBDT:
         self._use_input_grads = False
         self.mesh = None
         self._row_valid = None
-        self._frontier_rs = False
+        # which path training takes (boosting/plan.py); None: no training
+        self.plan: Optional[plan_mod.TrainPlan] = None
         # out-of-core streamed training (lightgbm_tpu.stream): the chunk
         # pipeline, the host-driven grower, and its pre/post jits — set by
         # _setup_train when the dataset is a StreamedDataset
@@ -300,8 +386,8 @@ class GBDT:
         self._stream_pre = None
         self._stream_post = None
         self._stream_capture = ()
-        self._stream_layout = None
-        self._stream_perm = None
+        self._stream_layout = None   # host [n0,...] -> padded-layout rows
+        self._stream_perm = None     # padded index of each original row
         self._stream_col_pad = 0
         # observability facade (lightgbm_tpu.obs): replaced by the
         # config-driven one in _setup_train; loaded/predict-only boosters
@@ -309,8 +395,9 @@ class GBDT:
         from ..obs.runtime import TrainingObs
         self.obs = TrainingObs.disabled()
         # the stored-tree replay (core/replay.py): the table's layout by
-        # real feature, made at first use, and executables readied ahead of
-        # their first call (compile_replay), by scope
+        # real feature, made at set-up where the table stores a column,
+        # and executables readied ahead of their first call
+        # (compile_replay), by scope
         self._replay_layout = None
         self._aot_replay: Dict[Tuple, Any] = {}
 
@@ -318,81 +405,29 @@ class GBDT:
             with recorder.span("train.setup",
                                rows=train_data.num_data) as span:
                 self._setup_train(train_data)
-                # which placement the exact grower's tile loop is built
-                # with, whether it maps rows to leaves with no gather over
-                # all rows (in row space, or once a tree through
-                # leaf_id_from_partition), and whether the kernel sees
-                # only the smaller child's range of a split (all 0 where
-                # another grower runs; the second 0 too where CEGB keeps
-                # the leaf ids split by split)
-                p = self.grow_params
-                exact_part = (p.use_partition and not p.frontier_mode
-                              and p.batch_splits == 0)
-                span.counts["partition_window_placement"] = int(
-                    exact_part and partition_mod.window_placement(
-                        p.hist_impl, p.vmapped_classes))
-                span.counts["leaf_ids_gather_free"] = int(
-                    exact_part and not p.with_cegb_lazy)
-                # whether the score update looks each row's leaf value up
-                # by selects (tree.leaf_value_of): every booster that trains
-                # on the device, while its trees hold few enough leaves
-                span.counts["score_update_gather_free"] = int(
-                    p.num_leaves <= tree_mod.SELECT_LOOKUP_MAX)
-                span.counts["hist_smaller_child"] = int(exact_part)
-                # whether the histograms go through the Pallas kernels,
-                # whose tile / root kernel contracts a feature in one MXU
-                # pass (histogram_pallas._hist_kernel)
-                span.counts["hist_one_pass"] = int(
-                    p.hist_impl.startswith("pallas"))
+                plan = self.plan
                 # the chips of the mesh, and whether the exact grower's
                 # counts over them are the row partition's integers
                 # (psummed as int32) and not a float32 histogram's
                 span.counts["mesh_chips"] = (
                     0 if self.mesh is None else int(self.mesh.devices.size))
-                span.counts["mesh_exact_counts"] = int(
-                    exact_part and p.partition_on_mesh
-                    and p.all_rows_in_bag)
-                # GOSS: whether the row partition starts from the bag, and
-                # the bag's static size (the others' draw is exact, so its
-                # capacity is its count)
-                span.counts["goss_bag_partition"] = int(self._goss_bag)
-                span.counts["replay_kernel"] = int(self._replay_kernel())
-                # whether the exact grower over all rows keeps every row's
-                # leaf id in row space, a routing pass a split
-                span.counts["leaf_ids_row_space"] = int(
-                    self._leaf_ids_row_space)
-                if self._bins_by_col is not None:
-                    # a tree on a bag or over all rows routes all rows, and
-                    # a replay of stored trees its columns, by the
-                    # feature-major copy of the bins
-                    if self._goss_bag:
-                        span.counts["goss_rowspace_routing"] = 1
-                    elif self.boosting_type == "dart":
-                        span.counts["replay_feature_major"] = 1
-                    span.counts["rowspace_bins_bytes"] = \
-                        self._bins_by_col.nbytes
-                if self._goss_counts is not None:
-                    top_cnt, other_cnt, _ = self._goss_counts
-                    span.counts["bag_rows"] = top_cnt + other_cnt
-                    span.counts["bag_top_rows"] = top_cnt
-                    span.counts["bag_other_capacity"] = other_cnt
-                # what the data made of its columns: those whose split
-                # search prices a missing direction, and those with fewer
-                # bins than max_bin allows
+                span.counts["mesh_exact_counts"] = int(plan.mesh_exact_counts)
+                # a replay of stored trees reads its columns from the
+                # feature-major copy of the bins
+                if self._bins_by_col is not None \
+                        and self.boosting_type == "dart":
+                    span.counts["replay_feature_major"] = 1
+                # the columns whose split search prices a missing direction
                 f = train_data.num_features     # not a mesh's padding
-                m = self.feature_meta
-                span.counts["features_with_missing"] = int(
-                    np.count_nonzero(np.asarray(m.missing_type)[:f]))
-                span.counts["features_short"] = int(np.count_nonzero(
-                    np.asarray(m.num_bin)[:f] < self.config.max_bin))
+                span.counts["features_with_missing"] = int(np.count_nonzero(
+                    np.asarray(self.feature_meta.missing_type)[:f]))
                 # categorical columns, and whether the grower tests a
                 # split's category set without a gather over the rows it
                 # routes (the exact grower's one-split form; the wave
                 # growers' per-row sets keep their take_along_axis)
-                span.counts["features_categorical"] = p.with_categorical
+                span.counts["features_categorical"] = plan.facts.categorical
                 span.counts["cat_route_gather_free"] = int(
-                    p.with_categorical > 0 and not p.frontier_mode
-                    and p.batch_splits == 0)
+                    plan.cat_route_gather_free)
 
     # ------------------------------------------------------------ setup
     def _setup_stream_mesh(self, ds) -> np.ndarray:
@@ -414,38 +449,15 @@ class GBDT:
                                        split_chunks_rows)
         from ..stream.source import shard_offsets
         mesh = self.mesh
-        axis = mesh_mod.DATA_AXIS
-        if axis not in mesh.axis_names:
-            raise LightGBMError(
-                "streamed mesh training is data-parallel only: mesh_shape "
-                "must map the %r axis (got axes %s)"
-                % (axis, list(mesh.axis_names)))
-        fsize = (mesh.shape[mesh_mod.FEATURE_AXIS]
-                 if mesh_mod.FEATURE_AXIS in mesh.axis_names else 1)
-        if fsize > 1:
-            raise LightGBMError(
-                "streamed training cannot shard the feature axis (the "
-                "chunk stream is row-partitioned); use a pure data mesh "
-                "(tree_learner=data|voting) or set "
-                "data_stream_chunk_rows=0")
-        if int(cfg.data_stream_chunk_rows) <= 0:
-            raise LightGBMError(
-                "streamed mesh training needs an explicit "
-                "data_stream_chunk_rows: the per-wave kernel shapes must "
-                "agree on every process")
-        dsize = int(mesh.shape[axis])
+        dsize = int(mesh.shape[mesh_mod.DATA_AXIS])
         # reduce-scatter wave histograms need the stored columns to tile
         # over the data axis (DataRSLearner); pad columns here and the
         # feature metadata below with unusable num_bin=1 entries
-        self._frontier_rs = (
-            cfg.tree_learner == "data"
-            and bool(cfg.tpu_frontier_rs)
-            and _hist_dtype(cfg) != "f64")
         ncols = int(ds.chunks[0].shape[1]) if ds.chunks \
             else int(ds.num_columns)
-        col_pad = (-ncols) % dsize if self._frontier_rs else 0
+        col_pad = (-ncols) % self.plan.col_multiple
         self._stream_col_pad = col_pad
-        world = int(getattr(ds, "shard_world", 1) or 1)
+        world = self.plan.facts.shard_world
         if world > 1:
             if world != dsize:
                 raise LightGBMError(
@@ -487,101 +499,54 @@ class GBDT:
         from ..parallel import mesh as mesh_mod
         self.mesh = mesh_mod.build_mesh(cfg)
         self.num_data_orig = ds.num_data
+        self._forced_splits, num_forced = self._setup_forced_splits()
+        if ds.num_features:     # a table of no stored column has no layout
+            self._replay_layout = replay_mod.TableLayout(ds)
+        self.plan = plan = plan_mod.resolve_plan(cfg, plan_mod.TrainFacts.of(
+            ds, self.mesh, self.boosting_type, self.num_tree_per_iteration,
+            num_forced, bool(ds.num_features and self._replay_layout.single)))
+        for level, message in plan.log:
+            getattr(Log, level)(message)
         xb_np = ds.X_binned
         row_valid = None
-        streamed = bool(getattr(ds, "is_streamed", False))
-        self._stream_layout = None   # host [n0,...] -> padded-layout rows
-        self._stream_perm = None     # padded index of each original row
-        self._stream_col_pad = 0
+        streamed = plan.facts.streamed
+        padded = lambda pad: np.concatenate(   # noqa: E731 - row_valid
+            [np.ones(ds.num_data, np.float32), np.zeros(pad, np.float32)])
         if streamed:
             # out-of-core path: the bin matrix exists only as host chunks;
             # everything per-row stays device-resident at padded length
-            if cfg.tree_growth != "frontier":
-                raise LightGBMError(
-                    "streamed training requires tree_growth=frontier")
-            if _hist_dtype(cfg) == "f64":
-                # the satellite gate for streamed mesh + f64 is this same
-                # branch: every streamed run accumulates f32 wave
-                # histograms (config.py pre-validates the mesh spelling)
-                raise LightGBMError(
-                    "streamed training accumulates f32 wave histograms; "
-                    "set gpu_use_dp=false" + (
-                        " (streamed + mesh_shape requires f32)"
-                        if self.mesh is not None else ""))
             if self.mesh is not None:
                 row_valid = self._setup_stream_mesh(ds)
             else:
-                if int(getattr(ds, "shard_world", 1) or 1) > 1:
-                    raise LightGBMError(
-                        "dataset was ingested as shard %d/%d but no mesh "
-                        "is configured; set mesh_shape=[%d] (or ingest "
-                        "without a ShardedSource)"
-                        % (ds.shard_rank, ds.shard_world, ds.shard_world))
-                from ..core.binpack import resolve_bin_packing
                 from ..stream.pipeline import ChunkPipeline
                 chunk_cap = int(cfg.data_stream_chunk_rows) or \
                     max(1, max(ds.chunk_row_counts))
-                # packed host chunks (core/binpack.py): word-pack at repack
-                # time so every host->device transfer ships the
-                # kernel-native int32-word layout; under
-                # tpu_bin_packing=nibble the DATASET pair coding already
-                # halved the stored columns, so the per-row transfer bytes
-                # halve with it
-                stream_packed = resolve_bin_packing(
-                    cfg.tpu_bin_packing, streamed=True,
-                    tpu_shaped=partition_mod.tpu_shaped_backend(),
-                    col_num_bin=list(ds.col_num_bin)) != "none"
+                # packed host chunks (core/binpack.py): word-packed at
+                # repack time, so every host->device transfer ships the
+                # kernel-native int32-word layout
                 self._stream = ChunkPipeline(
                     ds.chunks, chunk_cap,
                     prefetch=int(cfg.data_stream_prefetch),
-                    packed=stream_packed)
+                    packed=plan.word_packed_cols > 0)
                 pad = self._stream.num_padded - ds.num_data
                 if pad:
-                    row_valid = np.concatenate(
-                        [np.ones(ds.num_data, np.float32),
-                         np.zeros(pad, np.float32)])
-        if self.mesh is not None and not streamed:
-            # pad rows to a multiple of the data-axis size so every shard is
-            # even; padded rows carry mask 0 everywhere (the distributed
-            # loader's row partition, dataset_loader.cpp:469-495, without the
-            # loss of remainder rows)
-            axis = mesh_mod.DATA_AXIS
-            dsize = (self.mesh.shape[axis]
-                     if axis in self.mesh.axis_names else 1)
-            pad = (-ds.num_data) % dsize
+                    row_valid = padded(pad)
+        elif self.mesh is not None:
+            # pad rows so every shard is even; padded rows carry mask 0
+            # everywhere (the distributed loader's row partition,
+            # dataset_loader.cpp:469-495, without the loss of remainder
+            # rows). Padded columns get num_bin=1 metadata, which the
+            # split search treats as unusable
+            pad = (-ds.num_data) % plan.row_multiple
             if pad:
                 xb_np = np.concatenate(
                     [xb_np, np.zeros((pad, xb_np.shape[1]), xb_np.dtype)])
-            if pad:
-                row_valid = np.concatenate(
-                    [np.ones(ds.num_data, np.float32),
-                     np.zeros(pad, np.float32)])
-            # feature-parallel: pad columns to a multiple of the feature axis
-            # so the [N, F] bin matrix shards evenly; padded columns get
-            # num_bin=1 metadata which the split search treats as unusable
-            fsize = (self.mesh.shape[mesh_mod.FEATURE_AXIS]
-                     if mesh_mod.FEATURE_AXIS in self.mesh.axis_names else 1)
-            # frontier data-parallel reduce-scatter (parallel/learners.py
-            # DataRSLearner): the per-wave psum_scatter tiles the feature
-            # axis over the DATA axis, so columns must also divide dsize
-            self._frontier_rs = (
-                cfg.tree_growth == "frontier"
-                and cfg.tree_learner == "data"
-                and mesh_mod.DATA_AXIS in self.mesh.axis_names
-                and bool(cfg.tpu_frontier_rs)
-                and _hist_dtype(cfg) != "f64")
-            if self._frontier_rs:
-                fsize = fsize * dsize // math.gcd(fsize, dsize)
-            fpad = (-xb_np.shape[1]) % fsize
+                row_valid = padded(pad)
+            fpad = (-xb_np.shape[1]) % plan.col_multiple
             if fpad:
                 xb_np = np.concatenate(
                     [xb_np, np.zeros((xb_np.shape[0], fpad), xb_np.dtype)],
                     axis=1)
-        if self.mesh is not None and (ds.has_bundles or ds.has_packed):
-            raise LightGBMError(
-                "EFB bundles / nbit-packed columns are not yet supported "
-                "with a device mesh; set enable_bundle=false and "
-                "enable_nbit_packing=false for distributed training")
         self.num_data = (self._stream.num_padded if streamed
                          else xb_np.shape[0])
         self._feature_pad = (self._stream_col_pad if streamed
@@ -592,33 +557,20 @@ class GBDT:
             _feature_meta_from_dataset(ds, cfg), self._feature_pad)
         self.num_bins = max(ds.max_col_bins(), 2)
         self.num_feat_bins = max(ds.max_num_bin(), 2)
-        # explicit feature-parallel (feature_parallel_tree_learner.cpp:
-        # 30-60): rows REPLICATED, search work divided by a bin-balanced
-        # column assignment, best splits argmax-allreduced as structs.
-        # Order-dependent extras (forced splits, CEGB) keep the GSPMD
-        # fallback, whose comm the partitioner infers.
-        self._explicit_fp = (
-            self.mesh is not None
-            and cfg.tree_learner == "feature"
-            and _hist_dtype(cfg) == "f32"  # sync_best_split bitcasts f32
-            and mesh_mod.FEATURE_AXIS in self.mesh.axis_names
-            and not cfg.forcedsplits_filename
-            and not cfg.cegb_penalty_feature_coupled
-            and not cfg.cegb_penalty_feature_lazy
-            and cfg.cegb_penalty_split <= 0)
+        explicit_fp = plan.learner == plan_mod.FEATURE
         # the host side of the transfer: no barrier waits for the device
         with recorder.span("train.device_put_bins",
                            bytes=0 if streamed else xb_np.nbytes):
             self._fp_capture = None
             if streamed:
                 self.xb = None
-            elif self.mesh is not None and not self._explicit_fp:
+            elif self.mesh is not None and not explicit_fp:
                 # each chip's rows go from the host straight to that chip
                 self.xb = jax.device_put(
                     xb_np, mesh_mod.feature_sharding(self.mesh))
             else:
                 self.xb = jnp.asarray(xb_np)
-            if self._explicit_fp:
+            if explicit_fp:
                 # xb stays replicated (every FP worker holds the full data,
                 # like the reference's feature-parallel machines); each
                 # device additionally gets its own column slice for
@@ -640,112 +592,16 @@ class GBDT:
             for m in self.train_metrics:
                 m.init(ds.metadata, ds.num_data)
 
-        self._forced_splits, num_forced = self._setup_forced_splits()
         self._cegb_state = self._setup_cegb()
-        # histogram pool cap (histogram_pool_size MB, config.h; the
-        # HistogramPool LRU of feature_histogram.hpp:646-820). -1 = one
-        # slot per leaf.
-        pool_slots = 0
-        # mesh modes keep the full pool: the rebuild-on-miss cond cannot
-        # hold the psum a sharded rebuild needs (same SPMD constraint the
-        # growth loop documents for its dead-iteration histograms)
-        if cfg.histogram_pool_size > 0 and cfg.tree_learner != "voting" \
-                and self.mesh is None and not streamed:
-            bytes_per_hist = xb_np.shape[1] * self.num_bins * 3 * 4
-            pool_slots = int(cfg.histogram_pool_size * 1024 * 1024
-                             // max(bytes_per_hist, 1))
-            pool_slots = max(2, min(cfg.num_leaves, pool_slots))
-            if pool_slots >= cfg.num_leaves:
-                pool_slots = 0  # cap larger than the full pool: uncapped
-        if cfg.tree_learner == "voting" and self.mesh is not None and \
-                (num_forced > 0 or self._cegb_state is not None):
-            raise LightGBMError("forced splits / CEGB are not supported "
-                                "with the voting-parallel tree learner")
-
-        # batched-frontier growth (core/grow_batched.py) and frontier-wave
-        # growth (core/grow_frontier.py): both incompatible with anything
-        # whose bookkeeping depends on exact one-split-at-a-time ordering
-        batch_splits = 0
-        frontier_mode = False
-        if cfg.tree_growth in ("batched", "frontier"):
-            mode = "tree_growth=%s" % cfg.tree_growth
-            if num_forced > 0 or self._cegb_state is not None:
-                raise LightGBMError(
-                    mode + " requires exact split ordering; disable forced "
-                    "splits / CEGB or use tree_growth=exact")
-            # the frontier wave grower carries the voting-parallel election
-            # (parallel/learners.py VotingLearner); batched growth and the
-            # explicit feature-parallel learner still need exact ordering /
-            # the grow_tree fp context
-            if cfg.tree_learner == "feature" or (
-                    cfg.tree_learner == "voting"
-                    and cfg.tree_growth != "frontier"):
-                raise LightGBMError(
-                    mode + " does not support tree_learner=%s (serial and "
-                    "data always work; voting needs tree_growth=frontier)"
-                    % cfg.tree_learner)
-            if _hist_dtype(cfg) == "f64":
-                # both wave growers accumulate f32 (slot kernel layout);
-                # silently downgrading would betray the dp promise
-                Log.warning(mode + " does not support f64 histograms yet; "
-                            "falling back to exact growth")
-            elif cfg.tree_growth == "frontier":
-                frontier_mode = True
-            else:
-                batch_splits = min(cfg.tree_batch_splits,
-                                   cfg.num_leaves - 1)
-        # multiclass class batching: vmapped growth measured 1.9x SLOWER
-        # than sequential per-class growth on a v5e chip (1.65 vs 0.88
-        # s/iter at 500k x 28 x 5 classes, docs/Performance.md "Round 4",
-        # one pre-PR-1 datapoint) — vmap serializes the growth while_loop
-        # in lockstep AND forces the element scatter (a batched window
-        # start is a scatter again: partition.window_placement).
-        # TPU-shaped backends (partition.tpu_shaped_backend — NOT a
-        # hist-impl proxy, so f64/matmul TPU runs are covered too)
-        # therefore grow classes sequentially even with an
-        # uncapped pool; vmap remains the CPU default, where it wins.
-        vmapped = (self.num_tree_per_iteration > 1 and pool_slots == 0
-                   and not partition_mod.tpu_shaped_backend())
-        # explicit shard_map data-parallel learner: every device partitions
-        # its local row shard and only child histograms cross the mesh
-        # (data_parallel_tree_learner.cpp:146-161). Forced splits rebuild
-        # leaf histograms straight-line + psum (grow.py leaf_hist), and
-        # CEGB state threads through the shard_map with row_used sharded —
-        # neither drops this learner to the masked fallback anymore.
-        self._partition_on_mesh = (
-            self.mesh is not None
-            and cfg.tree_learner == "data"
-            and mesh_mod.DATA_AXIS in self.mesh.axis_names)
-        # GOSS where the exact grower runs over the row partition on one
-        # device: the sampler makes a bag and the partition starts from it
-        # (boosting/goss.py). Everywhere else it stays a multiplier.
-        self._goss_bag = (
-            self.boosting_type == "goss" and self.mesh is None
-            and not streamed and not frontier_mode and batch_splits == 0
-            and not vmapped and self._cegb_state is None)
-        # the bins feature-major, made on the device: a tree on a bag
-        # routes every row by one contiguous column a split
-        # (partition.route_in_row_space). An argument of the sampled
-        # iterations' block, as xb is of every block. DART replays its
-        # drop set over every row twice an iteration, one contiguous column
-        # a node (core/replay.py), from the same copy
-        dart_copy = (self.boosting_type == "dart" and self.mesh is None
-                     and not streamed)
-        # the exact grower over all rows on one device keeps its leaf ids
-        # in row space too, where the copy exists or fits beside the table
-        # (partition.row_space_fits): a routing pass a split, not a scatter
-        # through ``order`` once a tree. A GOSS job's unsampled iterations
-        # stay boosting=gbdt's program; CEGB's lazy cost keeps its ids
-        # split by split
-        self._leaf_ids_row_space = (
-            self.mesh is None and not streamed and not frontier_mode
-            and batch_splits == 0 and not self._goss_bag
-            and not cfg.cegb_penalty_feature_lazy and self.xb.shape[1] > 0
-            and (dart_copy or partition_mod.row_space_fits(self.xb)))
-        self._bins_by_col = (
-            jax.jit(partition_mod.bins_by_column)(self.xb)
-            if (self._goss_bag or dart_copy or self._leaf_ids_row_space)
-            and self.xb.shape[1] > 0 else None)
+        # read by the benchmark's GOSS job (bench/jobs/goss_window.py)
+        self._goss_bag = plan.goss_bag
+        # the bins feature-major, made on the device: a tree on a bag or
+        # over all rows routes every row by one contiguous column a split
+        # (partition.route_in_row_space), and a replay of stored trees
+        # reads its columns so (core/replay.py). An argument of the blocks
+        # that route so, as xb is of every block
+        self._bins_by_col = (jax.jit(partition_mod.bins_by_column)(self.xb)
+                             if plan.copy else None)
         # (top_cnt, other_cnt, the others' multiplier) from the REAL row
         # count, not the mesh-padding-inflated one — padded rows carry
         # |g·h| = 0 and sort last, so top-k over the padded array with real
@@ -756,40 +612,16 @@ class GBDT:
             self._goss_counts = bag_counts(
                 self.num_data_orig, cfg.top_rate, cfg.other_rate)
 
-        # observability: built before grow_params so the device-side
-        # health piggy-back (GrowParams.obs_health) keys off the resolved
-        # health action
+        # observability: the device-side health piggy-back
+        # (GrowParams.obs_health) keys off the resolved health action
         from ..obs.runtime import TrainingObs
         self.obs = TrainingObs.from_config(cfg)
-
-        # resolved once: _resolve_hist_impl logs a user-facing warning on
-        # the f64-routes-off-pallas path, which must not repeat per call
-        hist_impl = _resolve_hist_impl(cfg)
-        # packed-bin device matrix (core/binpack.py): the int32-word
-        # layout rides the frontier grower on single-device in-memory
-        # runs — mesh learners shard the feature axis of the plain
-        # matrix, and streamed chunks pack per-chunk in the pipeline.
-        # nibble vs byte only matters at the DATASET level (pair
-        # coding); on device both store 8-bit codes 4-per-word, so the
-        # decision here is solely mode != "none".
-        word_packed_cols = 0
-        if streamed:
-            if self._stream.packed:
-                word_packed_cols = int(self._stream.num_cols)
-        elif frontier_mode and self.mesh is None:
-            from ..core.binpack import resolve_bin_packing
-            pack_mode = resolve_bin_packing(
-                cfg.tpu_bin_packing, streamed=False,
-                tpu_shaped=partition_mod.tpu_shaped_backend(),
-                col_num_bin=list(ds.col_num_bin))
-            if pack_mode != "none":
-                word_packed_cols = int(xb_np.shape[1])
         self.grow_params = GrowParams(
             num_leaves=cfg.num_leaves,
             num_bins=self.num_bins,
             max_depth=cfg.max_depth,
             num_forced=num_forced,
-            pool_slots=pool_slots,
+            pool_slots=plan.pool_slots,
             cegb_split_penalty=float(cfg.cegb_tradeoff
                                      * cfg.cegb_penalty_split),
             with_cegb_coupled=bool(len(cfg.cegb_penalty_feature_coupled)),
@@ -804,55 +636,17 @@ class GBDT:
                 cat_smooth=cfg.cat_smooth, cat_l2=cfg.cat_l2,
                 max_cat_to_onehot=cfg.max_cat_to_onehot,
                 min_data_per_group=cfg.min_data_per_group),
-            # 0 = auto: 4096 where the tile loop is TPU-shaped (round-4
-            # on-chip sweep: 1.97 vs 1.80 iters/s at 16384; 65536+
-            # strictly worse), 16384 on CPU (fewer while-loop trips win
-            # when indexed ops are cheap); the same rule picks the tile's
-            # placement (partition.window_placement)
-            row_chunk=(int(cfg.tpu_row_chunk) or
-                       (4096 if partition_mod.tpu_tiles(hist_impl)
-                        else 16384)),
-            # CPU: XLA scatter-add wins; TPU: the Pallas VMEM-accumulator
-            # kernel is the default device path (the GPUTreeLearner analog,
-            # gpu_tree_learner.cpp:951-1045); one-hot matmul only when named
-            hist_impl=hist_impl,
-            hist_dtype=_hist_dtype(cfg),
-            voting_top_k=(cfg.top_k if cfg.tree_learner == "voting"
-                          and self.mesh is not None else 0),
-            with_categorical=int(np.count_nonzero(
-                np.asarray(self.feature_meta.is_categorical))),
-            all_rows_in_bag=(
-                not (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0)
-                and self.boosting_type != "rf"
-                and (self.boosting_type != "goss" or self._goss_bag)
-                and row_valid is None and not streamed),
-            use_partition=(self.mesh is None or self._partition_on_mesh),
-            partition_on_mesh=self._partition_on_mesh,
-            vmapped_classes=vmapped,
-            batch_splits=batch_splits,
-            frontier_mode=frontier_mode,
-            # reduce-scatter wave histograms (DataRSLearner): resolved at
-            # padding time — needs frontier + data learner + a data axis +
-            # tpu_frontier_rs + f32 histograms (and columns padded to the
-            # axis size, which _frontier_rs guaranteed above)
-            frontier_rs=(frontier_mode and self._frontier_rs),
-            # wave-width bucketing: single-device vmapped multiclass now
-            # routes to grow_tree_frontier_classes, which hoists the
-            # width switch OUTSIDE the vmap (an unbatched branch index),
-            # so bucketing stays on there; it remains off for vmapped
-            # growth over a mesh, where vmapping the shard_map'd grower
-            # would lower the switch to execute-ALL-branches. Also off
-            # when streaming: a ladder would multiply the per-chunk
-            # kernel set by its length and make the compiled-program
-            # count depend on which widths a run visits (the perf gate
-            # pins that count invariant in chunk count)
-            frontier_bucketing=(frontier_mode
-                                and not (vmapped and self.mesh is not None)
-                                and not streamed
-                                and bool(cfg.tpu_frontier_bucketing)),
-            word_packed_cols=word_packed_cols,
-            with_efb=ds.has_bundles or ds.has_packed,
-            num_feat_bins=self.num_feat_bins,
+            row_chunk=plan.row_chunk, hist_impl=plan.hist_impl,
+            hist_dtype=plan.hist_dtype, voting_top_k=plan.voting_top_k,
+            with_categorical=plan.facts.categorical,
+            all_rows_in_bag=plan.all_rows_in_bag,
+            use_partition=plan.use_partition,
+            partition_on_mesh=plan.partition_on_mesh,
+            vmapped_classes=plan.vmapped, batch_splits=plan.batch_splits,
+            frontier_mode=plan.frontier_mode, frontier_rs=plan.frontier_rs,
+            frontier_bucketing=plan.frontier_bucketing,
+            word_packed_cols=plan.word_packed_cols,
+            with_efb=plan.facts.bundled, num_feat_bins=self.num_feat_bins,
             # single source of truth: the marginalization width IS the
             # largest pack_partner the layout recorded, and the packed
             # subset is wherever a mod was recorded
@@ -863,23 +657,10 @@ class GBDT:
                 int(i) for i in np.nonzero(
                     np.asarray(self.feature_meta.pack_mod))[0])
             if self.feature_meta.pack_mod is not None else (),
-            # frontier health piggy-back rides the single-device /
-            # GSPMD growth call; the explicit shard_map learner slices
-            # the aux slot off, so it stays off there (iteration-level
-            # grad/hess health still applies on every path)
-            obs_health=(frontier_mode and not self._partition_on_mesh
-                        and not (streamed and self.mesh is not None)
-                        and self.obs.health_enabled),
-            # model statistics ride the same aux slot under the same
-            # guard; the shard_map learners slice aux off, so they fall
-            # back to host-side recomputation at materialize (the
-            # streamed mesh grower carries no aux slot at all)
-            obs_modelstats=(frontier_mode and not self._partition_on_mesh
-                            and not (streamed and self.mesh is not None)
-                            and bool(cfg.obs_modelstats)))
+            obs_health=plan.obs_aux and self.obs.health_enabled,
+            obs_modelstats=plan.obs_modelstats)
 
-        self._word_packed_cols = word_packed_cols
-        if word_packed_cols and not streamed:
+        if plan.word_packed_cols and not streamed:
             # replace the device matrix with its packed words NOW — the
             # uint8 copy was never materialized on device (self.xb above
             # is only committed lazily by jnp.asarray at first use on
@@ -892,14 +673,10 @@ class GBDT:
                 self.xb = jnp.asarray(words)
             Log.info("bin packing: %d uint8 columns stored as %d int32 "
                      "words/row on device (tpu_bin_packing=%s)",
-                     word_packed_cols, self.xb.shape[1],
+                     plan.word_packed_cols, self.xb.shape[1],
                      cfg.tpu_bin_packing)
 
         if streamed:
-            if not frontier_mode:
-                raise LightGBMError(
-                    "streamed training requires the frontier wave grower "
-                    "(tree_growth=frontier with f32 histograms)")
             from ..stream.grow_stream import StreamFrontierGrower
             self._stream_grower = StreamFrontierGrower(
                 self._stream, self.feature_meta, self.grow_params,
@@ -924,7 +701,6 @@ class GBDT:
         self._init_scores_provided = ds.metadata.init_score is not None
         self.scores = jnp.asarray(init_scores)
         if self.mesh is not None:
-            from ..parallel import mesh as mesh_mod
             self.scores = jax.device_put(
                 self.scores, mesh_mod.row_sharding(self.mesh, extra_dims=1))
         self.boost_from_average_done = False
@@ -1095,8 +871,8 @@ class GBDT:
         (xb_cols [D, N, Cd], meta_local FeatureMeta of [D, Fd] arrays,
         global_of_local [D, Fd]) device_put so device d holds row d.
 
-        Requires no EFB/packing (columns == features), which _setup_train
-        already enforces for meshes."""
+        Requires no EFB/packing (columns == features), which the plan
+        refuses on a mesh (boosting/plan.py)."""
         from ..parallel import mesh as mesh_mod
         from jax.sharding import NamedSharding, PartitionSpec as P
         d = self.mesh.shape[mesh_mod.FEATURE_AXIS]
@@ -1153,10 +929,10 @@ class GBDT:
         SerialTreeLearner feature_used / feature_used_in_data,
         serial_tree_learner.cpp:103-112). None when CEGB is off."""
         cfg = self.config
+        if not plan_mod.cegb_set(cfg):
+            return None
         coupled = list(cfg.cegb_penalty_feature_coupled)
         lazy = list(cfg.cegb_penalty_feature_lazy)
-        if not coupled and not lazy and cfg.cegb_penalty_split <= 0:
-            return None
         from ..core.grow import CegbState
         f = int(self.feature_meta.num_bin.shape[0])
         ds = self.train_data
@@ -1227,25 +1003,16 @@ class GBDT:
         ``self._iter_capture`` holds the tuple to pass each call.
         """
         params = self.grow_params
+        plan = self.plan
         mesh = self.mesh
         obj = self.objective
         k = self.num_tree_per_iteration
         n = self.num_data
         use_input = self._use_input_grads or obj is None
-        # per-row device arrays living on the objective (label, weights,
-        # trans_label, onehot, ...) — anything get_gradients might read
-        obj_row_names = tuple(sorted(
-            nm for nm, v in (obj.__dict__.items() if obj is not None else ())
-            if isinstance(v, jnp.ndarray) and v.ndim >= 1
-            and v.shape[0] in (n, self.num_data_orig)))
+        obj_row_names = _obj_row_names(obj, n, self.num_data_orig)
         self._iter_capture = (
             self.xb, tuple(getattr(obj, nm) for nm in obj_row_names),
             self._fp_capture, self.feature_meta)
-        import copy as _copy
-        # device-side health flags (lightgbm_tpu.obs): computed from
-        # values the step already holds — two reductions over grad/hess
-        # plus the grower's aux accumulator. Off: the step returns a
-        # constant zero vector and no health compute enters the program.
         health_on = self.obs.health_enabled
         # which GOSS sampler this program holds. None: no GOSS, or under a
         # bag (_goss_bag) the unsampled iterations' program, which is
@@ -1262,63 +1029,21 @@ class GBDT:
             top_cnt, other_cnt, goss_multiply = self._goss_counts
 
         forced_splits = self._forced_splits
-        # RenewTreeOutput objectives (L1/Quantile/MAPE): leaf refit runs
-        # IN-GRAPH (core/renew.py) — no host round-trip, and train_many
-        # block fusion stays eligible
-        renew_alpha = None
-        renew_w_attr = None
-        if not use_input and obj is not None \
-                and getattr(obj, "renew_percentile", None) is not None:
-            renew_alpha = float(obj.renew_percentile())
-            renew_w_attr = ("label_weight" if obj.name == "mape"
-                            else "weights")
+        renew = None if use_input else _renew_of(obj)
 
         def run_iter(xb, obj_rows, fp_capture, meta, scores, sample_mask,
                      feature_mask, grad_in, hess_in, lr, goss_active,
                      goss_key, cegb_state, stopped_in, bins_by_col):
             with jax.named_scope("lgbm.gradients"):
-                # gradients: objective or custom (grad_in) (gbdt.cpp:333-347)
-                if not use_input:
-                    # bind the argument arrays onto a shallow copy — the
-                    # traced values, not the captured originals, feed
-                    # get_gradients
-                    o = _copy.copy(obj)
-                    for nm, v in zip(obj_row_names, obj_rows):
-                        setattr(o, nm, v)
-                    if k == 1:
-                        g, h = o.get_gradients(scores[:, 0])
-                        g = g[:, None]
-                        h = h[:, None]
-                    else:
-                        g, h = o.get_gradients(scores)
-                else:
-                    g, h = grad_in, hess_in
-
+                # gradients: objective or custom (grad_in)
+                o = None if use_input else _bind(obj, obj_row_names,
+                                                 obj_rows)
+                g, h = (grad_in, hess_in) if use_input else \
+                    _gradients(o, scores)
                 if goss_mode == "mask":
-                    # GOSS one-side sampling as a multiplier
-                    # (goss.hpp:87-135): keep all of the top |g*h| rows,
-                    # Bernoulli-sample the rest, amplify their grad/hess by
-                    # (n - top)/other so expectations are unbiased; every
-                    # row stays in the grower's passes, the dropped ones at
-                    # weight 0. Warmup iterations (goss_active == 0) skip
-                    # the sort entirely.
-                    def goss_mult(_):
-                        gh = jnp.sum(jnp.abs(g * h), axis=1)
-                        thr = jax.lax.top_k(gh, top_cnt)[0][-1]
-                        is_top = gh >= thr
-                        u = jax.random.uniform(goss_key, (n,))
-                        p_rest = other_cnt / max(n_real - top_cnt, 1)
-                        keep_other = (~is_top) & (u < p_rest)
-                        return jnp.where(
-                            is_top, 1.0,
-                            jnp.where(keep_other, goss_multiply, 0.0))
-
-                    mult = jax.lax.cond(goss_active > 0, goss_mult,
-                                        lambda _: jnp.ones((n,), jnp.float32),
-                                        operand=None)
-                    g = g * mult[:, None]
-                    h = h * mult[:, None]
-                    sample_mask = sample_mask * (mult > 0).astype(jnp.float32)
+                    g, h, sample_mask = _goss_as_multiplier(
+                        g, h, sample_mask, goss_active, goss_key,
+                        self._goss_counts, n_real)
 
             bag = bag_code = None
             if goss_mode == "bag":
@@ -1338,19 +1063,20 @@ class GBDT:
                     h = h * mult[:, None]
                     sample_mask = (bag_code > 0).astype(jnp.float32)
                 bag = partition_mod.bag_partition(bag_code > 0,
-                                                  params.row_chunk)
+                                                  plan.row_chunk)
 
-            # one place decides which wave-batched grower runs (the
-            # shard_map and single-device branches below both use it)
+            # the plan's grower and learner pick the growth call: the
+            # wave-batched growers' (the shard_map and single-device
+            # branches below both use it), then the learner's
             grow_batched_fn = None
-            if params.frontier_mode:
+            if plan.grower == plan_mod.FRONTIER:
                 from ..core.grow_frontier import \
                     grow_tree_frontier as grow_batched_fn
-            elif params.batch_splits > 0:
+            elif plan.grower == plan_mod.BATCHED:
                 from ..core.grow_batched import \
                     grow_tree_batched as grow_batched_fn
 
-            if fp_capture is not None:
+            if plan.learner == plan_mod.FEATURE:
                 # explicit feature-parallel: one shard_map over the feature
                 # axis; rows replicated, column slices + local metas device-
                 # varying, best splits struct-allreduced inside grow_tree
@@ -1382,7 +1108,7 @@ class GBDT:
                     t, li = grow_fp(xb, xb_cols, meta_loc, gofl, gk, hk,
                                     sample_mask, meta, feature_mask)
                     return t, li, None, None
-            elif params.partition_on_mesh or params.voting_top_k > 0:
+            elif plan.shard_mapped:
                 # explicit shard_map learners (mutually exclusive configs):
                 # - data-parallel partition: each device partitions its
                 #   local rows and histograms the globally smaller child's,
@@ -1398,13 +1124,9 @@ class GBDT:
                 tree_spec = jax.tree.map(lambda _: P(),
                                          empty_tree(params.num_leaves))
                 meta_specs = jax.tree.map(lambda _: P(), meta)
-                has_cegb = self._cegb_state is not None \
-                    and params.voting_top_k == 0
-                # grow_one's definedness below depends on this invariant
-                # (enforced at config time, gbdt batched gating): keep it
-                # local so relaxing that check can't unbind grow_one
-                assert not (has_cegb and grow_batched_fn is not None), \
-                    "wave-batched growth cannot carry CEGB state"
+                # neither the wave growers nor the voting learner carry
+                # CEGB state: plan._refuse
+                has_cegb = self._cegb_state is not None
 
                 if grow_batched_fn is not None:
                     def _grow_core(xbj, gj, hj, mj, mt, fm):
@@ -1447,8 +1169,7 @@ class GBDT:
                         return grown[:2] + ((grown.work,) if with_work
                                             else ())
                 if not has_cegb:
-                    with_work = (params.partition_on_mesh
-                                 and grow_batched_fn is None)
+                    with_work = plan.exact_partition
                     grow_sharded = shard_map(
                         _grow_core,
                         mesh=mesh, in_specs=(P(DATA_AXIS), P(DATA_AXIS),
@@ -1474,16 +1195,9 @@ class GBDT:
                                      forced=forced_splits, cegb=cs, bag=bag,
                                      bins_by_col=bins_by_col)
 
-            # class batching: k == 1 calls directly; multiclass maps
-            # classes sequentially when (a) the pool is capped — vmap
-            # would turn the rebuild-on-miss lax.cond into a both-branches
-            # select, and sequential keeps one pool's worth of live
-            # memory, the point of the cap — or (b) the backend is
-            # TPU-shaped, where sequential measured 1.9x faster than vmap
-            # even uncapped (docs/Performance.md "Round 4").
-            # params.vmapped_classes is the ONE predicate: grow_tree keys
-            # its placement/pool decisions off the same flag this
-            # dispatch uses, so the two can never disagree.
+            # class batching: k == 1 calls directly, else the classes grow
+            # under one vmap or in sequence, as plan.vmapped says (the
+            # growers read the same flag as GrowParams.vmapped_classes).
             # every grow_one returns (tree, leaf ids, the grower's third
             # output, the tree's work counts or None: grow.Grown)
             if k == 1:
@@ -1493,10 +1207,9 @@ class GBDT:
                 cegb_out = (jax.tree.map(lambda a: a[None], cb1)
                             if cb1 is not None else None)
                 work = wk1[None] if wk1 is not None else None
-            elif params.vmapped_classes:
-                if params.frontier_mode and fp_capture is None \
-                        and not params.partition_on_mesh \
-                        and params.voting_top_k == 0:
+            elif plan.vmapped:
+                if plan.grower == plan_mod.FRONTIER \
+                        and not plan.shard_mapped:
                     # class-batched frontier growth with the wave-width
                     # switch OUTSIDE the vmap (grow_frontier.py): the
                     # branch index is an unbatched max-live scalar, so
@@ -1515,18 +1228,8 @@ class GBDT:
                 trees, leaf_ids, cegb_out, work = lax.map(
                     lambda gh: grow_one(gh[0], gh[1], cegb_state),
                     (g.T, h.T))
-            # the grower's third output is CEGB state on the exact path
-            # and, on the frontier path, the obs aux: the [K, 2] health
-            # accumulator with obs_health, or the (health_or_None,
-            # [K, F, MS_WIDTH] mstats) tuple with obs_modelstats (the
-            # frontier and CEGB paths are config-exclusive)
-            grower_health = None
-            grower_mstats = None
-            if params.frontier_mode and params.obs_modelstats:
-                aux, cegb_out = cegb_out, None
-                grower_health, grower_mstats = aux
-            elif params.frontier_mode and params.obs_health:
-                grower_health, cegb_out = cegb_out, None
+            grower_health, grower_mstats, cegb_out = _grower_aux(
+                plan, health_on, cegb_out)
             if cegb_state is not None:
                 # classes train from the iteration-start state; acquisitions
                 # merge across class trees for the next iteration (the
@@ -1538,50 +1241,10 @@ class GBDT:
             else:
                 cegb_new = None
             with jax.named_scope("lgbm.score_update"):
-                if renew_alpha is not None:
-                    # device RenewTreeOutput (serial_tree_learner.cpp:850-928):
-                    # refit leaf values to the weighted percentile of residuals
-                    # against the PRE-update scores, exactly like the
-                    # reference's post-growth renew
-                    from ..core.renew import renew_leaf_values
-                    rw = getattr(o, renew_w_attr, None)
-                    if rw is None:
-                        rw = jnp.ones_like(o.label)
-
-                    def renew_one(t, li, sc_col):
-                        # scores live in the (possibly reg_sqrt-transformed)
-                        # label space the gradients were computed in
-                        lab = getattr(o, "trans_label", None)
-                        lab = o.label if lab is None else lab
-                        new_lv = renew_leaf_values(
-                            lab - sc_col, rw, li, sample_mask,
-                            params.num_leaves, renew_alpha, t.leaf_value)
-                        return t._replace(leaf_value=new_lv)
-
-                    trees = jax.vmap(renew_one, in_axes=(0, 0, 1))(
-                        trees, leaf_ids, scores)
-                # score update fast path: leaf_id -> leaf_value (shrinkage
-                # applied), by selects up to SELECT_LOOKUP_MAX leaves
-                deltas = jax.vmap(
-                    lambda t, li: tree_mod.leaf_value_of(t.leaf_value, li)
-                    * lr)(trees, leaf_ids)                          # [K, N]
-                # A fully-stumped iteration (no class tree split) means training
-                # has converged; the reference discards the tree and stops
-                # (gbdt.cpp:379-396). The stop flag accumulates ON DEVICE across
-                # iterations: once any iteration stumps, every later dispatched
-                # iteration freezes the scores too — so the async driver can
-                # discard the overshoot trees at the next flush without
-                # rewinding anything, even when bagging/feature sampling would
-                # have let a later iteration split again.
-                any_split = jnp.any(trees.num_leaves > 1)
-                stopped_out = stopped_in | ~any_split
-                apply = (any_split & ~stopped_in).astype(jnp.float32)
-                new_scores = scores + deltas.T * apply
-            if health_on:
-                from ..obs.health import health_vec
-                health = health_vec(g, h, any_split, grower_health)
-            else:
-                health = jnp.zeros((4,), jnp.float32)
+                trees, new_scores, stopped_out, any_split = _add_trees(
+                    o, renew, trees, leaf_ids, scores, sample_mask, lr,
+                    stopped_in, params.num_leaves)
+            health = _health(health_on, g, h, any_split, grower_health)
             # grower_mstats is None unless obs_modelstats, the class
             # trees' work counts ([K, 2, W]: grow.Grown) unless they grew
             # over the single-device row partition, the bag's codes unless
@@ -1606,103 +1269,35 @@ class GBDT:
         matching the non-streamed capture convention.
         """
         obj = self.objective
-        k = self.num_tree_per_iteration
-        n = self.num_data
-        obj_row_names = tuple(sorted(
-            nm for nm, v in (obj.__dict__.items() if obj is not None else ())
-            if isinstance(v, jnp.ndarray) and v.ndim >= 1
-            and v.shape[0] in (n, self.num_data_orig)))
+        obj_row_names = _obj_row_names(obj, self.num_data,
+                                       self.num_data_orig)
         self._stream_capture = tuple(getattr(obj, nm)
                                      for nm in obj_row_names)
-        import copy as _copy
-
-        def bind(obj_rows):
-            o = _copy.copy(obj)
-            for nm, v in zip(obj_row_names, obj_rows):
-                setattr(o, nm, v)
-            return o
-
         health_on = self.obs.health_enabled
-        is_goss = self.boosting_type == "goss"
-        if is_goss:
-            n_real = self.num_data_orig
-            top_cnt, other_cnt, goss_multiply = self._goss_counts
+        goss_counts = self._goss_counts
+        # padded rows accumulate leaf deltas of whatever leaf id their
+        # slot happens to carry, so unlike the mesh-padding case their
+        # |g*h| is NOT zero — masked before ranking or they'd occupy top-k
+        # slots
         row_valid = self._row_valid
-        renew_alpha = None
-        renew_w_attr = None
-        if obj is not None \
-                and getattr(obj, "renew_percentile", None) is not None:
-            renew_alpha = float(obj.renew_percentile())
-            renew_w_attr = ("label_weight" if obj.name == "mape"
-                            else "weights")
+        renew = _renew_of(obj)
 
         def stream_pre(obj_rows, scores, sample_mask, goss_active,
                        goss_key):
-            o = bind(obj_rows)
-            if k == 1:
-                g, h = o.get_gradients(scores[:, 0])
-                g = g[:, None]
-                h = h[:, None]
-            else:
-                g, h = o.get_gradients(scores)
-            if is_goss:
-                def goss_mult(_):
-                    gh = jnp.sum(jnp.abs(g * h), axis=1)
-                    if row_valid is not None:
-                        # padded rows accumulate leaf deltas of whatever
-                        # leaf id their slot happens to carry, so unlike
-                        # the mesh-padding case their |g*h| is NOT zero —
-                        # mask before ranking or they'd occupy top-k slots
-                        gh = gh * row_valid
-                    thr = jax.lax.top_k(gh, top_cnt)[0][-1]
-                    is_top = gh >= thr
-                    u = jax.random.uniform(goss_key, (n,))
-                    p_rest = other_cnt / max(n_real - top_cnt, 1)
-                    keep_other = (~is_top) & (u < p_rest)
-                    return jnp.where(is_top, 1.0,
-                                     jnp.where(keep_other, goss_multiply,
-                                               0.0))
-
-                mult = jax.lax.cond(goss_active > 0, goss_mult,
-                                    lambda _: jnp.ones((n,), jnp.float32),
-                                    operand=None)
-                g = g * mult[:, None]
-                h = h * mult[:, None]
-                sample_mask = sample_mask * (mult > 0).astype(jnp.float32)
+            g, h = _gradients(_bind(obj, obj_row_names, obj_rows), scores)
+            if goss_counts is not None:
+                return _goss_as_multiplier(g, h, sample_mask, goss_active,
+                                      goss_key, goss_counts,
+                                      self.num_data_orig, row_valid)
             return g, h, sample_mask
 
         def stream_post(obj_rows, trees, leaf_ids, scores, sample_mask,
                         g, h, grower_health, lr, stopped_in):
-            if renew_alpha is not None:
-                from ..core.renew import renew_leaf_values
-                o = bind(obj_rows)
-                rw = getattr(o, renew_w_attr, None)
-                if rw is None:
-                    rw = jnp.ones_like(o.label)
-
-                def renew_one(t, li, sc_col):
-                    lab = getattr(o, "trans_label", None)
-                    lab = o.label if lab is None else lab
-                    new_lv = renew_leaf_values(
-                        lab - sc_col, rw, li, sample_mask,
-                        self.grow_params.num_leaves, renew_alpha,
-                        t.leaf_value)
-                    return t._replace(leaf_value=new_lv)
-
-                trees = jax.vmap(renew_one, in_axes=(0, 0, 1))(
-                    trees, leaf_ids, scores)
-            deltas = jax.vmap(
-                lambda t, li: tree_mod.leaf_value_of(t.leaf_value, li)
-                * lr)(trees, leaf_ids)
-            any_split = jnp.any(trees.num_leaves > 1)
-            stopped_out = stopped_in | ~any_split
-            apply = (any_split & ~stopped_in).astype(jnp.float32)
-            new_scores = scores + deltas.T * apply
-            if health_on:
-                from ..obs.health import health_vec
-                health = health_vec(g, h, any_split, grower_health)
-            else:
-                health = jnp.zeros((4,), jnp.float32)
+            trees, new_scores, stopped_out, any_split = _add_trees(
+                _bind(obj, obj_row_names, obj_rows), renew, trees, leaf_ids,
+                scores, sample_mask, lr, stopped_in,
+                self.grow_params.num_leaves)
+            health = _health(health_on, g, h, any_split, grower_health)
             return pack_trees(trees), new_scores, stopped_out, health
 
         self._stream_pre = jax.jit(stream_pre)
@@ -1727,7 +1322,6 @@ class GBDT:
         iter_idx = self.iter_
         obs = self.obs
         obs.perfetto_step(iter_idx, iter_idx + 1)
-        params = self.grow_params
         k = self.num_tree_per_iteration
         # request-scoped iteration trace (obs/reqtrace.py): a no-op span
         # unless obs_trace is on; mirrors the serving span tree with
@@ -1760,14 +1354,9 @@ class GBDT:
                     aux_l.append(aux)
                 trees = jax.tree.map(lambda *a: jnp.stack(a), *trees_l)
                 leaf_ids = jnp.stack(lids_l)
-                grower_health = None
-                mstats = None
-                if params.obs_modelstats:
-                    if aux_l[0][0] is not None:
-                        grower_health = jnp.stack([a[0] for a in aux_l])
-                    mstats = jnp.stack([a[1] for a in aux_l])
-                elif params.obs_health:
-                    grower_health = jnp.stack(aux_l)
+                grower_health, mstats, _ = _grower_aux(
+                    self.plan, obs.health_enabled,
+                    jax.tree.map(lambda *a: jnp.stack(a), *aux_l))
                 pspan = tspan.child("score_commit")
                 packed, new_scores, self._stopped_dev, health = \
                     self._stream_post(
@@ -1789,19 +1378,7 @@ class GBDT:
                                            if mstats is not None else None)}
         self._pending.append(pend)
         self.iter_ += 1
-        if obs.enabled:
-            hrow = np.asarray(health)[None]
-            dur_s, busy_s, wait_s = _host_device_split(block_span,
-                                                       dispatch_span)
-            obs.dispatch_done(iter_idx, 1, dur_s, health_rows=hrow,
-                              busy_s=busy_s, wait_s=wait_s)
-            obs.account_rows(self.num_data_orig)
-            if obs.per_iteration:
-                obs.record_hbm()
-            obs.check_health(hrow, iter_idx, booster=self)
-        elif obs.health_enabled:
-            obs.check_health(np.asarray(health)[None], iter_idx,
-                             booster=self)
+        self._dispatched(iter_idx, 1, health, block_span, dispatch_span)
         tspan.finish("ok")
         if sum(p["count"] for p in self._pending) >= self._flush_every:
             return self._materialize()
@@ -1945,23 +1522,13 @@ class GBDT:
         reload every specialization from disk. Returns per-bucket compile
         counts + seconds. No-op unless the booster grows frontier-mode.
         """
-        from .. import bucketing
         from ..profiling import backend_compile_count, compile_cache_stats
         params = self.grow_params
-        if not getattr(params, "frontier_mode", False) or \
-                self.mesh is not None or self.xb is None:
-            # mesh growth compiles inside shard_map on shard-local shapes,
-            # and streamed growth (self.xb is None) compiles its own
-            # fixed-chunk kernels on first dispatch; the standalone
-            # global-shape warmup would not match either
+        widths = self._frontier_widths()
+        if not widths:
             return {"widths": [], "per_bucket_compiles": {},
                     "seconds": 0.0, "cache_hits": 0, "cache_misses": 0}
         from ..core.histogram import build_histogram_frontier
-        widths = (bucketing.wave_width_ladder(params.num_leaves,
-                                              params.max_depth)
-                  if params.frontier_bucketing
-                  else [bucketing.frontier_max_width(params.num_leaves,
-                                                     params.max_depth)])
         n = self.num_data
         slot = jnp.full((n,), -1, jnp.int32)     # all-inactive: cheap sweep
         g = jnp.zeros((n,), jnp.float32)
@@ -1975,9 +1542,9 @@ class GBDT:
             # lgbm-lint: disable=LGL103 warmup probe, sync is the point
             jax.block_until_ready(build_histogram_frontier(
                 self.xb, slot, g, h, mask, num_bins=params.num_bins,
-                num_slots=w, row_chunk=params.row_chunk,
-                impl=params.hist_impl,
-                packed_cols=params.word_packed_cols))
+                num_slots=w, row_chunk=self.plan.row_chunk,
+                impl=self.plan.hist_impl,
+                packed_cols=self.plan.word_packed_cols))
             per_bucket[w] = backend_compile_count() - c0
         after = compile_cache_stats()
         return {
@@ -1989,6 +1556,18 @@ class GBDT:
             "cache_misses": (after["persistent_cache_misses"]
                              - before["persistent_cache_misses"]),
         }
+
+    def _frontier_widths(self) -> List[int]:
+        """The wave widths the frontier grower dispatches on one device,
+        in memory (a mesh's growth compiles inside shard_map, a streamed
+        one per chunk: a global-shape entry would match neither)."""
+        from .. import bucketing
+        p = self.grow_params
+        if self.plan.grower != plan_mod.FRONTIER or self.mesh is not None:
+            return []
+        if self.plan.frontier_bucketing:
+            return bucketing.wave_width_ladder(p.num_leaves, p.max_depth)
+        return [bucketing.frontier_max_width(p.num_leaves, p.max_depth)]
 
     def _maybe_warm_ladder(self) -> None:
         """Run the bucket-ladder warmup once, at train start — only when a
@@ -2034,22 +1613,14 @@ class GBDT:
                 *self.train_block_sds(block),
                 extra_key="block=%d" % block)
         params = self.grow_params
-        if getattr(params, "frontier_mode", False) and self.mesh is None \
-                and self.xb is not None:
-            # mesh growth lowers inside shard_map on shard-local shapes;
-            # the standalone global-shape entry would not price it
-            from .. import bucketing
+        widths = self._frontier_widths()
+        if widths:
             from ..core.grow_frontier import (wave_fused_entry,
                                               wave_hist_entry)
-            widths = (bucketing.wave_width_ladder(params.num_leaves,
-                                                  params.max_depth)
-                      if params.frontier_bucketing
-                      else [bucketing.frontier_max_width(
-                          params.num_leaves, params.max_depth)])
             n = self.xb.shape[0]
             # real stored-column count, not the word-matrix width: the
             # packed entry's SDS mirror derives its own word shape
-            ncols = params.word_packed_cols or self.xb.shape[1]
+            ncols = self.plan.word_packed_cols or self.xb.shape[1]
             fmask = jnp.ones((ncols,), bool)
             for w in widths:
                 hfn, hargs, hkw = wave_hist_entry(
@@ -2089,17 +1660,12 @@ class GBDT:
 
     def _block_eligible(self) -> bool:
         """Whether iterations may run as fused on-device blocks: no
-        per-iteration host work inside the tree's step. DART's drop set
-        is host work BETWEEN iterations: it runs blocks of one."""
-        # streamed growth is host-driven (per-chunk kernels under a host
-        # wave loop) — it cannot fuse into one scanned device program;
-        # per-iteration dispatch is the streamed fast path.
-        # observability=full wants TRUE per-iteration spans and
-        # health-within-one-iteration, so it forgoes block fusion — that
-        # cost is the documented basic/full trade
-        return (self.boosting_type in ("gbdt", "goss", "dart")
-                and not self._use_input_grads
-                and self._stream is None
+        per-iteration host work inside the tree's step (TrainPlan
+        .fuse_blocks; DART's drop set is host work BETWEEN iterations: it
+        runs blocks of one), no gradients made on the host, and not
+        observability=full, which wants TRUE per-iteration spans and
+        health within one iteration (the documented basic/full trade)."""
+        return (self.plan.fuse_blocks and not self._use_input_grads
                 and not self.obs.per_iteration)
 
     def train_many(self, num_iters: int) -> bool:
@@ -2168,23 +1734,33 @@ class GBDT:
             self._keep_aux(grow_aux, self.iter_ + block - 1)
             self.iter_ += block
             done += block
-            if obs.enabled:
-                hrows = np.asarray(healths)
-                dur_s, busy_s, wait_s = _host_device_split(block_span,
-                                                           dispatch_span)
-                obs.dispatch_done(self.iter_ - block, block, dur_s,
-                                  health_rows=hrows, busy_s=busy_s,
-                                  wait_s=wait_s)
-                obs.account_rows(self.num_data_orig * block)
-                obs.record_hbm()
-                obs.check_health(hrows, self.iter_ - block, booster=self)
-            elif obs.health_enabled:
-                obs.check_health(np.asarray(healths), self.iter_ - block,
-                                 booster=self)
+            self._dispatched(self.iter_ - block, block, healths, block_span,
+                             dispatch_span, hbm=True)
             if sum(p.get("count", 1) for p in self._pending) \
                     >= self._flush_every:
                 self._materialize()
         return self._stopped
+
+    def _dispatched(self, start: int, count: int, healths, block_span,
+                    dispatch_span, hbm: bool = False) -> None:
+        """A dispatched block of ``count`` iterations from ``start`` to the
+        observability facade (host-busy / device-wait split, rows, HBM
+        after a fused block or under observability=full) and its health
+        rows checked."""
+        obs = self.obs
+        if obs.enabled:
+            hrows = np.asarray(healths).reshape(count, -1)
+            dur_s, busy_s, wait_s = _host_device_split(block_span,
+                                                       dispatch_span)
+            obs.dispatch_done(start, count, dur_s, health_rows=hrows,
+                              busy_s=busy_s, wait_s=wait_s)
+            obs.account_rows(self.num_data_orig * count)
+            if hbm or obs.per_iteration:
+                obs.record_hbm()
+            obs.check_health(hrows, start, booster=self)
+        elif obs.health_enabled:
+            obs.check_health(np.asarray(healths).reshape(count, -1), start,
+                             booster=self)
 
     def _block_inputs(self, block: int):
         """A block's per-iteration inputs, made on the host: the feature
@@ -2232,9 +1808,9 @@ class GBDT:
     def _rowspace_bins(self) -> Optional[jnp.ndarray]:
         """The last argument of a block: the feature-major bins where its
         trees route every row in row space (on a bag, or over all rows
-        with ``_leaf_ids_row_space``), else None (an empty pytree: a GOSS
+        with TrainPlan.row_space_ids), else None (an empty pytree: a GOSS
         job's unsampled iterations keep the row partition's leaf ids)."""
-        if self._sampled_regime or self._leaf_ids_row_space:
+        if self._sampled_regime or self.plan.row_space_ids:
             return self._bins_by_col
         return None
 
@@ -2496,9 +2072,7 @@ class GBDT:
         self._compiled_block = None
         self._programs.clear()
         self._aot_blocks.clear()
-        if getattr(self, "grow_params", None) is not None \
-                and self.grow_params.frontier_mode \
-                and not self._partition_on_mesh:
+        if self.plan is not None and self.plan.obs_aux:
             self.grow_params = self.grow_params._replace(obs_health=True)
 
     def train_one_iter(self, grad: Optional[np.ndarray] = None,
@@ -2584,19 +2158,7 @@ class GBDT:
         self._pending.append(pend)
         self._keep_aux(grow_aux, iter_idx)
         self.iter_ += 1
-        if obs.enabled:
-            hrow = np.asarray(health)[None]
-            dur_s, busy_s, wait_s = _host_device_split(block_span,
-                                                       dispatch_span)
-            obs.dispatch_done(iter_idx, 1, dur_s, health_rows=hrow,
-                              busy_s=busy_s, wait_s=wait_s)
-            obs.account_rows(self.num_data_orig)
-            if obs.per_iteration:
-                obs.record_hbm()
-            obs.check_health(hrow, iter_idx, booster=self)
-        elif obs.health_enabled:
-            obs.check_health(np.asarray(health)[None], iter_idx,
-                             booster=self)
+        self._dispatched(iter_idx, 1, health, block_span, dispatch_span)
         if sum(p["count"] for p in self._pending) >= self._flush_every:
             return self._materialize()
         return False
@@ -2627,7 +2189,7 @@ class GBDT:
             if p.get("work") is not None:
                 p["span"].counts.update(_work_counts(p["work"]))
         row = 0
-        with_cat = self.grow_params.with_categorical > 0
+        with_cat = self.plan.facts.categorical > 0
         for p in pend:
             if self._stopped:
                 break
@@ -2827,42 +2389,26 @@ class GBDT:
             self._replay_layout = replay_mod.TableLayout(self.train_data)
         return self._replay_layout
 
-    def _replay_kernel(self) -> bool:
-        """Whether a replay over the training table takes the kernel
-        (replay.forest_total_kernel) and not forest_total's passes: over
-        the feature-major copy, on a TPU (or under the interpreted Pallas
-        histogram, so that the CPU tests run what the chip runs), where
-        every stored column holds one numerical feature, a row tile of the
-        copy fits the kernel's VMEM and a tree its SMEM."""
-        if self._bins_by_col is None:
-            return False
-        return (self._replay_layout_of().single
-                and self.grow_params.num_leaves <= replay_mod.KERNEL_LEAVES_MAX
-                and (partition_mod.tpu_shaped_backend()
-                     or self.grow_params.hist_impl == "pallas_interpret")
-                and replay_mod.kernel_vmem_bytes(
-                    self._bins_by_col.shape[0], self.num_tree_per_iteration)
-                <= replay_mod.KERNEL_VMEM_MAX)
-
     def _replay_input(self, xb=None):
         """What a replay reads and how: the training table's feature-major
         copy where the booster holds one, else the row-major ``xb`` (the
         training table's where None); the replay program's statics, the
-        kernel's over the copy where it engages (_replay_kernel)."""
+        kernel's over the copy where it engages (TrainPlan.replay_kernel)."""
         layout = self._replay_layout_of()
         feature_major = xb is None and self._bins_by_col is not None
-        kernel = feature_major and self._replay_kernel()
+        kernel = feature_major and self.plan.replay_kernel
         # the train matrix may be word-packed (int32 words); its
         # feature-major copy, made before the packing, and the valid
         # caches hold plain uint8 columns
-        packed = (getattr(self, "_word_packed_cols", 0) > 0 and xb is None
-                  and not feature_major and self.xb.dtype == jnp.int32)
+        packed = (xb is None and not feature_major
+                  and self.plan.word_packed_cols > 0
+                  and self.xb.dtype == jnp.int32)
         bins = (self._bins_by_col if feature_major
                 else self.xb if xb is None else xb)
         return bins, dict(feature_major=feature_major, packed=packed,
                           pairs=layout.pairs, categorical=layout.categorical,
-                          kernel=kernel, interpret=kernel and not
-                          partition_mod.tpu_shaped_backend())
+                          kernel=kernel, interpret=kernel
+                          and not self.plan.facts.tpu_shaped)
 
     @staticmethod
     def _replay_key(fn, args):

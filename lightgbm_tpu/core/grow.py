@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .histogram import build_histogram
+from .histogram import build_histogram, hist_kernel
 from .partition import (RowPartition, hist_for_leaf, init_partition,
                         leaf_id_from_partition, make_row_gather,
                         partition_rows, route_in_row_space,
@@ -992,7 +992,7 @@ def grow_tree(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
                 s.part, s.leaf_id, leaf, right_leaf, go_left_rows, valid,
                 params.row_chunk, gather_rows,
                 maintain_leaf_id=maintain_lid,
-                windows=window_placement(params.hist_impl,
+                windows=window_placement(hist_kernel(params.hist_impl).pallas,
                                          params.vmapped_classes))
             if row_space:
                 # every row, in a bag or out, takes the split's side in

@@ -48,7 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .histogram import build_histogram, build_histogram_frontier
+from .histogram import (build_histogram, build_histogram_frontier,
+                        hist_kernel)
 from .grow import (GrowParams, TreeArrays, _bin_go_left, _empty_best,
                    count_i32, decode_bundle_value, empty_tree, expand_hist,
                    propagate_monotone_bounds)
@@ -308,7 +309,8 @@ def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
 
         # ---- all 2K children's histograms in one combined build ---------
         hmask = sample_mask * active.astype(jnp.float32)
-        if params.hist_impl.startswith("pallas"):
+        kernel = hist_kernel(params.hist_impl)
+        if kernel.pallas:
             # parent-slot x 6-channel joint kernel: half the slot one-hot
             # width, double the MXU row utilization (round-4 on-chip fix)
             from .histogram_pallas import build_histogram_slots6
@@ -316,7 +318,7 @@ def grow_tree_batched(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
             h6 = psum(build_histogram_slots6(
                 xb, jnp.where(active, rs, -1), go_left.astype(jnp.float32),
                 vals3, num_bins=b, n_slots=kb,
-                interpret=params.hist_impl.endswith("interpret")))
+                interpret=kernel.interpret))
             # h6: [K, C, B, 6]
             ch_hist = jnp.stack([h6[..., :3], h6[..., 3:]],
                                 axis=1).reshape(2 * kb, ncols, b, 3)

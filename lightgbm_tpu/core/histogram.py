@@ -22,13 +22,23 @@ structure-of-arrays stack.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from .binpack import unpack_words
+
+
+class HistKernel(NamedTuple):
+    pallas: bool        # the Pallas kernels, not the XLA matmul / scatter
+    interpret: bool     # in Pallas's interpreter (the CPU tests' spelling)
+
+
+def hist_kernel(impl: str) -> HistKernel:
+    """The one reading of a resolved ``tpu_hist_impl`` spelling."""
+    return HistKernel(impl.startswith("pallas"), impl.endswith("interpret"))
 
 
 def _hist_chunk_matmul(xb_chunk: jnp.ndarray, vals_chunk: jnp.ndarray,
@@ -87,11 +97,11 @@ def hist_tile_vals(xb_rows: jnp.ndarray, vals: jnp.ndarray, num_bins: int,
     path (core/partition.py), which gathers the stacked values in a single
     indexed read per tile."""
     with jax.named_scope("lgbm.hist_tile"):
-        if impl.startswith("pallas"):
+        kernel = hist_kernel(impl)
+        if kernel.pallas:
             from .histogram_pallas import build_histogram_pallas_vals
             return build_histogram_pallas_vals(
-                xb_rows, vals.T, num_bins,
-                interpret=impl.endswith("interpret"))
+                xb_rows, vals.T, num_bins, interpret=kernel.interpret)
         if impl == "scatter":
             return _hist_scatter(xb_rows, vals, num_bins)
         return _hist_chunk_matmul(xb_rows, vals, num_bins)
@@ -127,11 +137,11 @@ def build_histogram(xb: jnp.ndarray, grad: jnp.ndarray, hess: jnp.ndarray,
     """
     n = xb.shape[0]
     f = packed_cols or xb.shape[1]
-    if impl.startswith("pallas"):
-        # pallas | pallas_interpret
+    kernel = hist_kernel(impl)
+    if kernel.pallas:
         from .histogram_pallas import ROW_BLOCK, build_histogram_pallas
         return build_histogram_pallas(xb, grad, hess, mask, num_bins,
-                                      interpret=impl.endswith("interpret"),
+                                      interpret=kernel.interpret,
                                       packed_cols=packed_cols,
                                       row_block=ROW_BLOCK * compensated)
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=-1)  # [N, 3]
@@ -244,12 +254,13 @@ def build_histogram_frontier(xb: jnp.ndarray, slot: jnp.ndarray,
     """
     n = xb.shape[0]
     f = packed_cols or xb.shape[1]
-    if impl.startswith("pallas"):
+    kernel = hist_kernel(impl)
+    if kernel.pallas:
         from .histogram_pallas import build_histogram_slots
         vals = jnp.stack([grad * mask, hess * mask, mask], axis=0)  # [3, N]
         return build_histogram_slots(
             xb, slot, vals, num_bins=num_bins, n_slots=num_slots,
-            interpret=impl.endswith("interpret"), packed_cols=packed_cols)
+            interpret=kernel.interpret, packed_cols=packed_cols)
     vals = jnp.stack([grad * mask, hess * mask, mask], axis=-1)     # [N, 3]
     if impl == "scatter":
         if packed_cols:

@@ -162,29 +162,29 @@ def bins_by_column(xb: jnp.ndarray) -> jnp.ndarray:
 ROW_STATE_BYTES = 32
 
 
-def device_bytes_limit(x: jnp.ndarray) -> Optional[int]:
-    """The memory limit of the device that holds ``x``, or None where the
-    backend reports none (the CPU)."""
-    stats = next(iter(x.devices())).memory_stats()
+def device_bytes_limit(device) -> Optional[int]:
+    """The memory limit of ``device``, or None where the backend reports
+    none (the CPU)."""
+    stats = device.memory_stats()
     return None if not stats else stats.get("bytes_limit")
 
 
-def row_space_bytes(xb: jnp.ndarray) -> int:
-    """The device bytes a tree over all rows holds with the feature-major
-    copy of ``xb`` (bins_by_column): the table, the copy, and a row's
-    lanes of packed rows, of their source and of per-row state."""
-    n, c = xb.shape
-    lanes = -(-max(MIN_PACKED_WIDTH, c + 12) // 128) * 128
-    return 2 * xb.nbytes + n * (2 * lanes + ROW_STATE_BYTES)
+def row_space_bytes(rows: int, cols: int) -> int:
+    """The device bytes a tree over all rows of a [rows, cols] uint8 table
+    holds with its feature-major copy (bins_by_column): the table, the
+    copy, and a row's lanes of packed rows, of their source and of per-row
+    state."""
+    lanes = -(-max(MIN_PACKED_WIDTH, cols + 12) // 128) * 128
+    return 2 * rows * cols + rows * (2 * lanes + ROW_STATE_BYTES)
 
 
-def row_space_fits(xb: jnp.ndarray) -> bool:
+def row_space_fits(rows: int, cols: int, bytes_limit: Optional[int]) -> bool:
     """Whether a tree over all rows may keep its leaf ids in row space:
     what it holds with the copy (row_space_bytes) fits the device's limit
     with room to spare. Where the backend gives no limit, yes, so that the
     CPU runs the chip's path."""
-    limit = device_bytes_limit(xb)
-    return limit is None or row_space_bytes(xb) <= limit - limit // 10
+    return bytes_limit is None or \
+        row_space_bytes(rows, cols) <= bytes_limit - bytes_limit // 10
 
 
 def row_space_leaf_ids0(bins_by_col: jnp.ndarray,
@@ -306,29 +306,23 @@ def make_row_gather(xb: jnp.ndarray, vals: jnp.ndarray,
     return gather_rows
 
 
-def tpu_shaped_backend() -> bool:
-    """Allow-list backend sniff, shared by the bin-packing policy and the
-    GBDT multiclass class-batching decision — an unknown plugin backend
-    counts as NOT TPU-shaped so untested backends keep the conservative
-    paths."""
-    import jax
-    return jax.default_backend() == "tpu"
+def tpu_shaped_backend(backend: Optional[str] = None) -> bool:
+    """Allow-list backend sniff of the dataset's bin-packing policy, the
+    rule a training plan reads too (boosting/plan.py TrainFacts) — an
+    unknown plugin backend counts as NOT TPU-shaped so untested backends
+    keep the conservative paths. ``backend``: a ``jax.default_backend()``
+    name; None reads this process's."""
+    return (backend or jax.default_backend()) == "tpu"
 
 
-def tpu_tiles(hist_impl: str) -> bool:
-    """The one rule for shaping the tile loop to the TPU: the Pallas
-    histogram spellings (the interpret ones too, so the CPU tests cover
-    what the chip runs). It sets the auto ``row_chunk`` (4096, else 16384)
-    and, through window_placement, how a tile's ids are placed."""
-    return hist_impl.startswith("pallas")
-
-
-def window_placement(hist_impl: str, vmapped: bool) -> bool:
+def window_placement(pallas: bool, vmapped: bool) -> bool:
     """Which of partition_rows' two placements its tile loop is built
-    with: the contiguous windows where the loop is TPU-shaped, the element
-    scatter elsewhere — and always under vmapped class batching, where a
-    batched start index turns each window write back into a scatter."""
-    return tpu_tiles(hist_impl) and not vmapped
+    with: the contiguous windows where the loop is TPU-shaped (the Pallas
+    histogram kernels, the interpreted ones too, so that the CPU tests
+    cover what the chip runs), the element scatter elsewhere — and always
+    under vmapped class batching, where a batched start index turns each
+    window write back into a scatter."""
+    return pallas and not vmapped
 
 
 def _write_window(order, packed, k, start):

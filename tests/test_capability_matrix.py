@@ -1,8 +1,8 @@
 """Capability matrix for the fast-path feature combinations: every combination of (learner) x (growth mode) x (forced/CEGB/
 plain) x (pool cap) x (classes) must either train on its EXPECTED path —
-asserted via the engagement flags, so a refactor cannot silently land a
-config on the O(N x leaves) masked fallback — or refuse loudly with
-LightGBMError. No silent third option.
+asserted via the fields of the plan it resolved (boosting/plan.py), so a
+refactor cannot silently land a config on the O(N x leaves) masked
+fallback — or refuse loudly with LightGBMError. No silent third option.
 """
 import json
 import os
@@ -34,15 +34,13 @@ def _forced_file():
 
 
 # rows: (case id, params overrides, expectation)
-# expectation: "raise" | dict of engagement flags to assert
-#   part_mesh -> _partition_on_mesh, fp -> _explicit_fp,
-#   use_part -> grow_params.use_partition, pool -> grow_params.pool_slots>0,
-#   vmapped -> grow_params.vmapped_classes,
-#   batch -> grow_params.batch_splits>0,
-#   frontier -> grow_params.frontier_mode,
-#   frontier_rs -> grow_params.frontier_rs
-#   bag -> _goss_bag (GOSS grows its sampled trees on a bag partition)
-#   cat_free -> train.setup's cat_route_gather_free (a categorical split
+# expectation: "raise" | dict of the plan's fields to assert
+#   part_mesh -> plan.partition_on_mesh, fp -> learner "feature",
+#   use_part -> plan.use_partition, pool -> plan.pool_slots>0,
+#   vmapped -> plan.vmapped, batch -> grower "batched",
+#   frontier -> plan.frontier_mode, frontier_rs -> plan.frontier_rs
+#   bag -> plan.goss_bag (GOSS grows its sampled trees on a bag partition)
+#   cat_free -> plan.cat_route_gather_free (a categorical split
 #     routes its rows without a gather over them: the exact grower's
 #     one-split form; the wave growers test per-row sets through
 #     take_along_axis). "CATEGORICAL" makes the last column one
@@ -206,21 +204,24 @@ def test_capability_matrix(case, overrides, expect):
             return
         bst = lgb.train(params, lgb.Dataset(X, y), num_boost_round=3)
         impl = bst._impl
+        plan = impl.plan
         flags = dict(
-            part_mesh=impl._partition_on_mesh,
-            fp=getattr(impl, "_explicit_fp", False),
-            use_part=impl.grow_params.use_partition,
-            pool=impl.grow_params.pool_slots > 0,
-            vmapped=impl.grow_params.vmapped_classes,
-            batch=impl.grow_params.batch_splits > 0,
-            frontier=impl.grow_params.frontier_mode,
-            frontier_rs=impl.grow_params.frontier_rs,
-            bag=impl._goss_bag,
-            cat_free=bool([s for s in recorded_spans()
-                           if s["name"] == "train.setup"][-1]["counts"]
-                          .get("cat_route_gather_free")))
+            part_mesh=plan.partition_on_mesh,
+            fp=plan.learner == "feature",
+            use_part=plan.use_partition,
+            pool=plan.pool_slots > 0,
+            vmapped=plan.vmapped,
+            batch=plan.grower == "batched",
+            frontier=plan.frontier_mode,
+            frontier_rs=plan.frontier_rs,
+            bag=plan.goss_bag,
+            cat_free=plan.cat_route_gather_free)
         for key, want in expect.items():
             assert flags[key] == want, (case, key, flags)
+        assert (impl._fp_capture is not None) == flags["fp"], case
+        setup = [s for s in recorded_spans()
+                 if s["name"] == "train.setup"][-1]["counts"]
+        assert setup["cat_route_gather_free"] == flags["cat_free"], case
         if warns:
             assert any("Warning" in m and warns in m for m in logged), logged
         # and the model actually learned (no silently-dead path)

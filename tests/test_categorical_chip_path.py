@@ -522,5 +522,103 @@ def test_the_numerical_train_block_lowers_to_the_recorded_program():
     assert hashlib.sha256(numerical_block_text().encode()).hexdigest() == want
 
 
+# ------------------------------- the other cells' blocks, unmoved
+def _other_table(n=3000, f=12, seed=46):
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, f))
+    X[rng.random(n) < 0.2, 1] = np.nan
+    return X, (X[:, 0] + 0.5 * X[:, 2] > 0).astype(float)
+
+
+def _lowered_block(params, rounds, X=None, y=None):
+    """The booster after ``rounds`` iterations and its one-iteration
+    block's lowered text. Over a mesh the block is lowered on the
+    arguments train_many hands it (their shapes mirrored with their
+    shardings would commit the metadata to one device of four)."""
+    if X is None:
+        X, y = _other_table()
+    p = dict({"objective": "binary", "num_leaves": 15, "max_bin": 63,
+              "verbosity": -1, "tpu_hist_impl": "pallas_interpret"}, **params)
+    bst = lgb.train(p, lgb.Dataset(X, y, params=dict(p)),
+                    num_boost_round=rounds)
+    g = bst._impl
+    if g.mesh is None:
+        args = g.train_block_sds(1)
+    else:
+        fmasks, gactive, idxs, _, keys = g._block_inputs(1)
+        args = (*g._iter_capture, g.scores, fmasks, gactive, idxs, keys,
+                g._bag_mask, g._cegb_state, g._stopped_dev,
+                jnp.float32(g.shrinkage_rate), g._rowspace_bins())
+    return g, jax.jit(g._build_run_block()).lower(*args).as_text()
+
+
+def categorical_block_text():
+    rng = np.random.default_rng(35)
+    X, y, cat, extra = both_branches(rng, 3000)
+    g, text = _lowered_block(dict(PARAMS, categorical_feature=cat,
+                                  tpu_hist_impl="pallas_interpret"), 1, X, y)
+    assert g.grow_params.with_categorical == 2
+    return text
+
+
+def goss_sampled_block_text():
+    g, text = _lowered_block({"boosting": "goss", "learning_rate": 0.5,
+                              "top_rate": 0.2, "other_rate": 0.1}, 3)
+    assert g._goss_bag and g._sampled_regime
+    return text
+
+
+def dart_block_and_replay_text():
+    from lightgbm_tpu.core import replay as replay_mod
+    g, text = _lowered_block({"boosting": "dart", "drop_rate": 0.5,
+                              "drop_seed": 7}, 2)
+    stacked = g._replay_layout_of().stack(g.models[:1], [0], g._drop_size)
+    bins, statics = g._replay_input()
+    assert statics["kernel"]
+    sds = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        (g.scores, stacked, np.int32(1), np.float32(-1.0), bins))
+    replay = replay_mod.replay_program(g.REPLAY_SCOPE).lower(
+        *sds, **statics).as_text()
+    return text + replay
+
+
+def data4_block_text():
+    g, text = _lowered_block({"tree_learner": "data", "mesh_shape": [4]}, 1)
+    assert g.mesh is not None and g.mesh.devices.size == 4
+    return text
+
+
+OTHER_BLOCKS = {"categorical": categorical_block_text,
+                "goss_sampled": goss_sampled_block_text,
+                "dart": dart_block_and_replay_text,
+                "data4": data4_block_text}
+
+
+def _golden_of(kind):
+    return os.path.join(ROOT, "tests", "golden",
+                        "train_block_%s_cpu.sha256" % kind)
+
+
+@pytest.mark.parametrize("kind", sorted(OTHER_BLOCKS))
+def test_the_other_cells_blocks_lower_to_the_recorded_programs(kind):
+    """The block each other kind of cell runs, at a small size on the CPU
+    under the interpreted Pallas kernels (the chip's tile loop): a
+    categorical table; a GOSS job's sampled iterations, the bag's
+    partition; a DART job's block and its drop set's replay; the data
+    learner over a mesh of four. Their lowered text's sha256, recorded by
+    ``python tests/test_categorical_chip_path.py --other``: a change that
+    moves none of these programs leaves every hash as it is."""
+    with open(_golden_of(kind)) as f:
+        want = f.read().split()[0]
+    got = hashlib.sha256(OTHER_BLOCKS[kind]().encode()).hexdigest()
+    assert got == want
+
+
 if __name__ == "__main__":
-    print(hashlib.sha256(numerical_block_text().encode()).hexdigest())
+    if sys.argv[1:] == ["--other"]:
+        for kind in sorted(OTHER_BLOCKS):
+            print(kind, hashlib.sha256(
+                OTHER_BLOCKS[kind]().encode()).hexdigest())
+    else:
+        print(hashlib.sha256(numerical_block_text().encode()).hexdigest())

@@ -134,7 +134,7 @@ def test_forced_splits_match_on_data_parallel_mesh(tmp_path, xy):
     serial = lgb.train(dict(kw), lgb.Dataset(X, label=y), num_boost_round=4)
     dp = lgb.train(dict(kw, tree_learner="data", mesh_shape=[8]),
                    lgb.Dataset(X, label=y), num_boost_round=4)
-    assert dp._impl._partition_on_mesh       # not the masked fallback
+    assert dp._impl.plan.partition_on_mesh   # not the masked fallback
     ps = serial.predict(X[:300], raw_score=True)
     pd = dp.predict(X[:300], raw_score=True)
     np.testing.assert_allclose(ps, pd, rtol=1e-5, atol=1e-5)
@@ -156,7 +156,7 @@ def test_cegb_lazy_matches_on_data_parallel_mesh(xy):
     serial = lgb.train(dict(kw), lgb.Dataset(X, label=y), num_boost_round=4)
     dp = lgb.train(dict(kw, tree_learner="data", mesh_shape=[8]),
                    lgb.Dataset(X, label=y), num_boost_round=4)
-    assert dp._impl._partition_on_mesh
+    assert dp._impl.plan.partition_on_mesh
     ps = serial.predict(X[:300], raw_score=True)
     pd = dp.predict(X[:300], raw_score=True)
     np.testing.assert_allclose(ps, pd, rtol=1e-5, atol=1e-5)

@@ -67,7 +67,7 @@ def test_the_mesh_grows_the_serial_trees_with_equal_counts(seed):
     X, y = table(seed)
     serial = train(X, y, {})._impl.models
     mesh = train(X, y, MESH)
-    assert mesh._impl._partition_on_mesh
+    assert mesh._impl.plan.partition_on_mesh
     assert mesh._impl.grow_params.all_rows_in_bag
     for a, b in zip(serial, mesh._impl.models):
         m = a.num_leaves_actual

@@ -376,27 +376,23 @@ def test_rowspace_routing_is_counted_where_the_bag_is_and_nowhere_else():
         params = dict(BASE, boosting=boosting, **extra)
         bst = lgb.Booster(params=params, train_set=lgb.Dataset(
             X, y.astype(float), params=dict(params)))
-        counts = [s["counts"] for s in trace.recorded_spans()
-                  if s["name"] == "train.setup"][-1]
         gbdt = bst._impl
+        plan = gbdt.plan
         if boosting == "goss" and not extra:
-            assert gbdt._goss_bag and counts["goss_bag_partition"] == 1
-            assert counts["goss_rowspace_routing"] == 1
+            assert gbdt._goss_bag and plan.goss_bag and plan.copy
             # one byte a stored bin, rows padded to whole lanes
-            assert counts["rowspace_bins_bytes"] == 6 * 2 * 1024
+            assert gbdt._bins_by_col.nbytes == 6 * 2 * 1024
             assert gbdt._rowspace_bins() is None     # unsampled so far
-            assert counts["leaf_ids_row_space"] == 0
+            assert not plan.row_space_ids
         else:
-            assert not gbdt._goss_bag
-            assert "goss_rowspace_routing" not in counts
+            assert not gbdt._goss_bag and not plan.goss_bag
         if boosting == "gbdt":
             # over all rows the ids live in row space, by the same copy
-            assert counts["leaf_ids_row_space"] == 1
-            assert counts["rowspace_bins_bytes"] == 6 * 2 * 1024
+            assert plan.row_space_ids and plan.copy
+            assert gbdt._bins_by_col.nbytes == 6 * 2 * 1024
             assert gbdt._rowspace_bins() is gbdt._bins_by_col
         elif extra:
-            assert counts["leaf_ids_row_space"] == 0
-            assert "rowspace_bins_bytes" not in counts
+            assert not plan.row_space_ids and not plan.copy
             assert gbdt._bins_by_col is None
 
 

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from lightgbm_tpu.core.histogram import (build_histogram, fix_histogram,
-                                         subtract_histogram)
+                                         hist_kernel, subtract_histogram)
 
 
 def _ref_hist(xb, g, h, mask, b):
@@ -344,18 +344,17 @@ def test_split_words_holds_both_bfloat16_terms():
 @pytest.mark.parametrize("impl,want", [
     ("scatter", 0), ("matmul", 0), ("auto", 0), ("pallas_interpret", 1)])
 def test_setup_span_counts_hist_one_pass(impl, want):
-    """train.setup's hist_one_pass: 1 where the booster's histograms go
-    through the Pallas kernels, 0 on the XLA paths (``auto`` is the
-    scatter on the CPU)."""
+    """The plan's hist_pallas: True where the booster's histograms go
+    through the Pallas kernels, whose tile / root kernel contracts a
+    feature in one MXU pass, False on the XLA paths (``auto`` is the
+    scatter on the CPU); GrowParams reads the same spelling so."""
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.obs import trace
     X = np.random.RandomState(0).randn(300, 4)
     y = (X[:, 0] > 0).astype(float)
-    lgb.Booster({"objective": "binary", "num_leaves": 4, "verbose": -1,
-                 "tpu_hist_impl": impl}, lgb.Dataset(X, y))
-    setup = [s for s in trace.recorded_spans()
-             if s["name"] == "train.setup"][-1]
-    assert setup["counts"]["hist_one_pass"] == want
+    g = lgb.Booster({"objective": "binary", "num_leaves": 4, "verbose": -1,
+                     "tpu_hist_impl": impl}, lgb.Dataset(X, y))._impl
+    assert hist_kernel(g.plan.hist_impl) == (bool(want), bool(want))
+    assert hist_kernel(g.grow_params.hist_impl).pallas == bool(want)
 
 
 @pytest.mark.parametrize("fake_backend", [
@@ -367,18 +366,28 @@ def test_tpu_shaped_gate_is_allow_list(monkeypatch, fake_backend):
     the backend at all: it follows the histogram impl, so the interpret
     spellings run on the CPU what "pallas" runs on the chip."""
     import jax
+    from lightgbm_tpu.boosting.plan import TrainFacts, resolve_plan
+    from lightgbm_tpu.config import Config
     from lightgbm_tpu.core import partition
     monkeypatch.setattr(jax, "default_backend", lambda: fake_backend)
     assert partition.tpu_shaped_backend() == (fake_backend == "tpu")
+
+    def plan(impl, classes=1):
+        return resolve_plan(Config({"tpu_hist_impl": impl}), TrainFacts(
+            rows=1000, cols=4, backend=fake_backend,
+            num_tree_per_iteration=classes))
     for impl in ("pallas", "pallas_interpret"):
-        assert partition.tpu_tiles(impl)
-        assert partition.window_placement(impl, vmapped=False)
+        assert hist_kernel(impl).pallas
+        assert plan(impl).row_chunk == 4096 and plan(impl).windows
+        assert partition.window_placement(True, vmapped=False)
         # a batched window start would be a scatter again
-        assert not partition.window_placement(impl, vmapped=True)
+        assert not partition.window_placement(True, vmapped=True)
+        assert plan(impl, classes=3).windows == (fake_backend == "tpu")
     for impl in ("matmul", "scatter"):
-        assert not partition.tpu_tiles(impl)
-        assert not partition.window_placement(impl, vmapped=False)
-        assert not partition.window_placement(impl, vmapped=True)
+        assert not hist_kernel(impl).pallas
+        assert plan(impl).row_chunk == 16384 and not plan(impl).windows
+        assert not partition.window_placement(False, vmapped=False)
+        assert not partition.window_placement(False, vmapped=True)
 
 
 def test_slot_kernel_matches_per_slot_scatter():

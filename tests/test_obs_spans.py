@@ -172,8 +172,9 @@ def test_find_bins_span_counts_the_values_its_zero_test_read(sparse,
     assert counts["nonzero_scan_s"] >= 0 and counts["find_bin_s"] > 0
 
 
-# want: (partition_window_placement, leaf_ids_gather_free,
-#        hist_smaller_child, leaf_ids_row_space)
+# want: the plan's (windows, exact_partition, row_space_ids) and whether
+# the exact grower maps rows to leaves once a tree, with no gather over
+# all rows (CEGB's lazy cost keeps its ids split by split)
 @pytest.mark.parametrize("extra,want", [
     ({}, (0, 1, 1, 1)),                          # the CPU's element scatter
     ({"tpu_hist_impl": "pallas_interpret"}, (1, 1, 1, 1)),  # the chip's loop
@@ -191,13 +192,13 @@ def test_setup_span_says_how_the_block_places_and_maps_rows(extra, want):
     y = (X[:, 0] > 0).astype(float) + (X[:, 1] > 0)
     if "num_class" not in extra:
         y = (y > 0).astype(float)
-    lgb.Booster(dict({"objective": "binary", "num_leaves": 4, "verbose": -1},
-                     **extra), lgb.Dataset(X, y))
+    g = lgb.Booster(dict({"objective": "binary", "num_leaves": 4,
+                          "verbose": -1}, **extra), lgb.Dataset(X, y))._impl
+    plan = g.plan
+    assert (plan.windows,
+            plan.exact_partition and not g.grow_params.with_cegb_lazy,
+            plan.exact_partition, plan.row_space_ids) == tuple(map(bool, want))
     (setup,) = spans_named("train.setup", since=mark)
-    assert (setup["counts"]["partition_window_placement"],
-            setup["counts"]["leaf_ids_gather_free"],
-            setup["counts"]["hist_smaller_child"],
-            setup["counts"]["leaf_ids_row_space"]) == want
     assert setup["counts"]["rows"] == 300
 
 

@@ -84,7 +84,7 @@ def test_data_parallel_uses_sharded_partition():
     X, y = make_binary(n=2000)
     dp = _train({"objective": "binary", "metric": "auc",
                  "tree_learner": "data", "verbosity": -1}, X, y)
-    assert dp._partition_on_mesh
+    assert dp.plan.partition_on_mesh
     assert dp.grow_params.partition_on_mesh
     serial = _train({"objective": "binary", "metric": "auc",
                      "verbosity": -1}, X, y)
@@ -98,7 +98,7 @@ def test_data_parallel_uses_sharded_partition():
     dp3 = _train({"objective": "binary", "tree_learner": "data",
                   "cegb_tradeoff": 0.0, "cegb_penalty_split": 5.0,
                   "verbosity": -1}, X, y, rounds=2)
-    assert dp3._partition_on_mesh
+    assert dp3.plan.partition_on_mesh
     import json, tempfile, os
     fs = {"feature": 0, "threshold": float(np.median(X[:, 0]))}
     with tempfile.NamedTemporaryFile("w", suffix=".json", delete=False) as f:
@@ -108,7 +108,7 @@ def test_data_parallel_uses_sharded_partition():
         dp2 = _train({"objective": "binary", "tree_learner": "data",
                       "forcedsplits_filename": path, "verbosity": -1},
                      X, y, rounds=2)
-        assert dp2._partition_on_mesh
+        assert dp2.plan.partition_on_mesh
     finally:
         os.unlink(path)
 
@@ -253,7 +253,7 @@ def test_explicit_feature_parallel_engaged_and_matches():
                     rounds=4)
     fp = _train({"objective": "binary", "tree_learner": "feature",
                  "verbosity": -1}, X, y, rounds=4)
-    assert fp._explicit_fp and fp._fp_capture is not None
+    assert fp.plan.learner == "feature" and fp._fp_capture is not None
     ps = serial.predict(X[:300], raw_score=True)
     pf = fp.predict(X[:300], raw_score=True)
     np.testing.assert_allclose(ps, pf, rtol=2e-4, atol=2e-4)
@@ -267,7 +267,7 @@ def test_explicit_feature_parallel_engaged_and_matches():
         fp2 = _train({"objective": "binary", "tree_learner": "feature",
                       "forcedsplits_filename": path, "verbosity": -1},
                      X, y, rounds=2)
-        assert not fp2._explicit_fp
+        assert fp2.plan.learner != "feature"
     finally:
         os.unlink(path)
 

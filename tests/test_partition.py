@@ -12,6 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from lightgbm_tpu.core.histogram import hist_kernel
 from lightgbm_tpu.core.grow import GrowParams, grow_tree
 from lightgbm_tpu.core.split import FeatureMeta, SplitParams
 
@@ -394,14 +395,15 @@ def test_tpu_tile_loop_places_ids_without_a_scatter_into_order():
                 for e in _eqns_under(jaxpr.jaxpr, "lgbm.partition_scatter")
                 if e.invars]
 
-    placed = names(window_placement(impl, vmapped=False))
+    pallas = hist_kernel(impl).pallas
+    placed = names(window_placement(pallas, vmapped=False))
     ops = [name for name, _ in placed]
     assert ops.count("sort") == 1
     assert ops.count("dynamic_update_slice") == 2
     assert not [o for o in ops if o.startswith("scatter")]
     # the walk does see the other placement's scatter into order
     assert ("scatter", part.order.shape) in names(
-        window_placement(impl, vmapped=True))
+        window_placement(pallas, vmapped=True))
 
 
 @pytest.mark.parametrize("impl", ["scatter", "pallas_interpret"])
@@ -431,7 +433,8 @@ def test_smaller_child_plus_sibling_is_the_parent(impl):
         part, _ = partition_rows(
             part, jnp.zeros((n,), jnp.int32), jnp.int32(0), jnp.int32(1),
             lambda rows: rows[:, 2] < 20, jnp.asarray(True), chunk, gr,
-            windows=window_placement(impl, vmapped=False))
+            windows=window_placement(hist_kernel(impl).pallas,
+                                     vmapped=False))
         return parent, hist(part, jnp.int32(0)), hist(part, jnp.int32(1))
 
     parent, left, right = map(np.asarray,

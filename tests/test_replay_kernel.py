@@ -7,7 +7,7 @@ total, over a table with NaN- and zero-missing columns for sets of 1, 7
 and 50 trees of 2, 31 and 255 leaves and a tree of no node, at a row count
 that is not a multiple of the tile; and where a booster takes it: only
 over the feature-major copy of a table whose stored columns each hold one
-numerical feature, which ``replay_kernel`` on ``train.setup`` and
+numerical feature, which the plan's ``replay_kernel`` and
 ``dart_replay_kernel_trees`` on ``train.block`` report.
 """
 import numpy as np
@@ -165,7 +165,7 @@ PATHS = {
     "plain": ({}, lambda x: x, True),
     "cpu-scatter": ({"tpu_hist_impl": "scatter"}, lambda x: x, False),
     # boosting=gbdt holds the copy for its leaf ids in row space
-    # (GBDT._leaf_ids_row_space), and a replay over the table reads it
+    # (TrainPlan.row_space_ids), and a replay over the table reads it
     "gbdt": ({"boosting": "gbdt"}, lambda x: x, True),
     # no copy where CEGB's lazy cost keeps the ids split by split
     "gbdt-no-copy": ({"boosting": "gbdt",
@@ -191,9 +191,7 @@ def test_where_the_kernel_engages(case):
     bst = lgb.Booster(params=dict(params),
                       train_set=lgb.Dataset(make(x), y, params=dict(params)))
     gbdt = bst._impl
-    setup = [s for s in trace.recorded_spans()
-             if s["name"] == "train.setup"][-1]["counts"]
-    assert setup["replay_kernel"] == int(engages)
+    assert gbdt.plan.replay_kernel == engages
     layout = gbdt._replay_layout_of()
     assert layout.single == (case not in ("pairs", "categorical", "efb"))
     if case == "pairs":

@@ -62,17 +62,17 @@ def _bundled(rng):
     return X, X[:, 0] - X[:, 2] + X[:, 5] + 0.3 * rng.normal(size=N) > 0.3
 
 
-def _no_limit(x):
+def _no_limit(rows, cols):
     return None
 
 
-def _room_to_spare(x):
-    return 2 * partition.row_space_bytes(x)
+def _room_to_spare(rows, cols):
+    return 2 * partition.row_space_bytes(rows, cols)
 
 
-def _no_room_to_spare(x):
+def _no_room_to_spare(rows, cols):
     # what the copy needs, and not the tenth of the device left beside it
-    return partition.row_space_bytes(x)
+    return partition.row_space_bytes(rows, cols)
 
 
 # case: (data, parameters, the limit the row-space side reads)
@@ -98,10 +98,13 @@ CASES = {
 
 
 def _train(monkeypatch, X, y, params, limit):
-    monkeypatch.setattr(partition, "device_bytes_limit", limit)
-    return lgb.train(params, lgb.Dataset(X, y.astype(np.float64),
-                                         params=dict(params)),
-                     num_boost_round=ROUNDS)
+    """Trained with the device's limit read as ``limit`` of the table's
+    rows and stored columns."""
+    ds = lgb.Dataset(X, y.astype(np.float64), params=dict(params)).construct()
+    table = ds._binned
+    monkeypatch.setattr(partition, "device_bytes_limit", lambda device: limit(
+        table.num_data, table.num_columns))
+    return lgb.train(params, ds, num_boost_round=ROUNDS)
 
 
 def _grow(gbdt, grad, hess, bins_by_col):
@@ -121,10 +124,10 @@ def test_row_space_leaf_ids_are_the_row_partitions(case, monkeypatch):
     rowspace = _train(monkeypatch, X, y, params, limit)
     fallback = _train(monkeypatch, X, y, params, _no_room_to_spare)
     rs, fb = rowspace._impl, fallback._impl
-    assert rs._leaf_ids_row_space and rs._rowspace_bins() is not None
+    assert rs.plan.row_space_ids and rs._rowspace_bins() is not None
     # a byte a stored column and row, rows in whole lanes
     assert rs._bins_by_col.nbytes == rs.xb.shape[1] * 2 * 1024
-    assert not fb._leaf_ids_row_space and fb._bins_by_col is None
+    assert not fb.plan.row_space_ids and fb._bins_by_col is None
     assert fb._rowspace_bins() is None
     assert rowspace.model_to_string() == fallback.model_to_string()
     np.testing.assert_array_equal(np.asarray(rs.scores),

@@ -1,8 +1,7 @@
 """The score update's leaf-value lookup (``core/tree.py`` ``leaf_value_of``):
 selects up to ``SELECT_LOOKUP_MAX`` leaves, a gather above, the table's entry
-bit for bit either way; the score update's jaxpr, the ``train.setup`` count
-that says which form a booster took, and a ``train_many`` block's scores
-against a numpy restatement.
+bit for bit either way; the score update's jaxpr on either side of the
+bound, and a ``train_many`` block's scores against a numpy restatement.
 """
 import numpy as np
 import pytest
@@ -14,7 +13,6 @@ import lightgbm_tpu as lgb
 from lightgbm_tpu.analysis.jaxpr_audit import iter_eqns
 from lightgbm_tpu.core.tree import (SELECT_LOOKUP_MAX, leaf_value_of,
                                     predict_tree_leaves_raw)
-from lightgbm_tpu.obs import trace
 
 BOUND = SELECT_LOOKUP_MAX
 
@@ -94,13 +92,11 @@ def _score_update_gathers(g):
 @pytest.mark.parametrize("num_leaves", [4, 255, BOUND + 1])
 def test_the_score_update_gathers_only_above_the_bound(num_leaves):
     """The block's score update holds no gather at L <= the bound and one
-    above it; train.setup's score_update_gather_free says which."""
+    above it (tree.leaf_value_of looks a leaf's value up by selects up to
+    SELECT_LOOKUP_MAX leaves)."""
     g = lgb.train(*_train_args(num_leaves), num_boost_round=1)._impl
-    setup = [s for s in trace.recorded_spans()
-             if s["name"] == "train.setup"][-1]
-    free = setup["counts"]["score_update_gather_free"]
-    assert free == int(num_leaves <= BOUND)
-    assert _score_update_gathers(g) == 1 - free
+    assert g.grow_params.num_leaves == num_leaves
+    assert _score_update_gathers(g) == int(num_leaves > BOUND)
 
 
 # a train_many block's scores = scores + leaf_value[leaf] x rate, on
